@@ -131,7 +131,7 @@ def main(argv=None):
     main_prog, startup, (ids, label), avg_cost = build_model()
     ds.set_use_var([ids, label])
 
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     exe.run(startup)
 
     cursor = DatasetCursor()

@@ -19,7 +19,7 @@ def main():
     img, label, pred, loss, acc = models.mnist.build(arch="mlp")
     fluid.optimizer.Adam(learning_rate=1e-3).minimize(loss)
 
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     exe.run(fluid.default_startup_program())
 
     train_reader = fluid.batch(dataset.mnist.train(), batch_size=128)

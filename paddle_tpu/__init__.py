@@ -85,12 +85,13 @@ from .reader import batch  # noqa: F401  (top-level paddle.batch parity)
 
 
 def cuda_places(device_ids=None):
-    """Alias: accelerator places (parity: framework.py cuda_places)."""
-    import jax
+    """Alias: accelerator places (parity: framework.py cuda_places) —
+    one TPUPlace per local chip. Raises on a process with no TPU."""
+    from .core.place import local_chips
 
-    n = len(jax.devices())
-    ids = device_ids if device_ids is not None else range(n)
-    return [TPUPlace(i) for i in ids]
+    if device_ids is None:
+        device_ids = range(len(local_chips()))
+    return [TPUPlace(i) for i in device_ids]
 
 
 tpu_places = cuda_places

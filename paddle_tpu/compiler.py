@@ -240,6 +240,9 @@ class CompiledProgram:
         # inference-optimized clones for the NON-data-parallel run path,
         # keyed by (program version, fetch names)
         self._infer_programs = {}
+        from .async_engine import setup_persistent_cache
+
+        setup_persistent_cache()
 
     def with_data_parallel(self, loss_name=None, build_strategy=None,
                            exec_strategy=None, share_vars_from=None,
@@ -363,9 +366,6 @@ class CompiledProgram:
             if step is None:
                 if rec:
                     _metrics.counter("compile_cache/miss").inc()
-                from .async_engine import (note_compiled_program,
-                                           persistent_cache_dir)
-
                 run_program = self._program
                 if pp == 1 and ir_passes.pipeline_enabled():
                     with _tracing.span("optimize"):
@@ -381,11 +381,6 @@ class CompiledProgram:
                     from .analysis import maybe_verify
 
                     maybe_verify(self._program, tuple(fetch_names))
-                if persistent_cache_dir():
-                    note_compiled_program(
-                        run_program.fingerprint(), key[1],
-                        tuple(fetch_names), key[3],
-                        tuple(self._get_mesh().shape.items()))
                 with _tracing.span("lower"):
                     if pp > 1:
                         from .parallel.pipeline_program import \

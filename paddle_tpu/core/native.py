@@ -133,12 +133,15 @@ def lib():
             return _lib
         _tried = True
         path = os.path.abspath(os.path.join(_NATIVE_DIR, _LIB_NAME))
-        if not os.path.exists(path):
-            try:
-                subprocess.run(["make", "-s"], cwd=os.path.dirname(path),
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                return None
+        # always ask make: a no-op when the library is newer than its
+        # sources, a rebuild when a stale (git-ignored) .so was carried
+        # along with newer committed sources
+        try:
+            subprocess.run(["make", "-s", _LIB_NAME],
+                           cwd=os.path.dirname(path), check=True,
+                           capture_output=True, timeout=120)
+        except (OSError, subprocess.SubprocessError):
+            return None
         try:
             _lib = _configure(ctypes.CDLL(path))
         except OSError:

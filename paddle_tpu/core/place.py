@@ -7,8 +7,6 @@ scripts run unchanged. `CUDAPinnedPlace` maps to host-committed memory used
 for async feeds.
 """
 
-import functools
-
 
 class Place:
     _kind = "base"
@@ -34,14 +32,27 @@ class CPUPlace(Place):
         import jax
 
         # local (addressable) devices: under a multi-process DCN runtime
-        # jax.devices() is global and rank>0 must not target rank 0's device
-        cpus = (jax.local_devices(backend="cpu") if _has_platform("cpu")
-                else jax.local_devices())
-        return cpus[0]
+        # jax.devices() is global and rank>0 must not target rank 0's
+        # device. Raises when jax was started without the cpu platform
+        # (JAX_PLATFORMS=tpu): a CPU place never means "whatever is there"
+        return jax.local_devices(backend="cpu")[0]
+
+
+def local_chips():
+    """This process's TPU devices; raises on a process that has none."""
+    import jax
+
+    try:
+        return jax.local_devices(backend="tpu")
+    except RuntimeError as e:
+        raise RuntimeError(
+            "this process has no TPU (jax default platform %r); use "
+            "CPUPlace(), or Executor() for the default device"
+            % jax.default_backend()) from e
 
 
 class TPUPlace(Place):
-    """The accelerator place. device_id indexes jax.devices()."""
+    """The accelerator place. device_id indexes this process's TPU chips."""
 
     _kind = "tpu"
 
@@ -49,10 +60,15 @@ class TPUPlace(Place):
         self.device_id = device_id
 
     def jax_device(self):
-        import jax
-
-        devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        """The chip itself. Raises on a process with no TPU and on an
+        index past the local chips: a place that named a chip and ran
+        somewhere else would hide the device from whoever reads the
+        result."""
+        chips = local_chips()
+        if not 0 <= self.device_id < len(chips):
+            raise RuntimeError("%r: this process holds %d TPU chip(s)"
+                               % (self, len(chips)))
+        return chips[self.device_id]
 
 
 class CUDAPlace(TPUPlace):
@@ -63,16 +79,6 @@ class CUDAPlace(TPUPlace):
 
 class CUDAPinnedPlace(CPUPlace):
     _kind = "pinned"
-
-
-@functools.lru_cache(maxsize=None)
-def _has_platform(name):
-    import jax
-
-    try:
-        return len(jax.devices(name)) > 0
-    except RuntimeError:
-        return False
 
 
 def default_place():

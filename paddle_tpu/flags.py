@@ -177,8 +177,6 @@ _declare("PTPU_BLACKBOX_EVENTS", "int", None,
 # -- executor / async engine (docs/ASYNC_EXECUTION.md) ----------------------
 _declare("PTPU_ASYNC_STEPS", "int", 12,
          "async in-flight window depth before dispatch backpressures")
-_declare("PTPU_CACHE_DIR", "path", None,
-         "persistent on-disk XLA compile cache directory")
 # -- compiler pipeline (docs/COMPILER_PASSES.md, docs/STATIC_ANALYSIS.md) ---
 _declare("PTPU_NO_PROGRAM_OPT", "bool", False,
          "disable the compile-time pass pipeline (exact unoptimized path)")
@@ -336,10 +334,6 @@ _declare("PTPU_EMBED_PUSH_QUEUE", "int", 64,
          "blocks the enqueueing (training) thread until the drain "
          "thread catches up (backpressure, embed/push_queue_depth "
          "gauge)")
-# -- tests / CI -------------------------------------------------------------
-_declare("PTPU_PARITY_TIMEOUT", "float", 45.0,
-         "seconds the TPU-backend parity test waits on its subprocess "
-         "before skipping")
 
 
 def env(name):

@@ -431,8 +431,6 @@ class Program:
         self.current_block_idx = 0
         # fingerprint for the executor's compile cache; bumped on any mutation
         self._version = 0
-        # (version, sha256-of-desc) pair backing fingerprint()
-        self._content_fp = None
         self._seed = 0
         self.random_seed = 0
         # populated by append_backward: param name -> grad var name
@@ -473,26 +471,6 @@ class Program:
     @property
     def version(self):
         return self._version
-
-    def fingerprint(self):
-        """Content hash of the program desc, stable ACROSS processes (the
-        cross-restart analogue of `version`, which only orders mutations
-        within one process). Keys the persistent compile-cache manifest
-        (async_engine.note_compiled_program); cached per mutation
-        version so the serialization runs once per program shape."""
-        if self._content_fp is None or self._content_fp[0] != self._version:
-            import hashlib
-
-            try:
-                desc = self.to_json()
-            except Exception:
-                # exotic non-serializable attrs: fall back to a process-
-                # local identity (persistent hits just won't dedup these)
-                desc = "unserializable:%d:%d" % (id(self), self._version)
-            self._content_fp = (
-                self._version,
-                hashlib.sha256(desc.encode("utf-8")).hexdigest())
-        return self._content_fp[1]
 
     # -- queries -----------------------------------------------------------
     def all_parameters(self):

@@ -16,7 +16,7 @@ executable).
 import numpy as np
 
 from . import framework, io
-from .core.place import CPUPlace, TPUPlace
+from .core.place import CPUPlace, TPUPlace, default_place
 from .core.scope import Scope
 from .executor import Executor
 
@@ -48,7 +48,9 @@ class AnalysisConfig:
         self.model_dir = model_dir
         self.prog_file = None
         self.params_file = params_file
-        self._use_accelerator = True
+        # None = the default device (the chip when there is one);
+        # enable_use_tpu() insists on the chip, disable_gpu() on the host
+        self._use_accelerator = None
         self._ir_optim = True
         self._aot_shapes = None
         self._quant_mode = None
@@ -117,11 +119,11 @@ class AnalysisPredictor:
     def __init__(self, config: AnalysisConfig):
         self._config = config
         self._scope = Scope()
-        place = TPUPlace(0) if config._use_accelerator else CPUPlace()
-        try:
-            self._exe = Executor(place)
-        except Exception:
-            self._exe = Executor(CPUPlace())
+        if config._use_accelerator is None:
+            place = default_place()
+        else:
+            place = TPUPlace(0) if config._use_accelerator else CPUPlace()
+        self._exe = Executor(place)
         from .core.scope import scope_guard
 
         with scope_guard(self._scope):

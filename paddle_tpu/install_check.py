@@ -13,8 +13,8 @@ def run_check():
     a success message like the reference."""
     import jax
 
-    from . import (CPUPlace, Executor, ParallelExecutor, Program, TPUPlace,
-                   layers, optimizer, program_guard)
+    from . import (Executor, ParallelExecutor, Program, layers, optimizer,
+                   program_guard)
     from .framework import switch_main_program, switch_startup_program
 
     main, startup = Program(), Program()
@@ -25,8 +25,7 @@ def run_check():
         loss = layers.mean(layers.square_error_cost(pred, y))
         optimizer.SGD(learning_rate=0.01).minimize(loss)
 
-    place = TPUPlace(0) if jax.default_backend() != "cpu" else CPUPlace()
-    exe = Executor(place)
+    exe = Executor()
     exe.run(startup)
     rng = np.random.RandomState(0)
     feed = {"inst_chk_x": rng.rand(8, 4).astype(np.float32),

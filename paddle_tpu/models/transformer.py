@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import device as _device
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -107,7 +109,7 @@ def causal_attention(q, k, v, seq_offset=0, use_flash=None):
     B, Tq, H, Dh = q.shape
     Tk = k.shape[1]
     if use_flash is None:
-        use_flash = (jax.default_backend() == "tpu" and seq_offset == 0
+        use_flash = (_device.on_tpu() and seq_offset == 0
                      and Tq == Tk and Tq >= 256 and Dh >= 64)
     if use_flash:
         from ..ops.pallas_kernels import flash_attention

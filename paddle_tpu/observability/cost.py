@@ -2,66 +2,63 @@
 
 On every compile-cache miss the executor's AOT path and the serving
 model's step builders hand their freshly compiled executable here; XLA's
-per-executable ``cost_analysis()``/``memory_analysis()`` (read through
-the version-guarded ``core.jax_compat`` shims — absent APIs are a data
-gap, not an error) become gauges:
+per-executable ``cost_analysis()``/``memory_analysis()`` become gauges:
 
   exec/step_flops           FLOPs of one compiled step
   exec/step_bytes_accessed  bytes read+written per step (memory traffic)
   exec/peak_hbm_bytes       argument+output+temp buffer footprint
 
-``mfu_pct`` is the Chinchilla/PaLM-era utilization headline:
-``step_flops * steps_per_sec / peak_flops``. The peak table is a
-NOMINAL per-platform figure (one chip, dense bf16 for accelerators; a
-token host figure for CPU so CI math stays finite and comparable run to
-run) — MFU here is for tracking regressions against yourself, not for
-cross-vendor marketing comparisons. bench.py publishes the
-``bench/mfu_pct`` gauge and per-leg receipts from these numbers.
+``mfu_pct`` is ``step_flops * steps_per_sec / peak_flops``. The peak
+comes from ONE table keyed by ``device_kind`` as JAX reports it, each
+entry with its source. A device that is not in the table has no peak:
+``peak_flops`` raises and ``mfu_pct`` publishes nothing. There is no
+CPU row and no default, so a run that found no chip cannot print a
+utilization. bench.py publishes the ``bench/mfu_pct`` gauge and per-leg
+receipts from these numbers.
 """
 
-from ..core import jax_compat as _jax_compat
+from ..core import device as _device
 
 __all__ = ["publish", "analyze", "peak_flops", "mfu_pct",
-           "PLATFORM_PEAK_FLOPS"]
+           "DEVICE_PEAK_FLOPS"]
 
-# nominal peak FLOPs per chip (dense bf16 class figures; CPU is a token
-# reference point, not a measured host capability)
-PLATFORM_PEAK_FLOPS = {
-    "tpu": 275e12,
-    "gpu": 312e12,
-    "cpu": 1e11,
+# dense bf16 peak FLOP/s of ONE chip, keyed by jax's device_kind
+DEVICE_PEAK_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    # (393 TOP/s int8, 16 GB HBM at 819 GB/s)
+    "TPU v5 lite": 197e12,
 }
 
 
-def peak_flops(platform=None):
-    """The table entry for `platform` (default: the first jax device's
-    platform; unknown platforms fall back to the CPU figure)."""
-    if platform is None:
-        try:
-            import jax
-
-            platform = jax.devices()[0].platform
-        except Exception:
-            platform = "cpu"
-    return PLATFORM_PEAK_FLOPS.get(platform, PLATFORM_PEAK_FLOPS["cpu"])
+def peak_flops(device_kind=None):
+    """The table entry for `device_kind` (default: the current device,
+    core.device.identity()). A device not in the table raises."""
+    if device_kind is None:
+        device_kind = _device.identity().kind
+    try:
+        return DEVICE_PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(
+            "no peak FLOP/s on record for device_kind %r (known: %s); "
+            "add it to observability/cost.py with its source"
+            % (device_kind, sorted(DEVICE_PEAK_FLOPS))) from None
 
 
 def analyze(compiled):
     """{step_flops, step_bytes_accessed, peak_hbm_bytes} for one
     compiled executable — only the keys the backend actually reports."""
     out = {}
-    ca = _jax_compat.compiled_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     if ca:
         if "flops" in ca:
-            out["step_flops"] = ca["flops"]
+            out["step_flops"] = float(ca["flops"])
         if "bytes accessed" in ca:
-            out["step_bytes_accessed"] = ca["bytes accessed"]
-    ma = _jax_compat.compiled_memory_analysis(compiled)
-    if ma:
-        out["peak_hbm_bytes"] = (
-            ma.get("argument_size_in_bytes", 0.0)
-            + ma.get("output_size_in_bytes", 0.0)
-            + ma.get("temp_size_in_bytes", 0.0))
+            out["step_bytes_accessed"] = float(ca["bytes accessed"])
+    ma = compiled.memory_analysis()
+    if ma is not None:
+        out["peak_hbm_bytes"] = float(
+            ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
     return out
 
 
@@ -86,9 +83,14 @@ def publish(compiled):
     return vals
 
 
-def mfu_pct(step_flops, steps_per_sec, platform=None):
-    """Model-FLOPs utilization percent against the platform peak."""
-    peak = peak_flops(platform)
-    if not step_flops or not steps_per_sec or peak <= 0:
-        return 0.0
+def mfu_pct(step_flops, steps_per_sec, device_kind=None):
+    """Model-FLOPs utilization percent against the device's peak, or
+    None when there is nothing to divide or the device has no peak on
+    record (an unknown device publishes no MFU)."""
+    if not step_flops or not steps_per_sec:
+        return None
+    try:
+        peak = peak_flops(device_kind)
+    except KeyError:
+        return None
     return 100.0 * float(step_flops) * float(steps_per_sec) / peak

@@ -462,6 +462,34 @@ def _alloc_continuous_space(ctx, ins, attrs):
     return {"Output": outs, "FusedOutput": [flat]}
 
 
+def _flash_on_mesh(q, k, v, causal, scale, mesh):
+    """The flash kernel on q, k, v: [B, H, T, Dh] inside a GSPMD-sharded
+    step. A compiled Pallas (Mosaic) kernel cannot be partitioned by
+    GSPMD ("Mosaic kernels cannot be automatically partitioned"), so on a
+    multi-device step mesh the call runs under a shard_map that makes
+    every mesh axis manual: batch split over dp and heads over tp where
+    they divide (attention is independent per batch row and head), the
+    operand replicated over an axis that does not divide or does not
+    apply. Inside a region that is already (partly) manual — a pipeline
+    stage, the SPMD trainer — the call is made directly, as before."""
+    from .pallas_kernels import flash_attention
+
+    if mesh is None or mesh.size == 1 \
+            or jax.sharding.get_abstract_mesh().manual_axes:
+        return flash_attention(q, k, v, causal, scale)
+    from jax.sharding import PartitionSpec as P
+
+    sizes = dict(mesh.shape)
+    spec = P(*[axis if sizes.get(axis, 1) > 1 and dim % sizes[axis] == 0
+               else None
+               for axis, dim in (("dp", q.shape[0]), ("tp", q.shape[1]))],
+             None, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal, scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
+
+
 @register("flash_attention")
 def _flash_attention_op(ctx, ins, attrs):
     """Fused attention exposed as a graph op. Q/K/V layout is [B, H, T, Dh]
@@ -473,8 +501,6 @@ def _flash_attention_op(ctx, ins, attrs):
     XLA-fused softmax path's ~1 GB materialized score/prob buffers); the
     XLA path covers shapes the blocked kernels can't tile.
     Differentiable through the kernels' own VJPs."""
-    from .pallas_kernels import flash_attention
-
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     if attrs.get("__amp_bf16__") and q.dtype == jnp.float32:
         # AMP white-list marking: bf16 QKV matmuls (softmax stays fp32
@@ -560,7 +586,7 @@ def _flash_attention_op(ctx, ins, attrs):
                       head_dim=Dh, causal=causal):
         if layout == "bthd":
             q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
-        out = flash_attention(q, k, v, causal, scale)
+        out = _flash_on_mesh(q, k, v, causal, scale, mesh)
         if layout == "bthd":
             out = jnp.swapaxes(out, 1, 2)
     else:

@@ -8,8 +8,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.lax import optimization_barrier
 
-from ..core.jax_compat import optimization_barrier
 from .registry import register
 
 

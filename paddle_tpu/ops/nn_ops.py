@@ -12,8 +12,8 @@ sparse-grad path of the reference maps to sorted segment-sum under XLA.
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.lax import optimization_barrier
 
-from ..core.jax_compat import optimization_barrier
 from .registry import register, simple_op, np_dtype
 
 

@@ -27,8 +27,10 @@ int8_matmul: fused int8×int8→int32 matmul for the full-int8 quant path —
   dequantize applies on the final K block, so the separate
   quantize/dequantize_linear HLOs around each rewritten matmul vanish.
 
-Kernels run under interpret=True off-TPU so the CPU test mesh exercises
-the same code path (tests/test_pallas.py).
+Whether a kernel compiles or runs in the Pallas interpreter is decided in
+one place, ``core.device.pallas_interpret()``: compiled on TPU (a kernel
+Mosaic refuses raises), interpreted everywhere else so the CPU test mesh
+exercises the same kernel bodies (tests/test_pallas.py).
 """
 
 import functools
@@ -36,14 +38,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.flash_attention import (
+    BlockSizes as _TpuFlashBlockSizes, flash_attention as _tpu_flash)
 
-try:  # TPU memory spaces; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from ..core import device as _device
 
 
 __all__ = ["flash_attention", "flash_attention_portable",
@@ -128,26 +127,20 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=128,
     # library path only for the self-attention shape it was profiled on;
     # cross-attention (Tk != Tq) runs the portable kernel, whose kv_len
     # masking handles ragged kv blocks
-    if jax.default_backend() == "tpu" and q.shape == k.shape:
+    if _device.on_tpu() and q.shape == k.shape:
         T = q.shape[2]
         blk = next((b for b in (512, 256, 128) if T % b == 0 and b <= T),
                    None)
         if blk is not None:
-            try:
-                from jax.experimental.pallas.ops.tpu.flash_attention import (
-                    BlockSizes, flash_attention as tpu_flash)
-            except ImportError:
-                tpu_flash = None
-            if tpu_flash is not None:
-                bs = BlockSizes(
-                    block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-                    block_q_major_dkv=blk, block_k_major_dkv=blk,
-                    block_k_dkv=blk, block_q_dkv=blk,
-                    block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
-                if sm_scale is None:
-                    sm_scale = q.shape[-1] ** -0.5
-                return tpu_flash(q, k, v, causal=causal, sm_scale=sm_scale,
-                                 block_sizes=bs)
+            bs = _TpuFlashBlockSizes(
+                block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
+                block_q_major_dkv=blk, block_k_major_dkv=blk,
+                block_k_dkv=blk, block_q_dkv=blk,
+                block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk)
+            if sm_scale is None:
+                sm_scale = q.shape[-1] ** -0.5
+            return _tpu_flash(q, k, v, causal=causal, sm_scale=sm_scale,
+                              block_sizes=bs)
     return flash_attention_portable(q, k, v, causal, sm_scale, block_q,
                                     block_k)
 
@@ -164,26 +157,11 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     Tk = k.shape[2]
     if sm_scale is None:
         sm_scale = D ** -0.5
-    interpret = jax.default_backend() != "tpu"
-
     qp = _pad_to(q.reshape(B * H, T, D), 1, block_q)
     kp = _pad_to(k.reshape(B * H, Tk, D), 1, block_k)
     vp = _pad_to(v.reshape(B * H, Tk, D), 1, block_k)
     Tq_p, Tk_p = qp.shape[1], kp.shape[1]
     grid = (B * H, Tq_p // block_q, Tk_p // block_k)
-
-    if pltpu is not None:
-        scratch = [
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ]
-    else:  # pragma: no cover - CPU-only install without the tpu module
-        scratch = [
-            jax.ShapeDtypeStruct((block_q, 128), jnp.float32),
-            jax.ShapeDtypeStruct((block_q, 128), jnp.float32),
-            jax.ShapeDtypeStruct((block_q, D), jnp.float32),
-        ]
 
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=block_q,
@@ -198,8 +176,12 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
-        scratch_shapes=scratch,
-        interpret=interpret,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+        ],
+        interpret=_device.pallas_interpret(),
     )(qp, kp, vp)
     return out[:, :T].reshape(B, H, T, D)
 
@@ -258,18 +240,37 @@ def attention_reference(q, k, v, causal=True, sm_scale=None):
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(tables_ref, lastpos_ref, q_ref, k_ref, v_ref,
-                       pos_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                       sm_scale, block_size):
-    """Grid (B, H, Mb); j (the block-table slot) is innermost, carrying
-    the online-softmax state across one row's pages. The k/v BlockSpec
-    index maps already resolved table slot j to its PHYSICAL page (null
-    pages land here too — harmless, their logical positions are masked
-    or the whole block is skipped)."""
+def _paged_attn_kernel(tables_ref, span_ref, q_ref, k_ref, v_ref, vis_ref,
+                       o_ref, m_scr, l_scr, acc_scr, *, sm_scale,
+                       block_size, n_heads, tree):
+    """Grid (B, Mb); j (the block-table slot) is innermost, carrying
+    the online-softmax state of every head across one row's pages. The
+    k/v BlockSpec index maps already resolved table slot j to its
+    PHYSICAL page (null pages land here too — harmless, their logical
+    positions are masked or the whole block is skipped).
+
+    A block is one whole page with the heads folded into the lane axis,
+    ``[block_size, H*Dh]``: Mosaic tiles the last two axes of a block
+    in (8, 128) units, so a block that took one head out of the
+    second-to-last axis (``(1, bs, 1, Dh)``) is refused. The heads are
+    walked with static lane slices inside the kernel.
+
+    ``span_ref`` (scalar prefetch, ``[2, B]``) holds each row's first
+    and last window CACHE position. ``vis_ref`` decides in-window
+    visibility. Linear window: the ``[C, 1]`` cache position of each
+    window slot, causal. ``tree``: the ``[C, C]`` float ancestor matrix
+    — window slot c sits at cache position pos0+c, and a key at logical
+    position t is visible to slot c iff t < pos0 (committed prefix,
+    strict — slot 0's own write is window-visible via anc[0, 0], never
+    prefix-visible) or t-pos0 is an ancestor of c. The ancestor lookup
+    runs as a one-hot matmul against the ancestor matrix — no in-kernel
+    gathers. pos0 comes from SMEM because Mosaic cannot broadcast a
+    loaded [1, 1] vector along sublanes and lanes at once."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
     C = q_ref.shape[1]
+    Dh = q_ref.shape[2] // n_heads
 
     @pl.when(j == 0)
     def _init():
@@ -280,37 +281,96 @@ def _paged_attn_kernel(tables_ref, lastpos_ref, q_ref, k_ref, v_ref,
     # pages wholly past the row's LAST query position hold nothing any
     # window slot may attend to — skip their compute (their table
     # entries are the null page anyway)
-    @pl.when(j * block_size <= lastpos_ref[b])
+    @pl.when(j * block_size <= span_ref[1, b])
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # [C, Dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # [bs, Dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [C, bs]
-        # logical positions covered by table slot j vs each window
-        # slot's own position (causal within the window)
+        q = q_ref[0].astype(jnp.float32)                # [C, H*Dh]
+        k = k_ref[0].astype(jnp.float32)                # [bs, H*Dh]
+        v = v_ref[0].astype(jnp.float32)
+        # logical positions covered by table slot j
         t_pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (C, block_size), 1)
-        mask = t_pos <= pos_ref[0]                      # pos: [C, 1]
-        s = jnp.where(mask, s, _NEG_INF)
+        if tree:
+            rel = t_pos - span_ref[0, b]                # row-constant
+            # anc[c, rel] via one-hot matmul: onehot[r, t] = (rel_t == r)
+            onehot = (jax.lax.broadcasted_iota(
+                jnp.int32, (C, block_size), 0) == rel).astype(jnp.float32)
+            win_vis = jax.lax.dot_general(
+                vis_ref[:], onehot, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) > 0.0
+            mask = (rel < 0) | win_vis                  # [C, bs]
+        else:
+            mask = t_pos <= vis_ref[0]                  # causal in-window
 
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        for h in range(n_heads):
+            lanes = slice(h * Dh, (h + 1) * Dh)
+            s = jax.lax.dot_general(
+                q[:, lanes], k[:, lanes], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [C, bs]
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_scr[h][:, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[h][:, :1] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v[:, lanes], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
     @pl.when(j == nj - 1)
     def _finish():
-        l = l_scr[:, :1]
-        o_ref[0, :, 0, :] = (acc_scr[:]
-                             / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = jnp.concatenate(
+            [acc_scr[h] / jnp.maximum(l_scr[h][:, :1], 1e-30)
+             for h in range(n_heads)], axis=-1).astype(o_ref.dtype)
+
+
+def _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
+                sm_scale):
+    B, C, H, Dh = q.shape
+    NB, bs = k_pages.shape[:2]
+    Mb = block_tables.shape[1]
+    HD = H * Dh
+    if sm_scale is None:
+        sm_scale = Dh ** -0.5
+    tree = anc is not None
+
+    def row(b, j, tables, span):
+        return (b, 0, 0)
+
+    def page(b, j, tables, span):
+        return (tables[b, j], 0, 0)
+
+    pos = jnp.maximum(positions, 0).astype(jnp.int32)    # [B, C]
+    if tree:
+        vis = jnp.asarray(anc, jnp.float32)
+        vis_spec = pl.BlockSpec((C, C), lambda b, j, tables, span: (0, 0))
+    else:
+        vis = pos[:, :, None]                            # [B, C, 1]
+        vis_spec = pl.BlockSpec((1, C, 1), row)
+    out = pl.pallas_call(
+        functools.partial(_paged_attn_kernel, sm_scale=sm_scale,
+                          block_size=bs, n_heads=H, tree=tree),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, Mb),
+            in_specs=[pl.BlockSpec((1, C, HD), row),
+                      pl.BlockSpec((1, bs, HD), page),
+                      pl.BlockSpec((1, bs, HD), page),
+                      vis_spec],
+            out_specs=pl.BlockSpec((1, C, HD), row),
+            scratch_shapes=[
+                pltpu.VMEM((H, C, 128), jnp.float32),
+                pltpu.VMEM((H, C, 128), jnp.float32),
+                pltpu.VMEM((H, C, Dh), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, C, HD), jnp.float32),
+        interpret=_device.pallas_interpret(),
+    )(block_tables.astype(jnp.int32),
+      jnp.stack([pos[:, 0], pos[:, C - 1]]),             # [2, B] span
+      q.reshape(B, C, HD), k_pages.reshape(NB, bs, HD),
+      v_pages.reshape(NB, bs, HD), vis)
+    return out.reshape(B, C, H, Dh)
 
 
 def paged_attention(k_pages, v_pages, q, block_tables, positions,
@@ -330,53 +390,8 @@ def paged_attention(k_pages, v_pages, q, block_tables, positions,
     Returns the ``[B, C, H, Dh]`` fp32 context. Numerics: online softmax
     (flash formulation) — token-identical to the gathered reference, not
     bitwise (docs/KERNELS.md)."""
-    if pltpu is None:  # pragma: no cover - guarded by registry qualify
-        raise RuntimeError("paged_attention needs pallas TPU support "
-                           "(scalar-prefetch grid specs)")
-    B, C, H, Dh = q.shape
-    bs = k_pages.shape[1]
-    Mb = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = Dh ** -0.5
-    interpret = jax.default_backend() != "tpu"
-
-    tables = block_tables.astype(jnp.int32)
-    pos = jnp.maximum(positions, 0).astype(jnp.int32)    # [B, C]
-    last_pos = pos[:, C - 1]                             # [B]
-    pos3 = pos[:, :, None]                               # [B, C, 1]
-
-    grid = (B, H, Mb)
-    kernel = functools.partial(_paged_attn_kernel, sm_scale=sm_scale,
-                               block_size=bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, C, 1, Dh),
-                         lambda b, h, j, tables, lp: (b, 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, Dh),
-                         lambda b, h, j, tables, lp: (tables[b, j],
-                                                      0, h, 0)),
-            pl.BlockSpec((1, bs, 1, Dh),
-                         lambda b, h, j, tables, lp: (tables[b, j],
-                                                      0, h, 0)),
-            pl.BlockSpec((1, C, 1),
-                         lambda b, h, j, tables, lp: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, C, 1, Dh),
-                               lambda b, h, j, tables, lp: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C, 128), jnp.float32),
-            pltpu.VMEM((C, 128), jnp.float32),
-            pltpu.VMEM((C, Dh), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, Dh), jnp.float32),
-        interpret=interpret,
-    )(tables, last_pos, q, k_pages, v_pages, pos3)
+    return _paged_call(k_pages, v_pages, q, block_tables, positions, None,
+                       sm_scale)
 
 
 def paged_attention_reference(k_pages, v_pages, q, block_tables,
@@ -407,71 +422,6 @@ def paged_attention_reference(k_pages, v_pages, q, block_tables,
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_tree_kernel(tables_ref, lastpos_ref, q_ref, k_ref, v_ref,
-                            pos_ref, anc_ref, o_ref, m_scr, l_scr,
-                            acc_scr, *, sm_scale, block_size):
-    """Grid (B, H, Mb), j innermost — the linear spec-window kernel with
-    the in-window causal diagonal swapped for the ancestor mask. Window
-    slot c sits at CACHE position pos0+c; a key at logical position t is
-    visible to slot c iff t < pos0 (committed prefix, strict — slot 0's
-    own write is window-visible via anc[0, 0], never prefix-visible) or
-    t-pos0 is an ancestor of c in the tree. The ancestor lookup runs as
-    a one-hot matmul against the [C, C] float ancestor matrix — no
-    in-kernel gathers."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    C = q_ref.shape[1]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j * block_size <= lastpos_ref[b])
-    def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # [C, Dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # [bs, Dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [C, bs]
-
-        # logical positions covered by table slot j, relative to the
-        # window base (pos_ref holds the CACHE position of each window
-        # slot: pos0 + c)
-        t_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (C, block_size), 1)
-        pos0 = pos_ref[0, 0]                            # pos: [C, 1]
-        rel = t_pos - pos0                              # row-constant
-        # anc[c, rel] via one-hot matmul: onehot[r, t] = (rel_t == r)
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (C, block_size), 0)
-                  == rel).astype(jnp.float32)
-        win_vis = jax.lax.dot_general(
-            anc_ref[:], onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) > 0.0   # [C, bs]
-        mask = (rel < 0) | win_vis
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        l = l_scr[:, :1]
-        o_ref[0, :, 0, :] = (acc_scr[:]
-                             / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
 def paged_attention_tree(k_pages, v_pages, q, block_tables, positions,
                          anc, sm_scale=None):
     """Tree-mask verify window over the paged KV cache, one kernel.
@@ -489,56 +439,8 @@ def paged_attention_tree(k_pages, v_pages, q, block_tables, positions,
     is numerically identical to the linear spec window. Returns the
     ``[B, C, H, Dh]`` fp32 context; online-softmax numerics, token-
     identical (not bitwise) to the gathered reference."""
-    if pltpu is None:  # pragma: no cover - guarded by registry qualify
-        raise RuntimeError("paged_attention_tree needs pallas TPU "
-                           "support (scalar-prefetch grid specs)")
-    B, C, H, Dh = q.shape
-    bs = k_pages.shape[1]
-    Mb = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = Dh ** -0.5
-    interpret = jax.default_backend() != "tpu"
-
-    tables = block_tables.astype(jnp.int32)
-    pos = jnp.maximum(positions, 0).astype(jnp.int32)    # [B, C]
-    last_pos = pos[:, C - 1]                             # [B] = pos0+C-1
-    pos3 = pos[:, :, None]                               # [B, C, 1]
-    anc_f = jnp.asarray(anc, jnp.float32)
-
-    grid = (B, H, Mb)
-    kernel = functools.partial(_paged_attn_tree_kernel, sm_scale=sm_scale,
-                               block_size=bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, C, 1, Dh),
-                         lambda b, h, j, tables, lp: (b, 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, Dh),
-                         lambda b, h, j, tables, lp: (tables[b, j],
-                                                      0, h, 0)),
-            pl.BlockSpec((1, bs, 1, Dh),
-                         lambda b, h, j, tables, lp: (tables[b, j],
-                                                      0, h, 0)),
-            pl.BlockSpec((1, C, 1),
-                         lambda b, h, j, tables, lp: (b, 0, 0)),
-            pl.BlockSpec((C, C),
-                         lambda b, h, j, tables, lp: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, C, 1, Dh),
-                               lambda b, h, j, tables, lp: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C, 128), jnp.float32),
-            pltpu.VMEM((C, 128), jnp.float32),
-            pltpu.VMEM((C, Dh), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, Dh), jnp.float32),
-        interpret=interpret,
-    )(tables, last_pos, q, k_pages, v_pages, pos3, anc_f)
+    return _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
+                       sm_scale)
 
 
 def paged_attention_tree_reference(k_pages, v_pages, q, block_tables,
@@ -608,7 +510,6 @@ def int8_matmul(x, w_int8, dq_scale, act_scale, block_m=32, block_k=128,
     final fp32 scale multiply (docs/KERNELS.md numerics policy)."""
     M, K = x.shape
     N = w_int8.shape[1]
-    interpret = jax.default_backend() != "tpu"
 
     xp = _pad_to(_pad_to(x, 0, block_m), 1, block_k)
     wp = _pad_to(_pad_to(w_int8, 0, block_k), 1, block_n)
@@ -617,11 +518,6 @@ def int8_matmul(x, w_int8, dq_scale, act_scale, block_m=32, block_k=128,
     Mp, Kp = xp.shape
     Np = wp.shape[1]
     grid = (Mp // block_m, Np // block_n, Kp // block_k)
-
-    if pltpu is not None:
-        scratch = [pltpu.VMEM((block_m, block_n), jnp.int32)]
-    else:  # pragma: no cover - CPU-only install without the tpu module
-        scratch = [jax.ShapeDtypeStruct((block_m, block_n), jnp.int32)]
 
     out = pl.pallas_call(
         functools.partial(_int8_mm_kernel, act_scale=act_scale),
@@ -634,8 +530,8 @@ def int8_matmul(x, w_int8, dq_scale, act_scale, block_m=32, block_k=128,
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        scratch_shapes=scratch,
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
+        interpret=_device.pallas_interpret(),
     )(xp, wp, sp)
     return out[:M, :N]
 
@@ -656,10 +552,6 @@ def int8_matmul_reference(x, w_int8, dq_scale, act_scale):
 # ---------------------------------------------------------------------------
 
 
-def _on_tpu():
-    return jax.default_backend() == "tpu"
-
-
 def _flash_qualify(T=None, Tk=None, head_dim=None, causal=False):
     """The compat_ops.py gate, promoted and FIXED: the historical check
     required q.shape == k.shape, silently dropping the tuned path for
@@ -677,8 +569,13 @@ def _flash_qualify(T=None, Tk=None, head_dim=None, causal=False):
 
 
 def _paged_qualify(head_dim=None, block_size=None, window=None):
-    if pltpu is None:
-        return False, "pallas TPU support (scalar prefetch) unavailable"
+    """What Mosaic refuses, found by compiling for the v5e topology
+    (tests/test_kernels_lower_tpu.py): a one-row page. Every other
+    geometry tried compiles — head_dim 8..128, 1..32 heads, block_size
+    2..128, windows of 1..33, fp32 and bf16 pages — because a block is
+    a whole page and so equals the array in its last two axes."""
+    if block_size is not None and block_size < 2:
+        return False, "block_size < 2 (Mosaic cannot lay out a one-row page)"
     return True, None
 
 
@@ -700,24 +597,24 @@ def _register_all():
             "everywhere (interpret off-TPU, its historical dispatch)")
     register_kernel(
         "paged_decode", paged_attention, paged_attention_reference,
-        qualify=_paged_qualify, default_on=_on_tpu,
+        qualify=_paged_qualify, default_on=_device.on_tpu,
         doc="one-token decode attention reading KVBlockPool pages "
             "through the block table in-kernel; default: TPU only")
     register_kernel(
         "spec_window", paged_attention, paged_attention_reference,
-        qualify=_paged_qualify, default_on=_on_tpu,
+        qualify=_paged_qualify, default_on=_device.on_tpu,
         doc="speculative verify-window (k+1 query positions) over the "
             "paged cache in one kernel; default: TPU only")
     register_kernel(
         "spec_window_tree", paged_attention_tree,
         paged_attention_tree_reference,
-        qualify=_paged_qualify, default_on=_on_tpu,
+        qualify=_paged_qualify, default_on=_device.on_tpu,
         doc="tree-mask verify window (width x depth token tree, one "
             "kernel) over the paged cache — in-window visibility by "
             "ancestor matrix via one-hot matmul; default: TPU only")
     register_kernel(
         "int8_matmul", int8_matmul, int8_matmul_reference,
-        qualify=_int8_qualify, default_on=_on_tpu,
+        qualify=_int8_qualify, default_on=_device.on_tpu,
         doc="fused quantize + int8 dot (int32 acc) + per-channel "
             "dequantize for full-int8 programs; default: TPU only")
 

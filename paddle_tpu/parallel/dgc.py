@@ -50,7 +50,7 @@ def make_dgc_step(mesh, loss_fn, lr=0.1, momentum=0.9, sparsity=0.99,
     (params, residuals, velocities, loss) — momentum SGD over DGC-compressed
     gradients (DGCMomentumOptimizer parity)."""
     from jax.sharding import PartitionSpec as P
-    from ..core.jax_compat import shard_map
+    from jax import shard_map
 
     def rank_step(params, residuals, velocities, *batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, *batch)

@@ -40,8 +40,5 @@ def current_abstract_mesh(fallback):
     shard_map region: the context abstract mesh carries the Manual axis
     types — a concrete-mesh NamedSharding there poisons downstream avals
     with a mismatched all-Auto mesh. Outside any region, `fallback`."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:  # jax < 0.5 has no tracing-context abstract mesh
-        return fallback
-    cmesh = get()
-    return fallback if cmesh is None or cmesh.empty else cmesh
+    cmesh = jax.sharding.get_abstract_mesh()
+    return fallback if cmesh.empty else cmesh

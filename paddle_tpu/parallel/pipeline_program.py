@@ -47,7 +47,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from ..core.jax_compat import axis_index as _axis_index, shard_map
+from jax import shard_map
+from jax.lax import axis_index as _axis_index
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.lowering import LoweringContext, execute_op

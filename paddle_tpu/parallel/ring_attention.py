@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..core.jax_compat import axis_index as _axis_index
+from jax.lax import axis_index as _axis_index
 
 _NEG_INF = -1e30
 
@@ -93,7 +93,7 @@ def ring_attention_sharded(q, k, v, mesh, axis_name="sp", causal=True,
     stay GSPMD-auto) — the form the descriptor-path flash_attention op
     uses inside a jitted step whose dp/tp axes GSPMD manages."""
     from jax.sharding import PartitionSpec as P
-    from ..core.jax_compat import shard_map
+    from jax import shard_map
 
     spec = P(None, None, axis_name, None)
     kwargs = ({"axis_names": {axis_name}, "check_vma": False}

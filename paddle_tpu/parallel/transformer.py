@@ -39,7 +39,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..core.jax_compat import axis_index as _axis_index, shard_map
+from jax import shard_map
+from jax.lax import axis_index as _axis_index
 
 from ..models import transformer as T
 
@@ -200,6 +201,9 @@ class SPMDTrainer:
     devices: Any = None
 
     def __post_init__(self):
+        from ..async_engine import setup_persistent_cache
+
+        setup_persistent_cache()
         dp, pp, tp = self.mesh_shape
         devs = self.devices if self.devices is not None else jax.devices()
         n = dp * pp * tp

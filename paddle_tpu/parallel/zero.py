@@ -56,10 +56,10 @@ trajectory is bitwise identical.
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import flags as _flags
-from ..core.jax_compat import shard_map
 from ..observability import metrics as _metrics
 
 __all__ = ["ShardedAdam", "ZeroLayoutError"]

@@ -178,34 +178,15 @@ class InjectedTransientError(RuntimeError):
     path a real RESOURCE_EXHAUSTED would."""
 
 
-_XLA_ERROR_TYPES = None
-
-
-def _xla_error_types():
-    global _XLA_ERROR_TYPES
-    if _XLA_ERROR_TYPES is None:
-        types = []
-        try:
-            from jax.errors import JaxRuntimeError
-            types.append(JaxRuntimeError)
-        except ImportError:
-            pass
-        try:
-            import jaxlib.xla_extension as _xe
-            types.append(_xe.XlaRuntimeError)
-        except (ImportError, AttributeError):
-            pass
-        _XLA_ERROR_TYPES = tuple(types)
-    return _XLA_ERROR_TYPES
-
-
 def is_transient_error(exc):
     """True when `exc` is a runtime failure worth a rollback-and-retry:
-    an XlaRuntimeError carrying a retryable status code, or an injected
+    a JaxRuntimeError carrying a retryable status code, or an injected
     stand-in for one."""
     if isinstance(exc, InjectedTransientError):
         return True
-    if isinstance(exc, _xla_error_types()):
+    from jax.errors import JaxRuntimeError
+
+    if isinstance(exc, JaxRuntimeError):
         msg = str(exc)
         return any(marker in msg for marker in _TRANSIENT_MARKERS)
     return False

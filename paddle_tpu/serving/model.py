@@ -605,6 +605,10 @@ class GenerationModel:
         # traces, so tests can pin "no retrace across join/retire"
         self.trace_count = 0
         self._steps = {}
+        # every step builder below compiles through jax's on-disk cache
+        from ..async_engine import setup_persistent_cache
+
+        setup_persistent_cache()
 
     @classmethod
     def random(cls, config, seed=0, name="model"):

@@ -4,8 +4,8 @@
 #   build      - compile the C++ runtime spine + its gtest binary
 #   test       - native tests, then the python suite on the 8-dev CPU mesh
 #   api_check  - enforce the frozen public API surface (API.spec)
-#   bench      - headline benchmark (single JSON line; runs on the default
-#                backend — real TPU when attached)
+#   bench      - headline benchmark (single JSON line; needs a TPU and
+#                fails without one)
 #   stress     - 5x back-to-back run of the rendezvous-heaviest file
 #   obs        - observability smoke: metrics dump + stats CLI render
 #   bench-smoke- tiny-model bench.py --metrics-out run asserting the async
@@ -630,7 +630,7 @@ do_serve() {
   for attempt in 1 2 3; do
     rm -f "$dump" "$legs"
     JAX_PLATFORMS=cpu PTPU_METRICS=1 \
-      python bench.py --serving-only --metrics-out "$dump" \
+      python bench.py --tiny --serving-only --metrics-out "$dump" \
       --legs-out "$legs"
     python tools/ptpu_stats.py "$dump" \
       --assert-has serving/request_latency serving/tokens_per_sec \
@@ -997,7 +997,7 @@ PYEOF
   for attempt in 1 2 3; do
     rm -f "$dump" "$legs"
     JAX_PLATFORMS=cpu PTPU_METRICS=1 \
-      python bench.py --quant-only --metrics-out "$dump" \
+      python bench.py --tiny --quant-only --metrics-out "$dump" \
       --legs-out "$legs"
     python tools/ptpu_stats.py "$dump" \
       --assert-has bench/quant_examples_per_sec_fp32 \
@@ -1141,7 +1141,7 @@ PYEOF
   for attempt in 1 2 3; do
     rm -f "$dump" "$legs"
     JAX_PLATFORMS=cpu PTPU_METRICS=1 \
-      python bench.py --rec-only --metrics-out "$dump" \
+      python bench.py --tiny --rec-only --metrics-out "$dump" \
       --legs-out "$legs"
     python tools/ptpu_stats.py "$dump" \
       --assert-has bench/rec_examples_per_sec_sync \
@@ -1228,16 +1228,17 @@ PYEOF
     --assert-min kernels/dispatches=1 "kernels/kernel:int8_matmul=1" \
                  quant/ops_rewritten=1 verify/programs_checked=1 \
     --assert-max verify/violations=0
-  # per-kernel bench receipts: gauges present and positive (floor),
-  # kernel-vs-fallback parity inside the documented bound per leg
+  # per-kernel parity receipts: kernel vs fallback inside the documented
+  # bound per leg. On the CPU the kernels run in the interpreter, so the
+  # run reports no time (a kernel's time comes from the chip only).
   rm -f "$dump" "$legs"
   JAX_PLATFORMS=cpu PTPU_METRICS=1 \
-    python bench.py --kernels-only --metrics-out "$dump" \
+    python bench.py --tiny --kernels-only --metrics-out "$dump" \
     --legs-out "$legs"
   python tools/ptpu_stats.py "$dump" \
-    --assert-min bench/kernel_paged_decode_speedup=0.0001 \
-                 bench/kernel_int8_matmul_speedup=0.0001 \
-                 bench/kernel_spec_window_speedup=0.0001
+    --assert-has bench/kernel_paged_decode_max_err \
+                 bench/kernel_int8_matmul_max_err \
+                 bench/kernel_spec_window_max_err
   python - "$legs" <<'PYEOF'
 import json, sys
 legs = {e["leg"]: e for e in json.load(open(sys.argv[1]))}
@@ -1245,9 +1246,9 @@ for need in ("kernel_paged_decode", "kernel_spec_window",
              "kernel_int8_matmul"):
     assert need in legs, (need, sorted(legs))
     assert legs[need]["max_err"] < 1e-4, legs[need]
+    assert "pallas_s" not in legs[need], legs[need]
 assert legs["kernel_int8_matmul"]["max_err"] == 0.0, legs
-print("kernels stage ok:",
-      {k: round(v[k + "_speedup"], 4) for k, v in legs.items()})
+print("kernels stage ok:", {k: v["max_err"] for k, v in legs.items()})
 PYEOF
 }
 
@@ -1452,7 +1453,7 @@ PYEOF
   for attempt in 1 2 3; do
     rm -f "$dump" "$legs"
     JAX_PLATFORMS=cpu PTPU_METRICS=1 \
-      python bench.py --fleet-only --metrics-out "$dump" \
+      python bench.py --tiny --fleet-only --metrics-out "$dump" \
       --legs-out "$legs"
     python tools/ptpu_stats.py "$dump" \
       --assert-has bench/serving_fleet_tokens_per_sec_1r \
@@ -1667,7 +1668,7 @@ PYEOF
   for attempt in 1 2 3; do
     rm -f "$dump" "$legs"
     JAX_PLATFORMS=cpu PTPU_METRICS=1 \
-      python bench.py --online-only --metrics-out "$dump" \
+      python bench.py --tiny --online-only --metrics-out "$dump" \
       --legs-out "$legs"
     python tools/ptpu_stats.py "$dump" \
       --assert-has bench/online_tokens_per_sec_steady \

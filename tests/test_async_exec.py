@@ -6,6 +6,8 @@ compilation cache across a process-sim (fresh Executor + cleared jax
 caches)."""
 
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -249,12 +251,10 @@ def test_deferred_warns_fire_after_drain_interval():
 
 
 def test_int64_guard_catches_device_arrays():
-    from jax.experimental import enable_x64
-
     loss = _sgd_program()
     exe = fluid.Executor(fluid.CPUPlace())
     exe.run(fluid.default_startup_program())
-    with enable_x64():
+    with jax.enable_x64():
         bad = jax.device_put(np.full((2, 4), 2 ** 40, np.int64))
     assert bad.dtype == np.int64
     with pytest.raises(ValueError, match="int64 ids above int32 range"):
@@ -279,22 +279,28 @@ def test_int64_guard_host_arrays_still_checked():
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def fresh_cache(tmp_path, monkeypatch):
-    """Point the persistent cache at a temp dir; restore on exit."""
-    prev = async_engine._PERSISTENT["dir"]
-    async_engine._PERSISTENT["dir"] = None
-    monkeypatch.setenv("PTPU_CACHE_DIR", str(tmp_path / "cache"))
-    yield str(tmp_path / "cache")
-    async_engine._PERSISTENT["dir"] = prev
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
+_CACHE_KNOBS = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_persistent_cache_min_compile_time_secs",
+                "jax_enable_compilation_cache")  # off in conftest
 
-        cc.reset_cache()  # drop the latched singleton too
-    except Exception:
-        pass
+
+@pytest.fixture
+def fresh_cache(tmp_path):
+    """Place jax's persistent cache in a temp dir the way an application
+    would (its own jax.config.update), with the write thresholds off so
+    a toy compile is worth an entry; restore on exit."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = [getattr(jax.config, k) for k in _CACHE_KNOBS]
+    cache = str(tmp_path / "cache")
+    for k, v in zip(_CACHE_KNOBS, (cache, -1, 0.0, True)):
+        jax.config.update(k, v)
+    cc.reset_cache()  # drop the singleton bound to the previous dir
+    yield cache
+    for k, v in zip(_CACHE_KNOBS, prev):
+        jax.config.update(k, v)
+    cc.reset_cache()
 
 
 def test_persistent_cache_process_sim(fresh_cache):
@@ -318,6 +324,7 @@ def test_persistent_cache_process_sim(fresh_cache):
         miss0, hit0 = (count("compile_cache/persistent_miss"),
                        count("compile_cache/persistent_hit"))
         exe = fluid.Executor(fluid.CPUPlace())
+        # a directory placed from outside is left alone
         assert async_engine.persistent_cache_dir() == fresh_cache
         exe.run(fluid.default_startup_program())
         (ref,) = exe.run(feed=feed, fetch_list=[loss])
@@ -336,6 +343,41 @@ def test_persistent_cache_process_sim(fresh_cache):
         np.testing.assert_allclose(lv, ref, rtol=1e-6)
     finally:
         obs_metrics.disable()
+
+
+def test_persistent_cache_default_placement():
+    """Constructing an Executor turned the cache on: where the
+    environment placed it, else at the one fixed path in the checkout —
+    with jax's own write thresholds either way."""
+    fluid.Executor(fluid.CPUPlace())
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(repo, ".jax_cache"))
+    assert async_engine.persistent_cache_dir() == want
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == float(
+        os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", 1.0))
+
+
+def test_persistent_cache_env_dir_is_not_overridden(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, jax has its directory before
+    the framework looks and no jax.config.update of the dir runs."""
+    script = (
+        "import jax\n"
+        "calls = []\n"
+        "orig = jax.config.update\n"
+        "jax.config.update = lambda k, v: (calls.append(k), orig(k, v))\n"
+        "import paddle_tpu as fluid\n"
+        "fluid.Executor(fluid.CPUPlace())\n"
+        "from paddle_tpu import async_engine\n"
+        "assert 'jax_compilation_cache_dir' not in calls, calls\n"
+        "print(async_engine.persistent_cache_dir())\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "outside"))
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == str(tmp_path / "outside")
 
 
 # ---------------------------------------------------------------------------

@@ -16,19 +16,8 @@ import sys
 import numpy as np
 import pytest
 
-from paddle_tpu.core.jax_compat import MULTIPROCESS_CPU_COLLECTIVES
-
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _WORKER = os.path.join(_ROOT, "tests", "dist_fit_a_line.py")
-
-# the jax.distributed workers need cross-process CPU collectives, which
-# this image's jaxlib lacks ("Multiprocess computations aren't
-# implemented on the CPU backend") — version-gated like the
-# AXIS_INDEX_SAFE_UNDER_PARTIAL_AUTO tests, re-enables on jaxlib >= 0.5
-needs_mp_cpu_collectives = pytest.mark.xfail(
-    condition=not MULTIPROCESS_CPU_COLLECTIVES, run=False,
-    reason="multi-process collectives unimplemented on this jaxlib's "
-           "CPU backend (jax_compat.MULTIPROCESS_CPU_COLLECTIVES)")
 
 
 def _free_port():
@@ -57,7 +46,6 @@ def _losses(out):
 _MP_WORKER = os.path.join(_ROOT, "tests", "dist_mp_worker.py")
 
 
-@needs_mp_cpu_collectives
 @pytest.mark.parametrize("mode", ["tp", "sp", "pp", "pptp"])
 def test_two_process_model_parallel_matches_single(mode):
     """dp over processes × {tp, sp, pp, pp×tp} within each (VERDICT r4
@@ -106,7 +94,6 @@ def test_two_process_model_parallel_matches_single(mode):
                                    rtol=1e-5, atol=1e-6)
 
 
-@needs_mp_cpu_collectives
 def test_two_process_dcn_training_matches_local():
     port = _free_port()
     coord = "127.0.0.1:%d" % port

@@ -419,24 +419,6 @@ def test_endpoint_healthz_aggregates_providers(live_endpoint):
 # ---------------------------------------------------------------------------
 
 
-def test_jax_compat_cost_and_memory_analysis_guarded():
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.core import jax_compat
-
-    compiled = jax.jit(
-        lambda a, b: jnp.dot(a, b)).lower(
-            jnp.ones((32, 32)), jnp.ones((32, 32))).compile()
-    ca = jax_compat.compiled_cost_analysis(compiled)
-    assert ca is not None and ca["flops"] > 0
-    ma = jax_compat.compiled_memory_analysis(compiled)
-    assert ma is not None and ma["output_size_in_bytes"] > 0
-    # garbage in -> None out, never a raise (the guard contract)
-    assert jax_compat.compiled_cost_analysis(object()) is None
-    assert jax_compat.compiled_memory_analysis(object()) is None
-
-
 def test_cost_publish_and_mfu():
     import jax
     import jax.numpy as jnp
@@ -458,9 +440,13 @@ def test_cost_publish_and_mfu():
     finally:
         if not was_metrics:
             metrics.disable()
-    assert cost.peak_flops("tpu") == 275e12
-    # 1e11 flops/step at 1 step/s on the cpu row (peak 1e11) = 100%
-    assert abs(cost.mfu_pct(1e11, 1.0, platform="cpu") - 100.0) < 1e-6
+    # the peak table is keyed by device_kind; a device it does not hold
+    # (this CPU) has no peak and publishes no MFU — never a default
+    assert cost.peak_flops("TPU v5 lite") == 197e12
+    assert abs(cost.mfu_pct(197e12, 1.0, "TPU v5 lite") - 100.0) < 1e-9
+    with pytest.raises(KeyError, match="no peak"):
+        cost.peak_flops()
+    assert cost.mfu_pct(1e11, 1.0) is None
 
 
 # ---------------------------------------------------------------------------

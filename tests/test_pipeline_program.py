@@ -15,20 +15,6 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.core import scope as scope_mod
-from paddle_tpu.core.jax_compat import AXIS_INDEX_SAFE_UNDER_PARTIAL_AUTO
-
-# pp×tp combos run the 1F1B shard_map manual over dp/pp with tp left
-# GSPMD-auto — a PARTIAL-auto region jaxlib < 0.5 cannot lower
-# (axis_index becomes a PartitionId instruction old XLA rejects under
-# SPMD partitioning; see core/jax_compat.py and the matching gate in
-# test_sequence_parallel.py). run=False: beyond the UNIMPLEMENTED
-# raise, some lowerings CHECK-abort the whole process on that XLA.
-_xfail_partial_auto = pytest.mark.xfail(
-    not AXIS_INDEX_SAFE_UNDER_PARTIAL_AUTO, run=False,
-    reason="jaxlib<0.5: PartitionId under partial-auto shard_map is "
-           "UNIMPLEMENTED in old XLA SPMD partitioning (ROADMAP "
-           "jax-version drift)")
-
 
 def _mlp(prefix, width=32, depth=3):
     x = layers.data(name=prefix + "_x", shape=[16], dtype="float32")
@@ -94,7 +80,6 @@ def test_pp_dp_loss_parity():
     assert sorted(set(step.stage_of)) == [0, 1]
 
 
-@_xfail_partial_auto
 def test_pp_tp_zero_combo_parity():
     """dp=2 × pp=2 × tp=2 with ZeRO-1 Reduce mode: parity + the planner
     really shards optimizer state over dp and fc weights over tp."""
@@ -152,7 +137,6 @@ def test_pipeline_stage_annotation():
     assert sorted(set(step.stage_of)) == [0, 1, 2, 3]
 
 
-@_xfail_partial_auto
 def test_pp_transformer_tp_parity():
     """A plain fluid.layers transformer (recompute + flash attention +
     chunked vocab head) trains dp=2 × pp=2 × tp=2 with exact loss parity —
@@ -331,7 +315,6 @@ def test_interleaved_virtual_stages_parity():
     assert 0.0 < st["bubble_fraction"] < 1.0
 
 
-@_xfail_partial_auto
 def test_interleaved_with_tp_parity():
     """dp×pp×tp with v=2 interleaving composes (tp stays GSPMD inside
     every chunk branch)."""

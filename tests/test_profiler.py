@@ -134,11 +134,7 @@ def test_named_scopes_reach_lowered_hlo():
     const = {n: np.asarray(sc.get(n)) for n in step.const_names}
     feeds = {"ns_x": np.ones((4, 8), np.float32)}
     lowered = step._jitted.lower(mut, const, feeds, np.uint32(1))
-    try:  # jax >= 0.4.38
-        txt = lowered.as_text(debug_info=True)
-    except TypeError:  # older jax: location metadata via the MLIR asm
-        txt = lowered.compiler_ir("stablehlo").operation.get_asm(
-            enable_debug_info=True)
+    txt = lowered.as_text(debug_info=True)
     for frag in ("fluid/mul__", "fluid/relu__", "fluid/sgd__"):
         assert frag in txt, frag
 

@@ -710,9 +710,3 @@ def test_dropout_remove_respects_rebinding():
                    fetch_list=[out])
     # a = 2*x = 2 each; b = 10*x = 10 each -> sum = 4*(2+10)
     assert np.asarray(res).item() == pytest.approx(48.0)
-
-
-def test_multiprocess_cpu_collectives_probe_exists():
-    from paddle_tpu.core import jax_compat
-
-    assert isinstance(jax_compat.MULTIPROCESS_CPU_COLLECTIVES, bool)

@@ -13,22 +13,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import scope as scope_mod
-from paddle_tpu.core.jax_compat import AXIS_INDEX_SAFE_UNDER_PARTIAL_AUTO
 from paddle_tpu.models import transformer_fluid
-
-# Every test here drives ring attention through a PARTIAL-auto shard_map
-# (manual over sp, dp/tp left to GSPMD). jaxlib < 0.5 cannot lower that
-# region: axis_index becomes a PartitionId instruction old XLA rejects
-# under SPMD partitioning (XlaRuntimeError UNIMPLEMENTED), and the
-# collective workarounds CHECK-abort the process outright (see
-# core/jax_compat.py). run=False because the failure mode on some paths
-# is that process-killing abort, not a catchable raise.
-pytestmark = pytest.mark.xfail(
-    not AXIS_INDEX_SAFE_UNDER_PARTIAL_AUTO, run=False,
-    reason="jaxlib<0.5: PartitionId under partial-auto shard_map is "
-           "UNIMPLEMENTED in old XLA SPMD partitioning (ROADMAP "
-           "jax-version drift)")
-
 
 def _build(seq, d_model=32, n_heads=4, n_layers=2, vocab=64,
            head_chunk=None):

@@ -51,16 +51,6 @@ def test_moe_expert_parallel_trains():
     assert losses[-1] < losses[0], losses
 
 
-@pytest.mark.xfail(
-    not __import__("paddle_tpu.core.jax_compat",
-                   fromlist=["x"]).AXIS_INDEX_SAFE_UNDER_PARTIAL_AUTO,
-    run=False,
-    reason="jaxlib<0.5: dryrun(8) factors to pp=2 x tp=2 with sequence "
-           "parallel — PartitionId under partial-auto shard_map is "
-           "UNIMPLEMENTED in old XLA SPMD partitioning (same gate as "
-           "test_sequence_parallel.py; ROADMAP jax-version drift). "
-           "Reached only since the activation-stash float0 fix — the "
-           "float0 residual crash used to mask it.")
 def test_dryrun_multichip():
     import sys
 

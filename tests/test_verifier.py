@@ -419,8 +419,8 @@ def test_flags_path_type_accepts_off_spellings(monkeypatch):
     for off in ("0", "false", "OFF", "no"):
         monkeypatch.setenv("PTPU_TRACE_DIR", off)
         assert flags.env("PTPU_TRACE_DIR") is None, off
-        monkeypatch.setenv("PTPU_CACHE_DIR", off)
-        assert flags.env("PTPU_CACHE_DIR") is None, off
+        monkeypatch.setenv("PTPU_BLACKBOX_DIR", off)
+        assert flags.env("PTPU_BLACKBOX_DIR") is None, off
     monkeypatch.setenv("PTPU_TRACE_DIR", "/tmp/traces")
     assert flags.env("PTPU_TRACE_DIR") == "/tmp/traces"
 

@@ -1,70 +1,16 @@
-"""XLA_FLAGS staging for the virtual host-device mesh.
+"""The virtual host-device mesh: N CPU devices in one process, for tests
+and multi-chip dry runs that must never touch the chip.
 
-Must run BEFORE the first jax import (XLA reads the env var once at
-backend init). Shared by tests/conftest.py and __graft_entry__.py so the
-flag set cannot drift between the test suite and the driver's dryrun.
+Both settings are read ONCE, when jax first initializes its backends, so
+`use_host_mesh` must run before the first device query. A chip belongs
+to one process at a time and a device query takes it; a dry run that
+asked for devices first and switched platform afterwards would hold the
+chip for nothing. Shared by tests/conftest.py, __graft_entry__.py,
+bench.py and the examples so the flag set cannot drift between them.
 """
 
 import os
 import re
-
-_FLAG_SUPPORT = {}
-
-
-def _xla_supports(*flag_names):
-    """Whether the installed jaxlib knows every one of `flag_names`.
-    XLA's env-flag parser FATALLY aborts the process on unknown --xla_*
-    flags (parse_flags_from_env.cc), so staging a flag an older jaxlib
-    lacks kills every jax-using process at backend init. Probe the
-    flag-name strings in xla_extension.so (mmap'd, no load) instead of
-    guessing from version numbers — ONE scan for all names, since a
-    miss means byte-scanning a multi-hundred-MB binary end to end."""
-    # cross-process cache: the negative probe byte-scans a ~265MB .so
-    # (~1s), and ci.sh/dist tests spawn many python processes that would
-    # each re-pay it — each flag's verdict rides the environment
-    for n in flag_names:
-        if n not in _FLAG_SUPPORT:
-            cached = os.environ.get("_PTPU_XLA_FLAG_PROBE_" + n)
-            if cached is not None:
-                _FLAG_SUPPORT[n] = cached == "1"
-    missing = [n for n in flag_names if n not in _FLAG_SUPPORT]
-    if missing:
-        try:
-            import glob
-            import mmap
-
-            import jaxlib
-
-            sos = sorted(glob.glob(
-                os.path.join(os.path.dirname(jaxlib.__file__), "*.so")),
-                key=os.path.getsize, reverse=True)
-        except Exception:
-            sos = []  # no jaxlib at all: nothing will parse XLA_FLAGS
-        for so in sos:
-            if not missing:
-                break
-            # per-file guard: one unreadable/empty .so (mmap of a
-            # zero-length file raises) must not abort the scan and
-            # wrongly cache 'unsupported' for a capable jaxlib
-            try:
-                with open(so, "rb") as f:
-                    mm = mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
-                    try:
-                        found = [n for n in missing
-                                 if mm.find(n.encode()) != -1]
-                    finally:
-                        mm.close()
-            except Exception:
-                continue
-            for n in found:
-                _FLAG_SUPPORT[n] = True
-                missing.remove(n)
-        for n in missing:  # scanned everything readable: genuinely absent
-            _FLAG_SUPPORT[n] = False
-    for n in flag_names:
-        os.environ["_PTPU_XLA_FLAG_PROBE_" + n] = \
-            "1" if _FLAG_SUPPORT[n] else "0"
-    return all(_FLAG_SUPPORT[n] for n in flag_names)
 
 
 def stage_host_mesh_flags(n_devices=8):
@@ -90,15 +36,25 @@ def stage_host_mesh_flags(n_devices=8):
         flags = (flags[:m.start()] +
                  "--xla_force_host_platform_device_count=%d" % n_devices +
                  flags[m.end():])
-    want = ("xla_cpu_collective_call_warn_stuck_timeout_seconds",
-            "xla_cpu_collective_call_terminate_timeout_seconds")
-    if (("collective_call_warn_stuck_timeout" not in flags
-         or "collective_call_terminate_timeout" not in flags)
-            and _xla_supports(*want)):
-        if "collective_call_warn_stuck_timeout" not in flags:
-            flags += (" --xla_cpu_collective_call_warn_stuck_timeout_"
-                      "seconds=60")
-        if "collective_call_terminate_timeout" not in flags:
-            flags += (" --xla_cpu_collective_call_terminate_timeout_"
-                      "seconds=600")
+    if "collective_call_warn_stuck_timeout" not in flags:
+        flags += " --xla_cpu_collective_call_warn_stuck_timeout_seconds=60"
+    if "collective_call_terminate_timeout" not in flags:
+        flags += " --xla_cpu_collective_call_terminate_timeout_seconds=600"
     os.environ["XLA_FLAGS"] = flags.strip()
+
+
+def use_host_mesh(n_devices=8):
+    """Make this process a virtual `n_devices` CPU mesh: stage XLA_FLAGS,
+    pin jax to the CPU platform, then check that is what came up. Raises
+    when jax had already initialized another backend (a device query ran
+    first), because then the pin no longer takes effect."""
+    stage_host_mesh_flags(n_devices)
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    devs = jax.devices()
+    if devs[0].platform != "cpu" or len(devs) < n_devices:
+        raise RuntimeError(
+            "use_host_mesh(%d) must run before the first jax device "
+            "query; this process already holds %r" % (n_devices, devs))
+    return jax
