@@ -1410,8 +1410,7 @@ def main(argv=None):
     ap.add_argument("--legs-out", metavar="bench_legs.json", default=None,
                     help="write a machine-readable per-leg JSON array "
                          "(leg name, tokens/s, step time, loss) so "
-                         "BENCH_r*.json can track fp32 vs AMP legs "
-                         "separately")
+                         "fp32 and AMP legs are recorded separately")
     ap.add_argument("--steps", type=int, default=24)
     ap.add_argument("--warmup", type=int, default=3)
     ap.add_argument("--tiny", action="store_true",
@@ -1793,8 +1792,8 @@ def main(argv=None):
 
     # AMP receipt (docs/MIXED_PRECISION.md): the SAME fp32 transformer
     # config trained plain and through paddle_tpu.amp.decorate — the
-    # bf16 dtype-rewrite's tokens/s/chip win is recorded per leg so the
-    # BENCH_r*.json trajectory tracks fp32 vs AMP separately. The tiny
+    # bf16 dtype-rewrite's tokens/s/chip win is recorded per leg, fp32 and
+    # AMP separately. The tiny
     # bench-smoke run skips the pair (ci.sh's dedicated `amp` stage
     # already pays the identical tiny pair via --amp-only).
     fp32_tps = amp_tps = fp32_step = amp_step = None
@@ -2076,8 +2075,8 @@ def main(argv=None):
                 1.0 if spec_res["int8"]["outputs_match"] else 0.0)
         reg.dump_json(args.metrics_out)
     if args.legs_out:
-        # machine-readable per-leg trajectory (ISSUE 5): BENCH_r*.json
-        # can track the fp32 vs AMP legs separately from the headline
+        # machine-readable per-leg record (ISSUE 5): the fp32 and AMP
+        # legs separately from the headline
         with open(args.legs_out, "w") as f:
             json.dump(legs, f, indent=2)
     result = {

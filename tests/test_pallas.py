@@ -19,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.core import device
 from paddle_tpu.ops import kernel_registry as kreg
 from paddle_tpu.ops.pallas_kernels import (
     flash_attention, int8_matmul, int8_matmul_reference, paged_attention,
@@ -106,7 +107,7 @@ def test_registry_auto_policy_is_platform_scoped(monkeypatch):
     monkeypatch.delenv("PTPU_KERNELS", raising=False)
     monkeypatch.delenv("PTPU_KERNELS_DISABLE", raising=False)
     assert kreg.enabled_for("flash_attention")
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = device.on_tpu()
     for name in ("paged_decode", "spec_window", "int8_matmul"):
         assert kreg.enabled_for(name) == on_tpu
 

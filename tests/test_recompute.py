@@ -1,6 +1,6 @@
 """layers.recompute (remat segments) + the lean softmax_with_cross_entropy
 custom vjp — the descriptor-path TPU knobs behind the Fluid-API transformer
-(models/transformer_fluid.py; VERDICT round-1 item 1).
+(models/transformer_fluid.py; round-1 review item 1).
 
 Parity anchor: the reference's later RecomputeOptimizer plays the remat
 role on GPU; here segments lower onto jax.checkpoint through the
