@@ -8,6 +8,7 @@ IO / reader queues / profiling / program framing run in C++.
 """
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -123,6 +124,41 @@ def _configure(lib):
     return lib
 
 
+def _build(native_dir):
+    """Bring ``native_dir``'s library up to date, safely beside other
+    processes doing the same (six test workers on a fresh checkout; the
+    chip machine's first run). One builder at a time holds an exclusive
+    ``flock`` on a file beside the Makefile; ``make`` writes to a
+    temporary name and ``os.replace`` puts it in place, so whoever loads
+    the library maps a whole file, and a process that already mapped the
+    old one keeps its inode. A no-op when the library is newer than its
+    sources; a rebuild when a stale (git-ignored) .so was carried along
+    with newer committed sources. Returns False where there is no
+    toolchain or the directory cannot be written."""
+    tmp = ".%s.%d.tmp" % (_LIB_NAME, os.getpid())
+    try:
+        with open(os.path.join(native_dir, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)   # released when closed
+            fresh = subprocess.run(["make", "-s", "-q", _LIB_NAME],
+                                   cwd=native_dir, capture_output=True,
+                                   timeout=120)
+            if fresh.returncode == 0:
+                return True
+            subprocess.run(["make", "-s", "OUT=" + tmp, tmp],
+                           cwd=native_dir, check=True,
+                           capture_output=True, timeout=120)
+            os.replace(os.path.join(native_dir, tmp),
+                       os.path.join(native_dir, _LIB_NAME))
+            return True
+    except (OSError, subprocess.SubprocessError):
+        return False
+    finally:
+        try:
+            os.unlink(os.path.join(native_dir, tmp))
+        except OSError:
+            pass
+
+
 def lib():
     """The loaded native library, or None if unavailable."""
     global _lib, _tried
@@ -132,21 +168,22 @@ def lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        path = os.path.abspath(os.path.join(_NATIVE_DIR, _LIB_NAME))
-        # always ask make: a no-op when the library is newer than its
-        # sources, a rebuild when a stale (git-ignored) .so was carried
-        # along with newer committed sources
-        try:
-            subprocess.run(["make", "-s", _LIB_NAME],
-                           cwd=os.path.dirname(path), check=True,
-                           capture_output=True, timeout=120)
-        except (OSError, subprocess.SubprocessError):
+        native_dir = os.path.abspath(_NATIVE_DIR)
+        if not _build(native_dir):
             return None
         try:
-            _lib = _configure(ctypes.CDLL(path))
+            _lib = _configure(ctypes.CDLL(
+                os.path.join(native_dir, _LIB_NAME)))
         except OSError:
             _lib = None
         return _lib
+
+
+def loaded():
+    """The library if some caller's `lib()` already loaded it, else
+    None. Never builds or loads: for hot paths (a tracing span's exit)
+    that only have something to do once the library is in use."""
+    return _lib
 
 
 def _take_buf(l, ptr, n):

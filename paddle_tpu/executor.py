@@ -46,6 +46,15 @@ from .framework import Program, dtype_to_np
 __all__ = ["Executor", "global_scope", "scope_guard", "as_numpy"]
 
 
+def _exe_phase(name):
+    """A phase of a step on the profiler's clock (``ptpu/exe.<name>`` on
+    the host plane of a `jax.profiler` capture, beside the device rows)
+    while metrics are on; the shared null span otherwise."""
+    if not _metrics.enabled():
+        return _tracing.NULL_SPAN
+    return _tracing.annotation("ptpu/exe." + name)
+
+
 def _feed_signature(feed):
     # duck-typed dtype: np.asarray on a device-resident jax.Array would
     # round-trip the whole buffer over the host link EVERY run() call
@@ -204,7 +213,9 @@ class _CompiledStep:
             state[name] = val
         return state
 
-    def run(self, scope, feed):
+    def _stage(self, scope, feed):
+        """The step's arguments: state read from the scope, feeds cast
+        to the program's dtypes."""
         mut = self._read_state(scope, self.mut_names)
         const = self._read_state(scope, self.const_names)
         feeds = {}
@@ -227,6 +238,11 @@ class _CompiledStep:
                     arr = arr.astype(want)
             feeds[name] = arr
         step_counter = np.uint32(scope.get("__step_counter__", 0) or 0)
+        return mut, const, feeds, step_counter
+
+    def run(self, scope, feed):
+        with _exe_phase("prepare"):
+            mut, const, feeds, step_counter = self._stage(scope, feed)
         fn = self._aot
         if fn is None:
             # tracing alone also takes the AOT path: without it the first
@@ -239,7 +255,7 @@ class _CompiledStep:
             else:
                 fn = self._jitted
                 self._ran_jit = True
-        with _tracing.span("execute"):
+        with _exe_phase("dispatch"), _tracing.span("execute"):
             fetches, new_state, finite, warns = fn(
                 mut, const, feeds, step_counter)
         # deferred: the all-false common case must not sync the device
@@ -437,6 +453,8 @@ class Executor:
         if isinstance(program, CompiledProgram):
             return program._run(self, feed, fetch_list, scope, return_numpy,
                                 fetch_every_n)
+        rec = _metrics.enabled()
+        t_run = time.perf_counter() if rec else 0.0
         feed = dict(feed or {})
         fetch_list = list(fetch_list or [])
         scope = scope if scope is not None else global_scope()
@@ -458,20 +476,23 @@ class Executor:
         from . import ir_passes
         from .flags import flag
 
-        key = (
-            id(program),
-            program.version,
-            _feed_signature(feed),
-            tuple(fetch_names),
-            bool(flag("check_nan_inf")),
-            # the compile-time pass pipeline is part of the step identity:
-            # toggling PTPU_NO_PROGRAM_OPT (or the program flipping
-            # between train/inference shape) must not hit a stale entry.
-            # The scope is NOT in the key: scope-bound compile artifacts
-            # (baked constants, promoted dead inputs) self-heal through
-            # ir_passes.state_fallback at state-read time
-            ir_passes.pipeline_key(None, program),
-        )
+        with _exe_phase("prepare"):
+            key = (
+                id(program),
+                program.version,
+                _feed_signature(feed),
+                tuple(fetch_names),
+                bool(flag("check_nan_inf")),
+                # the compile-time pass pipeline is part of the step identity:
+                # toggling PTPU_NO_PROGRAM_OPT (or the program flipping
+                # between train/inference shape) must not hit a stale entry.
+                # The scope is NOT in the key: scope-bound compile artifacts
+                # (baked constants, promoted dead inputs) self-heal through
+                # ir_passes.state_fallback at state-read time
+                ir_passes.pipeline_key(None, program),
+            )
+            compiled = (self._cache.get(key) if use_program_cache
+                        else None)
         # substitute staged device copies only AFTER the cache key is
         # computed from the ORIGINAL feed: device_put canonicalizes some
         # dtypes, and a signature drift here would force a spurious
@@ -480,9 +501,7 @@ class Executor:
             staged = self._prefetcher.take_if_match(feed)
             if staged is not None:
                 feed = staged
-        rec = _metrics.enabled()
         with _observability.step_scope():
-            compiled = self._cache.get(key) if use_program_cache else None
             if compiled is None:
                 # fault-injection hook (docs/RESILIENCE.md): the
                 # `transient_compile` site raises a retryable error here
@@ -526,7 +545,15 @@ class Executor:
             _metrics.counter("executor/feed_bytes").inc(
                 _nbytes(feed.values()))
             _metrics.counter("executor/fetch_bytes").inc(_nbytes(fetches))
+        t_finish = time.perf_counter() if rec else 0.0
         out = self._finish_run(fetches, return_numpy, fetch_every_n)
+        if rec:
+            # the host's share of the step: what is left of `run` is
+            # where it blocks on the device (`_finish_run`: materialised
+            # fetches, a full in-flight window; the sync point's warning
+            # flush), so the clock stops before it
+            _metrics.samples("executor/run_host_ms").add(
+                (t_finish - t_run) * 1e3)
         if not isinstance(out, LazyFetchList):
             # a materializing run is already a sync point: flush pending
             # runtime warnings so the per-step-sync loop warns promptly

@@ -3,8 +3,8 @@
 The measurement substrate for the whole framework (see
 docs/OBSERVABILITY.md). Two sub-facilities, individually switchable:
 
-  metrics  — process-wide counters/gauges/histograms with JSON and
-             Prometheus exposition. Enable with PTPU_METRICS=1; set
+  metrics  — process-wide counters/gauges/histograms/raw samples with
+             JSON and Prometheus exposition. Enable with PTPU_METRICS=1; set
              PTPU_METRICS_OUT=<path> to dump JSON at process exit.
   tracing  — nestable host spans exported as Chrome-trace/Perfetto
              JSON, forwarded to jax.profiler.TraceAnnotation (device
@@ -27,13 +27,14 @@ import time
 
 from . import flight_recorder, metrics, tracing
 from .metrics import (Counter, Gauge, Histogram,  # noqa: F401
-                      MetricsRegistry, counter, gauge, histogram, registry)
+                      MetricsRegistry, Samples, counter, gauge, histogram,
+                      registry, samples)
 from .tracing import span  # noqa: F401
 
 __all__ = ["metrics", "tracing", "flight_recorder", "span", "counter",
-           "gauge", "histogram", "registry", "enabled", "enable",
-           "disable", "dump_metrics", "dump_chrome_trace", "Counter",
-           "Gauge", "Histogram", "MetricsRegistry"]
+           "gauge", "histogram", "samples", "registry", "enabled",
+           "enable", "disable", "dump_metrics", "dump_chrome_trace",
+           "Counter", "Gauge", "Histogram", "Samples", "MetricsRegistry"]
 
 
 def enabled():

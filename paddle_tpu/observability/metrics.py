@@ -1,4 +1,5 @@
-"""Process-wide metrics registry (counters, gauges, histograms).
+"""Process-wide metrics registry (counters, gauges, histograms, raw
+samples).
 
 The measurement substrate every perf PR reports through (ROADMAP north
 star: before/after numbers come from the framework itself, not ad-hoc
@@ -23,14 +24,16 @@ thread and the main loop both live in one process) would drop updates
 without it.
 """
 
+import collections
 import json
 import math
 import sys
 import threading
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "registry", "counter", "gauge", "histogram", "enabled",
-           "enable", "disable", "dump_json", "to_prometheus", "reset"]
+__all__ = ["Counter", "Gauge", "Histogram", "Samples", "MetricsRegistry",
+           "registry", "counter", "gauge", "histogram", "samples",
+           "enabled", "enable", "disable", "dump_json", "to_prometheus",
+           "reset"]
 
 # default histogram bucket upper bounds, in seconds: 100us .. ~100s
 # exponential — wide enough for step times on one chip and compile times
@@ -187,6 +190,101 @@ class Histogram:
             for i, n in enumerate(self.bucket_counts)}}
 
 
+def _quantile_of_sorted(v, q):
+    at = q * (len(v) - 1)
+    lo = int(at)
+    hi = min(lo + 1, len(v) - 1)
+    return v[lo] + (v[hi] - v[lo]) * (at - lo)
+
+
+def exact_quantile(values, q):
+    """The q-quantile of raw values, linear between the two nearest
+    order statistics (``numpy.percentile``'s default), or None of
+    none."""
+    if not 0.0 <= q <= 1.0:
+        raise ValueError("quantile %r outside [0, 1]" % (q,))
+    return _quantile_of_sorted(sorted(values), q) if values else None
+
+
+SUMMARY_QUANTILES = (("p50", 0.5), ("p95", 0.95), ("p99", 0.99))
+
+
+def _summary(values):
+    d = {"count": len(values), "sum": float(sum(values))}
+    if values:
+        v = sorted(values)
+        for key, q in SUMMARY_QUANTILES:
+            d[key] = _quantile_of_sorted(v, q)
+        d["max"] = v[-1]
+    return d
+
+
+class Samples:
+    """Raw samples in a bounded ring: the newest ``maxlen`` win and the
+    evictions are counted. A sample is a number, or a record (a dict,
+    one per event: the serving step log) whose numeric ``fields`` are
+    the ones ``to_dict()`` and the Prometheus text summarise. Quantiles
+    are exact over what the ring holds, which is what a tail needs and
+    a bucketed :class:`Histogram` cannot give."""
+
+    __slots__ = ("name", "fields", "_ring", "added", "_restored", "_lock")
+
+    def __init__(self, name, maxlen=4096, fields=None):
+        self.name = name
+        self.fields = tuple(fields) if fields else None
+        self._ring = collections.deque(maxlen=int(maxlen))
+        self.added = 0
+        self._restored = None
+        self._lock = _make_lock("obs.metric")
+
+    @property
+    def maxlen(self):
+        return self._ring.maxlen
+
+    @property
+    def evicted(self):
+        return self.added - len(self._ring)
+
+    def add(self, sample):
+        with self._lock:
+            self._ring.append(sample)
+            self.added += 1
+
+    def records(self):
+        """What the ring holds, oldest first."""
+        with self._lock:
+            return list(self._ring)
+
+    def values(self, field=None):
+        """The samples themselves, or the records' ``field`` where a
+        record has one (None counts as absent)."""
+        recs = self.records()
+        if field is None:
+            return recs
+        return [r[field] for r in recs if r.get(field) is not None]
+
+    def quantile(self, q, field=None):
+        return exact_quantile(self.values(field), q)
+
+    def max(self, field=None):
+        return max(self.values(field), default=None)
+
+    def to_dict(self):
+        if self._restored is not None:
+            return self._restored
+        d = {"added": self.added, "evicted": self.evicted}
+        if self.fields is None:
+            return d | _summary(self.values())
+        return d | {"fields": {f: _summary(self.values(f))
+                               for f in self.fields}}
+
+    def restore(self, doc):
+        """Serve a dumped summary as it was dumped (tools/ptpu_stats.py
+        --prometheus): the raw samples did not travel with it."""
+        self._restored = dict(doc)
+        self.fields = tuple(doc["fields"]) if "fields" in doc else None
+
+
 class _NullMetric:
     """Shared no-op stand-in for every metric kind when telemetry is off:
     the instrumented call sites stay branch-free and allocation-free."""
@@ -194,6 +292,9 @@ class _NullMetric:
     __slots__ = ()
 
     def inc(self, n=1):
+        pass
+
+    def add(self, sample):
         pass
 
     def dec(self, n=1):
@@ -253,6 +354,9 @@ class MetricsRegistry:
                 % (name, h.buckets))
         return h
 
+    def samples(self, name, maxlen=4096, fields=None):
+        return self._get(name, Samples, maxlen, fields)
+
     def metrics(self):
         with self._lock:
             return dict(self._metrics)
@@ -265,8 +369,9 @@ class MetricsRegistry:
         out = {"counters": {}, "gauges": {}, "histograms": {}}
         for name, m in sorted(self.metrics().items()):
             kind = ("counters" if isinstance(m, Counter) else
-                    "gauges" if isinstance(m, Gauge) else "histograms")
-            out[kind][name] = m.to_dict()
+                    "gauges" if isinstance(m, Gauge) else
+                    "samples" if isinstance(m, Samples) else "histograms")
+            out.setdefault(kind, {})[name] = m.to_dict()
         return out
 
     def dump_json(self, path):
@@ -294,6 +399,19 @@ class MetricsRegistry:
             elif isinstance(m, Gauge):
                 lines.append("# TYPE %s gauge" % pn)
                 lines.append("%s %s" % (pn, _prom_num(m.value)))
+            elif isinstance(m, Samples):
+                d = m.to_dict()
+                for field, summary in (d["fields"].items()
+                                       if "fields" in d else [(None, d)]):
+                    fam = pn if field is None else pn + "_" + field
+                    lines.append("# TYPE %s summary" % fam)
+                    for key, q in SUMMARY_QUANTILES:
+                        if key in summary:
+                            lines.append('%s{quantile="%s"} %s' % (
+                                fam, q, _prom_num(float(summary[key]))))
+                    lines.append("%s_sum %s" % (
+                        fam, _prom_num(float(summary["sum"]))))
+                    lines.append("%s_count %d" % (fam, summary["count"]))
             else:
                 lines.append("# TYPE %s histogram" % pn)
                 cum = 0
@@ -373,6 +491,11 @@ def gauge(name):
 
 def histogram(name, buckets=None):
     return _REGISTRY.histogram(name, buckets) if _ENABLED else NULL_METRIC
+
+
+def samples(name, maxlen=4096, fields=None):
+    return (_REGISTRY.samples(name, maxlen, fields) if _ENABLED
+            else NULL_METRIC)
 
 
 def dump_json(path):

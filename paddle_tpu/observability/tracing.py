@@ -9,7 +9,10 @@ host spans show up on the XPlane timeline next to the XLA device rows —
 the CUPTI DeviceTracer correlation the reference had (SURVEY §5.1).
 Spans are additionally forwarded to the native C++ collector
 (native/profiler.cc ptpu_prof_mark) when it is loaded and enabled, so
-one chrome-trace dump can carry Python, C++, and device work.
+one chrome-trace dump can carry Python, C++, and device work. A span
+never loads (let alone builds) the library itself: only a caller that
+already loaded it (`profiler.start_profiler`) can have enabled its
+collector.
 
 Enablement mirrors metrics.py: OFF unless `PTPU_TRACE=1` or
 `PTPU_TRACE_DIR=<dir>` is set (or `enable()` is called); when off,
@@ -25,9 +28,9 @@ import os
 import threading
 import time
 
-__all__ = ["span", "complete", "instant", "new_trace_id", "enabled",
-           "enable", "disable", "events", "dump_chrome_trace", "reset",
-           "MAX_EVENTS"]
+__all__ = ["span", "complete", "instant", "annotation", "new_trace_id",
+           "enabled", "enable", "disable", "events", "dump_chrome_trace",
+           "reset", "MAX_EVENTS"]
 
 MAX_EVENTS = 200000
 
@@ -73,6 +76,17 @@ def _annotation(name):
         except Exception:
             return None
     return None
+
+
+def annotation(name):
+    """A host span on the PROFILER's clock only
+    (`jax.profiler.TraceAnnotation`: it lands on the `/host:CPU` plane
+    of a `jax.profiler` capture, beside the device rows, and costs about
+    a microsecond when no capture is running), or the null span where
+    jax is missing. Not gated on `enabled()`: the step logs
+    (`ptpu/engine.*`, `ptpu/exe.*`, docs/OBSERVABILITY.md) gate on their
+    own switch."""
+    return _annotation(name) or NULL_SPAN
 
 
 class _NullSpan:
@@ -162,17 +176,15 @@ def _record(ev):
 
 def _forward_native(name, us_start, us_end):
     """Mirror the span into the C++ collector when it is live+enabled,
-    so ptpu_prof_dump_chrome sees host spans too."""
-    try:
-        from ..core import native
-
-        l = native.lib()
-        if l is not None and l.ptpu_prof_enabled():
-            l.ptpu_prof_mark(name.encode(), us_start, us_end)
-    except Exception:
-        pass
+    so ptpu_prof_dump_chrome sees host spans too. `native.loaded()`,
+    never `native.lib()`: a span's exit must not run `make` (it did, on
+    the first traced step, inside the serving worker)."""
+    l = _native.loaded()
+    if l is not None and l.ptpu_prof_enabled():
+        l.ptpu_prof_mark(name.encode(), us_start, us_end)
 
 
+from ..core import native as _native  # stdlib-only, loads nothing
 from . import metrics as _metrics
 from .metrics import _env_on  # central flags-registry check
 

@@ -182,6 +182,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=_device.pallas_interpret(),
+        name="flash_attention",   # the kernel's row in a device trace
     )(qp, kp, vp)
     return out[:, :T].reshape(B, H, T, D)
 
@@ -366,6 +367,9 @@ def _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
             ]),
         out_shape=jax.ShapeDtypeStruct((B, C, HD), jnp.float32),
         interpret=_device.pallas_interpret(),
+        # its row in a device trace; unnamed, the TPU compiler called
+        # the call after the jitted function around it (`step`)
+        name="paged_attention_tree" if tree else "paged_attention",
     )(block_tables.astype(jnp.int32),
       jnp.stack([pos[:, 0], pos[:, C - 1]]),             # [2, B] span
       q.reshape(B, C, HD), k_pages.reshape(NB, bs, HD),
@@ -532,6 +536,7 @@ def int8_matmul(x, w_int8, dq_scale, act_scale, block_m=32, block_k=128,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=_device.pallas_interpret(),
+        name="int8_matmul",
     )(xp, wp, sp)
     return out[:M, :N]
 

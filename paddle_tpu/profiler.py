@@ -167,99 +167,3 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile",
         yield
     finally:
         stop_profiler(sorted_key, profile_path)
-
-
-def device_op_profile(trace_dir, top=None, _tool_data=None):
-    """Aggregate a `jax.profiler.trace` capture into the reference-style
-    per-op time table, keyed by FLUID op identity.
-
-    The descriptor lowering names every op's XLA region
-    `fluid/<op_type>__<first_output>` (core/lowering.py _op_scope_name via
-    jax.named_scope), XLA threads that through HLO metadata, and the
-    device trace's hlo_stats rows carry it back — so device time maps to
-    Fluid op names the way platform::RecordEvent tags kernels in the
-    reference (operator.cc:180-184; table format: profiler.cc
-    PrintProfiler "Event / Calls / Total / Ave").
-
-    Returns rows: {"op": fluid op identity, "type": op type, "calls": N,
-    "total_us": float, "avg_us": float, "share_pct": float}, sorted by
-    total descending. Use with:
-
-        with jax.profiler.trace(dir):
-            ... run steps ...
-        rows = profiler.device_op_profile(dir)
-
-    Device-op events require a real accelerator backend (XLA:CPU emits no
-    per-op device trace; on the CPU mesh this returns [])."""
-    import glob as _glob
-    import json as _json
-
-    if _tool_data is None:
-        paths = sorted(_glob.glob(
-            os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True))
-        if not paths:
-            return []
-        from xprof.convert import raw_to_tool_data as _r
-
-        data, _ = _r.xspace_to_tool_data(paths, "hlo_stats", {})
-        _tool_data = data.decode() if isinstance(data, (bytes, bytearray)) \
-            else data
-    parsed = _json.loads(_tool_data)
-    tbl = parsed[0] if isinstance(parsed, list) else parsed
-    labels = [str(c.get("label", "")).lower() for c in tbl.get("cols", [])]
-
-    def col_idx(label_part):
-        part = label_part.lower()
-        for i, lab in enumerate(labels):
-            if part in lab:
-                return i
-        return None
-
-    i_fw = col_idx("Framework op name")
-    i_occ = col_idx("#Occurrences")
-    i_total = col_idx("Total time (us)")
-    if i_fw is None or i_total is None:
-        return []
-
-    agg = {}
-    for r in tbl.get("rows", []):
-        cells = [cell.get("v") for cell in r.get("c", [])]
-        fw_name = str(cells[i_fw] or "")
-        if "fluid/" not in fw_name:
-            continue
-        ident = fw_name.split("fluid/", 1)[1].split("/", 1)[0]
-        occurrences = float(
-            cells[i_occ] or 0) if i_occ is not None else 0.0
-        total = float(cells[i_total] or 0.0)
-        a = agg.setdefault(ident, {"calls": 0.0, "total_us": 0.0})
-        a["calls"] = max(a["calls"], occurrences)
-        a["total_us"] += total
-    grand = sum(a["total_us"] for a in agg.values()) or 1.0
-    rows = []
-    for ident, a in agg.items():
-        calls = int(a["calls"]) or 1
-        rows.append({
-            "op": ident,
-            "type": ident.split("__", 1)[0],
-            "calls": calls,
-            "total_us": round(a["total_us"], 3),
-            "avg_us": round(a["total_us"] / calls, 3),
-            "share_pct": round(100.0 * a["total_us"] / grand, 2),
-        })
-    rows.sort(key=lambda r: -r["total_us"])
-    return rows[:top] if top else rows
-
-
-def print_device_op_profile(trace_dir, top=25):
-    """Print device_op_profile in the reference PrintProfiler layout."""
-    rows = device_op_profile(trace_dir, top=top)
-    if not rows:
-        print("no fluid-attributed device ops in trace (CPU backend?)")
-        return rows
-    print("%-44s %8s %14s %12s %8s" % ("Event", "Calls", "Total(us)",
-                                       "Ave(us)", "Ratio."))
-    for r in rows:
-        print("%-44s %8d %14.3f %12.3f %7.2f%%" % (
-            r["op"][:44], r["calls"], r["total_us"], r["avg_us"],
-            r["share_pct"]))
-    return rows

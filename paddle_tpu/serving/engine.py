@@ -64,7 +64,9 @@ kv_blocks_in_use,tokens_per_sec,request_latency(_p50/_p99),
 ttft(_p50/_p99),steps,prefill_tokens,decode_tokens,prefill_chunk_steps,
 prefix_blocks_reused,prefix_tokens_skipped,spec_steps,spec_proposed,
 spec_accepted,spec_rejected,spec_accept_rate,requests_submitted,
-requests_completed,requests_rejected,requests_failed}``.
+requests_completed,requests_rejected,requests_failed}``, and the step
+log ``serving/step``: one record per dispatched step, written by the
+worker where the work happens (:class:`_TickLog`).
 """
 
 import threading
@@ -85,6 +87,37 @@ from .scheduler import (AdmissionError, GenerationRequest, RequestQueue,
                         StepScheduler)
 
 __all__ = ["ServingEngine"]
+
+
+# the fields of a `serving/step` record that the registry dump and
+# /metrics summarise
+STEP_LOG_FIELDS = ("device_ms", "host_ms", "wait_ms")
+
+
+class _TickLog:
+    """The host stamps of one scheduler tick, taken only while metrics
+    or tracing are on (docs/OBSERVABILITY.md, "The serving step log").
+    A tick dispatches at most one step (``opened``: its record, which
+    gets this tick's host time) and consumes the result of at most one,
+    dispatched ``async_depth - 1`` ticks earlier (``done``; ``waited``
+    is the seconds this tick spent blocked on it)."""
+
+    __slots__ = ("t_tick", "t_planned", "waited", "opened", "done")
+
+    def __init__(self):
+        self.t_tick = self.t_planned = time.perf_counter()
+        self.waited = 0.0
+        self.opened = None
+        self.done = []
+
+
+def _phase(tick, name):
+    """The tick's phase on the profiler's clock (``ptpu/engine.<name>``
+    on the host plane of a `jax.profiler` capture, beside the device
+    rows) while the step log records; the shared null span otherwise."""
+    if tick is None:
+        return _tracing.NULL_SPAN
+    return _tracing.annotation("ptpu/engine." + name)
 
 
 class _ModelWorker:
@@ -158,7 +191,11 @@ class _ModelWorker:
         # with the per-step scheduling plan riding each admitted handle
         # so lagged processing can fold tokens back into sequences)
         self.async_depth = max(1, int(async_depth))
-        self._inflight = []  # [(next_tokens_handle, plan)], FIFO
+        # [(next_tokens_handle, plan, step-log record or None)], FIFO
+        self._inflight = []
+        self._tick_log = None      # this tick's _TickLog while recording
+        # (step, t_ready, t_ready is its completion) of the last record
+        self._last_consumed = None
 
         # isolated per-model scope: the weights the step consumes are
         # read from here each dispatch, so hot-swapping an entry (or
@@ -419,47 +456,56 @@ class _ModelWorker:
         # consistent, so a transient failure in this window is retried
         # in place by _run (the fault-injection sites fire here — BEFORE
         # any mutation — for exactly that reason)
-        self._tick_retryable = True
-        fault = _resil.maybe_inject_serve_fault(self._steps_dispatched)
-        if fault == "stall":
-            self._stall()
+        tick = self._tick_log = (
+            _TickLog() if _metrics.enabled() or _tracing.enabled()
+            else None)
         sched = self.scheduler
-        if self._track_deadlines:
-            sched.expire_deadlines(self.queue)
-        if self._pending_swap is None:
-            # a pending swap pauses admission so the active batch
-            # drains to the clean boundary the swap needs; queued
-            # requests wait and are served wholly on the new weights
-            sched.admit(self.queue)
-        _metrics.gauge("serving/queue_depth").set(len(self.queue))
-        self._tick_retryable = False
-        spec_plan = sched.plan_spec() if self.spec_k else None
+        plan, chunked = None, False
+        with _phase(tick, "plan"):
+            self._tick_retryable = True
+            fault = _resil.maybe_inject_serve_fault(self._steps_dispatched)
+            if fault == "stall":
+                self._stall()
+            if self._track_deadlines:
+                sched.expire_deadlines(self.queue)
+            if self._pending_swap is None:
+                # a pending swap pauses admission so the active batch
+                # drains to the clean boundary the swap needs; queued
+                # requests wait and are served wholly on the new weights
+                sched.admit(self.queue)
+            _metrics.gauge("serving/queue_depth").set(len(self.queue))
+            self._tick_retryable = False
+            spec_plan = sched.plan_spec() if self.spec_k else None
+            if not spec_plan:
+                if self.prefill_chunk:
+                    plan, chunked = sched.plan_chunk()
+                else:
+                    plan = sched.plan_step()
+        if tick is not None:
+            tick.t_planned = time.perf_counter()
         if spec_plan:
             # verify window: dispatched AND materialized in one round
             # (acceptance feeds the next window's drafts)
             self._dispatch_spec(spec_plan)
-        else:
-            if self.prefill_chunk:
-                plan, chunked = sched.plan_chunk()
-            else:
-                plan, chunked = sched.plan_step(), False
-            if plan:
-                self._dispatch(plan, chunked)
-                if self.spec_k:
-                    # spec mode is synchronous everywhere: the next
-                    # plan (a verify window) reads committed history
-                    while self._inflight:
-                        self._process_oldest()
-                elif len(self._inflight) > self.async_depth - 1:
+        elif plan:
+            self._dispatch(plan, chunked)
+            if self.spec_k:
+                # spec mode is synchronous everywhere: the next
+                # plan (a verify window) reads committed history
+                while self._inflight:
                     self._process_oldest()
-            elif self._inflight:
-                # nothing left to dispatch — drain the pipeline
+            elif len(self._inflight) > self.async_depth - 1:
                 self._process_oldest()
+        elif self._inflight:
+            # nothing left to dispatch — drain the pipeline
+            self._process_oldest()
         sched.reap()
         _metrics.gauge("serving/kv_blocks_in_use").set(
             self.pool.blocks_in_use)
         if self._lock_check:
             self._check_invariants()
+        if tick is not None:
+            self._close_tick(tick)
 
     def _check_invariants(self):
         """Step-boundary runtime audit (PTPU_LOCK_CHECK=1 only): the
@@ -519,13 +565,111 @@ class _ModelWorker:
                 detail=(self.name, "occupancy"))
         _conc.publish_metrics()
 
+    # -- the step log ---------------------------------------------------
+    def _open_record(self, tick, kind, rows, prefill_tokens, decode_tokens,
+                     slots_used, slots_total, traces0):
+        """The record of the step just dispatched: its dispatch side.
+        `_consume_record` adds the other side when the step's result is
+        taken, `_close_tick` this tick's host time."""
+        tick.opened = rec = {
+            "model": self.name, "step": self._steps_dispatched,
+            "kind": kind, "rows": rows,
+            "prefill_tokens": prefill_tokens,
+            "decode_tokens": decode_tokens,
+            "slots_used": slots_used, "slots_total": slots_total,
+            # steps dispatched before it whose results the host had not
+            # consumed yet: what may still be ahead of it on the device
+            "queued": len(self._inflight),
+            # the dispatch call traced or compiled (the first step of
+            # each shape): its times are not a warm step's
+            "cold": self.model.trace_count != traces0,
+            "t_tick": tick.t_tick, "t_planned": tick.t_planned,
+            "t_dispatched": time.perf_counter()}
+        return rec
+
+    def _materialize(self, tick, handle):
+        """A step's result on the host, and while the log records the
+        wait's stamps: (array, (was_ready, t_wait, t_ready) or None).
+        `was_ready`: the result was there before the host asked, so
+        `t_ready` is not the step's completion."""
+        if tick is None:
+            return np.asarray(handle), None
+        was_ready = handle.is_ready()
+        with _phase(tick, "wait"):
+            t_wait = time.perf_counter()
+            out = np.asarray(handle)
+            t_ready = time.perf_counter()
+        return out, (was_ready, t_wait, t_ready)
+
+    def _consume_record(self, tick, rec, waited):
+        """The consuming side, stamped once the step's tokens are
+        recorded and its stream callbacks have returned. The device
+        works through the steps in dispatch order, so while the host
+        was blocked on this result (`was_ready` false) `t_ready` is the
+        step's completion, and the step began when the one before it
+        completed, or when it was dispatched if nothing was queued
+        ahead of it: with steps queued and the device never idle,
+        `device_ms` is the device's own time for the step. It is None
+        where either end is not a completion the host saw."""
+        was_ready, t_wait, t_ready = waited
+        rec["t_wait"], rec["t_ready"] = t_wait, t_ready
+        rec["t_done"] = time.perf_counter()
+        rec["wait_ms"] = (t_ready - t_wait) * 1e3
+        prev = self._last_consumed
+        if not rec["queued"]:
+            began = rec["t_dispatched"]
+        elif prev is not None and prev[0] == rec["step"] - 1 and prev[2]:
+            began = prev[1]
+        else:
+            began = None
+        rec["device_ms"] = ((t_ready - began) * 1e3
+                            if began is not None and not was_ready
+                            else None)
+        self._last_consumed = (rec["step"], t_ready, not was_ready)
+        tick.waited += t_ready - t_wait
+        tick.done.append(rec)
+
+    def _close_tick(self, tick):
+        """The tick's host time goes to the step it dispatched: the
+        tick's wall time less its waits, whatever the host did in it
+        (planning and dispatching that step, an earlier step's token
+        and stream loop, reaping). A tick that only drains dispatched
+        nothing, and its host time is in no record. Then the records
+        whose results this tick consumed are complete (their own tick
+        closed earlier, or just now) and are written."""
+        t_end = time.perf_counter()
+        rec = tick.opened
+        if rec is not None:
+            rec["t_end"] = t_end
+            rec["tick_ms"] = (t_end - tick.t_tick) * 1e3
+            rec["host_ms"] = (t_end - tick.t_tick - tick.waited) * 1e3
+            # the step whose result this tick took, if it took one: the
+            # wait and the stream loop inside `tick_ms` are that record's
+            rec["consumed"] = tick.done[0]["step"] if tick.done else None
+        if not tick.done:
+            return
+        log = _metrics.samples("serving/step", fields=STEP_LOG_FIELDS)
+        traced = _tracing.enabled()
+        for rec in tick.done:
+            log.add(rec)
+            if traced:
+                fields = {k: v for k, v in rec.items()
+                          if not k.startswith("t_")}
+                _tracing.complete(
+                    "serving_spec_step" if rec["kind"] == "spec"
+                    else "serving_step", int(rec["t_tick"] * 1e9),
+                    int(rec["t_dispatched"] * 1e9), **fields)
+                _tracing.complete(
+                    "serving_wait", int(rec["t_wait"] * 1e9),
+                    int(rec["t_ready"] * 1e9), model=self.name,
+                    step=rec["step"])
+
     def _dispatch(self, plan, chunked=False):
         sched = self.scheduler
         occupancy = int(sched.active.sum())
-        traced = _tracing.enabled()
-        t0 = time.perf_counter_ns() if traced else 0
-        with _tracing.span("serving_step", model=self.name,
-                           occupancy=occupancy, chunked=chunked):
+        tick = self._tick_log
+        traces0 = self.model.trace_count
+        with _phase(tick, "dispatch"):
             weights = {n: self.scope.get(n) for n in self._weight_names}
             if chunked:
                 self.pool.k, self.pool.v, next_tokens = self._chunk_step(
@@ -540,30 +684,46 @@ class _ModelWorker:
                     sched.prompt_feed.copy(), sched.use_prompt.copy(),
                     self._prev_tokens, sched.positions.copy(),
                     sched.block_tables.copy(), sched.active.copy())
-        if traced:
-            # request-scoped view of the same step: one window event per
-            # traced request riding this dispatch, so a request's trace
-            # shows ITS prefill/decode activity, not just engine steps
-            t1 = time.perf_counter_ns()
-            for seq, gen_idx in plan:
-                tid = seq.request.trace_id
-                if tid is None:
-                    continue
-                prefill = (bool(sched.use_prompt[seq.slot]) if chunked
-                           else gen_idx is None)
-                _tracing.complete(
-                    "prefill_chunk" if prefill else "decode_window",
-                    t0, t1, trace_id=tid, request=seq.request.id,
-                    model=self.name)
-        self._prev_tokens = next_tokens
-        self._inflight.append((next_tokens, plan))
-        _metrics.gauge("serving/inflight_steps").set(len(self._inflight))
         self._steps_dispatched += 1
+        rec = None
+        if tick is not None:
+            if chunked:
+                n_prefill = int(sched.chunk_lens[sched.use_prompt].sum())
+                n_decode = len(plan) - int(sched.use_prompt.sum())
+                slots_total = self.max_batch * self.prefill_chunk
+            else:
+                n_prefill = sum(1 for _seq, g in plan if g is None)
+                n_decode = len(plan) - n_prefill
+                slots_total = self.max_batch
+            rec = self._open_record(
+                tick, "mixed" if chunked else "decode", occupancy,
+                n_prefill, n_decode, n_prefill + n_decode, slots_total,
+                traces0)
+            if _tracing.enabled():
+                # request-scoped view of the same step: one window event
+                # per traced request riding this dispatch, so a
+                # request's trace shows ITS prefill/decode activity, not
+                # just engine steps
+                t0 = int(rec["t_planned"] * 1e9)
+                t1 = int(rec["t_dispatched"] * 1e9)
+                for seq, gen_idx in plan:
+                    tid = seq.request.trace_id
+                    if tid is None:
+                        continue
+                    prefill = (bool(sched.use_prompt[seq.slot]) if chunked
+                               else gen_idx is None)
+                    _tracing.complete(
+                        "prefill_chunk" if prefill else "decode_window",
+                        t0, t1, trace_id=tid, request=seq.request.id,
+                        model=self.name)
+        self._prev_tokens = next_tokens
+        self._inflight.append((next_tokens, plan, rec))
+        _metrics.gauge("serving/inflight_steps").set(len(self._inflight))
         now = time.perf_counter()
         if self._t_first_step is None:
             self._t_first_step = now
         self._t_last_step = now
-        if _metrics.enabled():
+        if rec is not None and _metrics.enabled():
             reg = _metrics.registry()
             reg.counter("serving/steps").inc()
             reg.gauge("serving/batch_occupancy").set(occupancy)
@@ -572,16 +732,9 @@ class _ModelWorker:
                 peak.set(occupancy)
             if chunked:
                 reg.counter("serving/prefill_chunk_steps").inc()
-                n_prefill = int(
-                    sched.chunk_lens[sched.use_prompt].sum())
-                n_decode = len(plan) - int(sched.use_prompt.sum())
-                reg.counter("serving/prefill_tokens").inc(n_prefill)
-                reg.counter("serving/decode_tokens").inc(n_decode)
-            else:
-                n_prefill = sum(1 for _seq, g in plan if g is None)
-                reg.counter("serving/prefill_tokens").inc(n_prefill)
-                reg.counter("serving/decode_tokens").inc(
-                    len(plan) - n_prefill)
+            reg.counter("serving/prefill_tokens").inc(
+                rec["prefill_tokens"])
+            reg.counter("serving/decode_tokens").inc(rec["decode_tokens"])
 
     def _dispatch_spec(self, plan):
         """Dispatch one speculative verify window and fold it back
@@ -589,14 +742,11 @@ class _ModelWorker:
         depend on the materialized tokens, so spec steps run
         synchronously — the tokens-per-step win replaces the
         async-depth pipelining (docs/SERVING.md)."""
-        import jax.numpy as jnp
-
         sched = self.scheduler
         occupancy = int(sched.active.sum())
-        traced = _tracing.enabled()
-        t0 = time.perf_counter_ns() if traced else 0
-        with _tracing.span("serving_spec_step", model=self.name,
-                           occupancy=occupancy):
+        tick = self._tick_log
+        traces0 = self.model.trace_count
+        with _phase(tick, "dispatch"):
             weights = {n: self.scope.get(n) for n in self._weight_names}
             self.pool.k, self.pool.v, out = self._spec_step(
                 weights, self.pool.k, self.pool.v,
@@ -604,9 +754,17 @@ class _ModelWorker:
                 self._prev_tokens, sched.positions.copy(),
                 sched.spec_lens.copy(), sched.block_tables.copy(),
                 sched.active.copy())
-        outs = np.asarray(out)  # materialize NOW (the sync contract)
-        if traced:
-            t1 = time.perf_counter_ns()
+        self._steps_dispatched += 1
+        rec = None
+        if tick is not None:
+            rec = self._open_record(
+                tick, "spec", occupancy, 0, occupancy,
+                int(sched.spec_lens[sched.active].sum()),
+                self.max_batch * sched.spec_feed.shape[1], traces0)
+        # materialize NOW (the sync contract)
+        outs, waited = self._materialize(tick, out)
+        if rec is not None and _tracing.enabled():
+            t0, t1 = int(rec["t_planned"] * 1e9), int(waited[2] * 1e9)
             for seq, window in plan:
                 tid = seq.request.trace_id
                 if tid is not None:
@@ -614,11 +772,41 @@ class _ModelWorker:
                         "spec_window", t0, t1, trace_id=tid,
                         request=seq.request.id, model=self.name,
                         window=len(window))
-        self._steps_dispatched += 1
         now = time.perf_counter()
         if self._t_first_step is None:
             self._t_first_step = now
         self._t_last_step = now
+        with _phase(tick, "stream"):
+            n_emitted = self._fold_spec(plan, outs)
+        self._gen_tokens += n_emitted
+        if (self._t_first_step is not None
+                and self._t_last_step > self._t_first_step):
+            _metrics.gauge("serving/tokens_per_sec").set(
+                self._gen_tokens
+                / (self._t_last_step - self._t_first_step))
+        if rec is not None:
+            # a window's decode tokens are what it emitted (known only
+            # now): the accepted run plus the correction token
+            rec["decode_tokens"] = n_emitted
+            self._consume_record(tick, rec, waited)
+        if _metrics.enabled():
+            reg = _metrics.registry()
+            reg.counter("serving/steps").inc()
+            reg.gauge("serving/batch_occupancy").set(occupancy)
+            peak = reg.gauge("serving/peak_batch_occupancy")
+            if occupancy > peak.value:
+                peak.set(occupancy)
+            reg.counter("serving/decode_tokens").inc(n_emitted)
+            reg.gauge("serving/spec_accept_rate").set(
+                sched.spec_accepted / max(1, sched.spec_proposed))
+
+    def _fold_spec(self, plan, outs):
+        """Fold a materialized verify window back into its sequences
+        (acceptance, rollback, stream callbacks); returns the tokens
+        emitted."""
+        import jax.numpy as jnp
+
+        sched = self.scheduler
         n_emitted = 0
         # decode rows that later ride a mixed prefill step chain their
         # input from prev_tokens — re-point each spec row's entry at
@@ -676,37 +864,26 @@ class _ModelWorker:
                 if seq.request.finished and not was_done:
                     self._note_completion(seq.request)
         self._prev_tokens = jnp.asarray(prev)
-        self._gen_tokens += n_emitted
-        if (self._t_first_step is not None
-                and self._t_last_step > self._t_first_step):
-            _metrics.gauge("serving/tokens_per_sec").set(
-                self._gen_tokens
-                / (self._t_last_step - self._t_first_step))
-        if _metrics.enabled():
-            reg = _metrics.registry()
-            reg.counter("serving/steps").inc()
-            reg.gauge("serving/batch_occupancy").set(occupancy)
-            peak = reg.gauge("serving/peak_batch_occupancy")
-            if occupancy > peak.value:
-                peak.set(occupancy)
-            reg.counter("serving/decode_tokens").inc(n_emitted)
-            reg.gauge("serving/spec_accept_rate").set(
-                sched.spec_accepted / max(1, sched.spec_proposed))
+        return n_emitted
 
     def _process_oldest(self):
-        handle, plan = self._inflight.pop(0)
+        handle, plan, rec = self._inflight.pop(0)
         _metrics.gauge("serving/inflight_steps").set(len(self._inflight))
-        tokens = np.asarray(handle)
-        for seq, gen_idx in plan:
-            was_done = seq.request.finished
-            had_first = seq.request.first_token_time is not None
-            self.scheduler.record_token(seq, gen_idx,
-                                        tokens[seq.slot])
-            if (not had_first
-                    and seq.request.first_token_time is not None):
-                self._note_first_token(seq.request)
-            if seq.request.finished and not was_done:
-                self._note_completion(seq.request)
+        tick = self._tick_log if rec is not None else None
+        tokens, waited = self._materialize(tick, handle)
+        with _phase(tick, "stream"):
+            for seq, gen_idx in plan:
+                was_done = seq.request.finished
+                had_first = seq.request.first_token_time is not None
+                self.scheduler.record_token(seq, gen_idx,
+                                            tokens[seq.slot])
+                if (not had_first
+                        and seq.request.first_token_time is not None):
+                    self._note_first_token(seq.request)
+                if seq.request.finished and not was_done:
+                    self._note_completion(seq.request)
+        if tick is not None:
+            self._consume_record(tick, rec, waited)
         if gen_tokens := sum(1 for _, g in plan if g is not None):
             self._gen_tokens += gen_tokens
             if (self._t_first_step is not None

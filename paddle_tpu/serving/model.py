@@ -718,34 +718,47 @@ class GenerationModel:
             q = q.reshape(B, H, Dh)
             k_new = k_new.reshape(B, H, Dh)
             v_new = v_new.reshape(B, H, Dh)
-            kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
-            kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
-            if use_paged:
-                ctx = paged_attention(
-                    kv_k[i], kv_v[i], q[:, None], block_tables,
-                    positions[:, None], sm_scale=sm_scale)
-                ctx = ctx[:, 0].reshape(B, -1)
-            else:
-                # paged gather: [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]
-                k_ctx = kv_k[i][block_tables].reshape(B, max_ctx, H, Dh)
-                v_ctx = kv_v[i][block_tables].reshape(B, max_ctx, H, Dh)
-                scores = jnp.einsum("bhd,bthd->bht", q, k_ctx) * sm_scale
-                scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
-                w = jnp.exp(scores
-                            - jnp.max(scores, axis=-1, keepdims=True))
-                w = w / jnp.sum(w, axis=-1, keepdims=True)
-                ctx = jnp.einsum("bht,bthd->bhd", w, v_ctx) \
-                    .reshape(B, -1)
-            x = x + ctx @ self._w(jnp, weights, p + "wproj") \
-                + weights[p + "bproj"]
-            b2 = ln(x, weights[p + "ln2_scale"], weights[p + "ln2_bias"])
-            f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
-                            + weights[p + "bff1"], approximate=False)
-            x = x + f @ self._w(jnp, weights, p + "wff2") \
-                + weights[p + "bff2"]
+            with jax.named_scope("kv_write"):
+                kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
+                kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
+            with jax.named_scope("kv_read"):
+                # the layer's pages, sliced out of the 5-D pool
+                k_pages, v_pages = kv_k[i], kv_v[i]
+            with jax.named_scope("attention"):
+                if use_paged:
+                    ctx = paged_attention(
+                        k_pages, v_pages, q[:, None], block_tables,
+                        positions[:, None], sm_scale=sm_scale)
+                    ctx = ctx[:, 0].reshape(B, -1)
+                else:
+                    # paged gather: [B, Mb, bs, H, Dh]
+                    # -> [B, max_ctx, H, Dh]
+                    k_ctx = k_pages[block_tables].reshape(
+                        B, max_ctx, H, Dh)
+                    v_ctx = v_pages[block_tables].reshape(
+                        B, max_ctx, H, Dh)
+                    scores = jnp.einsum("bhd,bthd->bht", q, k_ctx) \
+                        * sm_scale
+                    scores = jnp.where(valid[:, None, :], scores,
+                                       -jnp.inf)
+                    w = jnp.exp(scores
+                                - jnp.max(scores, axis=-1, keepdims=True))
+                    w = w / jnp.sum(w, axis=-1, keepdims=True)
+                    ctx = jnp.einsum("bht,bthd->bhd", w, v_ctx) \
+                        .reshape(B, -1)
+                x = x + ctx @ self._w(jnp, weights, p + "wproj") \
+                    + weights[p + "bproj"]
+            with jax.named_scope("ffn"):
+                b2 = ln(x, weights[p + "ln2_scale"],
+                        weights[p + "ln2_bias"])
+                f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
+                                + weights[p + "bff1"], approximate=False)
+                x = x + f @ self._w(jnp, weights, p + "wff2") \
+                    + weights[p + "bff2"]
 
-        x = ln(x, weights["final_ln_scale"], weights["final_ln_bias"])
-        return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
+        with jax.named_scope("head"):
+            x = ln(x, weights["final_ln_scale"], weights["final_ln_bias"])
+            return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
 
     def make_decode_step(self, max_batch, max_blocks_per_seq,
                          return_logits=False):
@@ -763,8 +776,8 @@ class GenerationModel:
         pe = jnp.asarray(_position_encoding_table(cfg))
         emb_scale = float(cfg.d_model) ** 0.5
 
-        def step(weights, kv_k, kv_v, prompt_feed, use_prompt,
-                 prev_tokens, positions, block_tables, active):
+        def decode_step(weights, kv_k, kv_v, prompt_feed, use_prompt,
+                        prev_tokens, positions, block_tables, active):
             self.trace_count += 1
             tok = jnp.where(use_prompt, prompt_feed, prev_tokens)
             tok = jnp.clip(tok, 0, cfg.vocab_size - 1)
@@ -786,7 +799,7 @@ class GenerationModel:
             return kv_k, kv_v, next_tokens
 
         jitted = self._instrument_step("decode", jax.jit(
-            step, donate_argnums=(1, 2)))
+            decode_step, donate_argnums=(1, 2)))
         self._steps[key] = jitted
         return jitted
 
@@ -911,46 +924,59 @@ class GenerationModel:
             q = q.reshape(B, C, H, Dh)
             k_new = k_new.reshape(B, C, H, Dh)
             v_new = v_new.reshape(B, C, H, Dh)
-            kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
-            kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
-            if use_paged:
-                if tree_anc is None:
-                    ctx = paged_attention(
-                        kv_k[i], kv_v[i], q, block_tables, pos2d,
-                        sm_scale=sm_scale).reshape(B, C, -1)
+            with jax.named_scope("kv_write"):
+                kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
+                kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
+            with jax.named_scope("kv_read"):
+                # the layer's pages, sliced out of the 5-D pool
+                k_pages, v_pages = kv_k[i], kv_v[i]
+            with jax.named_scope("attention"):
+                if use_paged:
+                    if tree_anc is None:
+                        ctx = paged_attention(
+                            k_pages, v_pages, q, block_tables, pos2d,
+                            sm_scale=sm_scale).reshape(B, C, -1)
+                    else:
+                        ctx = paged_attention_tree(
+                            k_pages, v_pages, q, block_tables, pos2d,
+                            anc_f, sm_scale=sm_scale).reshape(B, C, -1)
                 else:
-                    ctx = paged_attention_tree(
-                        kv_k[i], kv_v[i], q, block_tables, pos2d,
-                        anc_f, sm_scale=sm_scale).reshape(B, C, -1)
-            else:
-                # paged gather: [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]
-                k_ctx = kv_k[i][block_tables].reshape(B, max_ctx, H, Dh)
-                v_ctx = kv_v[i][block_tables].reshape(B, max_ctx, H, Dh)
-                scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) \
-                    * sm_scale
-                scores = jnp.where(attn_valid[:, :, None, :], scores,
-                                   -jnp.inf)
-                w = jnp.exp(scores
-                            - jnp.max(scores, axis=-1, keepdims=True))
-                w = w / jnp.sum(w, axis=-1, keepdims=True)
-                ctx = jnp.einsum("bcht,bthd->bchd", w, v_ctx) \
-                    .reshape(B, C, -1)
-            x = x + ctx @ self._w(jnp, weights, p + "wproj") \
-                + weights[p + "bproj"]
-            b2 = ln(x, weights[p + "ln2_scale"], weights[p + "ln2_bias"])
-            f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
-                            + weights[p + "bff1"], approximate=False)
-            x = x + f @ self._w(jnp, weights, p + "wff2") \
-                + weights[p + "bff2"]
+                    # paged gather: [B, Mb, bs, H, Dh]
+                    # -> [B, max_ctx, H, Dh]
+                    k_ctx = k_pages[block_tables].reshape(
+                        B, max_ctx, H, Dh)
+                    v_ctx = v_pages[block_tables].reshape(
+                        B, max_ctx, H, Dh)
+                    scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) \
+                        * sm_scale
+                    scores = jnp.where(attn_valid[:, :, None, :], scores,
+                                       -jnp.inf)
+                    w = jnp.exp(scores
+                                - jnp.max(scores, axis=-1, keepdims=True))
+                    w = w / jnp.sum(w, axis=-1, keepdims=True)
+                    ctx = jnp.einsum("bcht,bthd->bchd", w, v_ctx) \
+                        .reshape(B, C, -1)
+                x = x + ctx @ self._w(jnp, weights, p + "wproj") \
+                    + weights[p + "bproj"]
+            with jax.named_scope("ffn"):
+                b2 = ln(x, weights[p + "ln2_scale"],
+                        weights[p + "ln2_bias"])
+                f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
+                                + weights[p + "bff1"], approximate=False)
+                x = x + f @ self._w(jnp, weights, p + "wff2") \
+                    + weights[p + "bff2"]
 
-        if all_slots:
-            x = ln(x, weights["final_ln_scale"], weights["final_ln_bias"])
-            return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
-        last = jnp.clip(lengths - 1, 0, C - 1).astype(jnp.int32)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-        x_last = ln(x_last, weights["final_ln_scale"],
-                    weights["final_ln_bias"])
-        return kv_k, kv_v, x_last @ self._w(jnp, weights, "lm_head")
+        with jax.named_scope("head"):
+            if all_slots:
+                x = ln(x, weights["final_ln_scale"],
+                       weights["final_ln_bias"])
+                return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
+            last = jnp.clip(lengths - 1, 0, C - 1).astype(jnp.int32)
+            x_last = jnp.take_along_axis(x, last[:, None, None],
+                                         axis=1)[:, 0]
+            x_last = ln(x_last, weights["final_ln_scale"],
+                        weights["final_ln_bias"])
+            return kv_k, kv_v, x_last @ self._w(jnp, weights, "lm_head")
 
     def make_prefill_step(self, max_batch, max_blocks_per_seq, chunk,
                           return_logits=False):
@@ -1039,6 +1065,9 @@ class GenerationModel:
                 return kv_k, kv_v, next_tokens, logits
             return kv_k, kv_v, next_tokens
 
+        # the program's name in a device trace (`XLA Modules`:
+        # jit_chunk_step, jit_spec_step, jit_spec_tree_step)
+        step.__name__ = step.__qualname__ = kind + "_step"
         jitted = self._instrument_step(kind, jax.jit(
             step, donate_argnums=(1, 2)))
         self._steps[key] = jitted
@@ -1141,8 +1170,8 @@ class GenerationModel:
 
         C = int(window)
 
-        def commit(kv_k, kv_v, positions, src_slots, n_commit,
-                   block_tables, active):
+        def tree_commit_step(kv_k, kv_v, positions, src_slots, n_commit,
+                             block_tables, active):
             self.trace_count += 1
             Mb = block_tables.shape[1]
             bs = kv_k.shape[2]
@@ -1167,7 +1196,7 @@ class GenerationModel:
             return kv_k, kv_v
 
         jitted = self._instrument_step("tree_commit", jax.jit(
-            commit, donate_argnums=(0, 1)))
+            tree_commit_step, donate_argnums=(0, 1)))
         self._steps[key] = jitted
         return jitted
 
@@ -1214,8 +1243,8 @@ class GenerationModel:
             return (emb * emb_scale * cfg.pe_alpha
                     + cfg.pe_beta * jnp.take(pe, pe_idx, axis=0))
 
-        def draft(weights, kv_k, kv_v, first_tokens, positions,
-                  block_tables, active):
+        def draft_step(weights, kv_k, kv_v, first_tokens, positions,
+                       block_tables, active):
             self.trace_count += 1
 
             def micro(carry, i):
@@ -1234,7 +1263,7 @@ class GenerationModel:
             return kv_k, kv_v, jnp.transpose(toks)      # [B, n_new]
 
         jitted = self._instrument_step("draft", jax.jit(
-            draft, donate_argnums=(1, 2)))
+            draft_step, donate_argnums=(1, 2)))
         self._steps[key] = jitted
         return jitted
 

@@ -64,51 +64,6 @@ def test_reset_clears_stats(capsys):
     assert "gone" not in out
 
 
-def test_device_op_profile_aggregation():
-    """Aggregation of hlo_stats tool rows into the reference PrintProfiler
-    table (profiler.cc parity): groups HLO rows by fluid op identity,
-    sums totals, keeps call counts, computes shares. Uses injected tool
-    data (XLA:CPU emits no per-op device trace; on TPU the same path is
-    fed by xprof from a real jax.profiler capture — see
-    profiler.device_op_profile docstring)."""
-    import json
-
-    from paddle_tpu import profiler
-
-    cols = [{"id": "rank", "label": "Rank"},
-            {"id": "program_id", "label": "Program id"},
-            {"id": "category", "label": "HLO op category"},
-            {"id": "name", "label": "HLO op name"},
-            {"id": "text", "label": "HLO op text"},
-            {"id": "fw", "label": "Framework op name"},
-            {"id": "occ", "label": "#Occurrences"},
-            {"id": "total", "label": "Total time (us)"},
-            {"id": "avg", "label": "Avg. time (us)"}]
-
-    def row(fw, occ, total):
-        vals = [0, 1, "fusion", "f", "t", fw, occ, total, total / occ]
-        return {"c": [{"v": v} for v in vals]}
-
-    tool = json.dumps([{
-        "cols": cols,
-        "rows": [
-            row("jit(step)/fluid/mul__fc_0.tmp_0/dot", 5, 100.0),
-            row("jit(step)/fluid/mul__fc_0.tmp_0/convert", 5, 20.0),
-            row("jit(step)/fluid/softmax__fc_1.tmp_2", 5, 30.0),
-            row("jit(step)/not_fluid_thing", 5, 999.0),
-        ]}])
-    rows = profiler.device_op_profile("/nonexistent", _tool_data=tool)
-    assert [r["op"] for r in rows] == ["mul__fc_0.tmp_0",
-                                      "softmax__fc_1.tmp_2"]
-    mul = rows[0]
-    assert mul["type"] == "mul" and mul["calls"] == 5
-    assert abs(mul["total_us"] - 120.0) < 1e-6
-    assert abs(mul["avg_us"] - 24.0) < 1e-6
-    assert abs(mul["share_pct"] - 80.0) < 1e-6
-    # empty trace dir -> [] (CPU mesh path)
-    assert profiler.device_op_profile("/nonexistent/none") == []
-
-
 def test_named_scopes_reach_lowered_hlo():
     """Every descriptor op's identity must appear in the lowered module
     (jax.named_scope threading — the attribution the trace table keys on)."""

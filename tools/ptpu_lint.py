@@ -497,9 +497,9 @@ class _Linter(ast.NodeVisitor):
             elif func.attr == "setenv" and len(node.args) >= 2 \
                     and _const_str(node.args[0]) == "PTPU_FAULT_INJECT":
                 self._check_fault_spec(node, _const_str(node.args[1]))
-            # metric name literals: counter/gauge/histogram("a/b")
-            if func.attr in ("counter", "gauge", "histogram") \
-                    and node.args:
+            # metric name literals: counter/gauge/histogram/samples("a/b")
+            if func.attr in ("counter", "gauge", "histogram",
+                             "samples") and node.args:
                 name = _const_str(node.args[0])
                 if name and "/" in name and name not in self.doc_text:
                     self._add(node, "metric-undocumented",

@@ -3,9 +3,9 @@ profiler PrintProfiler tables, now fed from files instead of process
 state).
 
 Accepts either exposition schema the framework writes:
-  - a registry dump ({"counters": ..., "gauges": ..., "histograms": ...})
-    from PTPU_METRICS_OUT / MetricsRegistry.dump_json / bench.py
-    --metrics-out
+  - a registry dump ({"counters": ..., "gauges": ..., "histograms": ...,
+    and "samples" where the run took raw samples) from PTPU_METRICS_OUT /
+    MetricsRegistry.dump_json / bench.py --metrics-out
   - a native stats dump ({"stats": {name: {count,sum,min,max,avg}}})
     from native_serve --train-loop --metrics-out (profiler.cc)
 
@@ -81,6 +81,23 @@ def render(doc, out=None):
                 _fmt(h.get("min") if count else None),
                 _fmt(h.get("max") if count else None)))
         wrote = True
+    samples = doc.get("samples", {})
+    if samples:
+        if wrote:
+            out.write("\n")
+        # raw samples: exact quantiles over what the ring still held
+        out.write("%-44s %8s %12s %12s %12s %12s\n" % (
+            "Samples", "Count", "P50", "P95", "P99", "Max"))
+        for name in sorted(samples):
+            s = samples[name]
+            for field, row in (s["fields"].items() if "fields" in s
+                               else [(None, s)]):
+                out.write("%-44s %8d %12s %12s %12s %12s\n" % (
+                    name if field is None else "%s.%s" % (name, field),
+                    row.get("count", 0), _fmt(row.get("p50")),
+                    _fmt(row.get("p95")), _fmt(row.get("p99")),
+                    _fmt(row.get("max"))))
+        wrote = True
     if not wrote:
         out.write("(no metrics)\n")
 
@@ -121,6 +138,8 @@ def _to_prometheus(doc):
         reg.gauge(name).set(v)
     for name, h in doc.get("histograms", {}).items():
         _fill(name, h)
+    for name, summary in doc.get("samples", {}).items():
+        reg.samples(name).restore(summary)
     return reg.to_prometheus()
 
 
@@ -258,13 +277,16 @@ def _lookup(doc, name):
     for kind in ("histograms", "stats"):
         if name in doc.get(kind, {}):
             return True, float(doc[kind][name].get("count", 0))
+    if name in doc.get("samples", {}):
+        return True, float(doc["samples"][name].get("added", 0))
     return False, None
 
 
 def check_assertions(doc, has, mins, maxs=None):
     """CI gating: every `has` name must exist in the dump; every
     `mins`/`maxs` "name=value" must exist with numeric value >=/<= the
-    bound (histograms compare their observation count). A NaN value
+    bound (histograms compare their observation count, raw samples the
+    number added). A NaN value
     fails ANY bound comparison loudly — NaN compares false against
     everything, so without the explicit check a poisoned metric would
     sail through `--assert-max` (and a NaN bound would never fire).
