@@ -1310,6 +1310,8 @@ def bench_kernels(repeats=30, warmup=3):
     about the kernel.
 
     Returns {kernel: {max_err}} plus {pallas_s, lax_s, speedup} on TPU."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
@@ -1341,22 +1343,24 @@ def bench_kernels(repeats=30, warmup=3):
 
     # paged attention: decode window (C=1) and spec verify window (C=4)
     NB, bs, H, Dh, B, Mb = 64, 16, 4, 64, 8, 8
-    k_pages = jnp.asarray(rng.randn(NB + 1, bs, H, Dh)
-                          .astype(np.float32))
-    v_pages = jnp.asarray(rng.randn(NB + 1, bs, H, Dh)
-                          .astype(np.float32))
+    # a one-layer pool, [L, NB + 1, bs, H, Dh]: the kernels take the
+    # pool whole
+    k_pool = jnp.asarray(rng.randn(1, NB + 1, bs, H, Dh)
+                         .astype(np.float32))
+    v_pool = jnp.asarray(rng.randn(1, NB + 1, bs, H, Dh)
+                         .astype(np.float32))
     tables = jnp.asarray(
         rng.permutation(NB)[:B * Mb].reshape(B, Mb).astype(np.int32) + 1)
-    pallas_fn = jax.jit(pk.paged_attention)
-    lax_fn = jax.jit(pk.paged_attention_reference)
+    pallas_fn = jax.jit(functools.partial(pk.paged_attention, layer=0))
+    lax_fn = jax.jit(functools.partial(pk.paged_attention_reference,
+                                       layer=0))
     for name, C in (("paged_decode", 1), ("spec_window", 4)):
         q = jnp.asarray(rng.randn(B, C, H, Dh).astype(np.float32))
         pos = jnp.asarray(
             np.tile(np.arange(Mb * bs - C, Mb * bs, dtype=np.int32),
                     (B, 1)))
-        t_pallas, got = timed(pallas_fn, k_pages, v_pages, q, tables,
-                              pos)
-        t_lax, want = timed(lax_fn, k_pages, v_pages, q, tables, pos)
+        t_pallas, got = timed(pallas_fn, k_pool, v_pool, q, tables, pos)
+        t_lax, want = timed(lax_fn, k_pool, v_pool, q, tables, pos)
         results[name] = receipt(t_pallas, t_lax, got, want)
 
     # fused int8 matmul vs the unfused chain (bitwise-identical)

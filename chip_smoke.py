@@ -458,7 +458,10 @@ def kernel_cases(sz):
     cases = {}
 
     def paged(name, C, anc=None):
-        specs = [((NB, bs, H, Dh), f32), ((NB, bs, H, Dh), f32),
+        # the pool goes in whole, as KVBlockPool stores it; two layers,
+        # the second one read, so the kernel's layer index is exercised
+        pool = (2, NB, bs, H, Dh)
+        specs = [(pool, f32), (pool, f32),
                  ((B, C, H, Dh), f32), ((B, Mb), i32), ((B, C), i32)]
         if anc is not None:
             specs.append(((C, C), f32))
@@ -468,8 +471,8 @@ def kernel_cases(sz):
             out = [rng.randn(*s).astype(f32) for s, _ in specs[:3]]
             return out + [tables, pos] + ([anc] if anc is not None else [])
 
-        cases[name] = (specs, {}, dict(head_dim=Dh, block_size=bs,
-                                       window=C), fill)
+        cases[name] = (specs, {"layer": 1},
+                       dict(head_dim=Dh, block_size=bs, window=C), fill)
 
     from paddle_tpu.serving.model import tree_topology
 

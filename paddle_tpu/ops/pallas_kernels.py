@@ -17,7 +17,9 @@ paged_attention: decode-side attention that reads the serving
   happens inside the kernel via scalar-prefetch BlockSpec index maps, the
   PagedAttention formulation) — the per-step contiguous
   ``kv[block_tables].reshape(...)`` gather the XLA path materializes
-  disappears. One kernel serves both the one-token decode window (C=1,
+  disappears. It takes the pool whole and resolves the layer in the same
+  index map, so nothing of a layer's size is sliced out for the call.
+  One kernel serves both the one-token decode window (C=1,
   ``kernel 'paged_decode'``) and the speculative verify window (C=k+1,
   ``kernel 'spec_window'``).
 
@@ -241,20 +243,23 @@ def attention_reference(q, k, v, causal=True, sm_scale=None):
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(tables_ref, span_ref, q_ref, k_ref, v_ref, vis_ref,
-                       o_ref, m_scr, l_scr, acc_scr, *, sm_scale,
+def _paged_attn_kernel(tables_ref, span_ref, layer_ref, q_ref, k_ref, v_ref,
+                       vis_ref, o_ref, m_scr, l_scr, acc_scr, *, sm_scale,
                        block_size, n_heads, tree):
     """Grid (B, Mb); j (the block-table slot) is innermost, carrying
     the online-softmax state of every head across one row's pages. The
-    k/v BlockSpec index maps already resolved table slot j to its
-    PHYSICAL page (null pages land here too — harmless, their logical
-    positions are masked or the whole block is skipped).
+    k/v BlockSpec index maps already resolved the layer (``layer_ref``,
+    read by the index maps only) and table slot j to its PHYSICAL page
+    (null pages land here too — harmless, their logical positions are
+    masked or the whole block is skipped).
 
-    A block is one whole page with the heads folded into the lane axis,
-    ``[block_size, H*Dh]``: Mosaic tiles the last two axes of a block
-    in (8, 128) units, so a block that took one head out of the
-    second-to-last axis (``(1, bs, 1, Dh)``) is refused. The heads are
-    walked with static lane slices inside the kernel.
+    A block is one whole page as the pool stores it, ``[block_size, H,
+    Dh]``: Mosaic tiles the last two axes of a block in (8, 128) units,
+    so a block that took one head out of the second-to-last axis
+    (``(1, bs, 1, Dh)``) is refused, while a whole page equals the
+    array in both. A head's ``[block_size, Dh]`` rows are read out of
+    the page with a static index on the head axis (a strided load);
+    the query window and the output keep the heads in the lane axis.
 
     ``span_ref`` (scalar prefetch, ``[2, B]``) holds each row's first
     and last window CACHE position. ``vis_ref`` decides in-window
@@ -285,8 +290,6 @@ def _paged_attn_kernel(tables_ref, span_ref, q_ref, k_ref, v_ref, vis_ref,
     @pl.when(j * block_size <= span_ref[1, b])
     def _body():
         q = q_ref[0].astype(jnp.float32)                # [C, H*Dh]
-        k = k_ref[0].astype(jnp.float32)                # [bs, H*Dh]
-        v = v_ref[0].astype(jnp.float32)
         # logical positions covered by table slot j
         t_pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (C, block_size), 1)
@@ -304,8 +307,10 @@ def _paged_attn_kernel(tables_ref, span_ref, q_ref, k_ref, v_ref, vis_ref,
 
         for h in range(n_heads):
             lanes = slice(h * Dh, (h + 1) * Dh)
+            k = k_ref[0, 0, :, h, :].astype(jnp.float32)    # [bs, Dh]
+            v = v_ref[0, 0, :, h, :].astype(jnp.float32)
             s = jax.lax.dot_general(
-                q[:, lanes], k[:, lanes], (((1,), (1,)), ((), ())),
+                q[:, lanes], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale  # [C, bs]
             s = jnp.where(mask, s, _NEG_INF)
             m_prev = m_scr[h][:, :1]
@@ -314,7 +319,7 @@ def _paged_attn_kernel(tables_ref, span_ref, q_ref, k_ref, v_ref, vis_ref,
             alpha = jnp.exp(m_prev - m_new)
             l_new = l_scr[h][:, :1] * alpha + p.sum(axis=-1, keepdims=True)
             acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                p, v[:, lanes], (((1,), (0,)), ((), ())),
+                p, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
             l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
@@ -326,26 +331,29 @@ def _paged_attn_kernel(tables_ref, span_ref, q_ref, k_ref, v_ref, vis_ref,
              for h in range(n_heads)], axis=-1).astype(o_ref.dtype)
 
 
-def _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
+def _paged_call(k_pool, v_pool, q, block_tables, positions, anc, layer,
                 sm_scale):
     B, C, H, Dh = q.shape
-    NB, bs = k_pages.shape[:2]
+    bs = k_pool.shape[2]
     Mb = block_tables.shape[1]
     HD = H * Dh
     if sm_scale is None:
         sm_scale = Dh ** -0.5
     tree = anc is not None
 
-    def row(b, j, tables, span):
+    def row(b, j, tables, span, layer):
         return (b, 0, 0)
 
-    def page(b, j, tables, span):
-        return (tables[b, j], 0, 0)
+    # the pool goes in whole: a block is one page of one layer, found
+    # by the index map, so nothing pool-sized is sliced or copied for
+    # the call
+    def page(b, j, tables, span, layer):
+        return (layer[0], tables[b, j], 0, 0, 0)
 
     pos = jnp.maximum(positions, 0).astype(jnp.int32)    # [B, C]
     if tree:
         vis = jnp.asarray(anc, jnp.float32)
-        vis_spec = pl.BlockSpec((C, C), lambda b, j, tables, span: (0, 0))
+        vis_spec = pl.BlockSpec((C, C), lambda b, j, *prefetch: (0, 0))
     else:
         vis = pos[:, :, None]                            # [B, C, 1]
         vis_spec = pl.BlockSpec((1, C, 1), row)
@@ -353,11 +361,11 @@ def _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
         functools.partial(_paged_attn_kernel, sm_scale=sm_scale,
                           block_size=bs, n_heads=H, tree=tree),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, Mb),
             in_specs=[pl.BlockSpec((1, C, HD), row),
-                      pl.BlockSpec((1, bs, HD), page),
-                      pl.BlockSpec((1, bs, HD), page),
+                      pl.BlockSpec((1, 1, bs, H, Dh), page),
+                      pl.BlockSpec((1, 1, bs, H, Dh), page),
                       vis_spec],
             out_specs=pl.BlockSpec((1, C, HD), row),
             scratch_shapes=[
@@ -372,17 +380,22 @@ def _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
         name="paged_attention_tree" if tree else "paged_attention",
     )(block_tables.astype(jnp.int32),
       jnp.stack([pos[:, 0], pos[:, C - 1]]),             # [2, B] span
-      q.reshape(B, C, HD), k_pages.reshape(NB, bs, HD),
-      v_pages.reshape(NB, bs, HD), vis)
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(B, C, HD), k_pool, v_pool, vis)
     return out.reshape(B, C, H, Dh)
 
 
-def paged_attention(k_pages, v_pages, q, block_tables, positions,
+def paged_attention(k_pool, v_pool, q, block_tables, positions, *, layer,
                     sm_scale=None):
     """Attention over a paged KV cache, block tables resolved in-kernel.
 
-    k_pages/v_pages: ``[num_blocks+1, block_size, H, Dh]`` — ONE layer of
-    the ``KVBlockPool`` device arrays (page 0 is the null page).
+    k_pool/v_pool: ``[n_layers, num_blocks+1, block_size, H, Dh]`` — the
+    ``KVBlockPool`` device arrays WHOLE, as they are stored (page 0 of
+    every layer is the null page). ``layer`` picks the layer inside the
+    kernel's index map. Do not hand it ``k_pool[layer]``: a custom call
+    cannot read through a slice, so XLA would copy the layer's pages
+    out of the pool first — half of a decode step before PR 25
+    (docs/KERNELS.md).
     q: ``[B, C, H, Dh]`` query window (C=1 for plain decode, C=k+1 for
     the speculative verify window). block_tables: ``[B, Mb]`` int32 —
     table slot j holds the physical page covering logical positions
@@ -394,22 +407,30 @@ def paged_attention(k_pages, v_pages, q, block_tables, positions,
     Returns the ``[B, C, H, Dh]`` fp32 context. Numerics: online softmax
     (flash formulation) — token-identical to the gathered reference, not
     bitwise (docs/KERNELS.md)."""
-    return _paged_call(k_pages, v_pages, q, block_tables, positions, None,
-                       sm_scale)
+    return _paged_call(k_pool, v_pool, q, block_tables, positions, None,
+                       layer, sm_scale)
 
 
-def paged_attention_reference(k_pages, v_pages, q, block_tables,
-                              positions, sm_scale=None):
+def _gathered_context(pool, layer, block_tables):
+    """One layer's pages gathered through the block tables, as the
+    serving model's lax path does it: ``[B, Mb, bs, H, Dh]`` ->
+    ``[B, T, H, Dh]``."""
+    B, Mb = block_tables.shape
+    return pool[layer][block_tables].reshape(
+        (B, Mb * pool.shape[2]) + pool.shape[3:])
+
+
+def paged_attention_reference(k_pool, v_pool, q, block_tables,
+                              positions, *, layer, sm_scale=None):
     """The unfused lax fallback: contiguous gather through the block
     table, then masked softmax attention — element-for-element the
     serving model's historical XLA decode-attention path."""
     B, C, H, Dh = q.shape
-    bs = k_pages.shape[1]
-    max_ctx = block_tables.shape[1] * bs
+    max_ctx = block_tables.shape[1] * k_pool.shape[2]
     if sm_scale is None:
         sm_scale = Dh ** -0.5
-    k_ctx = k_pages[block_tables].reshape(B, max_ctx, H, Dh)
-    v_ctx = v_pages[block_tables].reshape(B, max_ctx, H, Dh)
+    k_ctx = _gathered_context(k_pool, layer, block_tables)
+    v_ctx = _gathered_context(v_pool, layer, block_tables)
     scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) * sm_scale
     t_ids = jnp.arange(max_ctx)[None, None, :]
     valid = t_ids <= positions[:, :, None]
@@ -426,12 +447,13 @@ def paged_attention_reference(k_pages, v_pages, q, block_tables,
 # ---------------------------------------------------------------------------
 
 
-def paged_attention_tree(k_pages, v_pages, q, block_tables, positions,
-                         anc, sm_scale=None):
+def paged_attention_tree(k_pool, v_pool, q, block_tables, positions,
+                         anc, *, layer, sm_scale=None):
     """Tree-mask verify window over the paged KV cache, one kernel.
 
-    Same contract as :func:`paged_attention` except the window is a
-    speculation TREE: positions: ``[B, C]`` int32, the CACHE position of
+    Same contract as :func:`paged_attention` (the whole stored pool and
+    a ``layer``) except the window is a speculation TREE: positions:
+    ``[B, C]`` int32, the CACHE position of
     each window slot (``positions[b, c] = pos0_b + c`` — level-order slot
     c writes cache position pos0+c regardless of its tree depth). anc:
     ``[C, C]`` — ``anc[c, t]`` truthy iff window slot t is c or an
@@ -443,22 +465,22 @@ def paged_attention_tree(k_pages, v_pages, q, block_tables, positions,
     is numerically identical to the linear spec window. Returns the
     ``[B, C, H, Dh]`` fp32 context; online-softmax numerics, token-
     identical (not bitwise) to the gathered reference."""
-    return _paged_call(k_pages, v_pages, q, block_tables, positions, anc,
-                       sm_scale)
+    return _paged_call(k_pool, v_pool, q, block_tables, positions, anc,
+                       layer, sm_scale)
 
 
-def paged_attention_tree_reference(k_pages, v_pages, q, block_tables,
-                                   positions, anc, sm_scale=None):
+def paged_attention_tree_reference(k_pool, v_pool, q, block_tables,
+                                   positions, anc, *, layer,
+                                   sm_scale=None):
     """The unfused lax fallback: contiguous gather through the block
     table, tree-masked softmax — element-for-element the serving model's
     XLA tree-window attention branch."""
     B, C, H, Dh = q.shape
-    bs = k_pages.shape[1]
-    max_ctx = block_tables.shape[1] * bs
+    max_ctx = block_tables.shape[1] * k_pool.shape[2]
     if sm_scale is None:
         sm_scale = Dh ** -0.5
-    k_ctx = k_pages[block_tables].reshape(B, max_ctx, H, Dh)
-    v_ctx = v_pages[block_tables].reshape(B, max_ctx, H, Dh)
+    k_ctx = _gathered_context(k_pool, layer, block_tables)
+    v_ctx = _gathered_context(v_pool, layer, block_tables)
     scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) * sm_scale
     anc_b = jnp.asarray(anc) > 0
     pos0 = positions[:, 0]                               # [B]
@@ -574,13 +596,17 @@ def _flash_qualify(T=None, Tk=None, head_dim=None, causal=False):
 
 
 def _paged_qualify(head_dim=None, block_size=None, window=None):
-    """What Mosaic refuses, found by compiling for the v5e topology
-    (tests/test_kernels_lower_tpu.py): a one-row page. Every other
-    geometry tried compiles — head_dim 8..128, 1..32 heads, block_size
-    2..128, windows of 1..33, fp32 and bf16 pages — because a block is
-    a whole page and so equals the array in its last two axes."""
+    """One-row pages stay on the lax path. While a block was a page
+    with the heads folded into the lanes, Mosaic refused them; the
+    stored ``[bs, H, Dh]`` page of PR 25 compiles at ``block_size`` 1
+    too, but has never run on the chip, and a grid step per cached
+    token is no kernel to want. Every geometry tried compiles for the
+    v5e topology (tests/test_kernels_lower_tpu.py and a sweep):
+    head_dim 8..128, 1..32 heads, block_size 1..128, windows of 1..33,
+    fp32 and bf16 pages — a block is a whole page and so equals the
+    array in its last two axes."""
     if block_size is not None and block_size < 2:
-        return False, "block_size < 2 (Mosaic cannot lay out a one-row page)"
+        return False, "block_size < 2 (one-row pages stay on the lax path)"
     return True, None
 
 

@@ -4,12 +4,15 @@ decode step) — with content-addressed **radix prefix caching** (SGLang
 RadixAttention mapped onto flat block tables).
 
 The device side is two dense arrays per model —
-``k``/``v`` of shape ``[n_layers, num_blocks, block_size, n_heads,
+``k``/``v`` of shape ``[n_layers, num_blocks + 1, block_size, n_heads,
 head_dim]`` — that the jitted decode step takes as donated arguments and
 returns updated, so the pool never round-trips over the host link. A
 sequence's cache is NOT contiguous: it owns an ordered list of block ids
-(its *block table*), and the decode step gathers
-``k[layer][block_table]`` to reconstruct the sequence's logical
+(its *block table*). The paged kernels take ``k``/``v`` whole and find
+``(layer, block_table[b, j])`` in their index map; no step slices a
+layer out of the pool in front of a kernel (XLA would copy the layer's
+pages: docs/SERVING.md, "No kernel step slices the pool"). The lax path
+gathers ``k[layer][block_table]`` to reconstruct the sequence's logical
 ``[max_seq_len]`` key/value layout. Fixed shapes everywhere means XLA
 compiles the step exactly once no matter how sequences join and retire.
 

@@ -721,22 +721,29 @@ class GenerationModel:
             with jax.named_scope("kv_write"):
                 kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
                 kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
-            with jax.named_scope("kv_read"):
-                # the layer's pages, sliced out of the 5-D pool
-                k_pages, v_pages = kv_k[i], kv_v[i]
             with jax.named_scope("attention"):
                 if use_paged:
+                    # The kernel gets the pool WHOLE and finds layer and
+                    # page in its index map. `kv_k[i]` here would make
+                    # XLA copy the layer's pages out of the pool for the
+                    # custom call and lay them out again, which cost
+                    # more than the rest of the step (docs/SERVING.md,
+                    # "No kernel step slices the pool").
                     ctx = paged_attention(
-                        k_pages, v_pages, q[:, None], block_tables,
-                        positions[:, None], sm_scale=sm_scale)
+                        kv_k, kv_v, q[:, None], block_tables,
+                        positions[:, None], layer=i, sm_scale=sm_scale)
                     ctx = ctx[:, 0].reshape(B, -1)
                 else:
-                    # paged gather: [B, Mb, bs, H, Dh]
-                    # -> [B, max_ctx, H, Dh]
-                    k_ctx = k_pages[block_tables].reshape(
-                        B, max_ctx, H, Dh)
-                    v_ctx = v_pages[block_tables].reshape(
-                        B, max_ctx, H, Dh)
+                    # lax path: the layer's pages, then the paged gather
+                    # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]. XLA
+                    # fuses this slice with the convert the dot wants
+                    # (one pass, bf16 out on the chip); one gather from
+                    # the whole pool measured slower (docs/SERVING.md).
+                    with jax.named_scope("kv_read"):
+                        k_ctx = kv_k[i][block_tables].reshape(
+                            B, max_ctx, H, Dh)
+                        v_ctx = kv_v[i][block_tables].reshape(
+                            B, max_ctx, H, Dh)
                     scores = jnp.einsum("bhd,bthd->bht", q, k_ctx) \
                         * sm_scale
                     scores = jnp.where(valid[:, None, :], scores,
@@ -927,26 +934,27 @@ class GenerationModel:
             with jax.named_scope("kv_write"):
                 kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
                 kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
-            with jax.named_scope("kv_read"):
-                # the layer's pages, sliced out of the 5-D pool
-                k_pages, v_pages = kv_k[i], kv_v[i]
             with jax.named_scope("attention"):
                 if use_paged:
+                    # the pool goes to the kernel whole, never sliced
+                    # (see _forward_token)
                     if tree_anc is None:
                         ctx = paged_attention(
-                            k_pages, v_pages, q, block_tables, pos2d,
-                            sm_scale=sm_scale).reshape(B, C, -1)
+                            kv_k, kv_v, q, block_tables, pos2d,
+                            layer=i, sm_scale=sm_scale).reshape(B, C, -1)
                     else:
                         ctx = paged_attention_tree(
-                            k_pages, v_pages, q, block_tables, pos2d,
-                            anc_f, sm_scale=sm_scale).reshape(B, C, -1)
+                            kv_k, kv_v, q, block_tables, pos2d, anc_f,
+                            layer=i, sm_scale=sm_scale).reshape(B, C, -1)
                 else:
-                    # paged gather: [B, Mb, bs, H, Dh]
-                    # -> [B, max_ctx, H, Dh]
-                    k_ctx = k_pages[block_tables].reshape(
-                        B, max_ctx, H, Dh)
-                    v_ctx = v_pages[block_tables].reshape(
-                        B, max_ctx, H, Dh)
+                    # lax path: the layer's pages, then the paged gather
+                    # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]
+                    # (see _forward_token)
+                    with jax.named_scope("kv_read"):
+                        k_ctx = kv_k[i][block_tables].reshape(
+                            B, max_ctx, H, Dh)
+                        v_ctx = kv_v[i][block_tables].reshape(
+                            B, max_ctx, H, Dh)
                     scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) \
                         * sm_scale
                     scores = jnp.where(attn_valid[:, :, None, :], scores,

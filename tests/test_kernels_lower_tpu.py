@@ -137,18 +137,151 @@ def test_flash_in_a_gspmd_step_compiles_for_four_chips(shape, axes,
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile()
 
 
-def test_shape_mosaic_refuses_is_disqualified_with_a_reason(v5e_chip):
-    """A one-row page is the one paged geometry found that Mosaic
-    refuses; `qualify` must say so, so the dispatch takes the lax
-    fallback with a warning and never reaches the compiler."""
+def test_one_row_page_is_disqualified_with_a_reason(v5e_chip):
+    """A one-row page was the one paged geometry Mosaic refused while a
+    block was a page with its heads folded into the lanes. The stored
+    `[bs, H, Dh]` page compiles at block_size 1 as well; `qualify`
+    still keeps it on the lax path (it has never run on the chip), with
+    a reason, so the dispatch warns and never reaches the compiler."""
     B, H, Dh, bs, Mb, C = 4, 8, 64, 1, 16, 5
     spec = registered_kernels()["spec_window_tree"]
     ok, why = spec.qualify(head_dim=Dh, block_size=bs, window=C)
     assert not ok and "block_size" in why
     f32, i32 = jnp.float32, jnp.int32
     NB = B * Mb + 1
-    specs = [((NB, bs, H, Dh), f32), ((NB, bs, H, Dh), f32),
+    specs = [((1, NB, bs, H, Dh), f32), ((1, NB, bs, H, Dh), f32),
              ((B, C, H, Dh), f32), ((B, Mb), i32), ((B, C), i32),
              ((C, C), f32)]
-    with pytest.raises(Exception, match="Mosaic failed to compile"):
-        _compile(lambda *a: spec.pallas(*a), specs, v5e_chip)
+    _compile(lambda *a: spec.pallas(*a, layer=0), specs, v5e_chip)
+
+
+# ---------------------------------------------------------------------------
+# the serving steps read the KV pool in place (PR 25)
+# ---------------------------------------------------------------------------
+
+POOL_STEP = dict(n_layers=2, n_heads=16, head_dim=128, block_size=16,
+                 num_blocks=640, batch=4, blocks_per_seq=8, chunk=16,
+                 spec_window=5, tree=(2, 3))
+
+
+@pytest.fixture(scope="module")
+def pool_model():
+    """A two-layer model at the paged kernels' flagship widths (16 heads
+    of 128), zero weights: only its compiled steps are looked at."""
+    import numpy as np
+
+    from paddle_tpu.serving import GenerationConfig, GenerationModel
+    from paddle_tpu.serving.model import weight_names
+
+    g = POOL_STEP
+    D = g["n_heads"] * g["head_dim"]
+    cfg = GenerationConfig(64, D, g["n_heads"], g["n_layers"], 128,
+                           max_seq_len=g["blocks_per_seq"] * g["block_size"])
+    shapes = {"embedding": (64, D), "lm_head": (D, 64), "wqkv": (D, 3 * D),
+              "bqkv": (3 * D,), "wproj": (D, D), "wff1": (D, 128),
+              "bff1": (128,), "wff2": (128, D)}
+    return GenerationModel(cfg, {
+        n: np.zeros(shapes.get(n.split("/")[-1], (D,)), np.float32)
+        for n in weight_names(cfg)})
+
+
+def _pool_step(model, kind, chip):
+    """One serving step at a geometry the paged kernels qualify for,
+    compiled for the v5e: (compiled, elements of one layer's pages,
+    layers). The pool is sized so that a layer's pages (84 MB) dwarf
+    everything a step has reason to allocate and every weight it may
+    prefetch (`wqkv`, 50 MB); only its shape is made."""
+    from paddle_tpu.serving import KVBlockPool
+
+    g = POOL_STEP
+    B, Mb, L = g["batch"], g["blocks_per_seq"], g["n_layers"]
+    sharding = jax.sharding.SingleDeviceSharding(chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def like(a):
+        return arg(a.shape, a.dtype)
+
+    pool = like(jax.eval_shape(lambda: KVBlockPool(
+        L, g["n_heads"], g["head_dim"], g["block_size"],
+        g["num_blocks"]).k))
+    row, on, tables = arg((B,)), arg((B,), jnp.bool_), arg((B, Mb))
+    if kind == "decode":
+        step = model.make_decode_step(B, Mb)
+        # prompt_feed, use_prompt, prev, positions, tables, active
+        feed = (row, on, row, row, tables, on)
+    else:
+        width, depth = g["tree"]
+        C, step = {
+            "chunk": (g["chunk"],
+                      model.make_prefill_step(B, Mb, g["chunk"])),
+            "spec": (g["spec_window"],
+                     model.make_spec_step(B, Mb, g["spec_window"])),
+            "tree": (1 + width * depth,
+                     model.make_spec_tree_step(B, Mb, width, depth)),
+        }[kind]
+        # tokens, use_prompt, prev, positions, lengths, tables, active
+        feed = (arg((B, C)), on, row, row, row, tables, on)
+    with device.compiling_for(chip):
+        compiled = step.lower(
+            jax.tree_util.tree_map(like, model.weights), pool, pool,
+            *feed).compile()
+    return compiled, pool.size // L, L
+
+
+def _large_results(hlo, at_least):
+    """(opcode, dtype, elements) of every array an instruction of the
+    ENTRY computation produces with `at_least` elements or more.
+    Parameters, tuples and bitcasts move nothing and are left out."""
+    import re
+
+    found = []
+    entry = hlo[hlo.index("\nENTRY "):]
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(2) in ("parameter", "tuple", "bitcast",
+                                   "get-tuple-element"):
+            continue
+        for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]",
+                                      m.group(1)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d)
+            if n >= at_least:
+                found.append((m.group(2), dtype, n))
+    return found
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk", "spec", "tree"])
+def test_serving_step_reads_the_kv_pool_in_place(kind, pool_model,
+                                                 v5e_chip):
+    """No step that runs the paged kernel may copy a layer's pages out
+    of the pool. Before PR 25 each of them did, twice for K and twice
+    for V in every layer (`kv_k[i]` in front of a custom call: a
+    `slice`, then a `reshape` to the kernel's block), 35 ms of a 56 ms
+    decode step at the benchmark's size; the kernel now takes the pool
+    whole. So the optimised HLO holds nothing of a layer's size but the
+    in-place write of the new rows, and the step's temporaries stay
+    under one layer's pages (the parent needed over three).
+
+    The chunk step's attention is the lax path: one fused
+    slice-and-convert of the layer's pages to bf16 for K and for V,
+    which on the chip is faster than gathering from the whole pool
+    (docs/SERVING.md). It is pinned as it is: nothing fp32 of a layer's
+    size, at most that one bf16 pass."""
+    compiled, layer, n_layers = _pool_step(pool_model, kind, v5e_chip)
+    large = _large_results(compiled.as_text(), layer)
+    writes = [r for r in large if r[2] == layer * n_layers]
+    others = [r for r in large if r[2] != layer * n_layers]
+    assert writes and all(op in ("fusion", "scatter")
+                          for op, _, _ in writes), large
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if kind == "chunk":
+        assert all(dt == "bf16" and n == layer for _, dt, n in others), \
+            others
+        assert temp < 2 * layer * 4, temp
+    else:
+        assert "tpu_custom_call" in compiled.as_text()
+        assert not others, others
+        assert temp < layer * 4, temp

@@ -177,11 +177,16 @@ def test_disqualified_shape_counts_fallback_and_warns_once(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
+LAYER = 1   # the layer the paged tests read, of _paged_setup's three
+
+
 def _paged_setup(seed=0, NB=8, bs=4, H=2, Dh=16, B=2, Mb=4):
+    """A whole pool as KVBlockPool stores it, [L, NB + 1, bs, H, Dh]:
+    the kernels take it unsliced and find the layer themselves."""
     rng = np.random.RandomState(seed)
-    k_pages = jnp.asarray(rng.randn(NB + 1, bs, H, Dh).astype(np.float32))
-    v_pages = jnp.asarray(rng.randn(NB + 1, bs, H, Dh).astype(np.float32))
-    return rng, k_pages, v_pages
+    k_pool = jnp.asarray(rng.randn(3, NB + 1, bs, H, Dh).astype(np.float32))
+    v_pool = jnp.asarray(rng.randn(3, NB + 1, bs, H, Dh).astype(np.float32))
+    return rng, k_pool, v_pool
 
 
 @pytest.mark.parametrize("table,positions", [
@@ -194,12 +199,13 @@ def _paged_setup(seed=0, NB=8, bs=4, H=2, Dh=16, B=2, Mb=4):
     ([[6, 0, 0, 0], [2, 8, 0, 0]], [[1], [4]]),
 ])
 def test_paged_decode_matches_gathered_reference(table, positions):
-    rng, k_pages, v_pages = _paged_setup()
+    rng, k_pool, v_pool = _paged_setup()
     q = jnp.asarray(rng.randn(2, 1, 2, 16).astype(np.float32))
     tables = jnp.asarray(np.array(table, np.int32))
     pos = jnp.asarray(np.array(positions, np.int32))
-    got = paged_attention(k_pages, v_pages, q, tables, pos)
-    want = paged_attention_reference(k_pages, v_pages, q, tables, pos)
+    got = paged_attention(k_pool, v_pool, q, tables, pos, layer=LAYER)
+    want = paged_attention_reference(k_pool, v_pool, q, tables, pos,
+                                     layer=LAYER)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 
@@ -208,13 +214,14 @@ def test_spec_window_matches_gathered_reference():
     """The verify-window shape: k+1 query positions per row, each
     masked to its OWN causal prefix — exactly the serving chunk
     attention's `t <= pos2d[b, c]` contract."""
-    rng, k_pages, v_pages = _paged_setup(seed=3)
+    rng, k_pool, v_pool = _paged_setup(seed=3)
     C = 3
     q = jnp.asarray(rng.randn(2, C, 2, 16).astype(np.float32))
     tables = jnp.asarray(np.array([[5, 2, 7, 3], [4, 1, 0, 0]], np.int32))
     pos = jnp.asarray(np.array([[7, 8, 9], [0, 1, 2]], np.int32))
-    got = paged_attention(k_pages, v_pages, q, tables, pos)
-    want = paged_attention_reference(k_pages, v_pages, q, tables, pos)
+    got = paged_attention(k_pool, v_pool, q, tables, pos, layer=LAYER)
+    want = paged_attention_reference(k_pool, v_pool, q, tables, pos,
+                                     layer=LAYER)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 
@@ -237,12 +244,13 @@ def test_paged_decode_post_truncate_tables():
     Mb = 4
     padded = np.full((1, Mb), KVBlockPool.NULL_BLOCK, np.int32)
     padded[0, :len(table_ids)] = table_ids
-    rng, k_pages, v_pages = _paged_setup(seed=5)
+    rng, k_pool, v_pool = _paged_setup(seed=5)
     q = jnp.asarray(rng.randn(1, 1, 2, 16).astype(np.float32))
     pos = jnp.asarray(np.array([[3]], np.int32))  # last kept position
     tables = jnp.asarray(padded)
-    got = paged_attention(k_pages, v_pages, q, tables, pos)
-    want = paged_attention_reference(k_pages, v_pages, q, tables, pos)
+    got = paged_attention(k_pool, v_pool, q, tables, pos, layer=LAYER)
+    want = paged_attention_reference(k_pool, v_pool, q, tables, pos,
+                                     layer=LAYER)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 

@@ -429,16 +429,18 @@ def test_paged_attention_tree_matches_reference():
     C = 1 + W * D
     _p, _d, anc = tree_topology(W, D)
     n_pages = Mb * B + 1
-    k_pages = rng.randn(n_pages, bs, H, Dh).astype(np.float32)
-    v_pages = rng.randn(n_pages, bs, H, Dh).astype(np.float32)
+    # the whole pool, [L, pages, bs, H, Dh]; the kernels read layer 1
+    k_pool = rng.randn(2, n_pages, bs, H, Dh).astype(np.float32)
+    v_pool = rng.randn(2, n_pages, bs, H, Dh).astype(np.float32)
     q = rng.randn(B, C, H, Dh).astype(np.float32)
     tables = np.arange(B * Mb, dtype=np.int32).reshape(B, Mb) + 1
     pos0 = np.array([5, 9], np.int32)           # >= 1 past "prefill"
     positions = pos0[:, None] + np.arange(C, dtype=np.int32)[None, :]
     got = np.asarray(paged_attention_tree(
-        k_pages, v_pages, q, tables, positions, anc.astype(np.float32)))
+        k_pool, v_pool, q, tables, positions, anc.astype(np.float32),
+        layer=1))
     want = np.asarray(paged_attention_tree_reference(
-        k_pages, v_pages, q, tables, positions, anc))
+        k_pool, v_pool, q, tables, positions, anc, layer=1))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     # width 1 tree mask == the linear spec window kernel's semantics
     _p1, _d1, anc1 = tree_topology(1, 3)
@@ -446,9 +448,10 @@ def test_paged_attention_tree_matches_reference():
     q1 = q[:, :C1]
     pos1 = pos0[:, None] + np.arange(C1, dtype=np.int32)[None, :]
     got1 = np.asarray(paged_attention_tree(
-        k_pages, v_pages, q1, tables, pos1, anc1.astype(np.float32)))
+        k_pool, v_pool, q1, tables, pos1, anc1.astype(np.float32),
+        layer=1))
     lin = np.asarray(paged_attention_reference(
-        k_pages, v_pages, q1, tables, pos1))
+        k_pool, v_pool, q1, tables, pos1, layer=1))
     np.testing.assert_allclose(got1, lin, rtol=2e-5, atol=2e-5)
 
 
