@@ -53,7 +53,7 @@ Sizes = collections.namedtuple("Sizes", [
     "seq", "batch", "ref_batch", "train_steps", "lr",
     "serve_ctx", "serve_batch", "block_size", "prefill_chunk",
     "prompt_lens", "new_tokens", "spec_k", "spec_tree",
-    "int8_mkn", "rec"])
+    "int8_mkn", "latent", "latent_batch", "latent_chunk", "rec"])
 
 # the flagship at full width (bench_transformer_fluid's operating point)
 FULL = Sizes(
@@ -64,6 +64,16 @@ FULL = Sizes(
     prompt_lens=(32, 48, 64, 96, 128, 160, 200, 256), new_tokens=32,
     spec_k=4, spec_tree=(2, 3),
     int8_mkn=(160, 512, 2048),
+    # the latent-attention / routed-expert block at its published
+    # widths (kakaocorp/kanana-2-30b-a3b), one dense and one expert layer
+    latent=dict(vocab_size=128256, d_model=2048, n_heads=32, n_layers=2,
+                d_ff=6144, block=dict(
+                    qk_nope_head_dim=128, qk_rope_head_dim=64,
+                    v_head_dim=128, kv_lora_rank=512, rope_theta=1e6,
+                    n_routed_experts=128, experts_per_token=6,
+                    n_shared_experts=2, moe_d_ff=768,
+                    routed_scaling_factor=2.448)),
+    latent_batch=32, latent_chunk=16,
     # bench.py --rec-only sizes
     rec=dict(n_shards=4, records_per_shard=320, batch_size=32, vocab=512,
              fields=6, embed_dim=16, cache_rows=128))
@@ -77,6 +87,14 @@ TOY = Sizes(
     prompt_lens=(8, 12, 20, 28, 33, 40), new_tokens=8,
     spec_k=3, spec_tree=(2, 2),
     int8_mkn=(32, 128, 256),
+    latent=dict(vocab_size=512, d_model=128, n_heads=4, n_layers=2,
+                d_ff=256, block=dict(
+                    qk_nope_head_dim=32, qk_rope_head_dim=16,
+                    v_head_dim=32, kv_lora_rank=128, rope_theta=1e6,
+                    n_routed_experts=8, experts_per_token=2,
+                    n_shared_experts=2, moe_d_ff=64,
+                    routed_scaling_factor=2.448)),
+    latent_batch=4, latent_chunk=8,
     rec=dict(n_shards=2, records_per_shard=64, batch_size=16, vocab=128,
              fields=4, embed_dim=8, cache_rows=32))
 
@@ -428,6 +446,105 @@ def leg_serve(sz, rehearsal):
 
 
 # ---------------------------------------------------------------------------
+# latent: the latent-attention / routed-expert block at published widths,
+# through the engine and step against step (kernels vs the lax path)
+# ---------------------------------------------------------------------------
+
+LATENT_KERNELS = ("gmm", "latent_decode", "latent_window", "latent_write")
+
+
+def latent_step_logits(model, sz, chunked):
+    """Logits of one decode or one chunk step of the block on a random
+    latent cache, under whatever kernel policy is in force."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import KVBlockPool
+
+    cfg = model.config
+    B, bs = sz.latent_batch, sz.block_size
+    Mb = sz.serve_ctx // bs
+    C = sz.latent_chunk if chunked else 1
+    rng = np.random.RandomState(13)
+    pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim, bs, B * Mb,
+                       entry=model.cache_entry())
+    latent = jnp.asarray(rng.randn(*pool.arrays[0].shape)
+                         .astype(np.float32) * 0.3, pool.dtype)
+    tables, pos = paged_layout(rng, B, Mb, bs, C)
+    toks = rng.randint(0, cfg.vocab_size, (B, C)).astype(np.int32)
+    on = np.ones(B, bool)
+    if chunked:
+        # full windows beside one-token rows, as a mixed step holds them
+        lens = np.where(np.arange(B) % 2, 1, C).astype(np.int32)
+        out = model.make_prefill_step(B, Mb, C, return_logits=True)(
+            model.weights, latent, toks, lens > 1, np.zeros(B, np.int32),
+            pos[:, 0], lens, tables, on)
+    else:
+        out = model.make_decode_step(B, Mb, return_logits=True)(
+            model.weights, latent, toks[:, 0], on, np.zeros(B, np.int32),
+            pos[:, 0], tables, on)
+    return np.asarray(out[3]), np.asarray(out[2])
+
+
+def leg_latent(sz, rehearsal):
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    ServingEngine)
+
+    assert "PTPU_KERNELS" not in os.environ
+    if rehearsal:
+        os.environ["PTPU_KERNELS"] = "1"
+    k0 = {n: counter("kernels/kernel:" + n) for n in LATENT_KERNELS}
+    fall0 = counter("kernels/fallbacks")
+    model = GenerationModel.random(
+        GenerationConfig(max_seq_len=sz.serve_ctx, **sz.latent), seed=7)
+    try:
+        eng = ServingEngine(model, max_batch=sz.latent_batch,
+                            max_seq_len=sz.serve_ctx,
+                            block_size=sz.block_size,
+                            prefill_chunk=sz.latent_chunk)
+        try:
+            outs = [r.wait(900) for r in
+                    [eng.submit(p, max_new_tokens=sz.new_tokens)
+                     for p in prompts(sz)]]
+        finally:
+            eng.close()
+        assert all(len(o) == sz.new_tokens for o in outs)
+        kernel = {k: latent_step_logits(model, sz, k == "chunk")
+                  for k in ("decode", "chunk")}
+        dispatched = {n: counter("kernels/kernel:" + n) - k0[n]
+                      for n in LATENT_KERNELS}
+        fallbacks = counter("kernels/fallbacks") - fall0
+    finally:
+        os.environ.pop("PTPU_KERNELS", None)
+    assert all(v >= 1 for v in dispatched.values()), dispatched
+    assert fallbacks == 0, fallbacks
+    os.environ["PTPU_KERNELS"] = "0"
+    try:
+        lax = {k: latent_step_logits(model, sz, k == "chunk")
+               for k in kernel}
+    finally:
+        del os.environ["PTPU_KERNELS"]
+    report = {}
+    for k, (got, counters) in kernel.items():
+        want = lax[k][0]
+        assert got.shape == want.shape and np.isfinite(got).all(), k
+        # a row's error as a share of the largest logit. The two paths
+        # round differently (absorbed against expanded attention), so a
+        # token at a near-tie of the router may take another expert on
+        # one of them and its row then differs by the logits' own size:
+        # the median row is held to the bound, the worst row reported
+        rows = np.abs(got - want).max(axis=1) / np.abs(want).max()
+        assert np.median(rows) <= LOGITS_REL_BOUND, (k, np.median(rows))
+        assert (counters == lax[k][1]).all() or k == "chunk", k
+        report[k] = {"median_row_err": float("%.3g" % np.median(rows)),
+                     "worst_row_err": float("%.3g" % rows.max()),
+                     "counters": [int(c) for c in counters]}
+    return {"requests": len(outs), "kernel_dispatches": dispatched,
+            "kernel_fallbacks": fallbacks, "steps": report,
+            "logits_rel_bound": LOGITS_REL_BOUND}
+
+
+
+# ---------------------------------------------------------------------------
 # kernels: the shapes the two legs above use (tests/test_kernels_lower_tpu.py
 # lowers the same cases for the TPU from the sandbox)
 # ---------------------------------------------------------------------------
@@ -489,6 +606,76 @@ def kernel_cases(sz):
         dict(T=sz.seq, head_dim=Dh, causal=True),
         lambda rng: [jnp.asarray(rng.randn(*s).astype(f32), d)
                      for s, d in qkv])
+
+    # the latent block's three kernels at its widths: the latent pool
+    # whole (two layers, the second used), rows at both ends of the
+    # context, one-token rows beside full windows
+    lb = sz.latent["block"]
+    Hl, Bl = sz.latent["n_heads"], sz.latent_batch
+    r = lb["kv_lora_rank"]
+    W = -(-(r + lb["qk_rope_head_dim"]) // 128) * 128
+    lpool = ((2, Bl * Mb + 1, bs, W), jnp.bfloat16)
+
+    def latent(name, C):
+        def layout(rng):
+            tables, pos = paged_layout(rng, Bl, Mb, bs, C)
+            lens = np.where(np.arange(Bl) % 2, 1, C).astype(i32)
+            return tables, pos[:, 0].copy(), lens
+
+        def fill_attn(rng):
+            tables, pos0, lens = layout(rng)
+            return [jnp.asarray(rng.randn(*lpool[0]).astype(f32), lpool[1]),
+                    (rng.randn(Bl, C, Hl, W) * 0.1).astype(f32),
+                    tables, pos0, lens]
+
+        cases[name] = (
+            [lpool, ((Bl, C, Hl, W), f32), ((Bl, Mb), i32), ((Bl,), i32),
+             ((Bl,), i32)], {"layer": 1, "v_width": r},
+            dict(width=W, v_width=r, block_size=bs, window=C), fill_attn)
+        if C > 1:
+            def fill_write(rng):
+                tables, pos0, lens = layout(rng)
+                lens[0] = 0                       # a row that sits out
+                return [jnp.asarray(rng.randn(*lpool[0]).astype(f32),
+                                    lpool[1]),
+                        rng.randn(Bl, C, W).astype(f32), tables, pos0, lens]
+
+            cases["latent_write"] = (
+                [lpool, ((Bl, C, W), f32), ((Bl, Mb), i32), ((Bl,), i32),
+                 ((Bl,), i32)], {"layer": 1},
+                dict(width=W, block_size=bs, window=C), fill_write)
+
+    latent("latent_decode", 1)
+    latent("latent_window", sz.latent_chunk)
+
+    # gmm at the expert layer's shapes: a decode step's pairs over all
+    # experts, uneven groups, two experts without a row, spare tiles
+    from paddle_tpu.ops.pallas_kernels import GMM_BLOCK_M as bm
+
+    E, Dm, Fe = lb["n_routed_experts"], sz.latent["d_model"], lb["moe_d_ff"]
+    pairs = Bl * lb["experts_per_token"]
+    n_tiles = -(-(pairs + E * (bm - 1)) // bm)
+
+    def fill_gmm(rng):
+        sizes = rng.multinomial(pairs, np.ones(E) / E)
+        sizes[:2] = 0
+        tiles = -(-sizes // bm)
+        lhs = np.zeros((n_tiles * bm, Dm), f32)
+        row = 0
+        for g, t in zip(sizes, tiles):
+            lhs[row:row + g] = rng.randn(g, Dm)
+            row += t * bm
+        te = np.repeat(np.arange(E), tiles)
+        te = np.concatenate([te, np.full(n_tiles - len(te), te[-1])])
+        return [jnp.asarray(lhs, jnp.bfloat16),
+                jnp.asarray(rng.randn(E, Dm, Fe).astype(f32) * 0.05,
+                            jnp.bfloat16),
+                te.astype(i32), np.array([tiles.sum()], i32)]
+
+    cases["gmm"] = (
+        [((n_tiles * bm, Dm), jnp.bfloat16), ((E, Dm, Fe), jnp.bfloat16),
+         ((n_tiles,), i32), ((1,), i32)], {},
+        dict(rows=n_tiles * bm, k=Dm, n=Fe), fill_gmm)
 
     M, K, N = sz.int8_mkn
     cases["int8_matmul"] = (
@@ -698,6 +885,8 @@ def main(argv=None):
         run_leg("train", lambda: leg_train(sz, rehearsal, shared), clock,
                 results)
         run_leg("serve", lambda: leg_serve(sz, rehearsal), clock, results)
+        run_leg("latent", lambda: leg_latent(sz, rehearsal), clock,
+                results)
         run_leg("kernels", lambda: leg_kernels(sz, rehearsal), clock,
                 results)
         run_leg("rec", lambda: leg_rec(sz, rehearsal), clock, results)
@@ -712,7 +901,8 @@ def main(argv=None):
     except Exception:
         traceback.print_exc()
     ok = (all(r["ok"] for r in results.values())
-          and set(results) >= {"device", "train", "serve", "kernels", "rec"})
+          and set(results) >= {"device", "train", "serve", "latent", "kernels",
+                               "rec"})
     summary = {
         "ok": ok,
         "device": ident._asdict(),

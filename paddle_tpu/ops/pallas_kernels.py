@@ -29,6 +29,26 @@ int8_matmul: fused int8×int8→int32 matmul for the full-int8 quant path —
   dequantize applies on the final K block, so the separate
   quantize/dequantize_linear HLOs around each rewritten matmul vanish.
 
+gmm: the grouped (ragged) matmul of an expert layer. Rows arrive sorted
+  by expert in a tile-aligned layout (every tile of ``block_m`` rows
+  belongs to ONE expert, named by a scalar-prefetched table), so the
+  kernel is a plain tile matmul whose weight block is found by the
+  index map: an expert's matrix is streamed once for all its tiles, an
+  expert with no row is never read, and tiles past the used count are
+  skipped. bf16 in, fp32 accumulate.
+
+latent_paged_attention: attention over a paged LATENT cache (MLA,
+  absorbed form): every query head of a row attends the same stored row
+  of ``width`` values a token (the normalised latent and the rotated
+  shared key), and the value is that row's first ``v_width`` lanes. It
+  takes the pool whole like ``paged_attention``, leaves it in HBM and
+  copies a row's own pages itself, ``pages_per_step`` to a matmul, so
+  a row of hundreds of cached tokens costs a few matmuls and no page
+  it does not own. One kernel serves the
+  one-token decode step (``kernel 'latent_decode'``) and the chunk
+  window (``kernel 'latent_window'``). ``latent_write`` puts a window's
+  new rows into that pool in place, a page at a time.
+
 Whether a kernel compiles or runs in the Pallas interpreter is decided in
 one place, ``core.device.pallas_interpret()``: compiled on TPU (a kernel
 Mosaic refuses raises), interpreted everywhere else so the CPU test mesh
@@ -51,7 +71,9 @@ __all__ = ["flash_attention", "flash_attention_portable",
            "attention_reference", "paged_attention",
            "paged_attention_reference", "paged_attention_tree",
            "paged_attention_tree_reference", "int8_matmul",
-           "int8_matmul_reference"]
+           "int8_matmul_reference", "gmm", "gmm_reference",
+           "latent_paged_attention", "latent_paged_attention_reference",
+           "latent_write", "latent_write_reference"]
 
 _NEG_INF = -1e30
 
@@ -574,6 +596,352 @@ def int8_matmul_reference(x, w_int8, dq_scale, act_scale):
 
 
 # ---------------------------------------------------------------------------
+# gmm: grouped matmul over experts, rows sorted by expert, tile-aligned
+# ---------------------------------------------------------------------------
+
+GMM_BLOCK_M = 16     # one bf16 sublane tile of rows
+
+
+def _gmm_kernel(tile_expert_ref, n_used_ref, lhs_ref, rhs_ref, o_ref):
+    """Grid (M // block_m,): tile i is rows of expert
+    ``tile_expert[i]``, whose whole ``[K, N]`` matrix is the weight
+    block (the index map found it; consecutive tiles of one expert
+    keep the block, so it is read once). Tiles at or past ``n_used``
+    hold no row: their table entry repeats the last used expert, so
+    no weight is fetched for them, and their output rows are zero."""
+    used = pl.program_id(0) < n_used_ref[0]
+
+    @pl.when(used)
+    def _tile():
+        o_ref[:] = jax.lax.dot_general(
+            lhs_ref[:], rhs_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(used))
+    def _empty():
+        o_ref[:] = jnp.zeros_like(o_ref)
+
+
+def gmm(lhs, rhs, tile_expert, n_used, *, block_m=GMM_BLOCK_M):
+    """Grouped matmul ``out[r] = lhs[r] @ rhs[expert_of_row(r)]``.
+
+    lhs: ``[M, K]`` rows sorted by expert in a TILE-ALIGNED layout: M
+    is a multiple of ``block_m`` and every tile of ``block_m`` rows
+    belongs to one expert (a group's tail tile is padded with zero
+    rows). rhs: ``[E, K, N]``, the held experts' matrices.
+    tile_expert: ``[M // block_m]`` int32, the expert of each tile;
+    entries at or past ``n_used`` repeat the last used expert.
+    n_used: int32 scalar (or ``[1]``), how many tiles hold rows.
+
+    Returns ``[M, N]`` float32; rows of tiles at or past ``n_used`` are
+    zero. bf16 operands, fp32 accumulation."""
+    M, K = lhs.shape
+    E, K2, N = rhs.shape
+    if K != K2 or M % block_m:
+        raise ValueError("gmm: lhs %r does not tile against rhs %r at "
+                         "block_m %d" % (lhs.shape, rhs.shape, block_m))
+    return pl.pallas_call(
+        _gmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(M // block_m,),
+            in_specs=[
+                pl.BlockSpec((block_m, K), lambda i, te, nu: (i, 0)),
+                pl.BlockSpec((1, K, N), lambda i, te, nu: (te[i], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_m, N), lambda i, te, nu: (i, 0))),
+        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_device.pallas_interpret(),
+        name="gmm",
+    )(tile_expert.astype(jnp.int32),
+      jnp.asarray(n_used, jnp.int32).reshape(1), lhs, rhs)
+
+
+def gmm_reference(lhs, rhs, tile_expert, n_used, *, block_m=GMM_BLOCK_M):
+    """The lax fallback: ``jax.lax.ragged_dot`` over the same
+    tile-aligned layout, each expert's group being its tiles. Rows
+    past the used tiles are made zero, as the kernel leaves them (the
+    chip's ragged_dot leaves rows past the last group unwritten)."""
+    n_tiles = lhs.shape[0] // block_m
+    n_used = jnp.asarray(n_used, jnp.int32).reshape(())
+    used = jnp.arange(n_tiles) < n_used
+    sizes = jnp.zeros((rhs.shape[0],), jnp.int32).at[
+        tile_expert.astype(jnp.int32)].add(
+            jnp.where(used, block_m, 0).astype(jnp.int32))
+    # the precision stated: the operands are what they are (bf16 on the
+    # chip), and under a caller's `default_matmul_precision("highest")`
+    # the TPU's ragged_dot kernel refuses bf16 operands ("Bad lhs type")
+    out = jax.lax.ragged_dot(lhs, rhs, sizes,
+                             precision=jax.lax.Precision.DEFAULT,
+                             preferred_element_type=jnp.float32)
+    return jnp.where((jnp.arange(lhs.shape[0]) < n_used * block_m)[:, None],
+                     out, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# latent paged attention: MLA's absorbed form over a paged latent cache
+# ---------------------------------------------------------------------------
+
+LATENT_PAGES_PER_STEP = 32
+
+def _latent_write_kernel(tables_ref, pos_ref, len_ref, layer_ref, rows_ref,
+                         page_ref, o_ref, *, block_size, window):
+    """Grid (B, pages a window can touch): the page (found by the index
+    map, the null page where the window does not reach) is read, the
+    window's rows that fall into it are put in place by selects on the
+    page's row index, and the page is written back over itself."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    pos0 = pos_ref[b]
+    first = (pos0 // block_size + j) * block_size     # page's first position
+    shape = page_ref.shape[2:]
+    t = first + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    rows = rows_ref[0].astype(jnp.float32)             # [C, W]
+    page = page_ref[0, 0].astype(jnp.float32)
+    for c in range(window):
+        hit = (t == pos0 + c) & (c < len_ref[b])
+        page = jnp.where(hit, jnp.broadcast_to(rows[c:c + 1, :], shape),
+                         page)
+    o_ref[0, 0] = page.astype(o_ref.dtype)
+
+
+def latent_write(pool, rows, block_tables, positions, lengths, *, layer):
+    """Write a window's cache rows into the paged latent pool in place.
+
+    pool: ``[n_layers, num_blocks+1, block_size, width]`` WHOLE (aliased
+    to the result); rows: ``[B, C, width]``, row b's window slot c goes
+    to logical position ``positions[b] + c`` for ``c < lengths[b]`` (a
+    row with length 0 writes nothing). The pool's dtype may pack two
+    tokens into one sublane (bfloat16), where XLA's own scatter changes
+    the whole pool's layout to update a row and copies it back for the
+    attention kernel: twice the pool in HBM (docs/KERNELS.md). This
+    kernel reads and rewrites whole pages instead."""
+    B, C, W = rows.shape
+    bs = pool.shape[2]
+    Mb = block_tables.shape[1]
+    n_pages = 1 if C == 1 else -(-(C - 1) // bs) + 1
+    pos = jnp.maximum(positions, 0).astype(jnp.int32)
+    lens = lengths.astype(jnp.int32)
+
+    def page(b, j, tables, pos, lens, layer):
+        slot = pos[b] // bs + j
+        reached = (lens[b] > 0) & (slot * bs < pos[b] + lens[b]) \
+            & (slot < Mb)
+        return (layer[0],
+                jnp.where(reached, tables[b, jnp.minimum(slot, Mb - 1)], 0),
+                0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_latent_write_kernel, block_size=bs, window=C),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B, n_pages),
+            in_specs=[pl.BlockSpec((1, C, W), lambda b, j, *_: (b, 0, 0)),
+                      pl.BlockSpec((1, 1, bs, W), page)],
+            out_specs=pl.BlockSpec((1, 1, bs, W), page)),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operand 5 (after the four prefetched scalars and the rows) is
+        # the pool: the result is the same buffer
+        input_output_aliases={5: 0},
+        interpret=_device.pallas_interpret(),
+        name="latent_write",
+    )(block_tables.astype(jnp.int32), pos, lens,
+      jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(pool.dtype),
+      pool)
+
+
+def latent_write_reference(pool, rows, block_tables, positions, lengths,
+                           *, layer):
+    """The lax fallback: one scatter; a slot past its row's length is
+    dropped (an index past the pool)."""
+    B, C, W = rows.shape
+    bs = pool.shape[2]
+    Mb = block_tables.shape[1]
+    slots = jnp.arange(C, dtype=jnp.int32)[None, :]
+    pos2d = jnp.maximum(positions, 0)[:, None] + slots
+    valid = (slots < lengths[:, None]) & (pos2d < Mb * bs)
+    blk = jnp.where(valid, jnp.take_along_axis(
+        block_tables, jnp.clip(pos2d // bs, 0, Mb - 1), axis=1),
+        pool.shape[1])
+    return pool.at[layer, blk, pos2d % bs].set(rows.astype(pool.dtype),
+                                               mode="drop")
+
+
+
+def _latent_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
+                        pool_ref, o_ref, buf, sems, m_scr, l_scr, acc_scr,
+                        *, block_size, pages, window, n_heads, v_width):
+    """Grid (B,): one row a grid step. The pool stays in HBM; the row's
+    own pages, and no others, are copied into one of two VMEM buffers
+    in runs of ``pages`` (one DMA a page, all of a run on one
+    semaphore, the next run in flight while this one is attended), each
+    run being one ``[pages * block_size, width]`` key block whose first
+    ``v_width`` lanes are the value. The online-softmax state is
+    carried over the row's runs.
+
+    Query rows are ``[window * n_heads, width]``, slot-major: row r is
+    head ``r % n_heads`` of window slot ``r // n_heads``, which sees
+    logical positions ``t <= pos + slot``. A row whose window holds one
+    token (every decode row of a mixed step) computes its first
+    ``n_heads`` query rows only. Lines of a buffer past the row's last
+    page keep an earlier row's values: finite (the buffers are zeroed
+    once) and masked by position."""
+    b = pl.program_id(0)
+    bs, P = block_size, pages
+    span = P * bs
+    n_tok = jnp.maximum(len_ref[b], 1)
+    pos0 = pos_ref[b]
+    n_pages = (pos0 + n_tok - 1) // bs + 1
+    n_runs = (n_pages + P - 1) // P
+    layer = layer_ref[0]
+
+    @pl.when(b == 0)
+    def _zero():
+        buf[...] = jnp.zeros_like(buf)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def copies(run, half, start):
+        def page(p, carry):
+            copy = pltpu.make_async_copy(
+                pool_ref.at[layer, tables_ref[b, run * P + p]],
+                buf.at[half, pl.ds(pl.multiple_of(p * bs, bs), bs)],
+                sems.at[half])
+            copy.start() if start else copy.wait()
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+
+    def attend(run, half, nq):
+        k = buf[half]
+        s = jax.lax.dot_general(
+            q_ref[0, :nq, :], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [nq, span]
+        t_pos = run * span + jax.lax.broadcasted_iota(
+            jnp.int32, (nq, span), 1)
+        slot = jax.lax.broadcasted_iota(
+            jnp.int32, (nq, span), 0) // n_heads
+        s = jnp.where(t_pos <= pos0 + slot, s, _NEG_INF)
+        m_prev = m_scr[:nq, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:nq, :1] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_scr[:nq, :] = acc_scr[:nq, :] * alpha + jax.lax.dot_general(
+            p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:nq, :] = jnp.broadcast_to(m_new, (nq, m_scr.shape[1]))
+        l_scr[:nq, :] = jnp.broadcast_to(l_new, (nq, l_scr.shape[1]))
+
+    copies(0, 0, True)
+
+    def one_run(run, carry):
+        half = run % 2
+
+        @pl.when(run + 1 < n_runs)
+        def _next():
+            copies(run + 1, 1 - half, True)
+
+        copies(run, half, False)
+        if window == 1:
+            attend(run, half, n_heads)
+        else:
+            pl.when(n_tok == 1)(lambda: attend(run, half, n_heads))
+            pl.when(n_tok > 1)(lambda: attend(run, half, window * n_heads))
+        return carry
+
+    jax.lax.fori_loop(0, n_runs, one_run, 0)
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def latent_paged_attention(pool, q, block_tables, positions, lengths, *,
+                           layer, v_width,
+                           pages_per_step=LATENT_PAGES_PER_STEP):
+    """Absorbed-form latent attention over a paged latent cache.
+
+    pool: ``[n_layers, num_blocks+1, block_size, width]``, the latent
+    ``KVBlockPool`` array WHOLE (one row a token a layer: the
+    normalised latent, then the rotated shared key); it is left in HBM
+    and ``layer`` and the block table pick the pages the kernel copies.
+    q: ``[B, C, H, width]`` queries in the cache's own space
+    (``q_nope @ W_UK`` beside the rotated ``q_pe``), ALREADY scaled.
+    positions: ``[B]`` int32, each row's first window position;
+    lengths: ``[B]`` int32, tokens in its window (window slot c sees
+    ``t <= positions[b] + c``; the window's own rows are written before
+    the call). Slots at or past a row's length are meaningless; for a
+    row of at most one token they are not computed and come out zero.
+
+    Returns the ``[B, C, H, v_width]`` fp32 context in latent space
+    (``@ W_UV`` follows). The query is rounded to the pool's dtype for
+    the MXU; softmax and both accumulations are fp32."""
+    B, C, H, W = q.shape
+    bs = pool.shape[2]
+    P = int(min(pages_per_step, block_tables.shape[1]))
+
+    def row(b, *_):
+        return (b, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_latent_attn_kernel, block_size=bs, pages=P,
+                          window=C, n_heads=H, v_width=v_width),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, C * H, W), row),
+                      pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=pl.BlockSpec((1, C * H, v_width), row),
+            scratch_shapes=[
+                pltpu.VMEM((2, P * bs, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((C * H, 128), jnp.float32),
+                pltpu.VMEM((C * H, 128), jnp.float32),
+                pltpu.VMEM((C * H, v_width), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, C * H, v_width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_device.pallas_interpret(),
+        name="latent_paged_attention",
+    )(block_tables.astype(jnp.int32),
+      jnp.maximum(positions, 0).astype(jnp.int32),
+      lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      q.reshape(B, C * H, W).astype(pool.dtype), pool)
+    return out.reshape(B, C, H, v_width)
+
+
+def latent_paged_attention_reference(pool, q, block_tables, positions,
+                                     lengths, *, layer, v_width,
+                                     pages_per_step=None):
+    """The lax fallback: the layer's pages gathered through the block
+    table, then the same absorbed attention, masked softmax in fp32."""
+    B, C, H, W = q.shape
+    # the kernel's roundings (query and weights to the pool's dtype),
+    # the products themselves in float32
+    ctx = _gathered_context(pool, layer, block_tables) \
+        .astype(jnp.float32)                               # [B, T, W]
+    T = ctx.shape[1]
+    scores = jnp.einsum("bchw,btw->bcht",
+                        q.astype(pool.dtype).astype(jnp.float32), ctx)
+    q_pos = (jnp.maximum(positions, 0)[:, None]
+             + jnp.arange(C, dtype=jnp.int32)[None, :])    # [B, C]
+    valid = jnp.arange(T)[None, None, :] <= q_pos[:, :, None]
+    scores = jnp.where(valid[:, :, None, :], scores, -jnp.inf)
+    w = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    out = jnp.einsum("bcht,btv->bchv",
+                     w.astype(pool.dtype).astype(jnp.float32),
+                     ctx[..., :v_width])
+    # as the kernel: a one-token row computes its first slot only
+    skipped = ((lengths <= 1)[:, None]
+               & (jnp.arange(C, dtype=jnp.int32)[None, :] >= 1))
+    return jnp.where(skipped[:, :, None, None], 0.0, out)
+
+
+# ---------------------------------------------------------------------------
 # registry entries (ops/kernel_registry — docs/KERNELS.md qualification
 # table; importing this module is what populates the registry)
 # ---------------------------------------------------------------------------
@@ -618,6 +986,25 @@ def _int8_qualify(x=None, w=None, *args, **kwargs):
     return True, None
 
 
+def _gmm_qualify(rows=None, k=None, n=None, block_m=GMM_BLOCK_M):
+    """The weight block is an expert's whole ``[K, N]`` matrix, held
+    twice in VMEM (double buffering) beside the row and output tiles."""
+    if rows is not None and rows % block_m:
+        return False, "rows not a multiple of block_m"
+    if k is not None and n is not None and 2 * k * n * 2 > 40 * 2 ** 20:
+        return False, "an expert's matrix does not fit VMEM twice"
+    return True, None
+
+
+def _latent_qualify(width=None, v_width=None, block_size=None,
+                    window=None):
+    if block_size is not None and block_size % 8:
+        return False, "block_size not a multiple of 8 (pages are stacked)"
+    if v_width is not None and v_width % 128:
+        return False, "v_width not a multiple of 128 (a lane slice)"
+    return True, None
+
+
 def _register_all():
     from .kernel_registry import register_kernel
 
@@ -643,6 +1030,31 @@ def _register_all():
         doc="tree-mask verify window (width x depth token tree, one "
             "kernel) over the paged cache — in-window visibility by "
             "ancestor matrix via one-hot matmul; default: TPU only")
+    register_kernel(
+        "gmm", gmm, gmm_reference,
+        qualify=_gmm_qualify, default_on=_device.on_tpu,
+        doc="grouped expert matmul over tile-aligned rows sorted by "
+            "expert, the weight block found by a scalar-prefetched "
+            "table; default: TPU only")
+    register_kernel(
+        "latent_decode", latent_paged_attention,
+        latent_paged_attention_reference,
+        qualify=_latent_qualify, default_on=_device.on_tpu,
+        doc="one-token absorbed MLA attention over the paged latent "
+            "cache, all heads on one shared row a token; default: TPU "
+            "only")
+    register_kernel(
+        "latent_window", latent_paged_attention,
+        latent_paged_attention_reference,
+        qualify=_latent_qualify, default_on=_device.on_tpu,
+        doc="the same kernel over a chunk window of query slots "
+            "(one-token rows compute one slot); default: TPU only")
+    register_kernel(
+        "latent_write", latent_write, latent_write_reference,
+        qualify=_latent_qualify, default_on=_device.on_tpu,
+        doc="a window's rows written into the paged latent pool page by "
+            "page, in place (XLA's scatter into a packed bf16 pool "
+            "copies the pool); default: TPU only")
     register_kernel(
         "int8_matmul", int8_matmul, int8_matmul_reference,
         qualify=_int8_qualify, default_on=_device.on_tpu,

@@ -12,7 +12,11 @@ caching (content-addressed refcounted KV block sharing across requests,
 ``prefix_cache=`` / ``$PTPU_SERVE_PREFIX_CACHE``) and speculative
 decoding (draft-k tokens — n-gram prompt lookup by default, or a
 pluggable draft model — verified in one batched target step,
-``spec_k=`` / ``$PTPU_SERVE_SPEC_K``). ``native_serve`` remains the
+``spec_k=`` / ``$PTPU_SERVE_SPEC_K``). A second decoder block,
+latent attention over a paged latent cache with sigmoid-routed experts
+(``GenerationConfig(block=LatentMoEBlock(...))``, ``latent_moe.py``),
+runs through the same engine, scheduler and pool accounting.
+``native_serve`` remains the
 Python-free deployment backend for the same exported artifact
 directory.
 
@@ -23,8 +27,9 @@ directory.
 """
 
 from .engine import ServingEngine  # noqa: F401
-from .kv_cache import (KVBlockPool, blocks_needed,  # noqa: F401
-                       prefix_chain_keys)
+from .kv_cache import (CacheEntry, KVBlockPool,  # noqa: F401
+                       blocks_needed, prefix_chain_keys)
+from .latent_moe import LatentMoEBlock  # noqa: F401
 from .loadgen import PoissonLoadGenerator  # noqa: F401
 from .model import (GenerationArtifactError,  # noqa: F401
                     GenerationConfig, GenerationModel,
@@ -41,7 +46,8 @@ from .scheduler import (AdmissionError,  # noqa: F401
                         spec_tree_acceptance)
 
 __all__ = ["ServingEngine", "ServingRouter", "RouterRequest",
-           "KVBlockPool", "blocks_needed", "prefix_chain_keys",
+           "KVBlockPool", "CacheEntry", "LatentMoEBlock", "blocks_needed",
+           "prefix_chain_keys",
            "PoissonLoadGenerator", "GenerationConfig", "GenerationModel",
            "GenerationArtifactError", "ModelDrafter", "NGramDrafter",
            "extract_decoder_weights", "load_generation_artifact",

@@ -141,8 +141,11 @@ class _ModelWorker:
             # sequence concurrently (no admission stalls from the pool)
             num_blocks = max_batch * blocks_needed(max_seq_len,
                                                    block_size)
+        # the model says what one token's cache entry is; the pool's
+        # accounting is the same for every entry
         self.pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
-                                block_size, num_blocks)
+                                block_size, num_blocks,
+                                entry=model.cache_entry())
         # chunk-size budgeting: the chunk is a compiled shape, so it is
         # clamped to the context; the per-step token budget (default
         # 4 chunks) bounds how much prefill compute a MIXED step carries
@@ -210,10 +213,14 @@ class _ModelWorker:
         # the second compiled shape (mixed prefill/decode window); jit
         # is lazy, so geometry that never sees a prompt mid-flight still
         # traces exactly one step
+        # (a mixed step holds at most one token a row plus the
+        # scheduler's prefill budget: a block that computes the window's
+        # real tokens only runs that many rows)
         self._chunk_step = (
-            model.make_prefill_step(self.max_batch,
-                                    self.scheduler.max_blocks_per_seq,
-                                    self.prefill_chunk)
+            model.make_prefill_step(
+                self.max_batch, self.scheduler.max_blocks_per_seq,
+                self.prefill_chunk,
+                max_tokens=self.max_batch + prefill_token_budget)
             if self.prefill_chunk else None)
         # the speculative verify window (third compiled shape; jit is
         # lazy, so geometry that never speculates still traces nothing).
@@ -613,6 +620,12 @@ class _ModelWorker:
         where either end is not a completion the host saw."""
         was_ready, t_wait, t_ready = waited
         rec["t_wait"], rec["t_ready"] = t_wait, t_ready
+        if "_counters" in rec:
+            # the block's device counters: the step that made them has
+            # completed, so this is a few bytes over the host link
+            rec.update(zip(self.model.step_counters,
+                           (int(c) for c in np.asarray(
+                               rec.pop("_counters")))))
         rec["t_done"] = time.perf_counter()
         rec["wait_ms"] = (t_ready - t_wait) * 1e3
         prev = self._last_consumed
@@ -671,19 +684,26 @@ class _ModelWorker:
         traces0 = self.model.trace_count
         with _phase(tick, "dispatch"):
             weights = {n: self.scope.get(n) for n in self._weight_names}
+            # a step takes the pool's arrays (K and V, or one latent
+            # array) and returns them updated, then its tokens, then
+            # whatever counters its block reduces on the device
+            arrays = self.pool.arrays
             if chunked:
-                self.pool.k, self.pool.v, next_tokens = self._chunk_step(
-                    weights, self.pool.k, self.pool.v,
+                out = self._chunk_step(
+                    weights, *arrays,
                     sched.chunk_feed.copy(), sched.use_prompt.copy(),
                     self._prev_tokens, sched.positions.copy(),
                     sched.chunk_lens.copy(), sched.block_tables.copy(),
                     sched.active.copy())
             else:
-                self.pool.k, self.pool.v, next_tokens = self._step(
-                    weights, self.pool.k, self.pool.v,
+                out = self._step(
+                    weights, *arrays,
                     sched.prompt_feed.copy(), sched.use_prompt.copy(),
                     self._prev_tokens, sched.positions.copy(),
                     sched.block_tables.copy(), sched.active.copy())
+            self.pool.arrays = tuple(out[:len(arrays)])
+            next_tokens = out[len(arrays)]
+            counters = out[len(arrays) + 1:]
         self._steps_dispatched += 1
         rec = None
         if tick is not None:
@@ -699,6 +719,13 @@ class _ModelWorker:
                 tick, "mixed" if chunked else "decode", occupancy,
                 n_prefill, n_decode, n_prefill + n_decode, slots_total,
                 traces0)
+            if counters:
+                # the context the step attends: each active row's
+                # position after it (the sum of their lengths)
+                lens = sched.chunk_lens if chunked else 1
+                rec["cached_tokens"] = int(
+                    ((sched.positions + lens) * sched.active).sum())
+                rec["_counters"] = counters[0]   # read when consumed
             if _tracing.enabled():
                 # request-scoped view of the same step: one window event
                 # per traced request riding this dispatch, so a

@@ -3,10 +3,15 @@
 decode step) — with content-addressed **radix prefix caching** (SGLang
 RadixAttention mapped onto flat block tables).
 
-The device side is two dense arrays per model —
-``k``/``v`` of shape ``[n_layers, num_blocks + 1, block_size, n_heads,
-head_dim]`` — that the jitted decode step takes as donated arguments and
-returns updated, so the pool never round-trips over the host link. A
+The device side is one dense array per PART of a token's cache entry,
+``[n_layers, num_blocks + 1, block_size, *part_shape]``, which the
+jitted steps take as donated arguments and return updated, so the pool
+never round-trips over the host link. What one token's entry is the
+MODEL says (:class:`CacheEntry`, ``GenerationModel.cache_entry()``): a
+per-head cache states two parts ``k`` and ``v`` of ``[n_heads,
+head_dim]`` (the default, float32), a latent cache one part of
+``[width]`` shared by every head and no ``v``. The block accounting
+below is the same whatever the entry. A
 sequence's cache is NOT contiguous: it owns an ordered list of block ids
 (its *block table*). The paged kernels take ``k``/``v`` whole and find
 ``(layer, block_table[b, j])`` in their index map; no step slices a
@@ -70,9 +75,33 @@ allocation, so outstanding reservations can never be left unbacked
 import hashlib
 from collections import OrderedDict
 
-import numpy as np
+__all__ = ["CacheEntry", "KVBlockPool", "blocks_needed",
+           "prefix_chain_keys"]
 
-__all__ = ["KVBlockPool", "blocks_needed", "prefix_chain_keys"]
+
+class CacheEntry:
+    """What a model caches for one token in one layer: named parts, each
+    with the shape of one token's values, and the dtype they are stored
+    in. ``CacheEntry.per_head(H, Dh)`` is the K and V of a multi-head
+    block; a latent block states ``CacheEntry((("latent", (width,)),),
+    "bfloat16")``."""
+
+    __slots__ = ("parts", "dtype")
+
+    def __init__(self, parts, dtype="float32"):
+        self.parts = tuple((str(n), tuple(int(d) for d in shape))
+                           for n, shape in parts)
+        if not self.parts:
+            raise ValueError("a cache entry needs at least one part")
+        self.dtype = str(dtype)
+
+    @classmethod
+    def per_head(cls, n_heads, head_dim, dtype="float32"):
+        return cls((("k", (n_heads, head_dim)), ("v", (n_heads, head_dim))),
+                   dtype)
+
+    def __repr__(self):
+        return "CacheEntry(%r, %r)" % (self.parts, self.dtype)
 
 
 def blocks_needed(num_tokens, block_size):
@@ -115,7 +144,10 @@ class KVBlockPool:
     NULL_BLOCK = 0
 
     def __init__(self, n_layers, n_heads, head_dim, block_size,
-                 num_blocks, dtype="float32", device=None):
+                 num_blocks, dtype=None, device=None, entry=None):
+        """``entry`` is the model's :class:`CacheEntry`; without one the
+        pool holds K and V ``[n_heads, head_dim]`` a token. ``dtype``,
+        when given, overrides the entry's."""
         if num_blocks < 1:
             raise ValueError("KVBlockPool needs at least one usable block")
         if block_size < 1:
@@ -125,21 +157,24 @@ class KVBlockPool:
         self.head_dim = int(head_dim)
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
-        self.dtype = np.dtype(dtype)
+        if entry is None:
+            entry = CacheEntry.per_head(self.n_heads, self.head_dim)
+        self.entry = entry
 
+        import contextlib
+
+        import jax
         import jax.numpy as jnp
 
-        shape = (self.n_layers, self.num_blocks + 1, self.block_size,
-                 self.n_heads, self.head_dim)
-        if device is not None:
-            import jax
-
-            with jax.default_device(device):
-                self.k = jnp.zeros(shape, self.dtype)
-                self.v = jnp.zeros(shape, self.dtype)
-        else:
-            self.k = jnp.zeros(shape, self.dtype)
-            self.v = jnp.zeros(shape, self.dtype)
+        # via jnp so that bfloat16 (no numpy type of its own) is a name
+        self.dtype = jnp.dtype(dtype if dtype is not None else entry.dtype)
+        lead = (self.n_layers, self.num_blocks + 1, self.block_size)
+        with (jax.default_device(device) if device is not None
+              else contextlib.nullcontext()):
+            # one device array per part, in the entry's order; the steps
+            # take and return them as a tuple
+            self.arrays = tuple(jnp.zeros(lead + shape, self.dtype)
+                                for _name, shape in entry.parts)
 
         from ..analysis.concurrency import make_lock
 
@@ -165,6 +200,24 @@ class KVBlockPool:
         # target-pool rollback volume is auditable per pool)
         self.truncate_calls = 0
         self.blocks_truncated = 0
+
+    # -- the two parts of a per-head entry, by name ----------------------
+    def _part(self, name):
+        for i, (n, _shape) in enumerate(self.entry.parts):
+            if n == name:
+                return i
+        raise AttributeError(
+            "this pool's cache entry %r has no part %r"
+            % (self.entry, name))
+
+    def _set_part(self, name, value):
+        i = self._part(name)
+        self.arrays = self.arrays[:i] + (value,) + self.arrays[i + 1:]
+
+    k = property(lambda self: self.arrays[self._part("k")],
+                 lambda self, v: self._set_part("k", v))
+    v = property(lambda self: self.arrays[self._part("v")],
+                 lambda self, v: self._set_part("v", v))
 
     # -- accounting ----------------------------------------------------
     @property

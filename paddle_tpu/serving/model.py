@@ -146,10 +146,23 @@ def _kernel_key_suffix():
 
 
 class GenerationConfig:
-    """Decoder-only LM hyperparameters (transformer_fluid.build shape)."""
+    """Decoder-only LM hyperparameters (transformer_fluid.build shape).
+
+    ``block`` is the ONE description of the decoder block that selects
+    the forwards, the weight layout and the cache entry: ``None`` is the
+    pre-LN multi-head block of ``transformer_fluid.build`` (XGLM's); a
+    :class:`~paddle_tpu.serving.latent_moe.LatentMoEBlock` (or its
+    ``to_dict()``) is the latent-attention / routed-expert block, whose
+    dense width is ``d_ff`` and which ignores ``pe_alpha``/``pe_beta``
+    (rotary positions)."""
 
     def __init__(self, vocab_size, d_model, n_heads, n_layers, d_ff,
-                 max_seq_len=512, pe_alpha=1.0, pe_beta=1.0):
+                 max_seq_len=512, pe_alpha=1.0, pe_beta=1.0, block=None):
+        if isinstance(block, dict):
+            from .latent_moe import LatentMoEBlock
+
+            block = LatentMoEBlock.from_dict(block)
+        self.block = block
         if d_model % n_heads:
             raise ValueError("n_heads must divide d_model")
         self.vocab_size = int(vocab_size)
@@ -166,9 +179,12 @@ class GenerationConfig:
         return self.d_model // self.n_heads
 
     def to_dict(self):
-        return {k: getattr(self, k) for k in
-                ("vocab_size", "d_model", "n_heads", "n_layers", "d_ff",
-                 "max_seq_len", "pe_alpha", "pe_beta")}
+        d = {k: getattr(self, k) for k in
+             ("vocab_size", "d_model", "n_heads", "n_layers", "d_ff",
+              "max_seq_len", "pe_alpha", "pe_beta")}
+        if self.block is not None:   # absent: artifacts stay as written
+            d["block"] = self.block.to_dict()
+        return d
 
     @classmethod
     def from_dict(cls, d):
@@ -182,6 +198,10 @@ _LAYER_KEYS = ("ln1_scale", "ln1_bias", "wqkv", "bqkv", "wproj", "bproj",
 
 
 def weight_names(config):
+    if config.block is not None:
+        from . import latent_moe
+
+        return latent_moe.weight_names(config)
     names = ["embedding", "lm_head", "final_ln_scale", "final_ln_bias"]
     for i in range(config.n_layers):
         names.extend("l%d/%s" % (i, k) for k in _LAYER_KEYS)
@@ -202,6 +222,10 @@ def _position_encoding_table(config):
 def random_weights(config, seed=0, scale=0.1):
     """Deterministic random weights (tests/bench: a servable model with
     no training program behind it)."""
+    if config.block is not None:
+        from . import latent_moe
+
+        return latent_moe.random_weights(config, seed, scale)
     rng = np.random.RandomState(seed)
     D, F, V = config.d_model, config.d_ff, config.vocab_size
 
@@ -440,6 +464,15 @@ def save_generation_artifact(dirname, config, weights):
     import os
     import shutil
 
+    if config.block is not None:
+        # this writer normalises every leaf to float32; a block that
+        # states its storage dtype must not be written as something else
+        raise NotImplementedError(
+            "save_generation_artifact writes float32 leaves only: the %s "
+            "block stores %s weights, and its artifact (the block "
+            "description and per-leaf dtypes in the meta file) is not "
+            "built yet (ROADMAP Queue 2a)"
+            % (config.block.kind, config.block.weight_dtype))
     dirname = os.path.abspath(dirname)
     parent = os.path.dirname(dirname) or "."
     os.makedirs(parent, exist_ok=True)
@@ -549,6 +582,10 @@ def load_generation_artifact(dirname, name=None, quantize=None,
         verify_generation_artifact(dirname)
     with open(meta_path) as f:
         config = GenerationConfig.from_dict(json.load(f))
+    if config.block is not None:
+        raise NotImplementedError(
+            "%s describes a %s block: this loader reads float32 leaves "
+            "only (ROADMAP Queue 2a)" % (dirname, config.block.kind))
     try:
         with np.load(os.path.join(dirname, GENERATION_WEIGHTS)) as z:
             weights = {k: z[k] for k in z.files}
@@ -593,12 +630,23 @@ class GenerationModel:
             raise ValueError("missing weights: %s" % missing[:4])
         import jax.numpy as jnp
 
-        # int8 entries (the weight-only-quantized store) keep their
-        # dtype; everything else normalizes to fp32 as before
-        self.weights = {
-            k: jnp.asarray(v if np.asarray(v).dtype == np.int8
-                           else np.asarray(v, np.float32))
-            for k, v in weights.items()}
+        if config.block is not None:
+            # the block states each leaf's storage dtype; a leaf that is
+            # already a device array of it is taken as it is (no pass
+            # through the host: the weights may be most of the chip)
+            from . import latent_moe
+
+            self.weights = {
+                k: jnp.asarray(weights[k], dtype)
+                for k, (_s, dtype) in
+                latent_moe.leaf_shapes(config).items()}
+        else:
+            # int8 entries (the weight-only-quantized store) keep their
+            # dtype; everything else normalizes to fp32 as before
+            self.weights = {
+                k: jnp.asarray(v if np.asarray(v).dtype == np.int8
+                               else np.asarray(v, np.float32))
+                for k, v in weights.items()}
         self.weight_only_int8 = any(
             str(v.dtype) == "int8" for v in self.weights.values())
         # python-trace counter: the body below only executes while jax
@@ -614,15 +662,47 @@ class GenerationModel:
     def random(cls, config, seed=0, name="model"):
         return cls(config, random_weights(config, seed), name=name)
 
+    def cache_entry(self):
+        """What this model caches for one token in one layer
+        (``kv_cache.CacheEntry``): the pool allocates from it."""
+        from .kv_cache import CacheEntry
+
+        if self.config.block is not None:
+            return self.config.block.cache_entry()
+        return CacheEntry.per_head(self.config.n_heads,
+                                   self.config.head_dim)
+
+    @property
+    def step_counters(self):
+        """Names of the integers a compiled step of this model returns
+        after its tokens, reduced on the device (the step log's fields
+        of that name); the XGLM block has none."""
+        block = self.config.block
+        return () if block is None else block.step_counters
+
+    def _no_such_step(self, what):
+        if self.config.block is not None:
+            raise NotImplementedError(
+                "%s is not built for the %s block: speculative, tree and "
+                "draft steps over a latent cache are ROADMAP Queue 2a"
+                % (what, self.config.block.kind))
+
     # -- weight-only int8 ---------------------------------------------------
     def quantized(self, name=None):
         """The weight-only-int8 variant of this model: 2-D matmul
         weights become int8 + ``@qscale`` per-output-channel scales;
         biases, layer norms and the model structure are untouched.
         Records quant/{weights_quantized,weight_bytes_saved,
-        weight_fp32_bytes} telemetry."""
+        weight_fp32_bytes} telemetry.
+
+        The latent/expert block has no int8 store yet (ROADMAP Queue
+        2a) and says so."""
         from ..quant import quantize_symmetric, record_weight_store
 
+        if self.config.block is not None:
+            raise NotImplementedError(
+                "quantized(): the %s block has no int8 weight store "
+                "(ROADMAP Queue 2a)" % self.config.block.kind)
         if self.weight_only_int8:
             return self
         qw = {}
@@ -780,6 +860,13 @@ class GenerationModel:
         import jax.numpy as jnp
 
         cfg = self.config
+        if cfg.block is not None:
+            from . import latent_moe
+
+            jitted = self._instrument_step(
+                "decode", latent_moe.make_decode_step(self, return_logits))
+            self._steps[key] = jitted
+            return jitted
         pe = jnp.asarray(_position_encoding_table(cfg))
         emb_scale = float(cfg.d_model) ** 0.5
 
@@ -987,7 +1074,7 @@ class GenerationModel:
             return kv_k, kv_v, x_last @ self._w(jnp, weights, "lm_head")
 
     def make_prefill_step(self, max_batch, max_blocks_per_seq, chunk,
-                          return_logits=False):
+                          return_logits=False, max_tokens=None):
         """Build (and cache) the jitted fixed-shape CHUNKED step for
         this engine geometry — the mixed prefill/decode shape
         (docs/SERVING.md). Calling convention:
@@ -1004,7 +1091,25 @@ class GenerationModel:
         slot chains ``prev_tokens`` on device. ``next_tokens[b]`` is
         the greedy token at the row's last valid slot — meaningful when
         the window consumed the final prompt token (the first generated
-        token) or for decode rows. The KV arrays are donated."""
+        token) or for decode rows. The KV arrays are donated.
+
+        ``max_tokens`` is the caller's promise of how many tokens a
+        window can hold at once (the engine: ``max_batch`` plus the
+        scheduler's prefill budget). The dense block computes every slot
+        and ignores it; the latent/expert block compacts the window's
+        real tokens to that many rows for everything a token does alone
+        (``None``: every slot)."""
+        if self.config.block is not None:
+            from . import latent_moe
+
+            key = ("chunk", int(max_batch), int(max_blocks_per_seq),
+                   int(chunk), bool(return_logits),
+                   max_tokens) + _kernel_key_suffix()
+            if key not in self._steps:
+                self._steps[key] = self._instrument_step(
+                    "chunk", latent_moe.make_window_step(
+                        self, int(chunk), return_logits, max_tokens))
+            return self._steps[key]
         return self._make_window_step("chunk", max_batch,
                                       max_blocks_per_seq, chunk,
                                       all_slots=False,
@@ -1105,6 +1210,7 @@ class GenerationModel:
         every window emits at least one sequential-greedy-identical
         token. Slots at or past ``lengths[b]`` write to the null block
         and their outputs are meaningless. The KV arrays are donated."""
+        self._no_such_step("the speculative verify window")
         return self._make_window_step("spec", max_batch,
                                       max_blocks_per_seq, window,
                                       all_slots=True,
@@ -1138,6 +1244,7 @@ class GenerationModel:
         to the null block. At ``width == 1`` the mask, positions and
         outputs are numerically the linear verify window. The KV arrays
         are donated."""
+        self._no_such_step("the tree verify window")
         width, depth = int(width), int(depth)
         return self._make_window_step("spec_tree", max_batch,
                                       max_blocks_per_seq,
@@ -1169,6 +1276,7 @@ class GenerationModel:
         dispatches this BEFORE ``truncate_owner`` re-points the tail
         blocks, so sources always live in still-owned blocks. Pure data
         movement — no weights are read. The KV arrays are donated."""
+        self._no_such_step("the tree commit step")
         key = ("tree_commit", int(max_batch), int(max_blocks_per_seq),
                int(window)) + _kernel_key_suffix()
         if key in self._steps:
@@ -1229,6 +1337,7 @@ class GenerationModel:
         deactivates rows near the cap — inactive rows write to the null
         block and their outputs are ignored). The KV arrays are
         donated."""
+        self._no_such_step("the draft step")
         key = ("draft", int(max_batch), int(max_blocks_per_seq),
                int(n_new)) + _kernel_key_suffix()
         if key in self._steps:
@@ -1479,6 +1588,7 @@ class ModelDrafter:
         if not isinstance(model, GenerationModel):
             raise TypeError("ModelDrafter needs a GenerationModel, got "
                             "%r" % (type(model).__name__,))
+        model._no_such_step("a draft model")
         self.model = model
         self.draft_steps = 0
         self._block_size = int(block_size)
@@ -1738,6 +1848,12 @@ def reference_decode(model, prompt, max_new_tokens, eos_id=None):
     import jax.numpy as jnp
 
     cfg = model.config
+    if cfg.block is not None:
+        raise NotImplementedError(
+            "reference_decode is the XGLM block's oracle; the %s block's "
+            "plain reference is perfbench/reference/kanana.py "
+            "(tests/test_latent_moe.py compares against it)"
+            % cfg.block.kind)
     w = model.dequantized_weights() if model.weight_only_int8 \
         else model.weights
     pe = _position_encoding_table(cfg)

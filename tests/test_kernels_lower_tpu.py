@@ -285,3 +285,68 @@ def test_serving_step_reads_the_kv_pool_in_place(kind, pool_model,
         assert "tpu_custom_call" in compiled.as_text()
         assert not others, others
         assert temp < layer * 4, temp
+
+
+# ---------------------------------------------------------------------------
+# the latent block's steps update and read the bf16 latent pool in place
+# (PR 27)
+# ---------------------------------------------------------------------------
+
+LATENT_STEP = dict(batch=8, blocks_per_seq=16, block_size=16, num_blocks=4096,
+                   chunk=16)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_latent_step_updates_the_bf16_pool_in_place(kind, v5e_chip):
+    """The latent pool is bfloat16, two tokens to a sublane, and XLA's
+    own scatter into it re-laid the WHOLE pool out to update a row and
+    copied it back for the attention kernel (18.25 GB asked of a 15.75
+    GB chip at the benchmark's size); a pool whose rows are 576 lanes
+    wide is laid out by the runtime with the blocks minor-most, and every
+    step copied it into the kernels' layout and back. So the rows are
+    written by `latent_write` (whole pages, in place), the entry is
+    stated in whole 128-lane tiles, and the compiled steps hold nothing
+    of the pool's size but the kernels' own results."""
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    KVBlockPool, latent_moe)
+
+    g = LATENT_STEP
+    block = dict(chip_smoke.FULL.latent["block"], n_routed_experts=8)
+    cfg = GenerationConfig(
+        **dict(chip_smoke.FULL.latent, vocab_size=1024, block=block),
+        max_seq_len=g["blocks_per_seq"] * g["block_size"])
+    assert cfg.block.cache_width == 576 and cfg.block.cache_row == 640
+    model = GenerationModel.__new__(GenerationModel)
+    model.config, model.trace_count = cfg, 0
+    sharding = jax.sharding.SingleDeviceSharding(v5e_chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=sharding)
+
+    weights = {k: arg(s, d) for k, (s, d) in
+               latent_moe.leaf_shapes(cfg).items()}
+    B, Mb = g["batch"], g["blocks_per_seq"]
+    (latent,) = jax.eval_shape(lambda: KVBlockPool(
+        cfg.n_layers, cfg.n_heads, cfg.head_dim, g["block_size"],
+        g["num_blocks"], entry=cfg.block.cache_entry()).arrays)
+    assert latent.dtype == jnp.bfloat16 and latent.shape[-1] == 640
+    pool = arg(latent.shape, latent.dtype)
+    row, on, tables = arg((B,)), arg((B,), jnp.bool_), arg((B, Mb))
+    with device.compiling_for(v5e_chip):
+        if kind == "decode":
+            compiled = latent_moe.make_decode_step(model).lower(
+                weights, pool, row, on, row, row, tables, on).compile()
+        else:
+            compiled = latent_moe.make_window_step(
+                model, g["chunk"], max_tokens=B + 4 * g["chunk"]).lower(
+                weights, pool, arg((B, g["chunk"])), on, row, row, row,
+                tables, on).compile()
+    hlo = compiled.as_text()
+    for name in ("gmm", "latent_paged_attention", "latent_write"):
+        assert name in hlo, name
+    large = _large_results(hlo, latent.size)
+    assert large and all(op == "custom-call" for op, _, _ in large), large
+    # workspace: far under the pool (84 MB here), whatever the step holds
+    assert compiled.memory_analysis().temp_size_in_bytes < latent.size, \
+        compiled.memory_analysis()

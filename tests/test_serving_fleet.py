@@ -311,8 +311,16 @@ def test_stall_watchdog_failover():
     model = shared_model()
     prompts = _prompts(6, seed=5)
     refs = [reference_decode(model, p, 10) for p in prompts]
+    # The watchdog's contract (ServingRouter): the budget must exceed
+    # the worst single step, a first step's compile included. So the
+    # step is warmed before the router exists, and the budget is seconds,
+    # not the 0.4 s a loaded machine (six test workers) can hold a
+    # HEALTHY replica's millisecond step for: the wedged replica never
+    # steps again, so it is still the only one the watchdog can catch.
+    with _router(model, replicas=1) as primer:
+        primer.submit(prompts[0], max_new_tokens=2).wait(300)
     with _inject("serve_stall_at_step:5"):
-        with _router(model, stall_timeout_s=0.4) as router:
+        with _router(model, stall_timeout_s=3.0) as router:
             reqs = [router.submit(p, max_new_tokens=10) for p in prompts]
             outs = [r.wait(300) for r in reqs]
             st = router.stats()
