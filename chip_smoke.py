@@ -320,7 +320,12 @@ def leg_train(sz, rehearsal, shared):
 def serving_model(sz):
     from paddle_tpu.serving import GenerationConfig, GenerationModel
 
-    cfg = GenerationConfig(max_seq_len=sz.serve_ctx, **sz.model)
+    # heads of 128 lanes, as the served configurations have them: the
+    # chunk step's kernel copies pages as the pool stores them and
+    # takes no narrower head (the `kernels` leg runs the other paged
+    # kernels at the trainer's heads of 64 too)
+    cfg = GenerationConfig(max_seq_len=sz.serve_ctx, **dict(
+        sz.model, n_heads=max(1, sz.model["d_model"] // 128)))
     return GenerationModel.random(cfg, seed=7)
 
 
@@ -399,7 +404,8 @@ def leg_serve(sz, rehearsal):
         # off-TPU the kernels are off by default; the rehearsal forces
         # them through the interpreter so the same counters move
         os.environ["PTPU_KERNELS"] = "1"
-    names = ("paged_decode", "spec_window", "spec_window_tree")
+    names = ("paged_decode", "chunk_window", "spec_window",
+             "spec_window_tree")
     k0 = {n: counter("kernels/kernel:" + n) for n in names}
     fall0 = counter("kernels/fallbacks")
     chunk0 = counter("serving/prefill_chunk_steps")
@@ -597,6 +603,36 @@ def kernel_cases(sz):
     paged("spec_window", sz.spec_k + 1)
     paged("spec_window_tree", 1 + sz.spec_tree[0] * sz.spec_tree[1],
           tree_topology(*sz.spec_tree)[2].astype(f32))
+
+    # the chunk window as query tiles: a full tile deep in its row's
+    # context, the tile after it (a chunk cut short), one-token tiles
+    # (decode rows) at both ends of the context, and an unused tile
+    from paddle_tpu.serving.model import CHUNK_TILE
+
+    # (heads of 128 lanes, the only ones the kernel takes: it copies
+    # pages as the pool stores them)
+    Cq = min(CHUNK_TILE, sz.serve_ctx // 2)
+    Hc, Dc = max(1, H * Dh // 128), 128
+    pool = (2, NB, bs, Hc, Dc)
+    chunk_specs = [(pool, f32), (pool, f32), ((B, Cq, Hc, Dc), f32),
+                   ((B, Mb), i32), ((B,), i32), ((B,), i32)]
+
+    def fill_chunk(rng):
+        tables = rng.permutation(np.arange(1, B * Mb + 1)) \
+            .reshape(B, Mb).astype(i32)
+        room = Mb * bs
+        pos = rng.randint(0, room - 1, B).astype(i32)
+        lens = np.ones(B, i32)
+        pos[0], lens[0] = room - 2 * Cq, Cq
+        tables[1], pos[1], lens[1] = tables[0], room - Cq, Cq - 3
+        pos[2], pos[3] = 0, room - 1
+        lens[4:5] = 0                  # (the toy's four tiles have none)
+        return [rng.randn(*s).astype(f32) for s, _ in chunk_specs[:3]] \
+            + [tables, pos, lens]
+
+    cases["chunk_window"] = (
+        chunk_specs, {"layer": 1},
+        dict(head_dim=Dc, block_size=bs, window=Cq), fill_chunk)
 
     import jax.numpy as jnp
 

@@ -49,6 +49,16 @@ latent_paged_attention: attention over a paged LATENT cache (MLA,
   window (``kernel 'latent_window'``). ``latent_write`` puts a window's
   new rows into that pool in place, a page at a time.
 
+paged_chunk_attention: the chunked-prefill window's attention over the
+  same fp32 ``KVBlockPool`` (``kernel 'chunk_window'``). The window
+  arrives cut into QUERY TILES (a decode row is a tile of one token, a
+  prefilling row's chunk several tiles of ``Cq`` slots), each with its
+  row's block-table line; the pool stays in HBM and a tile's own pages,
+  up to the page of its last token and no further, are copied in runs
+  by manual DMA, as ``latent_paged_attention`` does. Nothing of
+  ``[B, C, H, T]`` exists, and a one-token tile computes one sublane
+  tile of query rows.
+
 Whether a kernel compiles or runs in the Pallas interpreter is decided in
 one place, ``core.device.pallas_interpret()``: compiled on TPU (a kernel
 Mosaic refuses raises), interpreted everywhere else so the CPU test mesh
@@ -73,7 +83,8 @@ __all__ = ["flash_attention", "flash_attention_portable",
            "paged_attention_tree_reference", "int8_matmul",
            "int8_matmul_reference", "gmm", "gmm_reference",
            "latent_paged_attention", "latent_paged_attention_reference",
-           "latent_write", "latent_write_reference"]
+           "latent_write", "latent_write_reference",
+           "paged_chunk_attention", "paged_chunk_attention_reference"]
 
 _NEG_INF = -1e30
 
@@ -438,8 +449,9 @@ def _gathered_context(pool, layer, block_tables):
     serving model's lax path does it: ``[B, Mb, bs, H, Dh]`` ->
     ``[B, T, H, Dh]``."""
     B, Mb = block_tables.shape
-    return pool[layer][block_tables].reshape(
-        (B, Mb * pool.shape[2]) + pool.shape[3:])
+    with jax.named_scope("kv_read"):
+        return pool[layer][block_tables].reshape(
+            (B, Mb * pool.shape[2]) + pool.shape[3:])
 
 
 def paged_attention_reference(k_pool, v_pool, q, block_tables,
@@ -460,6 +472,222 @@ def paged_attention_reference(k_pool, v_pool, q, block_tables,
     w = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
     w = w / jnp.sum(w, axis=-1, keepdims=True)
     return jnp.einsum("bcht,bthd->bchd", w, v_ctx)
+
+
+# ---------------------------------------------------------------------------
+# paged chunk attention: the chunked-prefill window over KVBlockPool pages,
+# cut into query tiles; the pool stays in HBM, a tile's pages come by DMA
+# ---------------------------------------------------------------------------
+
+CHUNK_PAGES_PER_STEP = 8
+
+
+def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
+                       k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_scr, l_scr,
+                       acc_scr, *, sm_scale, block_size, pages, n_heads):
+    """Grid (tiles,): one query tile a grid step. Both pools stay in
+    HBM; the pages of the tile's row, up to the page of the tile's LAST
+    token and no further, are copied into one of two VMEM buffers in
+    runs of ``pages`` (one DMA a page and pool, a run's copies on one
+    semaphore a pool, the next run in flight while this one is
+    attended). A run is one ``[pages * block_size, H, Dh]`` key block; a
+    head's rows are read out of it with a static index on the head axis,
+    as ``_paged_attn_kernel`` reads them out of a page. The online
+    softmax of every head is carried over the tile's runs.
+
+    Tile slot c holds the token at logical position ``pos + c`` and sees
+    ``t <= pos + c``. A tile of one token (every decode row of a mixed
+    step) computes its first sublane tile of query rows only; an empty
+    tile computes nothing. Slots at or past the tile's length come out
+    zero. Lines of a buffer past the tile's last page keep an earlier
+    tile's values: finite (the buffers are zeroed once) and masked by
+    position. The operands of both products are rounded to bfloat16,
+    which is what a default-precision fp32 dot does on the chip; the
+    softmax statistics and both accumulations are fp32."""
+    t = pl.program_id(0)
+    bs, P = block_size, pages
+    span = P * bs
+    Cq = q_ref.shape[1]
+    Dh = q_ref.shape[2] // n_heads
+    one = min(Cq, 16)                  # a bf16 sublane tile of query rows
+    n_tok = len_ref[t]
+    pos0 = pos_ref[t]
+    n_pages = (pos0 + jnp.maximum(n_tok, 1) - 1) // bs + 1
+    n_runs = (n_pages + P - 1) // P
+    layer = layer_ref[0]
+
+    @pl.when(t == 0)
+    def _zero():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def copies(run, half, start):
+        def page(p, carry):
+            blk = tables_ref[t, run * P + p]
+            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                copy = pltpu.make_async_copy(
+                    pool.at[layer, blk], buf.at[half, dst],
+                    sems.at[which, half])
+                copy.start() if start else copy.wait()
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+
+    def attend(run, half, nq):
+        t_pos = run * span + jax.lax.broadcasted_iota(
+            jnp.int32, (nq, span), 1)
+        mask = t_pos <= pos0 + jax.lax.broadcasted_iota(
+            jnp.int32, (nq, span), 0)
+        for h in range(n_heads):
+            q = q_ref[0, :nq, h * Dh:(h + 1) * Dh]         # [nq, Dh] bf16
+            k = kbuf[half, :, h, :].astype(jnp.bfloat16)    # [span, Dh]
+            v = vbuf[half, :, h, :].astype(jnp.bfloat16)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_scr[h, :nq, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[h, :nq, :1] * alpha \
+                + p.sum(axis=-1, keepdims=True)
+            acc_scr[h, :nq, :] = acc_scr[h, :nq, :] * alpha \
+                + jax.lax.dot_general(
+                    p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            m_scr[h, :nq, :] = jnp.broadcast_to(m_new, (nq, m_scr.shape[2]))
+            l_scr[h, :nq, :] = jnp.broadcast_to(l_new, (nq, l_scr.shape[2]))
+
+    def finish(nq):
+        live = jax.lax.broadcasted_iota(jnp.int32, (nq, Dh), 0) < n_tok
+        for h in range(n_heads):
+            o_ref[0, :nq, h * Dh:(h + 1) * Dh] = jnp.where(
+                live, acc_scr[h, :nq, :]
+                / jnp.maximum(l_scr[h, :nq, :1], 1e-30), 0.0)
+
+    def by_size(fn):
+        """`fn(nq)` for the tile's size: one token, or a window."""
+        if one == Cq:
+            fn(Cq)
+        else:
+            pl.when(n_tok == 1)(lambda: fn(one))
+            pl.when(n_tok > 1)(lambda: fn(Cq))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tok > 0)
+    def _tile():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        copies(0, 0, True)
+
+        def one_run(run, carry):
+            half = run % 2
+
+            @pl.when(run + 1 < n_runs)
+            def _next():
+                copies(run + 1, 1 - half, True)
+
+            copies(run, half, False)
+            by_size(lambda nq: attend(run, half, nq))
+            return carry
+
+        jax.lax.fori_loop(0, n_runs, one_run, 0)
+        by_size(finish)
+
+
+def paged_chunk_attention(k_pool, v_pool, q, block_tables, positions,
+                          lengths, *, layer, sm_scale=None,
+                          pages_per_step=CHUNK_PAGES_PER_STEP):
+    """The chunked-prefill window's attention over the paged KV cache,
+    the window cut into query tiles.
+
+    k_pool/v_pool: ``[n_layers, num_blocks+1, block_size, H, Dh]``, the
+    ``KVBlockPool`` arrays WHOLE (never ``k_pool[layer]``; see
+    :func:`paged_attention`); they are left in HBM and ``layer`` and the
+    block tables pick the pages the kernel copies. q: ``[N, Cq, H, Dh]``
+    query tiles: tile n holds ``lengths[n]`` consecutive tokens of ONE
+    sequence, slot c at logical position ``positions[n] + c``, and
+    ``block_tables[n]`` (``[N, Mb]``) is that sequence's block-table
+    line. A decode row is a tile of one token; a prefilling row's chunk
+    is ``ceil(tokens / Cq)`` tiles; a tile of length 0 is skipped. The
+    window's K and V are written before the call, so slot c sees
+    ``t <= positions[n] + c``: the committed prefix and the window's
+    earlier tokens.
+
+    Returns the ``[N, Cq, H, Dh]`` fp32 context, zero at slots at or
+    past a tile's length. Operands of both products rounded to bfloat16
+    (a default-precision fp32 dot on the chip), online softmax in fp32:
+    token-identical to the gathered reference, not bitwise."""
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    # the layer goes in as a traced scalar, under one jitted function:
+    # a step calls this once a layer, and tracing and lowering the
+    # kernel's body (two sizes of tile, every head unrolled) for each
+    # call cost 10 s of a 24-layer step's first call, compile cache or
+    # not (a cached program is found by its lowered text)
+    return _chunk_call(k_pool, v_pool, q, block_tables, positions,
+                       lengths, jnp.asarray(layer, jnp.int32),
+                       sm_scale=float(sm_scale),
+                       pages=int(min(pages_per_step,
+                                     block_tables.shape[1])),
+                       interpret=_device.pallas_interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "pages", "interpret"))
+def _chunk_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
+                *, sm_scale, pages, interpret):
+    N, Cq, H, Dh = q.shape
+    bs = k_pool.shape[2]
+
+    def tile(n, *_):
+        return (n, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    out = pl.pallas_call(
+        functools.partial(_chunk_attn_kernel, sm_scale=sm_scale,
+                          block_size=bs, pages=pages, n_heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(N,),
+            in_specs=[pl.BlockSpec((1, Cq, H * Dh), tile), hbm, hbm],
+            out_specs=pl.BlockSpec((1, Cq, H * Dh), tile),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, H, Dh), k_pool.dtype),
+                pltpu.VMEM((2, pages * bs, H, Dh), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((H, Cq, 128), jnp.float32),
+                pltpu.VMEM((H, Cq, 128), jnp.float32),
+                pltpu.VMEM((H, Cq, Dh), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((N, Cq, H * Dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="paged_chunk_attention",
+    )(block_tables.astype(jnp.int32),
+      jnp.maximum(positions, 0).astype(jnp.int32),
+      lengths.astype(jnp.int32), layer.reshape(1),
+      q.reshape(N, Cq, H * Dh).astype(jnp.bfloat16), k_pool, v_pool)
+    return out.reshape(N, Cq, H, Dh)
+
+
+def paged_chunk_attention_reference(k_pool, v_pool, q, block_tables,
+                                    positions, lengths, *, layer,
+                                    sm_scale=None, pages_per_step=None):
+    """The lax fallback over the same tiles: each tile's block-table
+    line gathered, masked softmax (:func:`paged_attention_reference`
+    with slot c at ``positions[n] + c``), zero past a tile's length."""
+    Cq = q.shape[1]
+    slots = jnp.arange(Cq, dtype=jnp.int32)[None, :]
+    out = paged_attention_reference(
+        k_pool, v_pool, q, block_tables,
+        jnp.maximum(positions, 0)[:, None] + slots, layer=layer,
+        sm_scale=sm_scale)
+    return jnp.where((slots < lengths[:, None])[:, :, None, None], out, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -978,6 +1206,17 @@ def _paged_qualify(head_dim=None, block_size=None, window=None):
     return True, None
 
 
+def _chunk_qualify(head_dim=None, block_size=None, window=None):
+    """The kernel copies pages as the pool stores them in HBM, rows of
+    ``head_dim`` lanes, and Mosaic slices HBM in whole 128-lane tiles
+    ("Slice shape along dimension 4 must be aligned to tiling (128)",
+    the v5e compile at a head of 64)."""
+    if head_dim is not None and head_dim % 128:
+        return False, "head_dim not a multiple of 128 (pages are copied " \
+                      "as stored, whole lane tiles)"
+    return _paged_qualify(head_dim, block_size, window)
+
+
 def _int8_qualify(x=None, w=None, *args, **kwargs):
     xs = getattr(x, "shape", None)
     ws = getattr(w, "shape", None)
@@ -1030,6 +1269,14 @@ def _register_all():
         doc="tree-mask verify window (width x depth token tree, one "
             "kernel) over the paged cache — in-window visibility by "
             "ancestor matrix via one-hot matmul; default: TPU only")
+    register_kernel(
+        "chunk_window", paged_chunk_attention,
+        paged_chunk_attention_reference,
+        qualify=_chunk_qualify, default_on=_device.on_tpu,
+        doc="the chunked-prefill window's attention over query tiles "
+            "(a decode row is a tile of one token), a tile's own pages "
+            "copied from the pool in HBM by manual DMA; default: TPU "
+            "only")
     register_kernel(
         "gmm", gmm, gmm_reference,
         qualify=_gmm_qualify, default_on=_device.on_tpu,

@@ -216,12 +216,17 @@ class _ModelWorker:
         # (a mixed step holds at most one token a row plus the
         # scheduler's prefill budget: a block that computes the window's
         # real tokens only runs that many rows)
-        self._chunk_step = (
-            model.make_prefill_step(
+        self._chunk_step = self._chunk_rows = None
+        if self.prefill_chunk:
+            max_tokens = self.max_batch + prefill_token_budget
+            self._chunk_step = model.make_prefill_step(
                 self.max_batch, self.scheduler.max_blocks_per_seq,
-                self.prefill_chunk,
-                max_tokens=self.max_batch + prefill_token_budget)
-            if self.prefill_chunk else None)
+                self.prefill_chunk, max_tokens=max_tokens)
+            # the token rows a mixed step computes (the step log's
+            # `rows_computed`): the promise, or the window's slots
+            # where those are fewer
+            self._chunk_rows = min(self.max_batch * self.prefill_chunk,
+                                   max_tokens)
         # the speculative verify window (third compiled shape; jit is
         # lazy, so geometry that never speculates still traces nothing).
         # Tree mode swaps in the tree verify window plus the tiny
@@ -719,6 +724,8 @@ class _ModelWorker:
                 tick, "mixed" if chunked else "decode", occupancy,
                 n_prefill, n_decode, n_prefill + n_decode, slots_total,
                 traces0)
+            if chunked:
+                rec["rows_computed"] = self._chunk_rows
             if counters:
                 # the context the step attends: each active row's
                 # position after it (the sum of their lengths)

@@ -145,6 +145,87 @@ def _kernel_key_suffix():
     return () if key == "auto" else ("kernels:" + key,)
 
 
+# Query slots in one attention tile of the chunk step (docs/SERVING.md,
+# "Chunked prefill"): a prefilling row's chunk is cut into tiles of this
+# many consecutive tokens, a decode row is a tile of one. Settled by step
+# timings on the chip (PERF.md §6, PR 28): at the benchmark's chunk of
+# 256 a tile is a row's whole chunk, whose pages are then fetched once
+# (a mixed step of 33.5 / 33.8 / 35.6 ms against 33.3 / 36.0 / 41.0 at
+# tiles of 64, for windows of the batch, document and full-budget kind).
+CHUNK_TILE = 256
+
+
+def chunk_tile_count(max_batch, window, max_tokens=None, tile=CHUNK_TILE):
+    """How many query tiles the chunk step's attention is compiled for:
+    the most that ``max_batch`` rows holding ``max_tokens`` tokens
+    between them can need, sum of ``ceil(n / tile)`` over the rows,
+    which is ``max_batch + budget / tile`` under the engine's promise
+    of ``max_batch + budget`` tokens; every slot's tile without one."""
+    tile = min(int(tile), int(window))
+    dense = max_batch * -(-window // tile)
+    if max_tokens is None:
+        return dense
+    return min(dense, (max_tokens + max_batch * (tile - 1)) // tile)
+
+
+def _chunk_layout(jnp, positions, lengths, active, block_tables, window,
+                  rows, tile, n_tiles, block_size):
+    """The index arithmetic of a chunk window whose per-token work runs
+    over ``rows`` token rows: where each token row comes from, where its
+    K/V go, and the window's query tiles. A dict of int32 arrays.
+
+    With ``rows`` smaller than the window's ``B * window`` slots the
+    real tokens are COMPACTED, in slot order (a row's tokens stay
+    together and in order; slots past a row's length and inactive rows
+    get no token row; token rows past the window's tokens are padding:
+    ``live`` false, written to the null block). Otherwise token row
+    ``b * window + c`` is slot ``(b, c)``.
+
+    Tile ``n`` is up to ``tile`` consecutive tokens of one window row:
+    ``tile_rows[n]`` their token rows, ``tile_tables[n]`` the row's
+    block-table line, ``tile_pos[n]`` the first one's position,
+    ``tile_len[n]`` how many (0: an unused tile); ``back`` finds a
+    token row's slot among the ``n_tiles * tile`` tile slots."""
+    i32 = jnp.int32
+    B, Mb = block_tables.shape
+    C, T, Cq = window, B * window, tile
+    lens = jnp.where(active, jnp.clip(lengths, 0, C), 0).astype(i32)
+    if rows < T:
+        first = jnp.cumsum(lens) - lens           # a row's first token row
+        held = (jnp.arange(C, dtype=i32)[None, :] < lens[:, None]).reshape(T)
+        at = jnp.nonzero(held, size=rows, fill_value=T)[0].astype(i32)
+        live = at < T
+        at = jnp.minimum(at, T - 1)
+    else:
+        first = jnp.arange(B, dtype=i32) * C
+        at = jnp.arange(T, dtype=i32)
+        live = at % C < lens[at // C]
+    row, col = at // C, at % C
+    pos = positions[row] + col
+    tiles_of = (lens + Cq - 1) // Cq              # tiles a window row fills
+    tile_end = jnp.cumsum(tiles_of)
+    tile_0 = tile_end - tiles_of                  # a row's first tile
+    n = jnp.arange(n_tiles, dtype=i32)
+    t_row = jnp.minimum(
+        jnp.searchsorted(tile_end, n, side="right"), B - 1).astype(i32)
+    t_off = (n - tile_0[t_row]) * Cq              # first slot in its row
+    return {
+        "at": at, "live": live, "pos": pos,
+        "write_blk": jnp.where(live, block_tables[
+            row, jnp.clip(pos // block_size, 0, Mb - 1)], 0),
+        "slot_idx": pos % block_size,
+        "last": jnp.clip(first + lens - 1, 0, rows - 1),
+        "tile_rows": jnp.clip(
+            (first[t_row] + t_off)[:, None]
+            + jnp.arange(Cq, dtype=i32)[None, :], 0, rows - 1),
+        "tile_tables": block_tables[t_row],
+        "tile_pos": positions[t_row] + t_off,
+        "tile_len": jnp.where(n < tile_end[-1],
+                              jnp.clip(lens[t_row] - t_off, 0, Cq), 0),
+        "back": jnp.clip((tile_0[row] + col // Cq) * Cq + col % Cq,
+                         0, n_tiles * Cq - 1)}
+
+
 class GenerationConfig:
     """Decoder-only LM hyperparameters (transformer_fluid.build shape).
 
@@ -931,7 +1012,7 @@ class GenerationModel:
 
     def _forward_chunk(self, jnp, weights, x, pos2d, lengths,
                        block_tables, active, kv_k, kv_v,
-                       all_slots=False, tree_anc=None):
+                       all_slots=False, tree_anc=None, layout=None):
         """A ``[B, C]`` token window through all layers. x: [B, C, D];
         returns (kv_k, kv_v, logits[B, V]) — each row's logits at its
         LAST valid window slot (``lengths - 1``) — or, with
@@ -945,69 +1026,130 @@ class GenerationModel:
         block tables already cover), but attends the committed prefix
         (cache positions before the window) plus only its OWN root path
         inside the window — sibling branches are mutually invisible, so
-        one step verifies every branch of the token tree."""
+        one step verifies every branch of the token tree.
+
+        ``layout`` (:func:`_chunk_layout`; the chunk step) hands the
+        window over as TOKEN ROWS instead: x is ``[rows, D]``, every
+        row one token (``pos2d`` is not used), and everything a token
+        does alone runs over those rows. The attention sees the window
+        as query tiles (the ``chunk_window`` kernel, or its lax
+        fallback over the same tiles): nothing of ``[B, C, H, T]`` is
+        made. The verify and tree windows, where nearly every slot is a
+        token, keep the ``[B, C]`` window and their own kernels."""
         import jax
 
         cfg = self.config
-        B, C = x.shape[0], x.shape[1]
         H, Dh = cfg.n_heads, cfg.head_dim
         bs = kv_k.shape[2]
         Mb = block_tables.shape[1]
         max_ctx = Mb * bs
         sm_scale = Dh ** -0.5
-
-        # per-slot write targets: window slot j of row b lands at
-        # position pos2d[b, j]; slots past the row's valid length (and
-        # whole inactive rows) scatter into the null block instead
-        valid = ((jnp.arange(C, dtype=jnp.int32)[None, :]
-                  < lengths[:, None]) & active[:, None])
-        blk_idx = jnp.clip(pos2d // bs, 0, Mb - 1)
-        write_blk = jnp.where(
-            valid, jnp.take_along_axis(block_tables, blk_idx, axis=1), 0)
-        slot_idx = pos2d % bs
+        lead = x.shape[:-1]            # [B, C], or [rows] of tokens
 
         def ln(h, scale, bias):
             mu = jnp.mean(h, axis=-1, keepdims=True)
             var = jnp.mean((h - mu) ** 2, axis=-1, keepdims=True)
             return (h - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
 
-        # context validity per window slot: t <= that slot's position.
-        # The whole window's k/v are written BEFORE the gather, so
-        # in-chunk self-attention sees exactly the causal prefix; t=0 is
-        # always visible, so no softmax row is fully masked.
-        t_ids = jnp.arange(max_ctx)[None, None, :]
-        if tree_anc is None:
-            attn_valid = t_ids <= pos2d[:, :, None]      # [B, C, T]
-        else:
-            # tree window: slot j's visibility is the committed prefix
-            # (strictly before the window's first position) plus the
-            # static ancestor mask over in-window cache positions. The
-            # root slot sees itself via anc[0, 0]; pos0 >= 1 past
-            # prefill, so no softmax row is ever fully masked.
-            pos0 = pos2d[:, 0]
-            rel = t_ids - pos0[:, None, None]            # [B, 1, T]
-            in_win = (rel >= 0) & (rel < C)
-            rel_c = jnp.clip(rel, 0, C - 1)
-            anc_t = tree_anc[jnp.arange(C)[None, :, None], rel_c]
-            attn_valid = (rel < 0) | (in_win & anc_t)    # [B, C, T]
-
-        # the speculative verify window (all_slots) dispatches the
-        # fused spec_window kernel — k+1 query positions against the
-        # paged cache in one launch, block table resolved in-kernel;
-        # one decision per forward, shared by all layers. The tree
-        # window dispatches the tree-mask variant, which takes the
-        # ancestor mask as an extra operand.
+        # one kernel decision per forward (trace time), shared by all
+        # layers; every kernel gets the pool whole, never `kv_k[i]`
+        # (see _forward_token)
         from ..ops.kernel_registry import choose as _choose_kernel
 
-        use_paged = all_slots and _choose_kernel(
-            "spec_window" if tree_anc is None else "spec_window_tree",
-            head_dim=Dh, block_size=bs, window=C)
-        if use_paged:
+        if layout is not None:
+            from ..ops import pallas_kernels as _pk
+
+            # token rows: where each one's K/V go (padding rows, as
+            # invalid slots, into the null block), and the query tiles
+            write_blk, slot_idx = layout["write_blk"], layout["slot_idx"]
+            n_tiles, Cq = layout["tile_rows"].shape
+            tile_attention = (
+                _pk.paged_chunk_attention
+                if _choose_kernel("chunk_window", head_dim=Dh,
+                                  block_size=bs, window=Cq)
+                else _pk.paged_chunk_attention_reference)
+
+            def attend(i, q, kv_k, kv_v):
+                ctx = tile_attention(
+                    kv_k, kv_v, q[layout["tile_rows"]],
+                    layout["tile_tables"], layout["tile_pos"],
+                    layout["tile_len"], layer=i, sm_scale=sm_scale)
+                return ctx.reshape(n_tiles * Cq, -1)[layout["back"]]
+        else:
+            B, C = lead
+            # per-slot write targets: window slot j of row b lands at
+            # position pos2d[b, j]; slots past the row's valid length
+            # (and whole inactive rows) scatter into the null block
+            valid = ((jnp.arange(C, dtype=jnp.int32)[None, :]
+                      < lengths[:, None]) & active[:, None])
+            blk_idx = jnp.clip(pos2d // bs, 0, Mb - 1)
+            write_blk = jnp.where(
+                valid, jnp.take_along_axis(block_tables, blk_idx, axis=1),
+                0)
+            slot_idx = pos2d % bs
+
+            # context validity per window slot: t <= that slot's
+            # position. The whole window's k/v are written BEFORE the
+            # gather, so in-window self-attention sees exactly the
+            # causal prefix; t=0 is always visible, so no softmax row
+            # is fully masked.
+            t_ids = jnp.arange(max_ctx)[None, None, :]
             if tree_anc is None:
-                from ..ops.pallas_kernels import paged_attention
+                attn_valid = t_ids <= pos2d[:, :, None]      # [B, C, T]
             else:
-                from ..ops.pallas_kernels import paged_attention_tree
-                anc_f = tree_anc.astype(jnp.float32)
+                # tree window: slot j's visibility is the committed
+                # prefix (strictly before the window's first position)
+                # plus the static ancestor mask over in-window cache
+                # positions. The root slot sees itself via anc[0, 0];
+                # pos0 >= 1 past prefill, so no softmax row is ever
+                # fully masked.
+                pos0 = pos2d[:, 0]
+                rel = t_ids - pos0[:, None, None]            # [B, 1, T]
+                in_win = (rel >= 0) & (rel < C)
+                rel_c = jnp.clip(rel, 0, C - 1)
+                anc_t = tree_anc[jnp.arange(C)[None, :, None], rel_c]
+                attn_valid = (rel < 0) | (in_win & anc_t)    # [B, C, T]
+
+            # the verify window dispatches the fused spec_window kernel
+            # (k+1 query positions against the paged cache in one
+            # launch, block table resolved in-kernel), the tree window
+            # the tree-mask variant, which takes the ancestor mask as
+            # an extra operand
+            use_paged = all_slots and _choose_kernel(
+                "spec_window" if tree_anc is None else "spec_window_tree",
+                head_dim=Dh, block_size=bs, window=C)
+            if use_paged:
+                from ..ops.pallas_kernels import (paged_attention,
+                                                  paged_attention_tree)
+                anc_f = (None if tree_anc is None
+                         else tree_anc.astype(jnp.float32))
+
+            def attend(i, q, kv_k, kv_v):
+                if use_paged and tree_anc is None:
+                    return paged_attention(
+                        kv_k, kv_v, q, block_tables, pos2d,
+                        layer=i, sm_scale=sm_scale).reshape(B, C, -1)
+                if use_paged:
+                    return paged_attention_tree(
+                        kv_k, kv_v, q, block_tables, pos2d, anc_f,
+                        layer=i, sm_scale=sm_scale).reshape(B, C, -1)
+                # lax path: the layer's pages, then the paged gather
+                # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]
+                # (see _forward_token)
+                with jax.named_scope("kv_read"):
+                    k_ctx = kv_k[i][block_tables].reshape(
+                        B, max_ctx, H, Dh)
+                    v_ctx = kv_v[i][block_tables].reshape(
+                        B, max_ctx, H, Dh)
+                scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) \
+                    * sm_scale
+                scores = jnp.where(attn_valid[:, :, None, :], scores,
+                                   -jnp.inf)
+                w = jnp.exp(scores
+                            - jnp.max(scores, axis=-1, keepdims=True))
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+                return jnp.einsum("bcht,bthd->bchd", w, v_ctx) \
+                    .reshape(B, C, -1)
 
         for i in range(cfg.n_layers):
             p = "l%d/" % i
@@ -1015,42 +1157,14 @@ class GenerationModel:
             qkv = a @ self._w(jnp, weights, p + "wqkv") \
                 + weights[p + "bqkv"]
             q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, C, H, Dh)
-            k_new = k_new.reshape(B, C, H, Dh)
-            v_new = v_new.reshape(B, C, H, Dh)
+            q = q.reshape(lead + (H, Dh))
+            k_new = k_new.reshape(lead + (H, Dh))
+            v_new = v_new.reshape(lead + (H, Dh))
             with jax.named_scope("kv_write"):
                 kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
                 kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
             with jax.named_scope("attention"):
-                if use_paged:
-                    # the pool goes to the kernel whole, never sliced
-                    # (see _forward_token)
-                    if tree_anc is None:
-                        ctx = paged_attention(
-                            kv_k, kv_v, q, block_tables, pos2d,
-                            layer=i, sm_scale=sm_scale).reshape(B, C, -1)
-                    else:
-                        ctx = paged_attention_tree(
-                            kv_k, kv_v, q, block_tables, pos2d, anc_f,
-                            layer=i, sm_scale=sm_scale).reshape(B, C, -1)
-                else:
-                    # lax path: the layer's pages, then the paged gather
-                    # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]
-                    # (see _forward_token)
-                    with jax.named_scope("kv_read"):
-                        k_ctx = kv_k[i][block_tables].reshape(
-                            B, max_ctx, H, Dh)
-                        v_ctx = kv_v[i][block_tables].reshape(
-                            B, max_ctx, H, Dh)
-                    scores = jnp.einsum("bchd,bthd->bcht", q, k_ctx) \
-                        * sm_scale
-                    scores = jnp.where(attn_valid[:, :, None, :], scores,
-                                       -jnp.inf)
-                    w = jnp.exp(scores
-                                - jnp.max(scores, axis=-1, keepdims=True))
-                    w = w / jnp.sum(w, axis=-1, keepdims=True)
-                    ctx = jnp.einsum("bcht,bthd->bchd", w, v_ctx) \
-                        .reshape(B, C, -1)
+                ctx = attend(i, q, kv_k, kv_v)
                 x = x + ctx @ self._w(jnp, weights, p + "wproj") \
                     + weights[p + "bproj"]
             with jax.named_scope("ffn"):
@@ -1066,9 +1180,13 @@ class GenerationModel:
                 x = ln(x, weights["final_ln_scale"],
                        weights["final_ln_bias"])
                 return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
-            last = jnp.clip(lengths - 1, 0, C - 1).astype(jnp.int32)
-            x_last = jnp.take_along_axis(x, last[:, None, None],
-                                         axis=1)[:, 0]
+            if layout is not None:
+                x_last = x[layout["last"]]
+            else:
+                last = jnp.clip(lengths - 1, 0, lead[1] - 1) \
+                    .astype(jnp.int32)
+                x_last = jnp.take_along_axis(x, last[:, None, None],
+                                             axis=1)[:, 0]
             x_last = ln(x_last, weights["final_ln_scale"],
                         weights["final_ln_bias"])
             return kv_k, kv_v, x_last @ self._w(jnp, weights, "lm_head")
@@ -1095,10 +1213,16 @@ class GenerationModel:
 
         ``max_tokens`` is the caller's promise of how many tokens a
         window can hold at once (the engine: ``max_batch`` plus the
-        scheduler's prefill budget). The dense block computes every slot
-        and ignores it; the latent/expert block compacts the window's
-        real tokens to that many rows for everything a token does alone
-        (``None``: every slot)."""
+        scheduler's prefill budget, which ``plan_chunk`` keeps). Both
+        blocks compact the window's real tokens to that many rows for
+        everything a token does alone: embedding, norms, projections,
+        the K/V write, the FFN (``None``, or a promise no smaller than
+        the window: every slot is a row). A window holding more tokens
+        than promised would lose the ones past the promise, so only
+        the owner of the plan may make it. Either way the window's
+        attention runs over query tiles (:data:`CHUNK_TILE`,
+        docs/SERVING.md "Chunked prefill"), never over
+        ``[B, C, H, T]``."""
         if self.config.block is not None:
             from . import latent_moe
 
@@ -1113,10 +1237,12 @@ class GenerationModel:
         return self._make_window_step("chunk", max_batch,
                                       max_blocks_per_seq, chunk,
                                       all_slots=False,
-                                      return_logits=return_logits)
+                                      return_logits=return_logits,
+                                      max_tokens=max_tokens)
 
     def _make_window_step(self, kind, max_batch, max_blocks_per_seq,
-                          window, all_slots, return_logits, tree=None):
+                          window, all_slots, return_logits, tree=None,
+                          max_tokens=None):
         """The shared ``[max_batch, window]`` jitted step builder behind
         :meth:`make_prefill_step` (``all_slots=False`` — logits at each
         row's last valid slot), :meth:`make_spec_step`
@@ -1125,11 +1251,21 @@ class GenerationModel:
         tree verify window: tree attention mask, position encodings at
         each slot's tree DEPTH rather than its window offset). One
         body, so the token-splice/embedding/position plumbing can never
-        diverge between the shapes."""
+        diverge between the shapes. The chunk step (``kind ==
+        "chunk"``) runs it over token rows, ``max_tokens`` of them
+        where that is a smaller number than the window's slots
+        (:func:`_chunk_layout`); the verify windows over ``[B, C]``."""
+        C = int(window)
+        # token rows of the chunk step: the promise, or every slot
+        rows = int(max_batch) * C
+        if kind == "chunk" and max_tokens is not None:
+            rows = min(rows, int(max_tokens))
         key = (kind, int(max_batch), int(max_blocks_per_seq),
-               int(window), bool(return_logits)) + _kernel_key_suffix()
+               C, bool(return_logits)) + _kernel_key_suffix()
         if tree is not None:
             key = key + ("tree:%dx%d" % (int(tree[0]), int(tree[1])),)
+        if rows < int(max_batch) * C:
+            key = key + ("rows:%d" % rows,)
         if key in self._steps:
             return self._steps[key]
         import jax
@@ -1138,7 +1274,6 @@ class GenerationModel:
         cfg = self.config
         pe = jnp.asarray(_position_encoding_table(cfg))
         emb_scale = float(cfg.d_model) ** 0.5
-        C = int(window)
         if tree is None:
             depths_j = anc_j = None
         else:
@@ -1156,6 +1291,17 @@ class GenerationModel:
             tok = jnp.clip(tok, 0, cfg.vocab_size - 1)
             pos2d = (positions[:, None]
                      + jnp.arange(C, dtype=jnp.int32)[None, :])
+            layout = None
+            if kind == "chunk":
+                # the window as token rows, before anything is looked
+                # up for a slot that holds no token
+                tile = min(CHUNK_TILE, C)
+                layout = _chunk_layout(
+                    jnp, positions, lengths, active, block_tables, C,
+                    rows, tile,
+                    chunk_tile_count(int(max_batch), C, rows, tile),
+                    kv_k.shape[2])
+                tok, pos2d = tok.reshape(-1)[layout["at"]], layout["pos"]
             emb = jnp.take(weights["embedding"], tok, axis=0)
             es = weights.get("embedding@qscale")
             if es is not None:
@@ -1172,7 +1318,8 @@ class GenerationModel:
                  + cfg.pe_beta * jnp.take(pe, pe_idx, axis=0))
             kv_k, kv_v, logits = self._forward_chunk(
                 jnp, weights, x, pos2d, lengths, block_tables, active,
-                kv_k, kv_v, all_slots=all_slots, tree_anc=anc_j)
+                kv_k, kv_v, all_slots=all_slots, tree_anc=anc_j,
+                layout=layout)
             next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if return_logits:
                 return kv_k, kv_v, next_tokens, logits

@@ -256,6 +256,58 @@ def test_paged_decode_post_truncate_tables():
 
 
 # ---------------------------------------------------------------------------
+# paged chunk attention: the chunk window as query tiles
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 8])
+@pytest.mark.parametrize("tile", [4, 8, 32])
+def test_paged_chunk_attention_matches_gathered_reference(tile,
+                                                          pages_per_step):
+    """Tiles of one sequence's consecutive tokens against the paged
+    cache: a full tile deep in its context and the tile after it (same
+    block-table line), a tile cut short, one-token tiles at both ends
+    of a context, an unused tile; runs of 1, 2 and 8 pages, so a tile's
+    pages end inside a run, on a run's edge and in a later run. The
+    kernel rounds the operands of both products to bfloat16 (a
+    default-precision dot on the chip): within 8 half-ulps of the
+    reference's largest value, slots past a tile's length zero."""
+    from paddle_tpu.ops.pallas_kernels import (
+        paged_chunk_attention, paged_chunk_attention_reference)
+
+    rng, k_pool, v_pool = _paged_setup(seed=7, NB=40, bs=4, H=2, Dh=16)
+    Mb, N = 20, 6
+    room = Mb * 4
+    q = jnp.asarray(rng.randn(N, tile, 2, 16).astype(np.float32))
+    tables = rng.permutation(np.arange(1, 41))[:2 * Mb] \
+        .reshape(2, Mb).astype(np.int32)
+    tables = np.stack([tables[0], tables[0], tables[1], tables[1],
+                       tables[0], tables[1]])
+    pos = np.array([room - 2 * tile, room - tile, 5, 0, room - 1, 3],
+                   np.int32)
+    lens = np.array([tile, tile - 1, min(tile, 3), 1, 1, 0], np.int32)
+    got = np.asarray(paged_chunk_attention(
+        k_pool, v_pool, q, tables, pos, lens, layer=LAYER,
+        pages_per_step=pages_per_step))
+    want = np.asarray(paged_chunk_attention_reference(
+        k_pool, v_pool, q, tables, pos, lens, layer=LAYER))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got, want, atol=8 * 2.0 ** -9 * np.abs(want).max(), rtol=0)
+    past = np.arange(tile)[None, :] >= lens[:, None]
+    assert (got[past] == 0).all() and (want[past] == 0).all()
+    # and the fallback is the window's own lax attention, slot c of a
+    # tile at position pos + c
+    slots = np.minimum(np.arange(tile)[None, :], np.maximum(lens - 1, 0)
+                       [:, None])
+    dense = np.asarray(paged_attention_reference(
+        k_pool, v_pool, q, tables, pos[:, None] + slots, layer=LAYER))
+    live = ~past
+    live[:, 1:] &= (slots[:, 1:] == np.arange(1, tile)[None, :])
+    np.testing.assert_allclose(want[live], dense[live], atol=1e-6, rtol=0)
+
+
+# ---------------------------------------------------------------------------
 # fused int8 matmul
 # ---------------------------------------------------------------------------
 

@@ -186,6 +186,17 @@ def check_mixed_steps_are_the_chunk_steps(run):
                and r["rows"] <= 4 for r in run["records"])
 
 
+def check_mixed_records_carry_rows_computed(run):
+    # the toy engine's window (4 x 8 slots) is not larger than its
+    # promise (4 rows + a budget of 4 chunks): every slot is a row
+    for r in run["records"]:
+        if r["kind"] == "mixed":
+            assert r["rows_computed"] == r["slots_total"] == 4 * 8
+            assert r["slots_used"] <= r["rows_computed"]
+        else:
+            assert "rows_computed" not in r
+
+
 def check_slots_used_is_what_the_scheduler_planned(run):
     recs = run["records"]
     # every prompt token is prefilled once; a request's first token
@@ -248,6 +259,7 @@ def check_metrics_off_writes_nothing_and_changes_no_token(run):
 
 @pytest.mark.parametrize("check", [
     check_one_record_per_step, check_mixed_steps_are_the_chunk_steps,
+    check_mixed_records_carry_rows_computed,
     check_slots_used_is_what_the_scheduler_planned,
     check_stamps_are_ordered, check_host_and_wait_fit_in_the_tick,
     check_cold_is_the_first_step_of_each_shape,
@@ -282,6 +294,29 @@ def test_a_slow_stream_callback_is_host_time_not_wait(metrics_on,
     assert all(r["wait_ms"] < 50 for r in recs if not r["cold"])
     assert all(r["host_ms"] < 50 for r in recs
                if r is not tick and not r["cold"])
+
+
+@pytest.mark.parametrize("engine_kw,rows", [
+    # the default budget of four chunks: max_batch + budget token rows
+    (dict(max_batch=8), 8 + 4 * 8),
+    (dict(prefill_token_budget=8), 4 + 8),
+    # a window no larger than the promise: every slot
+    (dict(prefill_token_budget=64), 4 * 8),
+], ids=["default_budget", "small_budget", "window_within_promise"])
+def test_rows_computed_is_the_promise_or_the_window(metrics_on, engine_kw,
+                                                    rows):
+    """`rows_computed` of a mixed record: the token rows the compiled
+    chunk step runs its per-token work on, which is what the engine
+    promised `make_prefill_step` (`max_batch` + the prefill budget)
+    where that is fewer than the window's slots."""
+    tokens, _steps = serve(toy_model(), **engine_kw)
+    assert all(len(t) == NEW_TOKENS for t in tokens)
+    mixed = [r for r in metrics_on.samples("serving/step").records()
+             if r["kind"] == "mixed"]
+    assert mixed
+    for r in mixed:
+        assert r["rows_computed"] == rows <= r["slots_total"]
+        assert r["slots_used"] <= r["rows_computed"]
 
 
 def test_a_speculative_window_is_logged_as_spec(metrics_on):
@@ -403,6 +438,7 @@ def test_step_names_and_scopes_reach_the_lowered_hlo():
 @pytest.mark.parametrize("kernel,name", [
     ("paged_decode", "paged_attention"),
     ("spec_window_tree", "paged_attention_tree"),
+    ("chunk_window", "paged_chunk_attention"),
     ("flash_attention", "flash_attention"),
     ("int8_matmul", "int8_matmul")])
 def test_kernel_names_reach_the_lowered_hlo(kernel, name):
