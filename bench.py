@@ -665,18 +665,14 @@ def bench_serving_fastpath(n_requests=10, max_new_tokens=8,
     """Serving fast-path receipt (docs/SERVING.md): one
     shared-system-prompt request set — every prompt is one long shared
     prefix plus a short unique tail, the dominant traffic shape at
-    millions-of-users scale — served through (a) the legacy engine
-    (one-token prefill, no prefix reuse) and (b) the fast path
-    (chunked prefill + radix prefix caching). TTFT is the headline:
-    the legacy engine burns ``prefix_len`` decode steps before a
-    request's first token, the chunked step takes
-    ``ceil(prefix_len/chunk)`` calls — and once the first request
-    seals the shared blocks, later requests skip even those. Both legs
-    must stay token-identical to ``reference_decode`` (the functional
-    gate; the TTFT ratio is the retried measurement gate).
+    millions-of-users scale — served with radix prefix caching on:
+    the chunked step takes ``ceil(prefix_len/chunk)`` calls for a
+    prompt, and once the first request seals the shared blocks, later
+    requests skip even those. The outputs must stay token-identical to
+    ``reference_decode`` (the functional gate).
 
-    Returns a dict with per-leg ttft_p50/tokens_per_sec, the prefix
-    hit rate, the chunked-vs-legacy TTFT speedup and identity flags."""
+    Returns a dict with the leg's ttft_p50/tokens_per_sec, the prefix
+    hit rate and the identity flag."""
     from paddle_tpu import serving
 
     cfg = serving.GenerationConfig(
@@ -697,8 +693,8 @@ def bench_serving_fastpath(n_requests=10, max_new_tokens=8,
                                     max_seq_len=max_seq_len,
                                     block_size=block_size, **kw)
         # priming request: pays the one-time XLA compile for both step
-        # shapes AND (fast leg) prefills + seals the shared prefix
-        # blocks, the steady-state cache-warm serving condition
+        # shapes AND prefills + seals the shared prefix blocks, the
+        # steady-state cache-warm serving condition
         eng.generate(shared + [7], max_new_tokens=2, timeout=600)
         primed_reuse = eng.stats()["default"]["prefix_blocks_reused"]
         t0 = time.perf_counter()
@@ -717,16 +713,12 @@ def bench_serving_fastpath(n_requests=10, max_new_tokens=8,
                 stats["prefix_blocks_reused"] - primed_reuse,
         }
 
-    legacy = run_leg()
     fast = run_leg(prefill_chunk=chunk, prefix_cache=True)
     possible = n_requests * shared_blocks
     return {
-        "legacy": legacy,
         "fast": fast,
-        "ttft_speedup": legacy["ttft_p50"] / fast["ttft_p50"],
         "prefix_hit_rate": fast["prefix_blocks_reused"] / possible,
-        "outputs_match": legacy["outputs_match"]
-            and fast["outputs_match"],
+        "outputs_match": fast["outputs_match"],
     }
 
 
@@ -1837,8 +1829,7 @@ def main(argv=None):
                  serve_batched / serve_serial, 4))
 
     # serving fast-path receipt (docs/SERVING.md): chunked prefill +
-    # radix prefix caching vs the legacy one-token prefill on one
-    # shared-system-prompt stream — TTFT is the headline
+    # radix prefix caching on one shared-system-prompt stream
     fastpath_res = None
     if args.serving_only or not (args.tiny or args.amp_only
                                  or args.quant_only or args.spec_only
@@ -1849,11 +1840,6 @@ def main(argv=None):
              ttft_p50_s=round(fastpath_res["fast"]["ttft_p50"], 4),
              prefix_hit_rate=round(fastpath_res["prefix_hit_rate"], 4),
              outputs_match=bool(fastpath_res["outputs_match"]))
-        _leg("serving_legacy_prefill",
-             fastpath_res["legacy"]["tokens_per_sec"], 0.0,
-             ttft_p50_s=round(fastpath_res["legacy"]["ttft_p50"], 4),
-             chunked_ttft_speedup=round(
-                 fastpath_res["ttft_speedup"], 4))
 
     # speculative-decoding receipt (docs/SERVING.md): draft-k verified
     # in one step vs legacy one-token decode on the repetitive set —
@@ -2037,10 +2023,6 @@ def main(argv=None):
         if fastpath_res is not None:
             reg.gauge("bench/serving_ttft_chunked_s").set(
                 fastpath_res["fast"]["ttft_p50"])
-            reg.gauge("bench/serving_ttft_legacy_s").set(
-                fastpath_res["legacy"]["ttft_p50"])
-            reg.gauge("bench/serving_chunked_speedup").set(
-                fastpath_res["ttft_speedup"])
             reg.gauge("bench/serving_prefix_hit_rate").set(
                 fastpath_res["prefix_hit_rate"])
             reg.gauge("bench/serving_fastpath_outputs_match").set(
@@ -2137,10 +2119,6 @@ def main(argv=None):
     if fastpath_res is not None:
         result["serving_ttft_chunked_s"] = round(
             fastpath_res["fast"]["ttft_p50"], 4)
-        result["serving_ttft_legacy_s"] = round(
-            fastpath_res["legacy"]["ttft_p50"], 4)
-        result["serving_chunked_speedup"] = round(
-            fastpath_res["ttft_speedup"], 4)
         result["serving_prefix_hit_rate"] = round(
             fastpath_res["prefix_hit_rate"], 4)
         result["serving_fastpath_outputs_match"] = bool(

@@ -252,8 +252,8 @@ _declare("PTPU_DATA_EXCHANGE_TIMEOUT", "float", 300.0,
 _declare("PTPU_SERVE_ASYNC_STEPS", "int", 4,
          "decode steps kept in flight ahead of EOS/stream materialization")
 _declare("PTPU_SERVE_PREFILL_CHUNK", "int", 0,
-         "prompt tokens a prefill row consumes per serving step via the "
-         "chunked-prefill fast path (0 = legacy one-token prefill)")
+         "prompt tokens a prefill row consumes per mixed serving step "
+         "(0 = the server's default, 256, clamped to the context)")
 _declare("PTPU_SERVE_PREFIX_CACHE", "bool", False,
          "content-addressed KV block sharing: requests whose prompt "
          "prefix is cached skip its prefill compute and block "

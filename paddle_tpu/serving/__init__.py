@@ -5,13 +5,13 @@ The "millions of users" leg of the north star: a multi-model generation
 service that batches concurrent requests at decode-*step* granularity
 (Orca-style iteration-level scheduling over one fixed-shape XLA step, so
 joins/retires never retrace) with a blocked KV-cache pool (vLLM-style
-block tables) for memory feasibility. The opt-in serving fast path adds
-chunked prefill (Sarathi-style mixed prompt-window/decode steps,
-``prefill_chunk=`` / ``$PTPU_SERVE_PREFILL_CHUNK``) and radix prefix
-caching (content-addressed refcounted KV block sharing across requests,
-``prefix_cache=`` / ``$PTPU_SERVE_PREFIX_CACHE``) and speculative
-decoding (draft-k tokens — n-gram prompt lookup by default, or a
-pluggable draft model — verified in one batched target step,
+block tables) for memory feasibility. Prompts are prefilled in chunks
+(Sarathi-style mixed prompt-window/decode steps, ``prefill_chunk=`` /
+``$PTPU_SERVE_PREFILL_CHUNK`` sets the chunk's size). Opt-in on top:
+radix prefix caching (content-addressed refcounted KV block sharing
+across requests, ``prefix_cache=`` / ``$PTPU_SERVE_PREFIX_CACHE``) and
+speculative decoding (draft-k tokens — n-gram prompt lookup by default,
+or a pluggable draft model — verified in one batched target step,
 ``spec_k=`` / ``$PTPU_SERVE_SPEC_K``). A second decoder block,
 latent attention over a paged latent cache with sigmoid-routed experts
 (``GenerationConfig(block=LatentMoEBlock(...))``, ``latent_moe.py``),

@@ -26,14 +26,14 @@ so nothing is ever dispatched for a finished sequence, and rejected
 draft positions are rolled back — the contract
 ``test_spec_no_post_eos_emission_and_kv_rolled_back`` pins.
 
-The serving fast path (docs/SERVING.md) is two opt-in legs, both OFF by
-default (the legacy engine is bitwise unchanged): **chunked prefill**
-(``prefill_chunk`` / ``$PTPU_SERVE_PREFILL_CHUNK``) dispatches the
-second compiled step shape — a ``[max_batch, chunk]`` window where
-prefill rows consume whole prompt spans while decode rows ride along as
-1-token windows — with ``prefill_token_budget`` (default ``4 * chunk``)
-bounding the prompt tokens per mixed step so decode latency stays
-bounded; **radix prefix caching** (``prefix_cache`` /
+Prefill is chunked (docs/SERVING.md): a tick with a row mid-prompt
+dispatches the second compiled step shape — a ``[max_batch, chunk]``
+window (``prefill_chunk`` / ``$PTPU_SERVE_PREFILL_CHUNK``) where prefill
+rows consume whole prompt spans while decode rows ride along as 1-token
+windows — with ``prefill_token_budget`` (default ``4 * chunk``) bounding
+the prompt tokens per mixed step so decode latency stays bounded; any
+other tick dispatches the decode step, every row a window of one.
+**Radix prefix caching** (opt-in: ``prefix_cache`` /
 ``$PTPU_SERVE_PREFIX_CACHE``) content-addresses the KV pool so requests
 sharing a prompt prefix skip its prefill compute and block allocations.
 Prefix reuse assumes the weights that computed the cached KV state:
@@ -45,15 +45,15 @@ cv, so stale-prefix tokens can never leak across a swap and every
 request's tokens come from exactly one weight version
 (docs/SERVING.md "Online updates").
 
-The third opt-in leg is **speculative decoding** (``spec_k`` /
-``$PTPU_SERVE_SPEC_K``, 0 = off and bitwise-legacy): when every row is
+The other opt-in leg is **speculative decoding** (``spec_k`` /
+``$PTPU_SERVE_SPEC_K``, 0 = off): when every row is
 past its prompt, the engine dispatches a VERIFY window — each row's
 last committed token plus up to ``spec_k`` tokens proposed by the
 ``drafter`` (n-gram prompt lookup by default; any object with
 ``propose(history, k)``, e.g. ``ModelDrafter``) — and the target's
 argmax at all ``k+1`` positions decides per-row acceptance in ONE
 step. Every window emits the accepted run plus a correction token
-(never fewer tokens per step than legacy); rejected positions roll
+(never fewer tokens per step than plain decoding); rejected positions roll
 back through ``KVBlockPool.truncate_owner``. Spec windows run
 synchronously (the acceptance result feeds the next window's drafts),
 trading the async-depth pipelining for multi-token steps.
@@ -126,7 +126,7 @@ class _ModelWorker:
 
     def __init__(self, name, model, max_batch, max_seq_len, block_size,
                  num_blocks, max_queue, async_depth, engine,
-                 prefill_chunk=0, prefix_cache=False,
+                 prefill_chunk=None, prefix_cache=False,
                  prefill_token_budget=None, spec_k=0, drafter=None,
                  spec_tree=None, transient_tolerance=2):
         from .model import NGramDrafter, parse_tree_shape
@@ -146,15 +146,7 @@ class _ModelWorker:
         self.pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
                                 block_size, num_blocks,
                                 entry=model.cache_entry())
-        # chunk-size budgeting: the chunk is a compiled shape, so it is
-        # clamped to the context; the per-step token budget (default
-        # 4 chunks) bounds how much prefill compute a MIXED step carries
-        # alongside decode rows — the decode-latency bound
-        self.prefill_chunk = max(0, min(int(prefill_chunk or 0),
-                                        max_seq_len))
         self.prefix_cache = bool(prefix_cache)
-        if self.prefill_chunk and prefill_token_budget is None:
-            prefill_token_budget = 4 * self.prefill_chunk
         # speculative decoding: the verify window is a compiled shape,
         # clamped so a full window always fits the context. A tree
         # shape (PTPU_SERVE_SPEC_TREE) implies speculation — its depth
@@ -180,14 +172,16 @@ class _ModelWorker:
             # jitted ModelDrafter: size its draft-side KV pool/batch
             # geometry once, up front
             self.drafter.bind(max_batch, self.spec_k)
+        # the scheduler settles the chunk's size and the prefill budget
+        # of a mixed step (scheduler.DEFAULT_PREFILL_CHUNK, 4 chunks)
         self.scheduler = StepScheduler(
             max_batch, self.pool, max_seq_len,
-            prefill_chunk=self.prefill_chunk,
+            prefill_chunk=prefill_chunk,
             prefix_cache=self.prefix_cache,
-            prefill_token_budget=(prefill_token_budget
-                                  if self.prefill_chunk else None),
+            prefill_token_budget=prefill_token_budget,
             cache_namespace=name, spec_k=self.spec_k,
             drafter=self.drafter, spec_tree=self.spec_tree)
+        self.prefill_chunk = self.scheduler.prefill_chunk
         self.queue = RequestQueue(max_queue)
         self.max_batch = int(max_batch)
         # bounded in-flight step lag (the PR-2 InflightWindow contract,
@@ -208,25 +202,23 @@ class _ModelWorker:
             self.scope.set(wname, val)
         self._weight_names = list(model.weights)
 
+        # the two compiled shapes: the decode step (every row a window
+        # of one) and the mixed prefill/decode window. jit is lazy, so
+        # an engine that never sees a prompt mid-flight traces one.
         self._step = model.make_decode_step(
             self.max_batch, self.scheduler.max_blocks_per_seq)
-        # the second compiled shape (mixed prefill/decode window); jit
-        # is lazy, so geometry that never sees a prompt mid-flight still
-        # traces exactly one step
-        # (a mixed step holds at most one token a row plus the
+        # a mixed step holds at most one token a row plus the
         # scheduler's prefill budget: a block that computes the window's
-        # real tokens only runs that many rows)
-        self._chunk_step = self._chunk_rows = None
-        if self.prefill_chunk:
-            max_tokens = self.max_batch + prefill_token_budget
-            self._chunk_step = model.make_prefill_step(
-                self.max_batch, self.scheduler.max_blocks_per_seq,
-                self.prefill_chunk, max_tokens=max_tokens)
-            # the token rows a mixed step computes (the step log's
-            # `rows_computed`): the promise, or the window's slots
-            # where those are fewer
-            self._chunk_rows = min(self.max_batch * self.prefill_chunk,
-                                   max_tokens)
+        # real tokens only runs that many rows
+        max_tokens = self.max_batch + self.scheduler.prefill_token_budget
+        self._chunk_step = model.make_prefill_step(
+            self.max_batch, self.scheduler.max_blocks_per_seq,
+            self.prefill_chunk, max_tokens=max_tokens)
+        # the token rows a mixed step computes (the step log's
+        # `rows_computed`): the promise, or the window's slots where
+        # those are fewer
+        self._chunk_rows = min(self.max_batch * self.prefill_chunk,
+                               max_tokens)
         # the speculative verify window (third compiled shape; jit is
         # lazy, so geometry that never speculates still traces nothing).
         # Tree mode swaps in the tree verify window plus the tiny
@@ -250,6 +242,10 @@ class _ModelWorker:
         import jax.numpy as jnp
 
         self._prev_tokens = jnp.zeros((self.max_batch,), jnp.int32)
+        # the decode step's prompt operands (prompt_feed, use_prompt)
+        # stay all-false: a prompt token always goes through the window
+        self._no_prompt = (jnp.zeros((self.max_batch,), jnp.int32),
+                           jnp.zeros((self.max_batch,), bool))
 
         # named lock site (docs/STATIC_ANALYSIS.md): tracked under
         # PTPU_LOCK_CHECK=1, a plain Condition otherwise; the same flag
@@ -274,7 +270,7 @@ class _ModelWorker:
         self._consec_transient = 0
         self._transient_retries = 0  # host-side (live with metrics off)
         # flipped by the first deadline-carrying submit: the deadline
-        # scan never runs on a deadline-free engine (legacy identity)
+        # scan never runs on a deadline-free engine
         self._track_deadlines = False
         self._tick_retryable = False
         self._gen_tokens = 0
@@ -462,7 +458,7 @@ class _ModelWorker:
         """One scheduler round: admit at the boundary, dispatch one
         fixed-shape step (the speculative verify window when every row
         is past its prompt, else the mixed chunk shape whenever a row
-        is mid-prompt under the chunked fast path), lag-process
+        is mid-prompt and the decode shape otherwise), lag-process
         materialized tokens, retire."""
         # everything up to step planning leaves the scheduler/pool state
         # consistent, so a transient failure in this window is retried
@@ -472,7 +468,7 @@ class _ModelWorker:
             _TickLog() if _metrics.enabled() or _tracing.enabled()
             else None)
         sched = self.scheduler
-        plan, chunked = None, False
+        plan = kind = None
         with _phase(tick, "plan"):
             self._tick_retryable = True
             fault = _resil.maybe_inject_serve_fault(self._steps_dispatched)
@@ -489,10 +485,7 @@ class _ModelWorker:
             self._tick_retryable = False
             spec_plan = sched.plan_spec() if self.spec_k else None
             if not spec_plan:
-                if self.prefill_chunk:
-                    plan, chunked = sched.plan_chunk()
-                else:
-                    plan = sched.plan_step()
+                plan, kind = sched.plan_step()
         if tick is not None:
             tick.t_planned = time.perf_counter()
         if spec_plan:
@@ -500,7 +493,7 @@ class _ModelWorker:
             # (acceptance feeds the next window's drafts)
             self._dispatch_spec(spec_plan)
         elif plan:
-            self._dispatch(plan, chunked)
+            self._dispatch(plan, kind)
             if self.spec_k:
                 # spec mode is synchronous everywhere: the next
                 # plan (a verify window) reads committed history
@@ -682,7 +675,8 @@ class _ModelWorker:
                     int(rec["t_ready"] * 1e9), model=self.name,
                     step=rec["step"])
 
-    def _dispatch(self, plan, chunked=False):
+    def _dispatch(self, plan, kind):
+        mixed = kind == "mixed"
         sched = self.scheduler
         occupancy = int(sched.active.sum())
         tick = self._tick_log
@@ -693,7 +687,7 @@ class _ModelWorker:
             # array) and returns them updated, then its tokens, then
             # whatever counters its block reduces on the device
             arrays = self.pool.arrays
-            if chunked:
+            if mixed:
                 out = self._chunk_step(
                     weights, *arrays,
                     sched.chunk_feed.copy(), sched.use_prompt.copy(),
@@ -702,8 +696,7 @@ class _ModelWorker:
                     sched.active.copy())
             else:
                 out = self._step(
-                    weights, *arrays,
-                    sched.prompt_feed.copy(), sched.use_prompt.copy(),
+                    weights, *arrays, *self._no_prompt,
                     self._prev_tokens, sched.positions.copy(),
                     sched.block_tables.copy(), sched.active.copy())
             self.pool.arrays = tuple(out[:len(arrays)])
@@ -712,26 +705,21 @@ class _ModelWorker:
         self._steps_dispatched += 1
         rec = None
         if tick is not None:
-            if chunked:
-                n_prefill = int(sched.chunk_lens[sched.use_prompt].sum())
-                n_decode = len(plan) - int(sched.use_prompt.sum())
-                slots_total = self.max_batch * self.prefill_chunk
-            else:
-                n_prefill = sum(1 for _seq, g in plan if g is None)
-                n_decode = len(plan) - n_prefill
-                slots_total = self.max_batch
+            n_prefill = int(sched.chunk_lens[sched.use_prompt].sum())
+            n_decode = len(plan) - int(sched.use_prompt.sum())
             rec = self._open_record(
-                tick, "mixed" if chunked else "decode", occupancy,
-                n_prefill, n_decode, n_prefill + n_decode, slots_total,
+                tick, kind, occupancy, n_prefill, n_decode,
+                n_prefill + n_decode,
+                self.max_batch * (self.prefill_chunk if mixed else 1),
                 traces0)
-            if chunked:
+            if mixed:
                 rec["rows_computed"] = self._chunk_rows
             if counters:
                 # the context the step attends: each active row's
                 # position after it (the sum of their lengths)
-                lens = sched.chunk_lens if chunked else 1
                 rec["cached_tokens"] = int(
-                    ((sched.positions + lens) * sched.active).sum())
+                    ((sched.positions + sched.chunk_lens)
+                     * sched.active).sum())
                 rec["_counters"] = counters[0]   # read when consumed
             if _tracing.enabled():
                 # request-scoped view of the same step: one window event
@@ -740,14 +728,13 @@ class _ModelWorker:
                 # just engine steps
                 t0 = int(rec["t_planned"] * 1e9)
                 t1 = int(rec["t_dispatched"] * 1e9)
-                for seq, gen_idx in plan:
+                for seq, _gen_idx in plan:
                     tid = seq.request.trace_id
                     if tid is None:
                         continue
-                    prefill = (bool(sched.use_prompt[seq.slot]) if chunked
-                               else gen_idx is None)
                     _tracing.complete(
-                        "prefill_chunk" if prefill else "decode_window",
+                        "prefill_chunk" if sched.use_prompt[seq.slot]
+                        else "decode_window",
                         t0, t1, trace_id=tid, request=seq.request.id,
                         model=self.name)
         self._prev_tokens = next_tokens
@@ -764,7 +751,7 @@ class _ModelWorker:
             peak = reg.gauge("serving/peak_batch_occupancy")
             if occupancy > peak.value:
                 peak.set(occupancy)
-            if chunked:
+            if mixed:
                 reg.counter("serving/prefill_chunk_steps").inc()
             reg.counter("serving/prefill_tokens").inc(
                 rec["prefill_tokens"])

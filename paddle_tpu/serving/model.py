@@ -28,17 +28,20 @@ The decode step's calling convention (all shapes fixed per engine):
 ``prev_tokens`` is the *device* token vector the previous step returned:
 decode-phase slots chain their input token on device (the host never
 has to materialize a step before dispatching the next — the PR-2
-async-window contract), while prefill-phase slots override it with
-``prompt_feed`` under ``use_prompt``. Inactive slots route their cache
-writes to the pool's null block and their outputs are ignored.
+async-window contract). A slot under ``use_prompt`` takes its token
+from ``prompt_feed`` instead: the one-token window of a prompt (the
+engine prefills through the chunk step below and feeds these all-false;
+a caller stepping the model by hand may prefill through them). Inactive
+slots route their cache writes to the pool's null block and their
+outputs are ignored.
 
-``make_prefill_step`` is the second, chunked step shape (Sarathi-style
-mixed batches, docs/SERVING.md): every row carries a ``[chunk]`` token
-window — prefill rows consume up to ``chunk`` prompt tokens per call
-(writing that many KV slots, masked per row by ``lengths``), decode
-rows ride the same step as 1-token windows chaining ``prev_tokens`` on
-device. Each engine geometry compiles exactly TWO step shapes: this one
-and the one-token decode step.
+``make_prefill_step`` is the second step shape, the engine's prefill
+(Sarathi-style mixed batches, docs/SERVING.md): every row carries a
+``[chunk]`` token window — prefill rows consume up to ``chunk`` prompt
+tokens per call (writing that many KV slots, masked per row by
+``lengths``), decode rows ride the same step as 1-token windows
+chaining ``prev_tokens`` on device. Each engine geometry compiles
+exactly TWO step shapes: this one and the one-token decode step.
 
 ``make_spec_step`` is the speculative-decoding **verify window**
 (docs/SERVING.md): the same ``[max_batch, window]`` chunk shape, except
@@ -827,6 +830,58 @@ class GenerationModel:
         w = weights[key]
         return w.astype(jnp.float32) * s if s is not None else w
 
+    def _layers(self, jnp, weights, x, kv_k, kv_v, write_blk, slot_idx,
+                attend, pick=None):
+        """The XGLM decoder, written once: every layer, then the head.
+        x: ``[..., D]``, a token a row (``[B]`` decoding, ``[B, C]`` a
+        verify window, ``[rows]`` a chunk step's compacted tokens). The
+        steps hand in what differs: the page and slot each row's K/V go
+        to (``write_blk``/``slot_idx``, x's leading shape), layer
+        ``i``'s attention over the pool as written so far
+        (``attend(i, q, kv_k, kv_v)`` -> ``[..., H * Dh]``), and
+        ``pick(x)``, the rows whose logits are wanted (``None``: all).
+        Returns (kv_k, kv_v, logits)."""
+        import jax
+
+        cfg = self.config
+        H, Dh = cfg.n_heads, cfg.head_dim
+        lead = x.shape[:-1]
+
+        def ln(h, scale, bias):
+            mu = jnp.mean(h, axis=-1, keepdims=True)
+            var = jnp.mean((h - mu) ** 2, axis=-1, keepdims=True)
+            return (h - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
+
+        for i in range(cfg.n_layers):
+            p = "l%d/" % i
+            a = ln(x, weights[p + "ln1_scale"], weights[p + "ln1_bias"])
+            qkv = a @ self._w(jnp, weights, p + "wqkv") \
+                + weights[p + "bqkv"]
+            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(lead + (H, Dh))
+            k_new = k_new.reshape(lead + (H, Dh))
+            v_new = v_new.reshape(lead + (H, Dh))
+            with jax.named_scope("kv_write"):
+                kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
+                kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
+            with jax.named_scope("attention"):
+                ctx = attend(i, q, kv_k, kv_v)
+                x = x + ctx @ self._w(jnp, weights, p + "wproj") \
+                    + weights[p + "bproj"]
+            with jax.named_scope("ffn"):
+                b2 = ln(x, weights[p + "ln2_scale"],
+                        weights[p + "ln2_bias"])
+                f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
+                                + weights[p + "bff1"], approximate=False)
+                x = x + f @ self._w(jnp, weights, p + "wff2") \
+                    + weights[p + "bff2"]
+
+        with jax.named_scope("head"):
+            if pick is not None:
+                x = pick(x)
+            x = ln(x, weights["final_ln_scale"], weights["final_ln_bias"])
+            return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
+
     def _forward_token(self, jnp, weights, x, positions, block_tables,
                        active, kv_k, kv_v):
         """One token through all layers. x: [B, D]; returns
@@ -860,73 +915,39 @@ class GenerationModel:
         if use_paged:
             from ..ops.pallas_kernels import paged_attention
 
-        def ln(h, scale, bias):
-            mu = jnp.mean(h, axis=-1, keepdims=True)
-            var = jnp.mean((h - mu) ** 2, axis=-1, keepdims=True)
-            return (h - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
-
         # context-position validity: t <= position (the current token's
         # k/v are written before the gather, so self-attention sees them)
         t_ids = jnp.arange(max_ctx)[None, :]
         valid = t_ids <= positions[:, None]
 
-        for i in range(cfg.n_layers):
-            p = "l%d/" % i
-            a = ln(x, weights[p + "ln1_scale"], weights[p + "ln1_bias"])
-            qkv = a @ self._w(jnp, weights, p + "wqkv") \
-                + weights[p + "bqkv"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(B, H, Dh)
-            k_new = k_new.reshape(B, H, Dh)
-            v_new = v_new.reshape(B, H, Dh)
-            with jax.named_scope("kv_write"):
-                kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
-                kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
-            with jax.named_scope("attention"):
-                if use_paged:
-                    # The kernel gets the pool WHOLE and finds layer and
-                    # page in its index map. `kv_k[i]` here would make
-                    # XLA copy the layer's pages out of the pool for the
-                    # custom call and lay them out again, which cost
-                    # more than the rest of the step (docs/SERVING.md,
-                    # "No kernel step slices the pool").
-                    ctx = paged_attention(
-                        kv_k, kv_v, q[:, None], block_tables,
-                        positions[:, None], layer=i, sm_scale=sm_scale)
-                    ctx = ctx[:, 0].reshape(B, -1)
-                else:
-                    # lax path: the layer's pages, then the paged gather
-                    # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]. XLA
-                    # fuses this slice with the convert the dot wants
-                    # (one pass, bf16 out on the chip); one gather from
-                    # the whole pool measured slower (docs/SERVING.md).
-                    with jax.named_scope("kv_read"):
-                        k_ctx = kv_k[i][block_tables].reshape(
-                            B, max_ctx, H, Dh)
-                        v_ctx = kv_v[i][block_tables].reshape(
-                            B, max_ctx, H, Dh)
-                    scores = jnp.einsum("bhd,bthd->bht", q, k_ctx) \
-                        * sm_scale
-                    scores = jnp.where(valid[:, None, :], scores,
-                                       -jnp.inf)
-                    w = jnp.exp(scores
-                                - jnp.max(scores, axis=-1, keepdims=True))
-                    w = w / jnp.sum(w, axis=-1, keepdims=True)
-                    ctx = jnp.einsum("bht,bthd->bhd", w, v_ctx) \
-                        .reshape(B, -1)
-                x = x + ctx @ self._w(jnp, weights, p + "wproj") \
-                    + weights[p + "bproj"]
-            with jax.named_scope("ffn"):
-                b2 = ln(x, weights[p + "ln2_scale"],
-                        weights[p + "ln2_bias"])
-                f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
-                                + weights[p + "bff1"], approximate=False)
-                x = x + f @ self._w(jnp, weights, p + "wff2") \
-                    + weights[p + "bff2"]
+        def attend(i, q, kv_k, kv_v):
+            if use_paged:
+                # The kernel gets the pool WHOLE and finds layer and page
+                # in its index map. `kv_k[i]` here would make XLA copy
+                # the layer's pages out of the pool for the custom call
+                # and lay them out again, which cost more than the rest
+                # of the step (docs/SERVING.md, "No kernel step slices
+                # the pool").
+                ctx = paged_attention(
+                    kv_k, kv_v, q[:, None], block_tables,
+                    positions[:, None], layer=i, sm_scale=sm_scale)
+                return ctx[:, 0].reshape(B, -1)
+            # lax path: the layer's pages, then the paged gather
+            # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]. XLA fuses this
+            # slice with the convert the dot wants (one pass, bf16 out on
+            # the chip); one gather from the whole pool measured slower
+            # (docs/SERVING.md).
+            with jax.named_scope("kv_read"):
+                k_ctx = kv_k[i][block_tables].reshape(B, max_ctx, H, Dh)
+                v_ctx = kv_v[i][block_tables].reshape(B, max_ctx, H, Dh)
+            scores = jnp.einsum("bhd,bthd->bht", q, k_ctx) * sm_scale
+            scores = jnp.where(valid[:, None, :], scores, -jnp.inf)
+            w = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+            return jnp.einsum("bht,bthd->bhd", w, v_ctx).reshape(B, -1)
 
-        with jax.named_scope("head"):
-            x = ln(x, weights["final_ln_scale"], weights["final_ln_bias"])
-            return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
+        return self._layers(jnp, weights, x, kv_k, kv_v, write_blk,
+                            slot_idx, attend)
 
     def make_decode_step(self, max_batch, max_blocks_per_seq,
                          return_logits=False):
@@ -1046,11 +1067,6 @@ class GenerationModel:
         sm_scale = Dh ** -0.5
         lead = x.shape[:-1]            # [B, C], or [rows] of tokens
 
-        def ln(h, scale, bias):
-            mu = jnp.mean(h, axis=-1, keepdims=True)
-            var = jnp.mean((h - mu) ** 2, axis=-1, keepdims=True)
-            return (h - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
-
         # one kernel decision per forward (trace time), shared by all
         # layers; every kernel gets the pool whole, never `kv_k[i]`
         # (see _forward_token)
@@ -1151,45 +1167,17 @@ class GenerationModel:
                 return jnp.einsum("bcht,bthd->bchd", w, v_ctx) \
                     .reshape(B, C, -1)
 
-        for i in range(cfg.n_layers):
-            p = "l%d/" % i
-            a = ln(x, weights[p + "ln1_scale"], weights[p + "ln1_bias"])
-            qkv = a @ self._w(jnp, weights, p + "wqkv") \
-                + weights[p + "bqkv"]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(lead + (H, Dh))
-            k_new = k_new.reshape(lead + (H, Dh))
-            v_new = v_new.reshape(lead + (H, Dh))
-            with jax.named_scope("kv_write"):
-                kv_k = kv_k.at[i, write_blk, slot_idx].set(k_new)
-                kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
-            with jax.named_scope("attention"):
-                ctx = attend(i, q, kv_k, kv_v)
-                x = x + ctx @ self._w(jnp, weights, p + "wproj") \
-                    + weights[p + "bproj"]
-            with jax.named_scope("ffn"):
-                b2 = ln(x, weights[p + "ln2_scale"],
-                        weights[p + "ln2_bias"])
-                f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
-                                + weights[p + "bff1"], approximate=False)
-                x = x + f @ self._w(jnp, weights, p + "wff2") \
-                    + weights[p + "bff2"]
-
-        with jax.named_scope("head"):
-            if all_slots:
-                x = ln(x, weights["final_ln_scale"],
-                       weights["final_ln_bias"])
-                return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
+        def last_slot(x):
             if layout is not None:
-                x_last = x[layout["last"]]
-            else:
-                last = jnp.clip(lengths - 1, 0, lead[1] - 1) \
-                    .astype(jnp.int32)
-                x_last = jnp.take_along_axis(x, last[:, None, None],
-                                             axis=1)[:, 0]
-            x_last = ln(x_last, weights["final_ln_scale"],
-                        weights["final_ln_bias"])
-            return kv_k, kv_v, x_last @ self._w(jnp, weights, "lm_head")
+                return x[layout["last"]]
+            last = jnp.clip(lengths - 1, 0, lead[1] - 1).astype(jnp.int32)
+            return jnp.take_along_axis(x, last[:, None, None],
+                                       axis=1)[:, 0]
+
+        # the verify windows want every slot's logits
+        return self._layers(jnp, weights, x, kv_k, kv_v, write_blk,
+                            slot_idx, attend,
+                            None if all_slots else last_slot)
 
     def make_prefill_step(self, max_batch, max_blocks_per_seq, chunk,
                           return_logits=False, max_tokens=None):
@@ -1213,7 +1201,7 @@ class GenerationModel:
 
         ``max_tokens`` is the caller's promise of how many tokens a
         window can hold at once (the engine: ``max_batch`` plus the
-        scheduler's prefill budget, which ``plan_chunk`` keeps). Both
+        scheduler's prefill budget, which ``plan_step`` keeps). Both
         blocks compact the window's real tokens to that many rows for
         everything a token does alone: embedding, norms, projections,
         the K/V write, the FFN (``None``, or a promise no smaller than

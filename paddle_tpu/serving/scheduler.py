@@ -1,9 +1,10 @@
 """Request queue and iteration-level (continuous-batching) scheduler
 (Orca OSDI '22 mapped onto a fixed-shape XLA decode step).
 
-The unit of scheduling is one *decode step*: every active batch slot
-advances by exactly one token per step, and sequences join/retire only
-at step boundaries. The compiled step's shapes never change — admission
+The unit of scheduling is one *step*: every active batch slot advances
+by one generated token per step (or by a chunk of its prompt), and
+sequences join/retire only at step boundaries. The compiled steps'
+shapes never change — admission
 fills a free slot's row in the (fixed ``[max_batch]``) input arrays and
 flips its ``active`` flag, retirement flips it back — so XLA never
 retraces no matter how traffic arrives.
@@ -19,45 +20,37 @@ Admission control is two-gated:
     Head-of-line order is preserved: if the head request doesn't fit,
     nothing behind it jumps the queue (no starvation of big requests).
 
-Prefill rides the same step (Orca's iteration-level scheduling): a
-just-admitted sequence consumes one prompt token per step (``use_prompt``
-rows) until its prompt is exhausted, after which its input token chains
-on-device from the previous step's output.
+Prefill rides the same steps (Orca's iteration-level scheduling, in
+Sarathi-Serve's chunks): whenever a row is mid-prompt ``plan_step``
+plans a MIXED step, in which prefill rows consume up to
+``prefill_chunk`` prompt tokens (all of whose blocks are allocated at
+the boundary, still drawn from the admission reservation) and decode
+rows ride along as 1-token windows; when no row is mid-prompt it plans
+a DECODE step, every row a window of one whose input token chains
+on-device from the previous step's output. ``prefill_token_budget``
+caps the TOTAL prompt tokens per mixed step (rows past the budget sit
+the step out, in slot order), so decode rows' per-step latency stays
+bounded no matter how many prompts arrive at once.
 
-Two fast-path modes stack on top (docs/SERVING.md), both OFF by
-default — with ``prefill_chunk=0`` and ``prefix_cache=False`` the
-scheduler's plan sequence and pool accounting are exactly the legacy
-PR-6 behavior:
+**Radix prefix caching** (``prefix_cache=True``, off by default):
+admission runs a longest-prefix-match of the prompt's chain keys
+(:func:`~paddle_tpu.serving.kv_cache.prefix_chain_keys`) against the
+pool's content index; matched blocks are adopted refcounted into the
+block table and ``pos`` starts past the shared span — the request
+skips both the prefill compute and the block allocations for it. As a
+sequence's own prefill crosses each full-prompt-block boundary the
+block is sealed into the index for later requests.
 
-  * **chunked prefill** (``prefill_chunk=C``, Sarathi-Serve style):
-    ``plan_chunk`` plans MIXED steps whenever any row is mid-prompt —
-    prefill rows consume up to ``C`` prompt tokens (all of whose blocks
-    are allocated at the boundary, still drawn from the admission
-    reservation), decode rows ride the same step as 1-token windows —
-    and falls back to the one-token decode plan when nobody is in
-    prefill. ``prefill_token_budget`` caps the TOTAL prompt tokens per
-    mixed step (rows past the budget sit the step out, in slot order),
-    so decode rows' per-step latency stays bounded no matter how many
-    prompts arrive at once.
-  * **radix prefix caching** (``prefix_cache=True``): admission runs a
-    longest-prefix-match of the prompt's chain keys
-    (:func:`~paddle_tpu.serving.kv_cache.prefix_chain_keys`) against
-    the pool's content index; matched blocks are adopted refcounted
-    into the block table and ``pos`` starts past the shared span — the
-    request skips both the prefill compute and the block allocations
-    for it. As a sequence's own prefill crosses each full-prompt-block
-    boundary the block is sealed into the index for later requests.
-
-A third opt-in mode, **speculative decoding** (``spec_k=K`` /
-``$PTPU_SERVE_SPEC_K``, docs/SERVING.md), changes what a decode step
-emits: when every occupied row is past its prompt, ``plan_spec`` plans
-a VERIFY window — each row feeds its last committed token plus up to
+**Speculative decoding** (``spec_k=K`` / ``$PTPU_SERVE_SPEC_K``,
+docs/SERVING.md, off by default) changes what a decode step emits:
+when every occupied row is past its prompt, ``plan_spec`` plans a
+VERIFY window — each row feeds its last committed token plus up to
 ``K`` continuations proposed by a ``drafter`` (n-gram prompt lookup by
 default) — and ``record_spec`` folds the materialized window back:
 per-row acceptance is the longest prefix where draft == the target's
 argmax, the accepted run plus the target's correction token are
-emitted (>= 1 token per window, so speculation is never slower in
-steps than legacy), and the KV blocks past the rewound position are
+emitted (>= 1 token per window, so speculation never takes more steps
+than plain decoding), and the KV blocks past the rewound position are
 returned through ``KVBlockPool.truncate_owner`` (rollback).
 """
 
@@ -76,6 +69,14 @@ __all__ = ["AdmissionError", "DeadlineExceededError", "GenerationRequest",
            "spec_tree_acceptance"]
 
 _req_ids = itertools.count()
+
+# The prompt tokens a prefill row consumes in one mixed step where the
+# deployment names no size (``prefill_chunk`` / $PTPU_SERVE_PREFILL_CHUNK
+# unset, None or 0), clamped to the context. 256 is what both XGLM cells
+# of the benchmark run and one query tile of the chunk step's attention
+# (``model.CHUNK_TILE``, settled on the chip in PR 28), so a default
+# engine compiles the shape that was measured.
+DEFAULT_PREFILL_CHUNK = 256
 
 
 def spec_tree_acceptance(window, outs, width):
@@ -296,7 +297,7 @@ class StepScheduler:
     (lagged) ``record_token()`` per decode output → ``reap()``.
     """
 
-    def __init__(self, max_batch, pool, max_seq_len, prefill_chunk=0,
+    def __init__(self, max_batch, pool, max_seq_len, prefill_chunk=None,
                  prefix_cache=False, prefill_token_budget=None,
                  cache_namespace="", spec_k=0, drafter=None,
                  spec_tree=None):
@@ -311,26 +312,29 @@ class StepScheduler:
         mb = blocks_needed(self.max_seq_len, pool.block_size)
         self.max_blocks_per_seq = mb
         self.block_tables = np.zeros((self.max_batch, mb), np.int32)
-        self.prompt_feed = np.zeros(self.max_batch, np.int32)
         self.use_prompt = np.zeros(self.max_batch, bool)
         self.positions = np.zeros(self.max_batch, np.int32)
         self.active = np.zeros(self.max_batch, bool)
-        # -- fast-path configuration (both OFF = exact legacy PR-6) ----
-        self.prefill_chunk = max(0, int(prefill_chunk or 0))
+        # the chunk is a compiled shape, so it is clamped to the
+        # context; the per-step token budget (default 4 chunks) bounds
+        # how much prefill compute a MIXED step carries alongside decode
+        # rows: the decode-latency bound
+        self.prefill_chunk = min(
+            max(0, int(prefill_chunk or 0)) or DEFAULT_PREFILL_CHUNK,
+            self.max_seq_len)
+        self.prefill_token_budget = max(1, int(
+            4 * self.prefill_chunk if prefill_token_budget is None
+            else prefill_token_budget))
+        self.chunk_feed = np.zeros(
+            (self.max_batch, self.prefill_chunk), np.int32)
+        self.chunk_lens = np.zeros(self.max_batch, np.int32)
         self.prefix_cache = bool(prefix_cache)
-        self.prefill_token_budget = (
-            None if prefill_token_budget is None
-            else max(1, int(prefill_token_budget)))
         self.cache_namespace = str(cache_namespace)
         # host-side reuse telemetry (live even with metrics disabled —
         # engine.stats()/bench read these)
         self.prefix_blocks_reused = 0
         self.prefix_tokens_skipped = 0
-        if self.prefill_chunk:
-            self.chunk_feed = np.zeros(
-                (self.max_batch, self.prefill_chunk), np.int32)
-            self.chunk_lens = np.zeros(self.max_batch, np.int32)
-        # -- speculative decoding (docs/SERVING.md; OFF = exact legacy)
+        # -- speculative decoding (docs/SERVING.md; off by default)
         from .model import parse_tree_shape
 
         self.spec_tree = parse_tree_shape(spec_tree)
@@ -468,90 +472,49 @@ class StepScheduler:
 
     # -- step planning --------------------------------------------------
     def plan_step(self):
-        """Fill the fixed step-input arrays for the next decode step and
-        return the per-step processing plan: a list of
+        """Fill the fixed window arrays for the next step and return
+        ``(plan, kind)``. The plan is a list of
         ``(seq, generated_index | None)`` rows, one per dispatching
-        slot (``None`` while the slot is still consuming its prompt)."""
-        plan = []
-        for slot, seq in enumerate(self.slots):
-            if seq is None or seq.dispatch_done:
-                self.active[slot] = False
-                self.use_prompt[slot] = False
-                continue
-            pos = seq.pos
-            # lazy block allocation at boundary crossings (drawn from
-            # the admission-time reservation, so it cannot fail)
-            if pos % self.pool.block_size == 0:
-                bid = self.pool.alloc_block(seq)
-                self.block_tables[slot, pos // self.pool.block_size] = bid
-            self.positions[slot] = pos
-            self.active[slot] = True
-            if seq.in_prefill:
-                self.prompt_feed[slot] = seq.request.prompt[pos]
-                self.use_prompt[slot] = True
-                # the step consuming the LAST prompt token emits the
-                # first generated token
-                gen_idx = (0 if pos == len(seq.request.prompt) - 1
-                           else None)
-            else:
-                self.use_prompt[slot] = False
-                gen_idx = seq.n_dispatched
-            if gen_idx is not None:
-                seq.n_dispatched = gen_idx + 1
-            seq.pos = pos + 1
-            seq.pending += 1
-            plan.append((seq, gen_idx))
-            if (seq.n_dispatched >= seq.request.max_new_tokens
-                    or seq.pos >= self.max_seq_len):
-                seq.dispatch_done = True
-            if seq.prefix_keys:
-                self._seal_ready(slot, seq)
-        return plan
+        slot (``None`` while the slot is still consuming its prompt);
+        ``kind`` says which compiled program the window is for:
+        ``"mixed"`` whenever an active row is mid-prompt, ``"decode"``
+        otherwise (every row a window of one, no prompt token fed).
 
-    def plan_chunk(self):
-        """Chunked-prefill planning (Sarathi-style mixed batches).
-        When no active row is mid-prompt this delegates to the
-        one-token ``plan_step`` (the engine then dispatches the cheap
-        decode shape). Otherwise fills the ``chunk_feed``/``chunk_lens``
-        window arrays — prefill rows consume up to ``prefill_chunk``
-        prompt tokens (bounded further by ``prefill_token_budget``
-        across rows; rows past the budget sit this step out), decode
-        rows are 1-token windows — and returns ``(plan, True)``.
-        Returns ``(plan, used_chunk)``."""
-        if not any(s is not None and not s.dispatch_done and s.in_prefill
-                   for s in self.slots):
-            return self.plan_step(), False
+        Prefill rows consume up to ``prefill_chunk`` prompt tokens
+        (bounded further by ``prefill_token_budget`` across rows; rows
+        past the budget sit the step out), decode rows one token
+        chained on the device."""
+        kind = ("mixed" if any(
+            s is not None and not s.dispatch_done and s.in_prefill
+            for s in self.slots) else "decode")
         bs = self.pool.block_size
         budget = self.prefill_token_budget
         plan = []
         for slot, seq in enumerate(self.slots):
-            if seq is None or seq.dispatch_done:
+            n = 0
+            if seq is not None and not seq.dispatch_done:
+                prefill = seq.in_prefill
+                pos, prompt = seq.pos, seq.request.prompt
+                # a prefill row takes what the step's budget still
+                # holds; where that is nothing it sits the step out, so
+                # that decode rows' latency stays bounded, and resumes
+                # next step
+                n = (min(self.prefill_chunk, len(prompt) - pos, budget)
+                     if prefill else 1)
+            if not n:
                 self.active[slot] = False
                 self.use_prompt[slot] = False
                 self.chunk_lens[slot] = 0
                 continue
-            pos = seq.pos
-            prompt = seq.request.prompt
-            if seq.in_prefill:
-                n = min(self.prefill_chunk, len(prompt) - pos)
-                if budget is not None:
-                    if budget <= 0:
-                        # prefill budget for this step is spent: the
-                        # row sits the step out so decode rows' latency
-                        # stays bounded (it resumes next step)
-                        self.active[slot] = False
-                        self.use_prompt[slot] = False
-                        self.chunk_lens[slot] = 0
-                        continue
-                    n = min(n, budget)
-                    budget -= n
+            if prefill:
+                budget -= n
                 self.chunk_feed[slot, :n] = prompt[pos:pos + n]
-                self.use_prompt[slot] = True
+                # the window consuming the LAST prompt token emits the
+                # first generated token
                 gen_idx = 0 if pos + n == len(prompt) else None
             else:
-                n = 1
-                self.use_prompt[slot] = False
                 gen_idx = seq.n_dispatched
+            self.use_prompt[slot] = prefill
             # lazy block allocation for EVERY boundary the window
             # crosses (drawn from the admission-time reservation, so it
             # cannot fail)
@@ -572,7 +535,7 @@ class StepScheduler:
                 seq.dispatch_done = True
             if seq.prefix_keys:
                 self._seal_ready(slot, seq)
-        return plan, True
+        return plan, kind
 
     def plan_spec(self):
         """Speculative verify-window planning (docs/SERVING.md).
@@ -683,8 +646,8 @@ class StepScheduler:
         under verify-based acceptance (a pad is just a draft that will
         not match the target argmax). Rows whose drafter proposes
         nothing (or whose clamp hits 0) ride as 1-slot windows — plain
-        decode through the tree step, so tree mode is never slower in
-        steps than legacy. Returns the spec plan
+        decode through the tree step, so tree mode never takes more
+        steps than plain decoding. Returns the spec plan
         ``[(seq, window_tokens), ...]``."""
         bs = self.pool.block_size
         W, D = self.spec_tree

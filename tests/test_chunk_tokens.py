@@ -9,7 +9,7 @@ runs over query tiles. Pinned here, CPU, toy widths:
     logits, the same K/V pages for every real token; on the lax path
     tightly, through the ``chunk_window`` kernel (interpreted) within
     the rounding of its bf16 operands;
-  * the promise the step rests on: ``plan_chunk`` never plans more than
+  * the promise the step rests on: ``plan_step`` never plans more than
     ``max_batch + prefill_token_budget`` tokens;
   * the tiles: how many the step is compiled for, and that a window's
     tiles cover each of its tokens once.
@@ -156,7 +156,7 @@ def test_a_window_holding_fewer_tokens_than_rows_pads(monkeypatch):
 
 
 @pytest.mark.parametrize("seed", range(4))
-def test_plan_chunk_keeps_the_promise_of_max_tokens(seed):
+def test_plan_step_keeps_the_promise_of_max_tokens(seed):
     """The step computes `max_batch + prefill_token_budget` token rows
     and nothing past them: over random admissions no planned window
     holds more, whatever the prompts, the chunk and the budget."""
@@ -175,8 +175,8 @@ def test_plan_chunk_keeps_the_promise_of_max_tokens(seed):
                 max_new_tokens=int(rng.randint(1, 6))))
             pending -= 1
         sched.admit(queue)
-        plan, chunked = sched.plan_chunk()
-        if chunked:
+        plan, kind = sched.plan_step()
+        if kind == "mixed":
             planned_mixed += 1
             lens = sched.chunk_lens[sched.active]
             assert lens.sum() <= max_batch + budget

@@ -522,10 +522,10 @@ def test_online_updater_skips_corrupt_checkpoint(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_online_off_defaults_bitwise_legacy(monkeypatch):
+def test_online_off_defaults_do_no_online_work(monkeypatch):
     """No OnlineUpdater attached and $PTPU_SERVE_CANARY_PCT unset: no
     canary pin, no version ledger accrual, every replica stays on
-    version 0, and routing/tokens are the PR-13 path exactly."""
+    version 0, and the tokens are reference_decode's."""
     monkeypatch.delenv("PTPU_SERVE_CANARY_PCT", raising=False)
     m0, _ = model_pair()
     prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9]]

@@ -183,8 +183,8 @@ def test_eos_stops_early_and_truncates():
 
 def test_no_retrace_across_join_and_retire():
     """Sequences joining and retiring at step boundaries never change
-    the compiled step's shapes: exactly ONE trace for the whole
-    staggered workload."""
+    the compiled steps' shapes: exactly TWO traces (the mixed window
+    and the decode step) for the whole staggered workload."""
     model = tiny_model(seed=5)
     assert model.trace_count == 0
     with serving.ServingEngine(model, max_batch=3, max_seq_len=64,
@@ -198,7 +198,7 @@ def test_no_retrace_across_join_and_retire():
                 eng.submit([5, 6, 7, 8, 9], max_new_tokens=8)]
         for r in first + late:
             r.wait(120)
-    assert model.trace_count == 1
+    assert model.trace_count == 2
 
 
 def test_queue_admission_control():
@@ -437,11 +437,13 @@ def test_serving_metrics_surface():
     reg = obs.registry()
     done0 = reg.counter("serving/requests_completed").value
     lat0 = reg.histogram("serving/request_latency").count
+    dec0 = reg.counter("serving/decode_tokens").value
+    pre0 = reg.counter("serving/prefill_tokens").value
+    prompts = _prompts(8, model.config.vocab_size)
     try:
         with serving.ServingEngine(model, max_batch=4, max_seq_len=64,
                                    block_size=4) as eng:
-            reqs = [eng.submit(p, max_new_tokens=8)
-                    for p in _prompts(8, model.config.vocab_size)]
+            reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
             for r in reqs:
                 r.wait(120)
     finally:
@@ -453,7 +455,11 @@ def test_serving_metrics_surface():
     assert reg.gauge("serving/request_latency_p99").value > 0
     assert np.isfinite(reg.gauge("serving/request_latency_p99").value)
     assert reg.gauge("serving/tokens_per_sec").value > 0
-    assert reg.counter("serving/decode_tokens").value >= 8 * 8
+    # a request's first token comes out of the window that consumed
+    # the end of its prompt; the other seven are decode tokens
+    assert reg.counter("serving/decode_tokens").value - dec0 == 8 * 7
+    assert reg.counter("serving/prefill_tokens").value - pre0 \
+        == sum(len(p) for p in prompts)
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,10 @@ Covers the two tentpole legs and their satellites:
     torture across chunk boundaries pinned token-identical to
     ``reference_decode`` with exactly TWO traces (one per step shape),
     and the per-step prefill token budget (decode-latency bound);
-  * flags-off legacy identity — the PR-6 one-token plan sequence and
-    pool accounting are pinned against an in-test oracle;
+  * the one planner — its plan sequence (mixed windows, then decode
+    windows of one) and pool accounting pinned against an in-test
+    oracle; what a default engine builds; a default engine prefills a
+    long prompt in ceil(len / chunk) mixed steps;
   * TTFT telemetry (histogram + p50/p99 gauges).
 """
 
@@ -274,24 +276,24 @@ def test_chunk_budget_bounds_prefill_per_step():
     q.submit(r1)
     q.submit(r2)
     assert len(sched.admit(q)) == 2
-    plan, chunked = sched.plan_chunk()
-    assert chunked
+    plan, kind = sched.plan_step()
+    assert kind == "mixed"
     # slot0 burns the whole budget; slot1 is deferred, not starved
     assert sched.chunk_lens.tolist() == [4, 0]
     assert sched.active.tolist() == [True, False]
     assert [g for _, g in plan] == [None]
     for seq, g in plan:
         sched.record_token(seq, g, 1)
-    plan, chunked = sched.plan_chunk()
-    assert chunked
+    plan, kind = sched.plan_step()
+    assert kind == "mixed"
     assert sched.chunk_lens.tolist() == [4, 0]  # r1 finishes its prompt
     assert [g for _, g in plan] == [0]
     for seq, g in plan:
         sched.record_token(seq, g, 1)
     # mixed step: r1 decodes (1-token window, budget-exempt), r2 gets
     # the whole replenished budget
-    plan, chunked = sched.plan_chunk()
-    assert chunked
+    plan, kind = sched.plan_step()
+    assert kind == "mixed"
     assert sched.chunk_lens.tolist() == [1, 4]
     assert sched.use_prompt.tolist() == [False, True]
     assert sched.active.tolist() == [True, True]
@@ -399,17 +401,18 @@ def test_prefix_cache_multi_model_namespaced():
 
 
 # ---------------------------------------------------------------------------
-# legacy identity (flags unset/0)
+# the one planner, and what the defaults build
 # ---------------------------------------------------------------------------
 
 
-def test_legacy_plan_sequence_pinned_against_oracle():
-    """With both fast-path knobs off, the scheduler's observable plan
-    trace (positions/use_prompt/active/prompt_feed/gen indices and the
-    lazily-built block tables) is the exact PR-6 one-token-prefill
-    sequence, pinned literally."""
+def test_plan_sequence_pinned_against_oracle():
+    """The planner's observable trace (step kind, positions, window
+    lengths, use_prompt, active, the prompt tokens fed, gen indices and
+    the lazily-built block tables), pinned literally: mixed windows
+    while a row is mid-prompt (decode rows riding as windows of one),
+    then decode windows of one that feed no prompt token."""
     pool = KVBlockPool(1, 1, 4, 4, num_blocks=16)
-    sched = StepScheduler(2, pool, max_seq_len=16)
+    sched = StepScheduler(2, pool, max_seq_len=16, prefill_chunk=2)
     q = RequestQueue(8)
     r1 = GenerationRequest([5, 6, 7], max_new_tokens=3)
     r2 = GenerationRequest([9, 8], max_new_tokens=2)
@@ -418,20 +421,26 @@ def test_legacy_plan_sequence_pinned_against_oracle():
     assert len(sched.admit(q)) == 2
     trace = []
     for _ in range(6):
-        plan = sched.plan_step()
-        trace.append((sched.positions.tolist(), sched.use_prompt.tolist(),
-                      sched.active.tolist(), sched.prompt_feed.tolist(),
+        plan, kind = sched.plan_step()
+        lens = sched.chunk_lens.tolist()
+        trace.append((kind, sched.positions.tolist(), lens,
+                      sched.use_prompt.tolist(), sched.active.tolist(),
+                      [sched.chunk_feed[i, :n].tolist() if u else []
+                       for i, (n, u) in enumerate(
+                           zip(lens, sched.use_prompt))],
                       [g for _, g in plan]))
         for seq, g in plan:
             sched.record_token(seq, g, 1)
         sched.reap()
+    T, F = True, False
     assert trace == [
-        ([0, 0], [True, True], [True, True], [5, 9], [None, None]),
-        ([1, 1], [True, True], [True, True], [6, 8], [None, 0]),
-        ([2, 2], [True, False], [True, True], [7, 8], [0, 1]),
-        ([3, 2], [False, False], [True, False], [7, 8], [1]),
-        ([4, 2], [False, False], [True, False], [7, 8], [2]),
-        ([4, 2], [False, False], [False, False], [7, 8], []),
+        ("mixed", [0, 0], [2, 2], [T, T], [T, T], [[5, 6], [9, 8]],
+         [None, 0]),
+        ("mixed", [2, 2], [1, 1], [T, F], [T, T], [[7], []], [0, 1]),
+        ("decode", [3, 2], [1, 0], [F, F], [T, F], [[], []], [1]),
+        ("decode", [4, 2], [1, 0], [F, F], [T, F], [[], []], [2]),
+        ("decode", [4, 2], [0, 0], [F, F], [F, F], [[], []], []),
+        ("decode", [4, 2], [0, 0], [F, F], [F, F], [[], []], []),
     ]
     # LIFO pool: slot0 drew block 1 then (at pos 4) block 3; slot1 drew
     # block 2 — and everything is back in the pool after retirement
@@ -441,7 +450,7 @@ def test_legacy_plan_sequence_pinned_against_oracle():
     assert st["blocks_free"] == 16
 
 
-def test_legacy_defaults_build_one_step_and_no_index(monkeypatch):
+def test_defaults_build_decode_and_chunk_shapes_and_no_index(monkeypatch):
     monkeypatch.delenv("PTPU_SERVE_PREFILL_CHUNK", raising=False)
     monkeypatch.delenv("PTPU_SERVE_PREFIX_CACHE", raising=False)
     model = tiny_model(seed=9)
@@ -450,19 +459,40 @@ def test_legacy_defaults_build_one_step_and_no_index(monkeypatch):
     with serving.ServingEngine(model, max_batch=2, max_seq_len=64,
                                block_size=4) as eng:
         w = eng._workers["default"]
-        assert w.prefill_chunk == 0 and w.prefix_cache is False
-        assert w._chunk_step is None
+        # the default chunk, clamped to the context; four chunks of
+        # prefill budget a mixed step
+        assert serving.scheduler.DEFAULT_PREFILL_CHUNK == 256
+        assert w.prefill_chunk == 64 and w.prefix_cache is False
+        assert w.scheduler.prefill_token_budget == 256
+        assert w.scheduler.chunk_feed.shape == (2, 64)
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         assert [r.wait(120) for r in reqs] == refs
         st = eng.stats()["default"]
-    assert model.trace_count == 1          # only the decode shape
-    assert len(model._steps) == 1
+    assert model.trace_count == 2          # the decode and chunk shapes
+    assert len(model._steps) == 2
+    assert st["prefill_chunk"] == 64
     assert st["prefix_blocks_reused"] == 0
     assert st["blocks_shared"] == 0 and st["blocks_cached"] == 0
     assert not w.pool._sealed              # content index never touched
 
 
-def test_env_flags_activate_fast_path(monkeypatch):
+def test_default_engine_prefills_a_long_prompt_in_chunks(monkeypatch):
+    """A default engine given a prompt longer than one chunk reaches
+    its first token in ceil(len / chunk) mixed steps, not one step a
+    prompt token."""
+    monkeypatch.delenv("PTPU_SERVE_PREFILL_CHUNK", raising=False)
+    model = tiny_model(seed=4, max_seq_len=1024)
+    prompt = np.random.RandomState(2).randint(0, 64, size=600).tolist()
+    with serving.ServingEngine(model, max_batch=1, max_seq_len=1024,
+                               block_size=16) as eng:
+        got = eng.generate(prompt, max_new_tokens=1, timeout=300)
+        st = eng.stats()["default"]
+    assert st["prefill_chunk"] == 256
+    assert st["steps"] == 3                # 256 + 256 + 88 prompt tokens
+    assert got == reference_decode(model, prompt, 1)
+
+
+def test_env_flags_set_chunk_size_and_prefix_cache(monkeypatch):
     monkeypatch.setenv("PTPU_SERVE_PREFILL_CHUNK", "4")
     monkeypatch.setenv("PTPU_SERVE_PREFIX_CACHE", "1")
     model = shared_model()
@@ -474,6 +504,8 @@ def test_env_flags_activate_fast_path(monkeypatch):
         assert w.prefill_chunk == 4 and w.prefix_cache is True
         assert w.scheduler.prefill_token_budget == 16  # 4 * chunk
         assert eng.generate(prompt, max_new_tokens=5, timeout=120) == ref
+        # 14 prompt tokens in chunks of 4, then 4 decode steps
+        assert eng.stats()["default"]["steps"] == 4 + 4
 
 
 # ---------------------------------------------------------------------------

@@ -476,10 +476,10 @@ def test_router_deadline_backstop_on_wedged_replica():
 
 def test_replica_killed_mid_prefill_drains_pool():
     model = shared_model()
-    prompt = list(range(1, 33))  # 32 prefill steps at one token/step
+    prompt = list(range(1, 33))  # 8 mixed steps at 4 tokens a chunk
     with _inject("serve_die_at_step:5"):
         with serving.ServingEngine(model, max_batch=2, max_seq_len=64,
-                                   block_size=4) as eng:
+                                   block_size=4, prefill_chunk=4) as eng:
             req = eng.submit(prompt, max_new_tokens=8)
             with pytest.raises(resilience.InjectedReplicaDeathError):
                 req.wait(120)
@@ -494,7 +494,7 @@ def test_replica_killed_mid_spec_window_drains_pool():
     model = shared_model()
     pattern = [3, 5, 7, 9]
     prompt = pattern * 3  # repetitive: spec windows will accept
-    die_at = len(prompt) + 2  # past prefill, inside the spec phase
+    die_at = 1 + 2  # past the one-chunk prefill, inside the spec phase
     with _inject("serve_die_at_step:%d" % die_at):
         with serving.ServingEngine(model, max_batch=2, max_seq_len=64,
                                    block_size=4, spec_k=3) as eng:
@@ -511,17 +511,17 @@ def test_replica_killed_mid_spec_window_drains_pool():
 # ---------------------------------------------------------------------------
 
 
-def test_fleet_off_defaults_bitwise_legacy(monkeypatch):
-    """No router in play and the new flags unset: the engine is the
-    PR-12 path — no deadline scan, no injector work, the same single
-    compiled shape, and the same tokens."""
+def test_fleet_off_defaults_do_no_fleet_work(monkeypatch):
+    """No router in play and the fleet flags unset: no deadline scan,
+    no injector work, the engine's two compiled shapes and no other,
+    and reference_decode's tokens."""
     for name in ("PTPU_SERVE_REPLICAS", "PTPU_SERVE_DEADLINE_S",
                  "PTPU_SERVE_RETRY_BUDGET", "PTPU_FAULT_INJECT"):
         monkeypatch.delenv(name, raising=False)
     model = GenerationModel.random(
         GenerationConfig(vocab_size=64, d_model=32, n_heads=2,
                          n_layers=2, d_ff=64, max_seq_len=64),
-        seed=9, name="fleet-legacy")
+        seed=9, name="fleet-off")
     prompts = _prompts(4, seed=13)
     refs = [reference_decode(model, p, 6) for p in prompts]
     prev = resilience.set_global_injector(resilience.FaultInjector(""))
@@ -537,8 +537,8 @@ def test_fleet_off_defaults_bitwise_legacy(monkeypatch):
             st = eng.stats()["default"]
     finally:
         resilience.set_global_injector(prev)
-    assert model.trace_count == 1  # only the one decode shape compiled
-    assert len(model._steps) == 1
+    assert model.trace_count == 2  # the decode and chunk shapes, no other
+    assert len(model._steps) == 2
     assert st["deadline_expired"] == 0 and st["transient_retries"] == 0
     # the default router width is one replica (flag default)
     from paddle_tpu.flags import env

@@ -19,9 +19,9 @@ Covers the tentpole and its satellites:
     (serving/engine.py docstring, docs/SERVING.md): with spec windows
     on, no post-EOS token is ever emitted and discarded-position KV
     writes are rolled back or overwritten-before-visible;
-  * flag-off identity — ``PTPU_SERVE_SPEC_K`` unset keeps the engine
-    bitwise-legacy (no third compiled shape, no spec state, same
-    tokens), the AMP-off identity pattern.
+  * flag-off identity — ``PTPU_SERVE_SPEC_K`` unset builds no third
+    compiled shape and no spec state, and the tokens are
+    ``reference_decode``'s.
 """
 
 import threading
@@ -229,13 +229,13 @@ def test_pool_invariants_cover_rollback_states():
 
 
 def _drive_prefill(sched, q, request, token=5):
-    """Admit and run one-token prefill to completion, feeding `token`
-    as every materialized output (host-side unit driving)."""
+    """Admit and run the prefill to completion, feeding `token` as
+    every materialized output (host-side unit driving)."""
     q.submit(request)
     assert len(sched.admit(q)) == 1
     seq = next(s for s in sched.slots if s is not None)
     while seq.in_prefill:
-        plan = sched.plan_step()
+        plan, _kind = sched.plan_step()
         for s, g in plan:
             sched.record_token(s, g, token)
     return seq
@@ -295,7 +295,7 @@ def test_scheduler_plan_spec_defers_to_prefill():
     assert sched.plan_spec() is None  # mid-prompt
     seq = next(s for s in sched.slots if s is not None)
     while seq.in_prefill:
-        for s, g in sched.plan_step():
+        for s, g in sched.plan_step()[0]:
             sched.record_token(s, g, 5)
     assert sched.plan_spec() is not None
     # spec_k=0 scheduler: plan_spec is inert
@@ -465,7 +465,7 @@ def test_model_drafter_hook_perfect_acceptance():
 def test_spec_tokens_per_step_exceeds_one_on_repetitive_set():
     """The perf receipt shape the bench/CI gate uses: repetitive
     prompts + n-gram drafting emit > 1 token per compiled step per
-    sequence (legacy is exactly 1)."""
+    sequence (plain decoding is exactly 1)."""
     model = tiny_model(seed=0, max_seq_len=128)
     rng = np.random.RandomState(11)
     prompts = [(rng.randint(0, 64, size=4).tolist()) * 3
@@ -489,12 +489,11 @@ def test_spec_tokens_per_step_exceeds_one_on_repetitive_set():
 # ---------------------------------------------------------------------------
 
 
-def test_spec_off_defaults_bitwise_legacy(monkeypatch):
+def test_spec_off_defaults_build_no_spec_state(monkeypatch):
     """PTPU_SERVE_SPEC_K unset: no drafter, no third compiled shape, no
-    spec state, and the emitted tokens are the legacy engine's — the
-    AMP-off identity pattern (the literal legacy plan-sequence oracle
-    lives in test_serving_fastpath and runs against this same default
-    scheduler)."""
+    spec state, and the emitted tokens are reference_decode's (the
+    planner's literal plan-sequence oracle lives in
+    test_serving_fastpath)."""
     monkeypatch.delenv("PTPU_SERVE_SPEC_K", raising=False)
     model = tiny_model(seed=9)
     prompts = _prompts(4, model.config.vocab_size, seed=13)
@@ -507,8 +506,8 @@ def test_spec_off_defaults_bitwise_legacy(monkeypatch):
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         assert [r.wait(120) for r in reqs] == refs
         st = eng.stats()["default"]
-    assert model.trace_count == 1          # only the decode shape
-    assert len(model._steps) == 1
+    assert model.trace_count == 2          # the decode and chunk shapes
+    assert len(model._steps) == 2
     assert not any(isinstance(k, tuple) and k and k[0] == "spec"
                    for k in model._steps)
     assert st["spec_steps"] == 0 and st["spec_proposed"] == 0

@@ -320,12 +320,13 @@ def test_rows_computed_is_the_promise_or_the_window(metrics_on, engine_kw,
 
 
 def test_a_speculative_window_is_logged_as_spec(metrics_on):
-    _tokens, steps = serve(toy_model(), lens=(6, 9), prefill_chunk=0,
-                           spec_k=3)
+    _tokens, steps = serve(toy_model(), lens=(6, 9), spec_k=3)
     recs = metrics_on.samples("serving/step").records()
     assert len(recs) == steps
     spec = [r for r in recs if r["kind"] == "spec"]
-    assert spec and {r["kind"] for r in recs} == {"decode", "spec"}
+    # the prompts go through mixed windows, everything after them
+    # through verify windows
+    assert spec and {r["kind"] for r in recs} == {"mixed", "spec"}
     for r in spec:
         # dispatched and taken in one tick, nothing queued ahead of it
         assert r["consumed"] == r["step"] and r["queued"] == 0

@@ -1,0 +1,173 @@
+"""Did a refactor change a compiled serving step? Answered without a chip.
+
+    python tools/lowered_serving_steps.py write <checkout> <out-dir> [--compile]
+    python tools/lowered_serving_steps.py compare <out-dir-a> <out-dir-b>
+
+``write`` imports ``paddle_tpu`` from ``<checkout>`` (this tree or a
+``git archive`` of another commit), builds the decode and chunk steps of
+both serving configurations of the benchmark at their engines' geometry
+(``perfbench/configs/*-serve.json``; only shapes are made, no weights),
+lowers them for a described ``v5e:2x2`` device and writes the StableHLO
+text with debug locations stripped. ``--compile`` also compiles each for
+the v5e and prints its argument, output and workspace bytes.
+
+``compare`` says whether two such directories hold the same programs. A
+Mosaic kernel's body rides in its custom call as base64 MLIR bytecode,
+the Python call stack of its trace included, so each body is parsed and
+reprinted without locations before the lines are compared, first in
+order and then sorted (set-up lines ahead of the layer loop may move).
+
+Keep JAX_PLATFORMS=cpu set: nothing here runs, and nothing it prints is
+a device number.
+"""
+
+import base64
+import glob
+import hashlib
+import json
+import os
+import re
+import sys
+
+
+def write(root, out, compile_too):
+    root = os.path.realpath(root)
+    sys.path.insert(0, root)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    os.makedirs(out, exist_ok=True)
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    import paddle_tpu
+    assert os.path.realpath(paddle_tpu.__file__).startswith(root), \
+        "paddle_tpu came from %s, not %s" % (paddle_tpu.__file__, root)
+    from paddle_tpu.core import device
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    KVBlockPool, latent_moe)
+    from paddle_tpu.serving.model import weight_names
+    from perfbench.runners import serve_latent
+
+    chip = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+    sharding = jax.sharding.SingleDeviceSharding(chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    def model_of(cfg, shapes):
+        # a model of shapes: `GenerationModel.__init__` wants arrays
+        model = GenerationModel.__new__(GenerationModel)
+        model.config, model.name, model._steps = cfg, "shapes", {}
+        model.weights = {k: arg(s, d) for k, (s, d) in shapes.items()}
+        model.weight_only_int8, model.trace_count = False, 0
+        return model
+
+    def emit(name, step, args):
+        with device.compiling_for(chip):
+            lowered = step.lower(*args)
+            text = re.sub(r"\s*loc\(.*?\)$", "", lowered.as_text(),
+                          flags=re.M)
+            text = "\n".join(line for line in text.splitlines()
+                             if not line.lstrip().startswith("#loc"))
+            with open(os.path.join(out, name + ".mlir"), "w") as f:
+                f.write(text + "\n")
+            info = {"step": name, "lines": text.count("\n") + 1}
+            if compile_too:
+                m = lowered.compile().memory_analysis()
+                info.update(argument_bytes=m.argument_size_in_bytes,
+                            output_bytes=m.output_size_in_bytes,
+                            workspace_bytes=m.temp_size_in_bytes)
+        print(json.dumps(info), flush=True)
+
+    def both_steps(name, model, e):
+        cfg = model.config
+        B, bs, C = e["max_batch"], e["block_size"], e["prefill_chunk"]
+        Mb = -(-e["max_seq_len"] // bs)
+        pool = tuple(arg(a.shape, a.dtype) for a in jax.eval_shape(
+            lambda: KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
+                                bs, e["num_blocks"],
+                                entry=model.cache_entry()).arrays))
+        row, on, tables = arg((B,)), arg((B,), jnp.bool_), arg((B, Mb))
+        # the engine's calls: prompt_feed, use_prompt, prev_tokens,
+        # positions, (lengths,) block_tables, active; its promise of
+        # max_batch + four chunks of token rows
+        emit(name + "_decode_step", model.make_decode_step(B, Mb),
+             (model.weights,) + pool + (row, on, row, row, tables, on))
+        emit(name + "_chunk_step",
+             model.make_prefill_step(B, Mb, C, max_tokens=B + 4 * C),
+             (model.weights,) + pool
+             + (arg((B, C)), on, row, row, row, tables, on))
+
+    def config(name):
+        with open(os.path.join(root, "perfbench/configs", name)) as f:
+            return json.load(f)
+
+    c = config("xglm-1.7b-serve.json")
+    cfg = GenerationConfig(
+        vocab_size=c["vocab_size"], d_model=c["d_model"],
+        n_heads=c["attention_heads"], n_layers=c["num_layers"],
+        d_ff=c["ffn_dim"], max_seq_len=c["engine"]["max_seq_len"])
+    D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
+    by_leaf = {"embedding": (V, D), "lm_head": (D, V), "wqkv": (D, 3 * D),
+               "bqkv": (3 * D,), "wproj": (D, D), "wff1": (D, F),
+               "bff1": (F,), "wff2": (F, D)}
+    both_steps("xglm", model_of(cfg, {
+        n: (by_leaf.get(n.split("/")[-1], (D,)), jnp.float32)
+        for n in weight_names(cfg)}), c["engine"])
+
+    c = config("kanana-2-30b-a3b-serve.json")
+    cfg = serve_latent.generation_config(c, c["engine"]["max_seq_len"])
+    both_steps("kanana", model_of(cfg, latent_moe.leaf_shapes(cfg)),
+               c["engine"])
+
+
+def compare(dir_a, dir_b):
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    bodies = {}
+
+    def kernel(match):
+        b64 = match.group(1)
+        if b64 not in bodies:
+            ctx = ir.Context()
+            ctx.allow_unregistered_dialects = True   # `stable_mosaic`
+            tpu.register_dialect(ctx)
+            with ctx:
+                text = ir.Module.parse(base64.b64decode(b64)) \
+                    .operation.get_asm(enable_debug_info=False)
+            bodies[b64] = "<kernel %s, %d lines>" % (
+                hashlib.sha256(text.encode()).hexdigest()[:16],
+                text.count("\n"))
+        return '\\22body\\22: \\22%s\\22' % bodies[b64]
+
+    def lines(path):
+        with open(path) as f:
+            return [re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           kernel, line) for line in f]
+
+    names = sorted(os.path.basename(p)
+                   for p in glob.glob(os.path.join(dir_a, "*.mlir")))
+    different = not names
+    for name in names:
+        a = lines(os.path.join(dir_a, name))
+        b = lines(os.path.join(dir_b, name))
+        verdict = ("identical" if a == b else
+                   "identical once sorted" if sorted(a) == sorted(b)
+                   else "DIFFERENT")
+        print("%-24s %6d / %6d lines: %s" % (name[:-5], len(a), len(b),
+                                             verdict))
+        different |= verdict == "DIFFERENT"
+    return 1 if different else 0
+
+
+if __name__ == "__main__":
+    if len(sys.argv) >= 4 and sys.argv[1] == "write":
+        write(sys.argv[2], sys.argv[3], "--compile" in sys.argv[4:])
+    elif len(sys.argv) == 4 and sys.argv[1] == "compare":
+        sys.exit(compare(sys.argv[2], sys.argv[3]))
+    else:
+        sys.exit(__doc__)
