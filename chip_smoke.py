@@ -580,7 +580,7 @@ def kernel_cases(sz):
     f32, i32 = np.float32, np.int32
     cases = {}
 
-    def paged(name, C, anc=None):
+    def paged(name, C, anc=None, H=H, Dh=Dh):
         # the pool goes in whole, as KVBlockPool stores it; two layers,
         # the second one read, so the kernel's layer index is exercised
         pool = (2, NB, bs, H, Dh)
@@ -599,7 +599,11 @@ def kernel_cases(sz):
 
     from paddle_tpu.serving.model import tree_topology
 
-    paged("paged_decode", 1)
+    # the decode step at heads of 128 lanes, as the served
+    # configurations have them: one grid step a row, the row's own
+    # pages by DMA. A narrower head takes the verify window's kernel,
+    # run below at the trainer's heads of 64.
+    paged("paged_decode", 1, H=max(1, H * Dh // 128), Dh=128)
     paged("spec_window", sz.spec_k + 1)
     paged("spec_window_tree", 1 + sz.spec_tree[0] * sz.spec_tree[1],
           tree_topology(*sz.spec_tree)[2].astype(f32))

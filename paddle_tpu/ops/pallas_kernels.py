@@ -19,9 +19,19 @@ paged_attention: decode-side attention that reads the serving
   ``kv[block_tables].reshape(...)`` gather the XLA path materializes
   disappears. It takes the pool whole and resolves the layer in the same
   index map, so nothing of a layer's size is sliced out for the call.
-  One kernel serves both the one-token decode window (C=1,
-  ``kernel 'paged_decode'``) and the speculative verify window (C=k+1,
-  ``kernel 'spec_window'``).
+  It is the kernel of the speculative verify window (C=k+1,
+  ``kernel 'spec_window'``) and, at heads narrower than a 128-lane
+  tile, of the one-token decode window (C=1). Its grid is rows x table
+  slots, taken whatever the rows hold.
+
+paged_decode_attention: the one-token decode window (``kernel
+  'paged_decode'``). At heads of whole lane tiles, as the served
+  configurations have them, ONE GRID STEP A ROW: the pool stays in HBM
+  and the row's own pages, up to its position's and no further, are
+  copied in runs by manual DMA (the copies of ``paged_chunk_attention``);
+  the row's heads are the query rows of one product over the run as it
+  lies, ``[tokens * H, Dh]``. An inactive row costs nothing. At a
+  narrower head it hands over to ``paged_attention``.
 
 int8_matmul: fused int8×int8→int32 matmul for the full-int8 quant path —
   the activation quantizes IN-KERNEL (per-tensor scale), the dot
@@ -79,6 +89,7 @@ from ..core import device as _device
 
 __all__ = ["flash_attention", "flash_attention_portable",
            "attention_reference", "paged_attention",
+           "paged_decode_attention",
            "paged_attention_reference", "paged_attention_tree",
            "paged_attention_tree_reference", "int8_matmul",
            "int8_matmul_reference", "gmm", "gmm_reference",
@@ -482,6 +493,27 @@ def paged_attention_reference(k_pool, v_pool, q, block_tables,
 CHUNK_PAGES_PER_STEP = 8
 
 
+def _run_copies(tables_ref, row, run, n_pages, layer, k_hbm, v_hbm, kbuf,
+                vbuf, sems, half, *, pages, block_size, start):
+    """Start, or wait for, the copies of run ``run`` of ``row``'s pages
+    (table slots ``run * pages`` on, up to the row's ``n_pages``) from
+    the pools in HBM into half ``half`` of the two VMEM buffers: one DMA
+    a page and pool, a run's copies on one semaphore a pool. Shared by
+    the kernels that walk a row's own pages."""
+    P, bs = pages, block_size
+
+    def page(p, carry):
+        blk = tables_ref[row, run * P + p]
+        dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+        for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            copy = pltpu.make_async_copy(
+                pool.at[layer, blk], buf.at[half, dst],
+                sems.at[which, half])
+            copy.start() if start else copy.wait()
+        return carry
+    jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+
+
 def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
                        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_scr, l_scr,
                        acc_scr, *, sm_scale, block_size, pages, n_heads):
@@ -522,16 +554,8 @@ def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     def copies(run, half, start):
-        def page(p, carry):
-            blk = tables_ref[t, run * P + p]
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                copy = pltpu.make_async_copy(
-                    pool.at[layer, blk], buf.at[half, dst],
-                    sems.at[which, half])
-                copy.start() if start else copy.wait()
-            return carry
-        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+        _run_copies(tables_ref, t, run, n_pages, layer, k_hbm, v_hbm, kbuf,
+                    vbuf, sems, half, pages=P, block_size=bs, start=start)
 
     def attend(run, half, nq):
         t_pos = run * span + jax.lax.broadcasted_iota(
@@ -688,6 +712,196 @@ def paged_chunk_attention_reference(k_pool, v_pool, q, block_tables,
         jnp.maximum(positions, 0)[:, None] + slots, layer=layer,
         sm_scale=sm_scale)
     return jnp.where((slots < lengths[:, None])[:, :, None, None], out, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention: one token a row over KVBlockPool pages, one grid
+# step a ROW; the pool stays in HBM, the row's own pages come by DMA and
+# all its heads go through the MXU together
+# ---------------------------------------------------------------------------
+
+DECODE_PAGES_PER_STEP = 8
+
+
+def _decode_attn_kernel(tables_ref, pos_ref, act_ref, layer_ref, q_ref,
+                        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, half_ref,
+                        m_scr, l_scr, acc_scr, *, sm_scale, block_size,
+                        pages):
+    """Grid (B,): one row a grid step, whatever the table's length. Both
+    pools stay in HBM; the row's pages ``0 .. pos // block_size`` are
+    copied into one of two VMEM buffers in runs of ``pages``, as
+    ``_chunk_attn_kernel`` copies a tile's (one DMA a page and pool, the
+    next run in flight while this one is attended). A row's last run
+    also starts the NEXT live row's first one, so a row does not open on
+    an empty pipe (0.3 ms of a 12.8 ms step at 16 rows); ``half_ref``
+    (SMEM) hands over which buffer it went to.
+
+    A row has one query token and ``H`` heads, and ``H`` query rows are
+    the sublane tile the MXU wants anyway: the run's keys are read as
+    they lie, ``[tokens * H, Dh]`` (token-major, no strided head reads),
+    every head's query meets every line in ONE product ``[H, tokens *
+    H]``, and the mask keeps the entries whose line belongs to the
+    query's own head (``col % H == row``) at a visible position. The
+    same mask makes ``p @ v`` over all lines the per-head context. An
+    inactive row computes nothing and comes out zero. Arithmetic as
+    ``_chunk_attn_kernel``: operands of both products rounded to
+    bfloat16, softmax statistics and accumulations fp32."""
+    b = pl.program_id(0)
+    last_row = pl.num_programs(0) - 1
+    bs, P = block_size, pages
+    H, Dh = q_ref.shape[1], q_ref.shape[2]
+    layer = layer_ref[0]
+
+    def pages_of(row):
+        return pos_ref[row] // bs + 1
+
+    n_pages = pages_of(b)
+    n_runs = (n_pages + P - 1) // P
+
+    @pl.when(b == 0)
+    def _zero():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def copies(row, run, half, start):
+        _run_copies(tables_ref, row, run, pages_of(row), layer, k_hbm,
+                    v_hbm, kbuf, vbuf, sems, half, pages=P, block_size=bs,
+                    start=start)
+
+    def attend(run, half):
+        n = P * bs * H
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, n), 1)
+        own = col % H == jax.lax.broadcasted_iota(jnp.int32, (H, n), 0)
+        k = kbuf[half].reshape(n, Dh).astype(jnp.bfloat16)
+        v = vbuf[half].reshape(n, Dh).astype(jnp.bfloat16)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # [H, n]
+        s = jnp.where(own & (run * P * bs + col // H <= pos_ref[b]), s,
+                      _NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(act_ref[b] > 0)
+    def _row():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # the row before, if it was live, has started this row's first
+        # run and left word of the buffer
+        opened = (b > 0) & (act_ref[jnp.maximum(b - 1, 0)] > 0)
+        half0 = jnp.where(opened, half_ref[0], 0)
+        nxt = jnp.minimum(b + 1, last_row)
+        follows = (b < last_row) & (act_ref[nxt] > 0)
+        pl.when(jnp.logical_not(opened))(lambda: copies(b, 0, half0, True))
+
+        def one_run(run, carry):
+            half = (half0 + run) % 2
+
+            @pl.when(run + 1 < n_runs)
+            def _next():
+                copies(b, run + 1, 1 - half, True)
+
+            @pl.when((run + 1 == n_runs) & follows)
+            def _next_row():
+                half_ref[0] = 1 - half
+                copies(nxt, 0, 1 - half, True)
+
+            copies(b, run, half, False)
+            attend(run, half)
+            return carry
+
+        jax.lax.fori_loop(0, n_runs, one_run, 0)
+        o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+
+
+def paged_decode_attention(k_pool, v_pool, q, block_tables, positions, *,
+                           layer, sm_scale=None, active=None,
+                           pages_per_step=DECODE_PAGES_PER_STEP):
+    """One-token decode attention over the paged KV cache; the contract
+    of :func:`paged_attention` at ``C == 1`` (the pools whole, ``layer``
+    picked inside, q ``[B, 1, H, Dh]``, positions ``[B, 1]``), plus
+    ``active`` (``[B]`` bool, all rows if None): an inactive row is
+    skipped and comes out zero.
+
+    Where the geometry allows (:func:`_chunk_qualify`: heads of whole
+    lane tiles, as the served configurations have them) the kernel takes
+    ONE GRID STEP A ROW and copies that row's pages, ``0 .. position //
+    block_size``, from the pool in HBM itself: a step costs what its
+    rows hold. ``paged_attention``'s BlockSpec grid takes ``B x Mb``
+    grid steps whatever they hold (2,048 a layer at 16 rows of 128
+    blocks: 7-12 ms of a decode step); it stays the kernel of narrower
+    heads, which Mosaic cannot slice out of HBM, and of the verify
+    windows. Operands of both products rounded to bfloat16 (a
+    default-precision fp32 dot on the chip), online softmax in fp32:
+    token-identical to the gathered reference, not bitwise."""
+    B, C, H, Dh = q.shape
+    bs = k_pool.shape[2]
+    if C != 1 or not _chunk_qualify(head_dim=Dh, block_size=bs)[0]:
+        return paged_attention(k_pool, v_pool, q, block_tables, positions,
+                               layer=layer, sm_scale=sm_scale)
+    if sm_scale is None:
+        sm_scale = Dh ** -0.5
+    if active is None:
+        active = jnp.ones((B,), jnp.int32)
+    # under one jitted function with the layer a traced scalar, as
+    # `_chunk_call`: a step's 24 calls are traced and lowered once
+    return _decode_call(k_pool, v_pool, q[:, 0], block_tables,
+                        positions[:, 0], active,
+                        jnp.asarray(layer, jnp.int32),
+                        sm_scale=float(sm_scale),
+                        pages=int(min(pages_per_step,
+                                      block_tables.shape[1])),
+                        interpret=_device.pallas_interpret())[:, None]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "pages", "interpret"))
+def _decode_call(k_pool, v_pool, q, block_tables, positions, active, layer,
+                 *, sm_scale, pages, interpret):
+    B, H, Dh = q.shape
+    bs = k_pool.shape[2]
+
+    def row(b, *_):
+        return (b, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    return pl.pallas_call(
+        functools.partial(_decode_attn_kernel, sm_scale=sm_scale,
+                          block_size=bs, pages=pages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, Dh), row), hbm, hbm],
+            out_specs=pl.BlockSpec((1, H, Dh), row),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, H, Dh), k_pool.dtype),
+                pltpu.VMEM((2, pages * bs, H, Dh), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, 128), jnp.float32),
+                pltpu.VMEM((H, Dh), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(block_tables.astype(jnp.int32),
+      jnp.maximum(positions, 0).astype(jnp.int32),
+      active.astype(jnp.int32), layer.reshape(1),
+      q.astype(jnp.bfloat16), k_pool, v_pool)
 
 
 # ---------------------------------------------------------------------------
@@ -1192,7 +1406,14 @@ def _flash_qualify(T=None, Tk=None, head_dim=None, causal=False):
 
 
 def _paged_qualify(head_dim=None, block_size=None, window=None):
-    """One-row pages stay on the lax path. While a block was a page
+    """The BlockSpec kernels over the paged cache (``paged_attention``,
+    the tree window), and ``paged_decode``: that one takes every head
+    width this admits, because ``paged_decode_attention`` walks pages
+    where ``_chunk_qualify`` holds and is ``paged_attention`` where it
+    does not; a narrow head is a reason to pick the other kernel, not to
+    leave for the lax path.
+
+    One-row pages stay on the lax path. While a block was a page
     with the heads folded into the lanes, Mosaic refused them; the
     stored ``[bs, H, Dh]`` page of PR 25 compiles at ``block_size`` 1
     too, but has never run on the chip, and a grid step per cached
@@ -1253,10 +1474,14 @@ def _register_all():
         doc="blocked online-softmax attention ([B,H,T,D]); default: on "
             "everywhere (interpret off-TPU, its historical dispatch)")
     register_kernel(
-        "paged_decode", paged_attention, paged_attention_reference,
+        "paged_decode", paged_decode_attention, paged_attention_reference,
         qualify=_paged_qualify, default_on=_device.on_tpu,
-        doc="one-token decode attention reading KVBlockPool pages "
-            "through the block table in-kernel; default: TPU only")
+        doc="one-token decode attention over KVBlockPool pages: at "
+            "heads of whole 128-lane tiles one grid step a row, the "
+            "row's own pages copied from the pool in HBM by manual DMA "
+            "and all heads in one product; at narrower heads the "
+            "BlockSpec grid over every table slot (paged_attention); "
+            "default: TPU only")
     register_kernel(
         "spec_window", paged_attention, paged_attention_reference,
         qualify=_paged_qualify, default_on=_device.on_tpu,

@@ -712,14 +712,23 @@ class _ModelWorker:
                 n_prefill + n_decode,
                 self.max_batch * (self.prefill_chunk if mixed else 1),
                 traces0)
+            # the context the step attends: each active row's position
+            # after it (the sum of their lengths)
+            rec["cached_tokens"] = int(
+                ((sched.positions + sched.chunk_lens)
+                 * sched.active).sum())
             if mixed:
                 rec["rows_computed"] = self._chunk_rows
+            else:
+                # the pages a decode kernel that walks each row's own
+                # pages copies, beside the grid steps of one that visits
+                # every table slot of every row
+                rec["pages_walked"] = int(
+                    (sched.positions[sched.active]
+                     // self.pool.block_size + 1).sum())
+                rec["pages_grid"] = (self.max_batch
+                                     * sched.max_blocks_per_seq)
             if counters:
-                # the context the step attends: each active row's
-                # position after it (the sum of their lengths)
-                rec["cached_tokens"] = int(
-                    ((sched.positions + sched.chunk_lens)
-                     * sched.active).sum())
                 rec["_counters"] = counters[0]   # read when consumed
             if _tracing.enabled():
                 # request-scoped view of the same step: one window event

@@ -905,15 +905,18 @@ class GenerationModel:
             0)
 
         # one dispatch decision per forward (trace time), shared by all
-        # layers: the paged flash-decode kernel reads the pool pages
-        # through the block table in-kernel, so the contiguous
-        # kv[block_tables] gather below never materializes
+        # layers: the decode kernel reads the pool's pages through the
+        # block table itself, so the contiguous kv[block_tables] gather
+        # below never materializes. Which kernel that is follows the
+        # head's width (`paged_decode_attention`): at heads of whole
+        # lane tiles one grid step a row that copies the row's own
+        # pages, else the BlockSpec grid over every table slot.
         from ..ops.kernel_registry import choose as _choose_kernel
 
         use_paged = _choose_kernel("paged_decode", head_dim=Dh,
                                    block_size=bs)
         if use_paged:
-            from ..ops.pallas_kernels import paged_attention
+            from ..ops import pallas_kernels as _pk
 
         # context-position validity: t <= position (the current token's
         # k/v are written before the gather, so self-attention sees them)
@@ -923,14 +926,14 @@ class GenerationModel:
         def attend(i, q, kv_k, kv_v):
             if use_paged:
                 # The kernel gets the pool WHOLE and finds layer and page
-                # in its index map. `kv_k[i]` here would make XLA copy
-                # the layer's pages out of the pool for the custom call
-                # and lay them out again, which cost more than the rest
-                # of the step (docs/SERVING.md, "No kernel step slices
-                # the pool").
-                ctx = paged_attention(
+                # itself. `kv_k[i]` here would make XLA copy the layer's
+                # pages out of the pool for the custom call and lay them
+                # out again, which cost more than the rest of the step
+                # (docs/SERVING.md, "No kernel step slices the pool").
+                ctx = _pk.paged_decode_attention(
                     kv_k, kv_v, q[:, None], block_tables,
-                    positions[:, None], layer=i, sm_scale=sm_scale)
+                    positions[:, None], layer=i, sm_scale=sm_scale,
+                    active=active)
                 return ctx[:, 0].reshape(B, -1)
             # lax path: the layer's pages, then the paged gather
             # [B, Mb, bs, H, Dh] -> [B, max_ctx, H, Dh]. XLA fuses this

@@ -155,6 +155,38 @@ def test_one_row_page_is_disqualified_with_a_reason(v5e_chip):
     _compile(lambda *a: spec.pallas(*a, layer=0), specs, v5e_chip)
 
 
+def test_decode_page_walk_compiles_at_the_served_geometry(v5e_chip):
+    """The decode step's attention call as `xglm-1.7b-serve` makes it:
+    24 layers of 896 blocks of 16 tokens, 16 heads of 128, 16 rows of
+    128 blocks, the layer a traced scalar. At that head width
+    `paged_decode` is the kernel that walks each row's own pages."""
+    import json
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__),
+                           "perfbench/configs/xglm-1.7b-serve.json")) as f:
+        c = json.load(f)
+    e = c["engine"]
+    H, L, bs, B = (c["attention_heads"], c["num_layers"], e["block_size"],
+                   e["max_batch"])
+    Dh, Mb = c["d_model"] // H, e["max_seq_len"] // e["block_size"]
+    assert (H, Dh, L, bs, B, Mb) == (16, 128, 24, 16, 16, 128)
+    spec = registered_kernels()["paged_decode"]
+    assert spec.qualify(head_dim=Dh, block_size=bs)[0]
+    f32, i32 = jnp.float32, jnp.int32
+    pool = ((L, e["num_blocks"] + 1, bs, H, Dh), f32)
+    specs = [pool, pool, ((B, 1, H, Dh), f32), ((B, Mb), i32),
+             ((B, 1), i32), ((), i32), ((B,), jnp.bool_)]
+    compiled = _compile(
+        lambda k, v, q, tables, pos, layer, active: spec.pallas(
+            k, v, q, tables, pos, layer=layer, active=active),
+        specs, v5e_chip)
+    hlo = compiled.as_text()
+    assert "paged_decode_attention" in hlo
+    assert 'kernel_name = "paged_attention"' not in hlo
+    # nothing of the pool's size is made for the call
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 # ---------------------------------------------------------------------------
 # the serving steps read the KV pool in place (PR 25)
 # ---------------------------------------------------------------------------
@@ -296,6 +328,9 @@ def test_serving_step_reads_the_kv_pool_in_place(kind, pool_model,
     assert compiled.memory_analysis().temp_size_in_bytes < layer * 4
     if kind.startswith("chunk"):
         assert "paged_chunk_attention" in hlo
+    if kind == "decode":
+        # heads of 128: the kernel that walks each row's own pages
+        assert "paged_decode_attention" in hlo
     if kind == "chunk_tokens":
         g = POOL_STEP
         scores = (g["batch"] * g["chunk"] * g["n_heads"]

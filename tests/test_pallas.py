@@ -226,33 +226,146 @@ def test_spec_window_matches_gathered_reference():
                                atol=1e-5, rtol=1e-5)
 
 
-def test_paged_decode_post_truncate_tables():
-    """Block tables after the speculative KV rollback
-    (KVBlockPool.truncate_owner): dropped tail blocks leave the table,
-    the padded tail reverts to the null block, and attention over the
-    kept prefix matches the reference."""
+def _truncated_tables(Mb, **page):
+    """One row's block table after the speculative KV rollback
+    (KVBlockPool.truncate_owner): three blocks held, two dropped again,
+    the padded tail back on the null block."""
     from paddle_tpu.serving.kv_cache import KVBlockPool
 
-    pool = KVBlockPool(n_layers=1, n_heads=2, head_dim=16, block_size=4,
-                       num_blocks=8)
+    pool = KVBlockPool(n_layers=1, num_blocks=8, **page)
     assert pool.reserve("s", 3)
     for _ in range(3):
         pool.alloc_block("s")
     dropped = pool.truncate_owner("s", 1)
     table_ids = pool.block_table("s")
     assert len(table_ids) == 1 and len(dropped) == 2
-    Mb = 4
     padded = np.full((1, Mb), KVBlockPool.NULL_BLOCK, np.int32)
     padded[0, :len(table_ids)] = table_ids
+    return padded
+
+
+def test_paged_decode_post_truncate_tables():
+    """Block tables after the speculative KV rollback: dropped tail
+    blocks leave the table, the padded tail reverts to the null block,
+    and attention over the kept prefix matches the reference."""
     rng, k_pool, v_pool = _paged_setup(seed=5)
     q = jnp.asarray(rng.randn(1, 1, 2, 16).astype(np.float32))
     pos = jnp.asarray(np.array([[3]], np.int32))  # last kept position
-    tables = jnp.asarray(padded)
+    tables = jnp.asarray(_truncated_tables(
+        4, n_heads=2, head_dim=16, block_size=4))
     got = paged_attention(k_pool, v_pool, q, tables, pos, layer=LAYER)
     want = paged_attention_reference(k_pool, v_pool, q, tables, pos,
                                      layer=LAYER)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention: one grid step a row, the row's own pages by DMA
+# ---------------------------------------------------------------------------
+
+WALK = dict(bs=16, Mb=128, H=8, Dh=128)     # heads of whole lane tiles
+
+
+def _walk_tables(rng, positions, NB, Mb=WALK["Mb"], bs=WALK["bs"]):
+    """Distinct physical pages up to each row's position, the null page
+    past it (what the scheduler hands a step)."""
+    free = list(rng.permutation(np.arange(1, NB + 1)))
+    tables = np.zeros((len(positions), Mb), np.int32)
+    for b, pos in enumerate(positions):
+        for j in range(pos // bs + 1):
+            tables[b, j] = free.pop()
+    return tables
+
+
+@pytest.mark.parametrize("positions,active,tables_of", [
+    # a context of one token; of exactly one page; one short of a page
+    # boundary and one past it; eight pages (one run) less one, exact,
+    # and one token into the next run
+    ([0, 15, 14, 16], None, None),
+    ([126, 127, 128], None, None),
+    # the whole 2,048-token table beside a short row
+    ([2047, 40], None, None),
+    # inactive rows beside live ones: first, between, last (their
+    # positions and tables are whatever the scheduler left there)
+    ([300, 5, 200, 130, 9], [False, True, False, True, False], None),
+    ([70, 0, 0, 35], [True, False, False, True], None),
+    # tables after truncate_owner: the last kept position
+    ([15], None, lambda _positions: _truncated_tables(
+        WALK["Mb"], n_heads=WALK["H"], head_dim=WALK["Dh"],
+        block_size=WALK["bs"])),
+], ids=["page-edges", "run-edges", "full-table", "inactive-ends",
+        "inactive-between", "post-truncate"])
+@pytest.mark.parametrize("pages_per_step", [3, 8])
+def test_paged_decode_walks_each_rows_own_pages(positions, active,
+                                                tables_of, pages_per_step):
+    """The decode kernel of heads of whole lane tiles: one grid step a
+    row, the row's pages copied from the pool in runs (of 8, and of 3 so
+    that rows of odd and even run counts hand the next row either
+    buffer), every head in one product. Against the gathered reference,
+    operands rounded to bfloat16 as a default-precision dot on the chip
+    rounds them (within 8 half-ulps of the reference's largest value);
+    an inactive row comes out zero and disturbs no live one."""
+    from paddle_tpu.ops.pallas_kernels import paged_decode_attention
+
+    g = WALK
+    NB = 160
+    rng, k_pool, v_pool = _paged_setup(seed=11, NB=NB, bs=g["bs"],
+                                       H=g["H"], Dh=g["Dh"])
+    B = len(positions)
+    tables = (tables_of(positions) if tables_of
+              else _walk_tables(rng, positions, NB))
+    q = jnp.asarray(rng.randn(B, 1, g["H"], g["Dh"]).astype(np.float32))
+    pos = np.array(positions, np.int32)[:, None]
+    live = np.ones(B, bool) if active is None else np.array(active)
+    got = np.asarray(paged_decode_attention(
+        k_pool, v_pool, q, tables, pos, layer=LAYER,
+        active=None if active is None else live,
+        pages_per_step=pages_per_step))
+    want = np.asarray(paged_attention_reference(
+        k_pool, v_pool, q, tables, pos, layer=LAYER))
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got[live], want[live],
+        atol=8 * 2.0 ** -9 * np.abs(want).max(), rtol=0)
+    assert (got[~live] == 0).all()
+
+
+def test_paged_decode_picks_its_kernel_by_head_width(monkeypatch):
+    """`paged_decode` walks a row's own pages only at heads of whole
+    128-lane tiles (pages are copied as the pool stores them); a head
+    of 64, a served xglm-564M, stays on the BlockSpec kernel (not the
+    lax path: `qualify` still holds), fp32 to rounding. So does a
+    window of more than one token."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    calls = []
+    real = pk._paged_call
+    monkeypatch.setattr(pk, "_paged_call", lambda *a: (
+        calls.append(a[2].shape), real(*a))[1])
+    spec = kreg.get_kernel("paged_decode")
+    assert spec.pallas is pk.paged_decode_attention
+    assert spec.qualify(head_dim=64, block_size=16)[0]
+    assert spec.qualify(head_dim=128, block_size=16)[0]
+    rng, k_pool, v_pool = _paged_setup(seed=2, NB=8, bs=4, H=2, Dh=64)
+    q = jnp.asarray(rng.randn(2, 1, 2, 64).astype(np.float32))
+    tables = np.array([[5, 2, 7, 3], [1, 4, 0, 0]], np.int32)
+    pos = np.array([[15], [6]], np.int32)
+    got = spec.pallas(k_pool, v_pool, q, tables, pos, layer=LAYER)
+    assert calls == [(2, 1, 2, 64)]
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(spec.fallback(
+            k_pool, v_pool, q, tables, pos, layer=LAYER)),
+        atol=1e-5, rtol=1e-5)
+    # heads of 128: the page walk, and the grid kernel is not called
+    rng, k_pool, v_pool = _paged_setup(seed=2, NB=8, bs=4, H=2, Dh=128)
+    q = jnp.asarray(rng.randn(2, 1, 2, 128).astype(np.float32))
+    spec.pallas(k_pool, v_pool, q, tables, pos, layer=LAYER)
+    assert len(calls) == 1
+    q3 = jnp.asarray(rng.randn(2, 3, 2, 128).astype(np.float32))
+    spec.pallas(k_pool, v_pool, q3, tables, pos + np.arange(3)[None, :]
+                - 2, layer=LAYER)
+    assert calls[1:] == [(2, 3, 2, 128)]
 
 
 # ---------------------------------------------------------------------------

@@ -296,6 +296,59 @@ def test_a_slow_stream_callback_is_host_time_not_wait(metrics_on,
                if r is not tick and not r["cold"])
 
 
+def latent_toy_model():
+    from paddle_tpu.serving.latent_moe import LatentMoEBlock
+
+    return GenerationModel.random(GenerationConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+        max_seq_len=64, block=LatentMoEBlock(
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            kv_lora_rank=128, n_routed_experts=8, experts_per_token=2,
+            n_shared_experts=2, moe_d_ff=32)), seed=7)
+
+
+@pytest.mark.parametrize("make_model", [toy_model, latent_toy_model],
+                         ids=["xglm", "latent"])
+def test_decode_records_count_the_pages_their_rows_hold(metrics_on,
+                                                        make_model):
+    """Every record carries `cached_tokens`; a decode record of either
+    block also the pages a kernel that walks each row's own pages
+    copies (`pages_walked`) beside the grid steps of one that visits
+    every table slot (`pages_grid`): host integers from the scheduler's
+    arrays, the bytes and the ceiling a decode kernel is judged by."""
+    lens = (5, 11, 17)
+    serve(make_model(), lens=lens)
+    recs = metrics_on.samples("serving/step").records()
+    decode = [r for r in recs if r["kind"] == "decode"]
+    assert decode and len(decode) < len(recs)
+    for r in recs:
+        # each live row holds at least its step's token, and no more
+        # than a whole request
+        assert r["slots_used"] <= r["cached_tokens"] \
+            <= r["rows"] * (max(lens) + NEW_TOKENS)
+        assert ("pages_walked" in r) == ("pages_grid" in r) \
+            == (r["kind"] == "decode")
+    for r in decode:
+        # max_batch 4 x 16 blocks of 4 tokens a row
+        assert r["pages_grid"] == 4 * 16
+        # a row at position p holds p // 4 + 1 pages: at least
+        # tokens / 4 over the rows, under one more a row
+        assert r["cached_tokens"] / 4 <= r["pages_walked"] \
+            <= r["cached_tokens"] / 4 + r["rows"]
+        assert r["rows"] <= r["pages_walked"] <= r["pages_grid"]
+    # one request alone: the pages of ITS position, step by step
+    serve(make_model(), lens=(9,))
+    alone = [r for r in
+             metrics_on.samples("serving/step").records()[len(recs):]
+             if r["kind"] == "decode"]
+    # the prompt's last chunk made the first token, so the row of
+    # decode step k sits at position 9 + k
+    assert [r["pages_walked"] for r in alone] \
+        == [(9 + k) // 4 + 1 for k in range(len(alone))]
+    assert [r["cached_tokens"] for r in alone] \
+        == [9 + k + 1 for k in range(len(alone))]
+
+
 @pytest.mark.parametrize("engine_kw,rows", [
     # the default budget of four chunks: max_batch + budget token rows
     (dict(max_batch=8), 8 + 4 * 8),
@@ -437,7 +490,8 @@ def test_step_names_and_scopes_reach_the_lowered_hlo():
 
 
 @pytest.mark.parametrize("kernel,name", [
-    ("paged_decode", "paged_attention"),
+    ("paged_decode", "paged_decode_attention"),
+    ("spec_window", "paged_attention"),
     ("spec_window_tree", "paged_attention_tree"),
     ("chunk_window", "paged_chunk_attention"),
     ("flash_attention", "flash_attention"),
