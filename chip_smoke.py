@@ -374,7 +374,7 @@ def step_logits(model, sz, kind):
     cfg = model.config
     B, bs = sz.serve_batch, sz.block_size
     Mb = sz.serve_ctx // bs
-    C = {"decode": 1, "spec": sz.spec_k + 1,
+    C = {"decode": 1, "chunk": sz.prefill_chunk, "spec": sz.spec_k + 1,
          "tree": 1 + sz.spec_tree[0] * sz.spec_tree[1]}[kind]
     rng = np.random.RandomState(11)
     pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim, bs, B * Mb)
@@ -388,14 +388,50 @@ def step_logits(model, sz, kind):
         out = step(model.weights, kv[0], kv[1], toks[:, 0], on,
                    np.zeros(B, np.int32), pos[:, 0], tables, on)
     else:
-        step = (model.make_spec_step(B, Mb, C, return_logits=True)
-                if kind == "spec" else
-                model.make_spec_tree_step(B, Mb, *sz.spec_tree,
-                                          return_logits=True))
+        if kind == "tree":
+            step = model.make_spec_tree_step(B, Mb, *sz.spec_tree,
+                                             return_logits=True)
+        else:
+            make = (model.make_prefill_step if kind == "chunk"
+                    else model.make_spec_step)
+            step = make(B, Mb, C, return_logits=True)
         out = step(model.weights, kv[0], kv[1], toks, on,
                    np.zeros(B, np.int32), pos[:, 0],
                    np.full(B, C, np.int32), tables, on)
     return np.asarray(out[3])
+
+
+def store_parity(sz):
+    """One decode step and one chunk step from both weight stores: the
+    one this device gets (`serving.model.dot_operand_dtype`: on the
+    chip the dot operands in bfloat16, rounded once when the model is
+    built) and the float32 one, built the way the rule itself offers
+    (under a raised default matmul precision the store keeps float32)
+    and stepped at the default precision like the other, so that its
+    dots round the same operands on every step. Same arithmetic: the
+    tokens must be identical, and the logits apart by accumulation
+    order at most."""
+    import jax
+
+    from paddle_tpu.serving import GenerationModel
+
+    served = serving_model(sz)
+    with jax.default_matmul_precision("highest"):
+        wide = GenerationModel.random(served.config, seed=7)
+    out = {"served_dot_operand_dtype": str(served.weights["lm_head"].dtype),
+           "float32_store_dtype": str(wide.weights["lm_head"].dtype)}
+    assert out["float32_store_dtype"] == "float32", out
+    for kind in ("decode", "chunk"):
+        got = step_logits(served, sz, kind)
+        want = step_logits(wide, sz, kind)
+        assert got.shape == want.shape and np.isfinite(got).all(), kind
+        same = bool((got.argmax(-1) == want.argmax(-1)).all())
+        out[kind] = {"tokens_identical": same,
+                     "max_abs_dlogit": float(np.abs(got - want).max()),
+                     "logit_std": float(want.std()),
+                     "logit_max_abs": float(np.abs(want).max())}
+        assert same, (kind, out)
+    return out
 
 
 def leg_serve(sz, rehearsal):
@@ -416,6 +452,7 @@ def leg_serve(sz, rehearsal):
         tree = serve_all(model, sz, spec_tree=sz.spec_tree)
         kernel_logits = {k: step_logits(model, sz, k)
                          for k in ("decode", "spec", "tree")}
+        parity = store_parity(sz)
         dispatched = {n: counter("kernels/kernel:" + n) - k0[n]
                       for n in names}
         fallbacks = counter("kernels/fallbacks") - fall0
@@ -448,7 +485,8 @@ def leg_serve(sz, rehearsal):
             "token_agreement_linear_spec_vs_plain": agreement(linear, plain),
             "token_agreement_tree_spec_vs_plain": agreement(tree, plain),
             "logits_rel_err_vs_lax": logit_err,
-            "logits_rel_bound": LOGITS_REL_BOUND}
+            "logits_rel_bound": LOGITS_REL_BOUND,
+            "weight_store_parity": parity}
 
 
 # ---------------------------------------------------------------------------

@@ -193,21 +193,29 @@ def weight_store_bytes(weights):
     int8-stored entries, ``int8_bytes`` they occupy (int8 payload plus
     their fp32 ``@qscale`` companions) and ``fp32_bytes`` the same
     entries would occupy dequantized — the serving-stats receipt that a
-    model really is running off the int8 store. Shapes/dtypes only; no
-    device transfer."""
+    model really is running off the int8 store. ``bytes`` is what the
+    whole dict holds as stored and ``by_dtype`` the same by storage
+    dtype (a bfloat16 dot-operand store reads half its float32 size
+    there). Shapes/dtypes only; no device transfer."""
     n_int8 = 0
     int8_bytes = 0
     fp32_bytes = 0
+    by_dtype = {}
     for key, v in weights.items():
-        size = int(getattr(v, "size", np.asarray(v).size))
-        if str(getattr(v, "dtype", "")) == "int8":
+        v = v if hasattr(v, "dtype") else np.asarray(v)
+        size = int(v.size)
+        name = str(v.dtype)
+        by_dtype[name] = by_dtype.get(name, 0) \
+            + size * np.dtype(v.dtype).itemsize
+        if name == "int8":
             n_int8 += 1
             int8_bytes += size
             fp32_bytes += size * 4
         elif key.endswith("@qscale"):
             int8_bytes += size * 4
     return {"n_int8": n_int8, "int8_bytes": int8_bytes,
-            "fp32_bytes": fp32_bytes}
+            "fp32_bytes": fp32_bytes, "bytes": sum(by_dtype.values()),
+            "by_dtype": by_dtype}
 
 
 def quantize_to_int8(w, scale_broadcast, qmax=_QMAX):

@@ -82,7 +82,7 @@ from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
 from ..quant import weight_store_bytes as _weight_store_bytes
 from .kv_cache import KVBlockPool, blocks_needed
-from .model import GenerationModel, load_generation_artifact
+from .model import GenerationModel, load_generation_artifact, store_leaf
 from .scheduler import (AdmissionError, GenerationRequest, RequestQueue,
                         StepScheduler)
 
@@ -400,15 +400,18 @@ class _ModelWorker:
         the prefix-cache flush land in ONE cv critical section, so no
         step can read swapped weights against a stale prefix index and
         no token is ever computed by a half-installed weight set."""
-        import jax.numpy as jnp
-
         with self._cv:
             if self._pending_swap is None:
                 return
             weights, version, done, result = self._pending_swap
             self._pending_swap = None
+            # each leaf in the dtype it is SERVED in (a float32 source
+            # onto a bfloat16 leaf is rounded as the store rounds):
+            # the compiled steps are keyed on their arguments' dtypes,
+            # so any other would retrace and compile in the serving path
             for wname in self._weight_names:
-                self.scope.set(wname, jnp.asarray(weights[wname]))
+                self.scope.set(wname, store_leaf(
+                    weights[wname], self.scope.get(wname).dtype))
             flushed = self.pool.flush_prefix_cache()
             self.weight_version = version
             result["applied"] = True
@@ -717,6 +720,10 @@ class _ModelWorker:
             rec["cached_tokens"] = int(
                 ((sched.positions + sched.chunk_lens)
                  * sched.active).sum())
+            # the step's weight stream as stored: its dot operands'
+            # bytes and element count (the model's, known at build)
+            rec["weight_bytes"] = self.model.dot_operand_bytes
+            rec["weight_params"] = self.model.dot_operand_params
             if mixed:
                 rec["rows_computed"] = self._chunk_rows
             else:
@@ -966,7 +973,8 @@ def _resolve_swap_weights(source, worker):
     keyed, so a swap can never change geometry, only values. Artifact
     dirs are digest-verified on load (a torn export never serves); an
     fp32 source is re-quantized when the worker serves the int8
-    store."""
+    store. The values' dtypes are the source's: `_apply_swap` casts
+    each to the dtype its leaf is served in as it installs it."""
     if isinstance(source, str):
         source = load_generation_artifact(source, name=worker.name)
     if isinstance(source, GenerationModel):

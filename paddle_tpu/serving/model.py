@@ -276,20 +276,90 @@ class GenerationConfig:
 
 
 # weight-name layout (one flat dict; per-layer names carry an l<i>/
-# prefix). Everything is fp32 on the serving side.
+# prefix). `leaf_shapes` states each leaf's shape and storage dtype.
 _LAYER_KEYS = ("ln1_scale", "ln1_bias", "wqkv", "bqkv", "wproj", "bproj",
                "ln2_scale", "ln2_bias", "wff1", "bff1", "wff2", "bff2")
+# a layer's leaves that are only ever the weight operand of a
+# default-precision dot (`_layers`: `a @ w`), as `lm_head` is: stored as
+# that dot rounds them. With the gathered embedding they are also what
+# `quantized()` puts on the int8 grid.
+_DOT_OPERAND_KEYS = ("wqkv", "wproj", "wff1", "wff2")
 
 
-def weight_names(config):
+def default_dot_rounds_to_bf16():
+    """Whether a default-precision float32 dot, on the device this is
+    traced (or built) for, is one bf16 MXU pass with float32
+    accumulation: the TPU, unless the user raised
+    ``jax_default_matmul_precision``. Elsewhere the backend multiplies
+    float32 as it is. What the platform fixes, read in one place."""
+    import jax
+
+    from ..core import device
+
+    asked = jax.config.jax_default_matmul_precision
+    try:
+        one_pass = asked is None or \
+            jax.lax.Precision(asked) == jax.lax.Precision.DEFAULT
+    except ValueError:       # a dot-algorithm preset: the user chose
+        one_pass = False
+    return one_pass and device.on_tpu()
+
+
+def dot_operand_dtype():
+    """The dtype a default-precision float32 dot hands the multiplier:
+    ``bfloat16`` where :func:`default_dot_rounds_to_bf16`, ``float32``
+    elsewhere. THE one place that decides the XGLM block's
+    weight-operand storage (docs/SERVING.md, "What the store holds and
+    why"): a weight kept in this dtype is the value the dot computed
+    with anyway, at half the bytes to stream."""
+    return "bfloat16" if default_dot_rounds_to_bf16() else "float32"
+
+
+def leaf_shapes(config):
+    """{weight name: (shape, dtype name)}: the serving layout, and what
+    ``GenerationModel`` stores each leaf as. XGLM's block: the dot
+    operands in :func:`dot_operand_dtype`, everything else float32 (the
+    embedding is gathered and scaled, never multiplied on the MXU;
+    LayerNorm gains and every bias are added in float32)."""
     if config.block is not None:
         from . import latent_moe
 
-        return latent_moe.weight_names(config)
-    names = ["embedding", "lm_head", "final_ln_scale", "final_ln_bias"]
+        return latent_moe.leaf_shapes(config)
+    D, F, V = config.d_model, config.d_ff, config.vocab_size
+    shape = {"wqkv": (D, 3 * D), "bqkv": (3 * D,), "wproj": (D, D),
+             "wff1": (D, F), "bff1": (F,), "wff2": (F, D)}
+    w, f32 = dot_operand_dtype(), "float32"
+    out = {"embedding": ((V, D), f32), "lm_head": ((D, V), w),
+           "final_ln_scale": ((D,), f32), "final_ln_bias": ((D,), f32)}
     for i in range(config.n_layers):
-        names.extend("l%d/%s" % (i, k) for k in _LAYER_KEYS)
-    return names
+        for k in _LAYER_KEYS:
+            out["l%d/%s" % (i, k)] = (
+                shape.get(k, (D,)), w if k in _DOT_OPERAND_KEYS else f32)
+    return out
+
+
+def dot_operand_names(config):
+    """The leaves a step multiplies on the MXU (its weight stream), for
+    either block: every matrix but the gathered embedding."""
+    return [n for n, (shape, _d) in leaf_shapes(config).items()
+            if len(shape) > 1 and n != "embedding"]
+
+
+def weight_names(config):
+    return list(leaf_shapes(config))
+
+
+def store_leaf(value, dtype):
+    """``value`` on the device as the store keeps it: cast to ``dtype``
+    THERE (XLA's convert rounds to nearest even), so neither a second
+    host copy nor, leaf by leaf, a second copy of the model is held. An
+    int8 payload (the weight-only store) stays what it is."""
+    import jax.numpy as jnp
+
+    value = jnp.asarray(value)
+    if "int8" in (str(value.dtype), str(dtype)):
+        return value
+    return value.astype(dtype)
 
 
 def _position_encoding_table(config):
@@ -564,6 +634,8 @@ def save_generation_artifact(dirname, config, weights):
                        ".ptpu_tmp_" + os.path.basename(dirname))
     shutil.rmtree(tmp, ignore_errors=True)
     os.makedirs(tmp)
+    # float32 on disk whatever the store keeps (a bfloat16 leaf widens
+    # exactly, and the loader's store rounds it back to the same value)
     weights = {k: np.asarray(v, np.float32) for k, v in weights.items()}
     np.savez(os.path.join(tmp, GENERATION_WEIGHTS), **weights)
     with open(os.path.join(tmp, GENERATION_META), "w") as f:
@@ -696,10 +768,16 @@ def load_generation_artifact(dirname, name=None, quantize=None,
 class GenerationModel:
     """Config + weights + the jitted continuous-batching decode step.
 
+    ``weights`` holds every leaf on the device in the dtype
+    ``leaf_shapes(config)`` states: for XGLM's block the dot operands
+    as a default-precision dot rounds them (:func:`dot_operand_dtype`:
+    bfloat16 on the TPU, float32 elsewhere), everything else float32.
+
     ``quantized()`` derives the weight-only-int8 variant
-    (docs/QUANTIZATION.md): every 2-D matmul weight (embedding, qkv,
-    proj, ffn, lm head) is STORED int8 with a per-output-channel fp32
-    scale riding in the same weights dict under ``<name>@qscale``, and
+    (docs/QUANTIZATION.md): every matmul weight and the embedding
+    (chosen by role: qkv, proj, ffn, lm head, embedding) is STORED int8
+    with a per-output-channel fp32 scale riding in the same weights
+    dict under ``<name>@qscale``, and
     the decode step dequantizes on use — the compute stays fp32, the
     HBM-resident weight store (what a memory-bandwidth-bound decode
     step actually streams) shrinks ~4x. Decoding a quantized model is
@@ -709,30 +787,29 @@ class GenerationModel:
     def __init__(self, config, weights, name="model"):
         self.config = config
         self.name = name
-        missing = [n for n in weight_names(config) if n not in weights]
+        leaves = leaf_shapes(config)
+        missing = [n for n in leaves if n not in weights]
         if missing:
             raise ValueError("missing weights: %s" % missing[:4])
-        import jax.numpy as jnp
-
-        if config.block is not None:
-            # the block states each leaf's storage dtype; a leaf that is
-            # already a device array of it is taken as it is (no pass
-            # through the host: the weights may be most of the chip)
-            from . import latent_moe
-
-            self.weights = {
-                k: jnp.asarray(weights[k], dtype)
-                for k, (_s, dtype) in
-                latent_moe.leaf_shapes(config).items()}
-        else:
-            # int8 entries (the weight-only-quantized store) keep their
-            # dtype; everything else normalizes to fp32 as before
-            self.weights = {
-                k: jnp.asarray(v if np.asarray(v).dtype == np.int8
-                               else np.asarray(v, np.float32))
-                for k, v in weights.items()}
+        # every leaf in the dtype its block states (`leaf_shapes`), cast
+        # on the device a leaf at a time: one that is already a device
+        # array of it is taken as it is (no pass through the host: the
+        # weights may be most of the chip). An int8 payload (the
+        # weight-only store) stays int8 beside its float32 `@qscale`.
+        self.weights = {}
+        for k, (_shape, dtype) in leaves.items():
+            self.weights[k] = store_leaf(weights[k], dtype)
+            scale = weights.get(k + "@qscale")
+            if scale is not None:
+                self.weights[k + "@qscale"] = store_leaf(scale, "float32")
         self.weight_only_int8 = any(
             str(v.dtype) == "int8" for v in self.weights.values())
+        # what a step streams through the MXU, as stored (the step log's
+        # `weight_bytes` / `weight_params`)
+        operands = [self.weights[n] for n in dot_operand_names(config)]
+        self.dot_operand_params = sum(int(v.size) for v in operands)
+        self.dot_operand_bytes = sum(
+            int(v.size) * v.dtype.itemsize for v in operands)
         # python-trace counter: the body below only executes while jax
         # traces, so tests can pin "no retrace across join/retire"
         self.trace_count = 0
@@ -789,43 +866,53 @@ class GenerationModel:
                 "(ROADMAP Queue 2a)" % self.config.block.kind)
         if self.weight_only_int8:
             return self
+        # chosen by role, not by what the store keeps them as: the dot
+        # operands (float32 or bfloat16, `dot_operand_dtype`) and the
+        # gathered embedding
+        on_grid = set(dot_operand_names(self.config)) | {"embedding"}
         qw = {}
         n_q = saved = fp32 = 0
         for k, v in self.weights.items():
-            w = np.asarray(v)
-            if w.ndim == 2 and w.dtype == np.float32:
-                # the shared symmetric int8 grid (paddle_tpu.quant),
-                # per output column (axis 1 of the [in, out] layout;
-                # per d_model column for the [V, D] embedding)
+            if k in on_grid:
+                # the shared symmetric int8 grid (paddle_tpu.quant) over
+                # the STORED value, per output column (axis 1 of the
+                # [in, out] layout; per d_model column for the [V, D]
+                # embedding)
+                w = np.asarray(v, np.float32)
                 q, s = quantize_symmetric(w, channel_axis=1)
                 qw[k] = q
                 qw[k + "@qscale"] = (s / 127.0).astype(np.float32)
                 n_q += 1
-                saved += max(w.nbytes - q.nbytes - s.nbytes, 0)
+                saved += max(int(v.nbytes) - q.nbytes - s.nbytes, 0)
                 fp32 += w.nbytes
             else:
-                qw[k] = w
+                qw[k] = v
         record_weight_store(n_q, saved, fp32)
         return GenerationModel(self.config, qw,
                                name=name or self.name + ".int8")
 
     def dequantized_weights(self):
-        """fp32 weights dict with the int8 store multiplied back out —
-        the quantized model's numerics reference (a GenerationModel
-        built from these decodes token-identically to this one)."""
+        """fp32 weights dict on the host: the int8 store multiplied back
+        out, a bfloat16 leaf widened (exactly) — the model's numerics
+        reference (a GenerationModel built from these decodes
+        token-identically to this one)."""
         out = {}
         for k, v in self.weights.items():
             if k.endswith("@qscale"):
                 continue
-            w = np.asarray(v)
+            w = np.asarray(v, np.float32)
             s = self.weights.get(k + "@qscale")
-            out[k] = (w.astype(np.float32) * np.asarray(s)
-                      if s is not None else w)
+            out[k] = w * np.asarray(s) if s is not None else w
         return out
 
     def _w(self, jnp, weights, key):
-        """One weight in compute dtype: dequantize-on-use for the int8
-        store (XLA fuses the convert+scale into the consuming dot)."""
+        """One dot's weight operand: dequantize-on-use for the int8
+        store (XLA fuses the convert+scale into the consuming dot); a
+        float leaf as it is stored. A bfloat16 leaf (`leaf_shapes`)
+        goes into its dot as it is (`_layers`: ``dot``): the product is
+        the float32 dot at default precision it always was, and on the
+        TPU XLA reads the bf16 leaf straight into the matmul fusion (no
+        float32 copy in HBM: `tools/lowered_serving_steps.py`)."""
         s = weights.get(key + "@qscale")
         w = weights[key]
         return w.astype(jnp.float32) * s if s is not None else w
@@ -852,11 +939,26 @@ class GenerationModel:
             var = jnp.mean((h - mu) ** 2, axis=-1, keepdims=True)
             return (h - mu) * jax.lax.rsqrt(var + 1e-5) * scale + bias
 
+        # where this is traced for a device whose default-precision dot
+        # rounds BOTH operands to bfloat16, a bf16 leaf meets its
+        # activations rounded the same way in front of the dot: the
+        # same product, as the MXU's own bf16 x bf16 pass with float32
+        # accumulation (a mixed f32 x bf16 dot costs a 1,040-row chunk
+        # step 3 ms more: PERF.md §6, PR 35). Anywhere else the dot
+        # is `a @ w` as written
+        narrow = default_dot_rounds_to_bf16()
+
+        def dot(a, key):
+            w = self._w(jnp, weights, key)
+            if narrow and w.dtype == jnp.bfloat16:
+                return jnp.matmul(a.astype(w.dtype), w,
+                                  preferred_element_type=jnp.float32)
+            return a @ w
+
         for i in range(cfg.n_layers):
             p = "l%d/" % i
             a = ln(x, weights[p + "ln1_scale"], weights[p + "ln1_bias"])
-            qkv = a @ self._w(jnp, weights, p + "wqkv") \
-                + weights[p + "bqkv"]
+            qkv = dot(a, p + "wqkv") + weights[p + "bqkv"]
             q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(lead + (H, Dh))
             k_new = k_new.reshape(lead + (H, Dh))
@@ -866,21 +968,19 @@ class GenerationModel:
                 kv_v = kv_v.at[i, write_blk, slot_idx].set(v_new)
             with jax.named_scope("attention"):
                 ctx = attend(i, q, kv_k, kv_v)
-                x = x + ctx @ self._w(jnp, weights, p + "wproj") \
-                    + weights[p + "bproj"]
+                x = x + dot(ctx, p + "wproj") + weights[p + "bproj"]
             with jax.named_scope("ffn"):
                 b2 = ln(x, weights[p + "ln2_scale"],
                         weights[p + "ln2_bias"])
-                f = jax.nn.gelu(b2 @ self._w(jnp, weights, p + "wff1")
-                                + weights[p + "bff1"], approximate=False)
-                x = x + f @ self._w(jnp, weights, p + "wff2") \
-                    + weights[p + "bff2"]
+                f = jax.nn.gelu(dot(b2, p + "wff1") + weights[p + "bff1"],
+                                approximate=False)
+                x = x + dot(f, p + "wff2") + weights[p + "bff2"]
 
         with jax.named_scope("head"):
             if pick is not None:
                 x = pick(x)
             x = ln(x, weights["final_ln_scale"], weights["final_ln_bias"])
-            return kv_k, kv_v, x @ self._w(jnp, weights, "lm_head")
+            return kv_k, kv_v, dot(x, "lm_head")
 
     def _forward_token(self, jnp, weights, x, positions, block_tables,
                        active, kv_k, kv_v):
@@ -1980,9 +2080,9 @@ class ModelDrafter:
 def reference_decode(model, prompt, max_new_tokens, eos_id=None):
     """Greedy-decode ONE sequence with a plain contiguous KV cache and
     full attention — no blocks, no batching, no masking tricks. The
-    batched paged decode must match this token-for-token. A weight-only
-    quantized model decodes over its dequantized fp32 weights (the same
-    values the int8 step computes with)."""
+    batched paged decode must match this token-for-token. It decodes
+    over ``dequantized_weights()``: the values the step computes with
+    (an int8 store multiplied back out, a bfloat16 leaf widened)."""
     import jax.numpy as jnp
 
     cfg = model.config
@@ -1992,8 +2092,7 @@ def reference_decode(model, prompt, max_new_tokens, eos_id=None):
             "plain reference is perfbench/reference/kanana.py "
             "(tests/test_latent_moe.py compares against it)"
             % cfg.block.kind)
-    w = model.dequantized_weights() if model.weight_only_int8 \
-        else model.weights
+    w = model.dequantized_weights()
     pe = _position_encoding_table(cfg)
     emb_scale = float(cfg.d_model) ** 0.5
     H, Dh = cfg.n_heads, cfg.head_dim

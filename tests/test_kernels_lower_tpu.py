@@ -196,8 +196,7 @@ POOL_STEP = dict(n_layers=2, n_heads=16, head_dim=128, block_size=16,
                  spec_window=5, tree=(2, 3))
 
 
-@pytest.fixture(scope="module")
-def pool_model():
+def _zero_model():
     """A two-layer model at the paged kernels' flagship widths (16 heads
     of 128), zero weights: only its compiled steps are looked at."""
     import numpy as np
@@ -215,6 +214,11 @@ def pool_model():
     return GenerationModel(cfg, {
         n: np.zeros(shapes.get(n.split("/")[-1], (D,)), np.float32)
         for n in weight_names(cfg)})
+
+
+@pytest.fixture(scope="module")
+def pool_model():
+    return _zero_model()
 
 
 def _pool_step(model, kind, chip):
@@ -336,6 +340,50 @@ def test_serving_step_reads_the_kv_pool_in_place(kind, pool_model,
         scores = (g["batch"] * g["chunk"] * g["n_heads"]
                   * 3 * g["blocks_per_seq"] * g["block_size"])
         assert scores not in [n for _, _, n in _large_results(hlo, scores)]
+
+
+def _dot_operand_dtypes(hlo):
+    """{(lhs dtype, rhs dtype)} over a compiled module's convolutions
+    and dots, as XLA reads their operands."""
+    import re
+
+    types = dict(re.findall(r"(%[\w.\-]+) = (\w+)\[", hlo))
+    return {(types.get(a), types.get(b)) for a, b in re.findall(
+        r" (?:convolution|dot)\((%[\w.\-]+), (%[\w.\-]+)\)", hlo)}
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk_tokens"])
+@pytest.mark.parametrize("built_for", ["v5e", "cpu"])
+def test_xglm_step_multiplies_the_store_as_it_holds_it(kind, built_for,
+                                                       v5e_chip):
+    """PR 35. A model built FOR the v5e keeps its dot operands in
+    bfloat16 (`serving.model.dot_operand_dtype`), and its steps hand
+    the MXU that leaf beside activations rounded the same way: every
+    product of the compiled step is bf16 x bf16 (with float32
+    accumulation), none reads a float32 weight and none is the mixed
+    f32 x bf16 dot that cost a chunk step 3 ms. A float32 store
+    (built where the rule says float32, here the CPU) compiled for the
+    same chip keeps its f32 x f32 dots: the steps adapt to what they
+    are given (XLA may round their activations in a producer fusion, a
+    float32 weight it reads as float32)."""
+    if built_for == "v5e":
+        with device.compiling_for(v5e_chip):
+            model = _zero_model()
+        want = "bf16"
+    else:
+        model = _zero_model()
+        want = "f32"
+    assert {str(model.weights[k].dtype) for k in ("lm_head", "l0/wqkv",
+                                                  "l1/wff2")} \
+        == {{"bf16": "bfloat16", "f32": "float32"}[want]}
+    assert str(model.weights["embedding"].dtype) == "float32"
+    compiled, layer, _n = _pool_step(model, kind, v5e_chip)
+    dots = _dot_operand_dtypes(compiled.as_text())
+    if want == "bf16":
+        assert dots == {("bf16", "bf16")}
+    else:
+        assert dots and all("f32" in pair for pair in dots), dots
+    assert compiled.memory_analysis().temp_size_in_bytes < layer * 4
 
 
 # ---------------------------------------------------------------------------

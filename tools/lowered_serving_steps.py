@@ -8,8 +8,16 @@
 both serving configurations of the benchmark at their engines' geometry
 (``perfbench/configs/*-serve.json``; only shapes are made, no weights),
 lowers them for a described ``v5e:2x2`` device and writes the StableHLO
-text with debug locations stripped. ``--compile`` also compiles each for
-the v5e and prints its argument, output and workspace bytes.
+text with debug locations stripped, and prints each step's dots counted
+by operand dtypes (``dots``: XGLM's read ``bf16 x bf16``, activations
+rounded as the dot rounds them against a weight the store keeps in
+bfloat16 for the v5e, with no convert between the step's weight
+argument and the dot). ``--compile``
+also compiles each for the v5e and prints its argument, output and
+workspace bytes and the same count over the compiled program's
+convolutions (``compiled_dots``: the dtypes XLA really reads; a bf16
+weight there and a workspace smaller than a weight say no float32 copy
+of a weight is written to HBM).
 
 ``compare`` says whether two such directories hold the same programs. A
 Mosaic kernel's body rides in its custom call as base64 MLIR bytecode,
@@ -22,12 +30,33 @@ a device number.
 """
 
 import base64
+import collections
 import glob
 import hashlib
 import json
 import os
 import re
 import sys
+
+
+def lowered_dots(text):
+    """{"lhs x rhs": count} over a StableHLO module's dot_generals, by
+    the operands' element types."""
+    return dict(collections.Counter(
+        "%s x %s" % pair for pair in re.findall(
+            r"stablehlo\.dot_general .*: \(tensor<(?:\d+x)*(\w+)>, "
+            r"tensor<(?:\d+x)*(\w+)>\)", text)))
+
+
+def compiled_dots(text):
+    """The same count over a compiled module's convolutions and dots,
+    by the element types of the operands as XLA reads them."""
+    types = dict(re.findall(r"(%[\w.\-]+) = (\w+)\[", text))
+    dots = collections.Counter()
+    for a, b in re.findall(
+            r" (?:convolution|dot)\((%[\w.\-]+), (%[\w.\-]+)\)", text):
+        dots["%s x %s" % (types.get(a, "?"), types.get(b, "?"))] += 1
+    return dict(dots)
 
 
 def write(root, out, compile_too):
@@ -74,12 +103,16 @@ def write(root, out, compile_too):
                              if not line.lstrip().startswith("#loc"))
             with open(os.path.join(out, name + ".mlir"), "w") as f:
                 f.write(text + "\n")
-            info = {"step": name, "lines": text.count("\n") + 1}
+            info = {"step": name, "lines": text.count("\n") + 1,
+                    "dots": lowered_dots(text)}
             if compile_too:
-                m = lowered.compile().memory_analysis()
+                compiled = lowered.compile()
+                m = compiled.memory_analysis()
                 info.update(argument_bytes=m.argument_size_in_bytes,
                             output_bytes=m.output_size_in_bytes,
-                            workspace_bytes=m.temp_size_in_bytes)
+                            workspace_bytes=m.temp_size_in_bytes,
+                            compiled_dots=compiled_dots(
+                                compiled.as_text()))
         print(json.dumps(info), flush=True)
 
     def both_steps(name, model, e):
@@ -110,13 +143,20 @@ def write(root, out, compile_too):
         vocab_size=c["vocab_size"], d_model=c["d_model"],
         n_heads=c["attention_heads"], n_layers=c["num_layers"],
         d_ff=c["ffn_dim"], max_seq_len=c["engine"]["max_seq_len"])
-    D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
-    by_leaf = {"embedding": (V, D), "lm_head": (D, V), "wqkv": (D, 3 * D),
-               "bqkv": (3 * D,), "wproj": (D, D), "wff1": (D, F),
-               "bff1": (F,), "wff2": (F, D)}
-    both_steps("xglm", model_of(cfg, {
-        n: (by_leaf.get(n.split("/")[-1], (D,)), jnp.float32)
-        for n in weight_names(cfg)}), c["engine"])
+    try:
+        # the leaves as the store keeps them on the v5e
+        from paddle_tpu.serving.model import leaf_shapes
+
+        with device.compiling_for(chip):
+            shapes = leaf_shapes(cfg)
+    except ImportError:   # a checkout from before the store stated them
+        D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
+        by_leaf = {"embedding": (V, D), "lm_head": (D, V),
+                   "wqkv": (D, 3 * D), "bqkv": (3 * D,), "wproj": (D, D),
+                   "wff1": (D, F), "bff1": (F,), "wff2": (F, D)}
+        shapes = {n: (by_leaf.get(n.split("/")[-1], (D,)), jnp.float32)
+                  for n in weight_names(cfg)}
+    both_steps("xglm", model_of(cfg, shapes), c["engine"])
 
     c = config("kanana-2-30b-a3b-serve.json")
     cfg = serve_latent.generation_config(c, c["engine"]["max_seq_len"])
