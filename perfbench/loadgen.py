@@ -135,3 +135,38 @@ def percentile(values, q):
     if not len(values):
         return None
     return float(np.percentile(np.asarray(values, np.float64), q))
+
+
+SLOW_GAP_FACTOR = 2.0     # a gap this many medians long held a slow step
+
+
+def slow_gap_share(gaps):
+    """The share of token gaps longer than ``SLOW_GAP_FACTOR`` times
+    the run's median gap: with a server whose decode step is a fifth of
+    its mixed step, the gaps that held a prefill chunk (or a stall).
+    None for no gaps."""
+    if not len(gaps):
+        return None
+    g = np.asarray(gaps, np.float64)
+    return float(np.mean(g > SLOW_GAP_FACTOR * np.median(g)))
+
+
+def percentile_is_clear(shares, q):
+    """May the ``q``-th percentile (0..100) of a cell's token gaps be
+    judged? Only where it sits on one kind of step in every run: each
+    run's ``slow_gap_share`` stands a factor of two or more from the
+    ``1 - q/100`` of gaps beyond the percentile, all on the same side.
+    Between the two the percentile is interpolated across the cliff
+    from a decode step to a mixed step, and two or three gaps move it
+    by milliseconds (PERF.md section 2)."""
+    beyond = (100.0 - q) / 100.0
+    shares = [round(float(s), 9) for s in shares]   # 0.1 is 2 x 0.05
+    return bool(shares) and (
+        all(s >= round(2.0 * beyond, 9) for s in shares)
+        or all(s <= round(beyond / 2.0, 9) for s in shares))
+
+
+def samples_beyond(n, q):
+    """How many of ``n`` samples lie beyond the ``q``-th percentile
+    (0..100); a judged percentile wants ten."""
+    return int(np.floor(n * (1.0 - q / 100.0)))

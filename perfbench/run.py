@@ -8,7 +8,10 @@ JSON notes (set-up phases, the generator's lateness, every number the
 output check compared beside its limit); the LAST line is the result:
 
     {"correct": ..., "attempted": ..., "failed": ..., "metrics": {...},
-     "device": {...}}   (+ "breakdown" in a traced run)
+     (+ "breakdown" in a traced run) "device": {...}, "checks": {...}}
+
+``checks`` holds every number the output check compared, beside its
+limit; the same lines end standard error.
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics. A run that finds no TPU, or fewer
@@ -37,18 +40,35 @@ def note(**fields):
 
 
 def place_compile_cache():
-    """jax's persistent cache: where ``JAX_COMPILATION_CACHE_DIR`` says,
-    else the fixed ``<checkout>/.jax_cache`` (the path is part of the
-    cache key). Every program is kept, however quick its compile, so a
-    cell's second run compiles nothing."""
+    """jax's persistent cache, one directory a checkout and nothing
+    ever evicted: ``<checkout>/.jax_cache``, or, where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, a directory of this
+    checkout's own under it, named from the checkout's path (fixed, as
+    the path is part of the cache key). Two checkouts that share one
+    directory hold a copy each of every step program, because a Mosaic
+    kernel's body carries its checkout's paths; under a size limit
+    (``JAX_COMPILATION_CACHE_MAX_SIZE``: the chip tool states 192 MiB,
+    two sides of one XGLM cell need 290) each side's run pushed the
+    other's programs out and the next run compiled them again inside
+    ``setup_s`` (PERF.md section 6, PR 36). So the limit is stated
+    here, as none; every program is kept, however quick its compile;
+    and a cell's second run compiles nothing, whichever cells or
+    checkouts ran between the two."""
+    import hashlib
+
     import jax
 
-    if jax.config.jax_compilation_cache_dir is None:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(ROOT, ".jax_cache"))
+    shared = jax.config.jax_compilation_cache_dir
+    if shared is None:
+        own = os.path.join(ROOT, ".jax_cache")
+    else:
+        own = os.path.join(shared, "perfbench-" + hashlib.sha1(
+            ROOT.encode()).hexdigest()[:16])
+    jax.config.update("jax_compilation_cache_dir", own)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    return jax.config.jax_compilation_cache_dir
+    return own
 
 
 def find_device(chips, require_chip=True):
@@ -68,21 +88,41 @@ def find_device(chips, require_chip=True):
 
 
 class CompileCounter:
-    """Counts jax's backend compilations, so a window can show none."""
+    """Counts jax's backend compilations, so a window can show none,
+    and the persistent cache's hits and misses, so a set-up can show
+    that every program was found."""
 
     EVENT = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+    MISS = "/jax/compilation_cache/cache_misses"
 
     def __init__(self):
         import jax
 
         self.count = 0
         self.seconds = 0.0
+        self.cache_hits = self.cache_misses = 0
         jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_event)
 
     def _on(self, event, duration, **_kw):
         if event == self.EVENT:
             self.count += 1
             self.seconds += duration
+
+    def _on_event(self, event, **_kw):
+        if event == self.HIT:
+            self.cache_hits += 1
+        elif event == self.MISS:
+            self.cache_misses += 1
+
+    def setup_line(self):
+        """What the set-up line says of compilation: a backend
+        "compilation" is also counted where the program was read back
+        from the cache, so the seconds are small on a hit."""
+        return dict(compile_seconds_total=self.seconds,
+                    compilations=self.count, cache_hits=self.cache_hits,
+                    cache_misses=self.cache_misses)
 
 
 def layer_metrics(bench, cell_name, reported, obs, root=ROOT):
@@ -113,8 +153,17 @@ def run_cell(workload, seed, seconds, trace, require_chip=True,
     # a CPU rehearsal borrows the v5e's row: its numbers are never read
     peaks = spec.peaks(ident["kind"] if require_chip else "TPU v5 lite")
     compiles = CompileCounter()
+    reached_chip_s = time.perf_counter() - T_PROCESS_START
     note(phase="start", cell=workload, seed=seed, seconds=seconds,
-         trace=trace, device=ident, compile_cache=cache_dir)
+         trace=trace, device=ident, compile_cache=cache_dir,
+         reached_chip_s=reached_chip_s)
+
+    def note_setup(**fields):
+        """The runners' notes; the set-up line (``window_open``) also
+        says what set-up compiled and what it found in the cache."""
+        if fields.get("phase") == "window_open":
+            fields.update(compiles.setup_line())
+        note(**fields)
 
     from perfbench import trace_reduce
 
@@ -125,7 +174,7 @@ def run_cell(workload, seed, seconds, trace, require_chip=True,
         cell=w, config=config, traffic=traffic, seed=int(seed),
         seconds=float(seconds), tracer=tracer, devices=devices,
         peaks=peaks, compiles=compiles, t_start=T_PROCESS_START,
-        note=note, require_chip=require_chip), **(hooks or {}))
+        note=note_setup, require_chip=require_chip), **(hooks or {}))
 
     for c in run["checks"]:
         note(check=c["name"], **{k: v for k, v in c.items()
@@ -137,7 +186,8 @@ def run_cell(workload, seed, seconds, trace, require_chip=True,
             "failed": int(run["failed"])}
     if trace:
         obs = dict(run["observations"], config=config, peaks=peaks,
-                   chips=w["chips"], traffic=traffic)
+                   chips=w["chips"], traffic=traffic,
+                   reached_chip_s=[reached_chip_s])
         reported = [m["name"] for m in
                     spec.metrics_of(bench, "end_to_end", workload)]
         line["metrics"] = layer_metrics(bench, workload, reported, obs,
@@ -154,6 +204,9 @@ def run_cell(workload, seed, seconds, trace, require_chip=True,
             for m in spec.metrics_of(bench, "end_to_end", workload)
             if e2e.get(m["name"]) is not None}
     line["device"] = device
+    # every number compared, beside its limit: last in the line
+    line["checks"] = {c["name"]: {"value": c["value"], "limit": c["limit"],
+                                  "ok": c["ok"]} for c in run["checks"]}
     return line
 
 
@@ -166,6 +219,11 @@ def main(argv=None):
     args = ap.parse_args(argv)
     line = run_cell(args.workload, args.seed, args.seconds, args.trace)
     print(json.dumps(line), flush=True)
+    for name, c in line["checks"].items():    # and last on standard error
+        sys.stderr.write("perfbench check %s: %r (limit %r) %s\n"
+                         % (name, c["value"], c["limit"],
+                            "ok" if c["ok"] else "NOT OK"))
+    sys.stderr.flush()
     return 0
 
 

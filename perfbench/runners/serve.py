@@ -6,6 +6,16 @@ generator: an open loop that times every request from the moment it was
 due, or a closed loop of callers. Every token is stamped in the
 engine's ``stream`` callback; percentiles come from the raw samples.
 
+A token gap is one engine step: a decode step (5-8 ms) or, while any
+request prefills, a mixed step (25-45 ms). A percentile of the gaps is
+a step time only while it sits clear of the cliff between the two, so
+every run prints ``slow_gap_share`` (``loadgen.slow_gap_share``), and
+``BENCHMARK.json`` judges a gap percentile only in the cells whose
+runs kept that share a factor of two from the percentile
+(``loadgen.percentile_is_clear``; PERF.md section 2): ``itl_p50_ms``,
+how fast text streams, and ``itl_p99_ms``, the chunk a user waits
+behind. ``itl_p95_ms`` is computed too and read per layer.
+
 ``correct`` (builder's contract, a served model): once the window has
 closed, a sample of the requests it finished, drawn from the seed and
 with the longest in it, goes through the plain reference once, prompt
@@ -350,13 +360,23 @@ def run(ctx, tamper=None):
     queue_wait = [(r.request.start_time - r.due) * 1e3 for r in ok]
     itl = [d * 1e3 for r in ok for d in np.diff(r.stamps)]
     in_window = sum(1 for r in records for s in r.stamps if t0 <= s < t_end)
+    p = loadgen.percentile
+    slow_share = loadgen.slow_gap_share(itl)
+    ttft_p50, itl_p50, itl_p95, itl_p99 = (
+        p(ttft, 50), p(itl, 50), p(itl, 95), p(itl, 99))
     note(phase="window_closed", requests=len(records), finished=len(ok),
          failed=failed, engine_steps=steps, tokens_in_window=in_window,
+         token_gaps=len(itl), slow_gap_share=slow_share,
          unfinished_at_close=unfinished, drain_s=drain_s,
-         generator_late_ms_p50=loadgen.percentile(late, 50),
+         generator_late_ms_p50=p(late, 50),
          generator_late_ms_max=max(late),
-         ttft_ms_p50=loadgen.percentile(ttft, 50),
-         itl_ms_p50=loadgen.percentile(itl, 50),
+         ttft_ms_p50=ttft_p50, ttft_ms_p90=p(ttft, 90),
+         itl_ms_p50=itl_p50, itl_ms_p95=itl_p95, itl_ms_p99=itl_p99,
+         queue_wait_ms_p90=p(queue_wait, 90),
+         # a queue that grows over the window shows in the second half
+         ttft_ms_p50_by_half=[p(ttft[:len(ttft) // 2], 50),
+                              p(ttft[len(ttft) // 2:], 50)],
+         pool_used_pct_max=max(pool_used) if pool_used else None,
          occupancy_mean=float(np.mean(occupancy)) if occupancy else None,
          blocks_total=pool_stats["blocks_total"],
          weight_store=pool_stats["weight_store"])
@@ -365,15 +385,18 @@ def run(ctx, tamper=None):
     gc.collect()
     reduced = tracer.reduce() if tracer else None
     checks = output_checks(ref, config, mix, seed, ok, window, note)
-    p = loadgen.percentile
     return {
         "end_to_end": {
-            "ttft_p50_ms": p(ttft, 50), "itl_p95_ms": p(itl, 95),
+            "ttft_p50_ms": ttft_p50, "itl_p50_ms": itl_p50,
+            "itl_p95_ms": itl_p95, "itl_p99_ms": itl_p99,
             "serve_tokens_per_s": in_window / seconds,
             "setup_s": t0 - ctx["t_start"]},
         "observations": {
             "ttft_ms": ttft, "itl_ms": itl, "queue_wait_ms": queue_wait,
             "occupancy": occupancy, "pool_used_pct": pool_used,
+            # one sample, so that reader ``stat`` serves it
+            "slow_gap_share_pct": None if slow_share is None
+            else [100.0 * slow_share],
             "window_s": seconds, "engine_steps": steps,
             "trace": reduced},
         "attempted": len(records), "failed": failed,

@@ -93,18 +93,30 @@ def test_a_program_without_the_log_reads_nothing(registry):
     assert step_log.warm_records("serving/step") is None
 
 
+def step_log_entries(bench):
+    """PR 24's thirteen entries of the committed benchmark, by name."""
+    return [m for m in bench["per_layer"]
+            if m["name"].rpartition(".")[0] in NEW["serve"] + NEW["train"]]
+
+
 def test_the_committed_entries_name_the_reader_and_the_layers():
     bench = spec.load_benchmark()
-    added = [m for m in bench["per_layer"]
-             if m["name"].rpartition(".")[0] in NEW["serve"] + NEW["train"]]
-    assert len(added) == 13 and bench["per_layer"][-13:] == added
-    layers = {m["layer"] for m in bench["per_layer"][:-13]}
+    added = step_log_entries(bench)
+    assert len(added) == 13
+    layers = {m["layer"] for m in bench["per_layer"] if m not in added}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
     for m in added:
-        assert "workloads" not in m and m["layer"] in layers
+        assert m["layer"] in layers
         stem, _, suffix = m["name"].rpartition(".")
-        assert m["moves"] == {"serve": "itl_p95_ms",
-                              "batch": "serve_tokens_per_s",
-                              "train": "train_tokens_per_s"}[suffix]
+        # a step time moves the gap percentile that sits on that kind
+        # of step; the closed loops' and the trainer's move the rate
+        assert m["moves"] in {"serve": ("itl_p50_ms", "itl_p99_ms",
+                                        "itl_p95_ms"),
+                              "batch": ("serve_tokens_per_s",),
+                              "train": ("train_tokens_per_s",)}[suffix]
+        # and is read in cells that report what it moves
+        assert m["workloads"] and set(m["workloads"]) \
+            <= set(e2e[m["moves"]]["workloads"])
         meta = spec.read_json(os.path.join(
             spec.ROOT, "perfbench", "layer_metrics", stem + ".json"))
         assert meta["reader"] == "step_log" and "warm" in meta["source"]
@@ -113,12 +125,17 @@ def test_the_committed_entries_name_the_reader_and_the_layers():
 @pytest.fixture
 def toy_root(tmp_path):
     """The toy benchmark of perfbench/tests/root with the committed
-    benchmark's thirteen new entries appended to it."""
+    benchmark's thirteen step-log entries appended to it."""
     root = str(tmp_path / "root")
     shutil.copytree(TEST_ROOT, root)
     path = os.path.join(root, "BENCHMARK.json")
     toy = spec.read_json(path)
-    toy["per_layer"] += spec.load_benchmark()["per_layer"][-13:]
+    cells = {"serve": "tiny.open", "batch": "tiny.closed",
+             "train": "tiny.train"}
+    for m in step_log_entries(spec.load_benchmark()):
+        # the committed entry, in the toy cell of its suffix's kind
+        toy["per_layer"].append(dict(
+            m, workloads=[cells[m["name"].rpartition(".")[2]]]))
     with open(path, "w") as f:
         json.dump(toy, f)
     return root

@@ -17,9 +17,16 @@ clock, where ``jax.profiler.TraceAnnotation`` spans appear by name.
 * device_ops: seconds per operation kind (HLO name without its ``%``
   and trailing ``.<n>``), largest first.
 * idle_gaps: the stretches in which no operation ran, each attributed
-  to the benchmark's own host span (names starting ``bench/``) that
-  covers most of it, summed by that name. A gap under no such span is
-  ``host, unattributed``: the engine's own thread has no spans yet.
+  to the host span that covers most of it, summed by that name. The
+  program's own spans (``ptpu/engine.plan|dispatch|wait|stream``,
+  ``ptpu/exe.prepare|dispatch``; named with their prefix) come first:
+  the benchmark's generator sleeps or waits under a ``bench/`` span
+  whenever no request is due, whatever the server is doing, so its
+  span covers every gap, and what the engine's worker was doing says
+  why the device stood still. Where no span of the program covers
+  half of a gap (the worker had nothing to do), the benchmark's own
+  span (``bench/``, named without the prefix) takes it; a gap under no
+  span is ``host, unattributed``.
 """
 
 import bisect
@@ -33,13 +40,15 @@ import time
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 SPAN_PREFIX = "bench/"
+PROGRAM_PREFIX = "ptpu/"         # the program's own spans, named whole
 WINDOW_SPAN = "traced_window"    # the Tracer's own span: the window
 UNATTRIBUTED = "host, unattributed"
-_SUFFIX = re.compile(r"(\.\d+)+$")
+_SUFFIX = re.compile(r"(\.\d+|\.remat\d*)+$")
 
 
 def op_kind(name):
-    """``%fusion.12 = f32[..] fusion(...)`` -> ``fusion``."""
+    """``%fusion.12 = f32[..] fusion(...)`` -> ``fusion``; so is
+    ``fusion.25.remat``, the compiler's rematerialised copy."""
     head = name.split(" = ", 1)[0].strip().lstrip("%")
     return _SUFFIX.sub("", head) or head
 
@@ -82,10 +91,13 @@ def reduce_profile(profile, window_s=None, n_devices=None, min_gap_s=2e-5):
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
-                        spans.append((ev.start_ns,
-                                      ev.start_ns + ev.duration_ns,
-                                      ev.name[len(SPAN_PREFIX):]))
+                    name = ev.name
+                    if name.startswith(SPAN_PREFIX):
+                        name = name[len(SPAN_PREFIX):]
+                    elif not name.startswith(PROGRAM_PREFIX):
+                        continue
+                    spans.append((ev.start_ns,
+                                  ev.start_ns + ev.duration_ns, name))
     window = next(((a, b) for a, b, name in spans if name == WINDOW_SPAN),
                   None)
     ops_total = collections.Counter()
@@ -118,13 +130,17 @@ def reduce_profile(profile, window_s=None, n_devices=None, min_gap_s=2e-5):
     for (_s0, e0), (s1, _e1) in zip(u, u[1:]):
         if (s1 - e0) * 1e-9 < min_gap_s:
             continue
-        best, cover = UNATTRIBUTED, 0
+        # the span that covers most of the gap, of the benchmark's own
+        # (False) and of the program's (True)
+        best = {False: (0, UNATTRIBUTED), True: (0, UNATTRIBUTED)}
         for s, e, name in spans[bisect.bisect_left(starts, e0 - longest):
                                 bisect.bisect_right(starts, s1)]:
-            c = min(e, s1) - max(s, e0)
-            if c > cover:
-                best, cover = name, c
-        gaps[best] += (s1 - e0)
+            own = name.startswith(PROGRAM_PREFIX)
+            best[own] = max(best[own], (min(e, s1) - max(s, e0), name))
+        cover, name = best[True]
+        if 2 * cover < s1 - e0:      # the worker had nothing to do
+            name = best[False][1]
+        gaps[name] += (s1 - e0)
     return {
         "busy_s": busy_ns * 1e-9,
         "window_s": float(window_s),
