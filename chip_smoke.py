@@ -53,7 +53,8 @@ Sizes = collections.namedtuple("Sizes", [
     "seq", "batch", "ref_batch", "train_steps", "lr",
     "serve_ctx", "serve_batch", "block_size", "prefill_chunk",
     "prompt_lens", "new_tokens", "spec_k", "spec_tree",
-    "int8_mkn", "latent", "latent_batch", "latent_chunk", "rec"])
+    "int8_mkn", "latent", "latent_batch", "latent_chunk", "afmoe",
+    "afmoe_batch", "afmoe_chunk", "rec"])
 
 # the flagship at full width (bench_transformer_fluid's operating point)
 FULL = Sizes(
@@ -74,6 +75,20 @@ FULL = Sizes(
                     n_shared_experts=2, moe_d_ff=768,
                     routed_scaling_factor=2.448)),
     latent_batch=32, latent_chunk=16,
+    # the grouped-query window/global block at its published widths
+    # (arcee-ai/Trinity-Large-Preview): a dense window layer, then a
+    # global and a window expert layer holding 8 of 256 experts
+    afmoe=dict(vocab_size=25024, d_model=3072, n_heads=48, n_layers=3,
+               d_ff=12288, block=dict(
+                   kind="afmoe", n_kv_heads=8, head_dim=128,
+                   layer_types=["sliding_attention", "full_attention",
+                                "sliding_attention"],
+                   sliding_window=128, n_dense_layers=1,
+                   n_routed_experts=256, experts_per_token=4,
+                   n_shared_experts=1, moe_d_ff=3072,
+                   routed_scaling_factor=2.448,
+                   experts_held=list(range(8)))),
+    afmoe_batch=8, afmoe_chunk=256,
     # bench.py --rec-only sizes
     rec=dict(n_shards=4, records_per_shard=320, batch_size=32, vocab=512,
              fields=6, embed_dim=16, cache_rows=128))
@@ -95,6 +110,17 @@ TOY = Sizes(
                     n_shared_experts=2, moe_d_ff=64,
                     routed_scaling_factor=2.448)),
     latent_batch=4, latent_chunk=8,
+    afmoe=dict(vocab_size=512, d_model=64, n_heads=4, n_layers=3,
+               d_ff=128, block=dict(
+                   kind="afmoe", n_kv_heads=2, head_dim=128,
+                   layer_types=["sliding_attention", "full_attention",
+                                "sliding_attention"],
+                   sliding_window=16, n_dense_layers=1,
+                   n_routed_experts=16, experts_per_token=2,
+                   n_shared_experts=1, moe_d_ff=64,
+                   routed_scaling_factor=2.448,
+                   experts_held=list(range(4)))),
+    afmoe_batch=4, afmoe_chunk=32,
     rec=dict(n_shards=2, records_per_shard=64, batch_size=16, vocab=128,
              fields=4, embed_dim=8, cache_rows=32))
 
@@ -529,33 +555,41 @@ def latent_step_logits(model, sz, chunked):
     return np.asarray(out[3]), np.asarray(out[2])
 
 
-def leg_latent(sz, rehearsal):
+def block_leg(sz, rehearsal, config_kw, batch, chunk, kernels,
+              step_logits, seed, counters_agree=False, after_serving=None):
+    """A serving block's leg: serve the prompts through the engine with
+    the block's kernels on, then one decode and one chunk step on a
+    random cache, kernel path against lax path. ``after_serving(stats)``
+    adds to the report from the engine's statistics."""
     from paddle_tpu.serving import (GenerationConfig, GenerationModel,
                                     ServingEngine)
 
     assert "PTPU_KERNELS" not in os.environ
     if rehearsal:
         os.environ["PTPU_KERNELS"] = "1"
-    k0 = {n: counter("kernels/kernel:" + n) for n in LATENT_KERNELS}
+    k0 = {n: counter("kernels/kernel:" + n) for n in kernels}
     fall0 = counter("kernels/fallbacks")
     model = GenerationModel.random(
-        GenerationConfig(max_seq_len=sz.serve_ctx, **sz.latent), seed=7)
+        GenerationConfig(max_seq_len=sz.serve_ctx, **config_kw), seed=seed)
+    report = {}
     try:
-        eng = ServingEngine(model, max_batch=sz.latent_batch,
+        eng = ServingEngine(model, max_batch=batch,
                             max_seq_len=sz.serve_ctx,
-                            block_size=sz.block_size,
-                            prefill_chunk=sz.latent_chunk)
+                            block_size=sz.block_size, prefill_chunk=chunk)
         try:
             outs = [r.wait(900) for r in
                     [eng.submit(p, max_new_tokens=sz.new_tokens)
                      for p in prompts(sz)]]
+            if after_serving is not None:
+                report.update(after_serving(
+                    next(iter(eng.stats().values()))))
         finally:
             eng.close()
         assert all(len(o) == sz.new_tokens for o in outs)
-        kernel = {k: latent_step_logits(model, sz, k == "chunk")
+        kernel = {k: step_logits(model, sz, k == "chunk")
                   for k in ("decode", "chunk")}
         dispatched = {n: counter("kernels/kernel:" + n) - k0[n]
-                      for n in LATENT_KERNELS}
+                      for n in kernels}
         fallbacks = counter("kernels/fallbacks") - fall0
     finally:
         os.environ.pop("PTPU_KERNELS", None)
@@ -563,33 +597,94 @@ def leg_latent(sz, rehearsal):
     assert fallbacks == 0, fallbacks
     os.environ["PTPU_KERNELS"] = "0"
     try:
-        lax = {k: latent_step_logits(model, sz, k == "chunk")
-               for k in kernel}
+        lax = {k: step_logits(model, sz, k == "chunk") for k in kernel}
     finally:
         del os.environ["PTPU_KERNELS"]
-    report = {}
+    steps = {}
     for k, (got, counters) in kernel.items():
         want = lax[k][0]
         assert got.shape == want.shape and np.isfinite(got).all(), k
         # a row's error as a share of the largest logit. The two paths
-        # round differently (absorbed against expanded attention), so a
-        # token at a near-tie of the router may take another expert on
-        # one of them and its row then differs by the logits' own size:
-        # the median row is held to the bound, the worst row reported
+        # round differently, so a token at a near-tie of the router may
+        # take another expert on one of them and its row then differs by
+        # the logits' own size: the median row is held to the bound, the
+        # worst row reported
         rows = np.abs(got - want).max(axis=1) / np.abs(want).max()
         assert np.median(rows) <= LOGITS_REL_BOUND, (k, np.median(rows))
-        assert (counters == lax[k][1]).all() or k == "chunk", k
-        report[k] = {"median_row_err": float("%.3g" % np.median(rows)),
-                     "worst_row_err": float("%.3g" % rows.max()),
-                     "counters": [int(c) for c in counters]}
-    return {"requests": len(outs), "kernel_dispatches": dispatched,
-            "kernel_fallbacks": fallbacks, "steps": report,
-            "logits_rel_bound": LOGITS_REL_BOUND}
+        if counters_agree:
+            assert (counters == lax[k][1]).all() or k == "chunk", k
+        steps[k] = {"median_row_err": float("%.3g" % np.median(rows)),
+                    "worst_row_err": float("%.3g" % rows.max()),
+                    "counters": [int(c) for c in counters]}
+    return dict(report, requests=len(outs), kernel_dispatches=dispatched,
+                kernel_fallbacks=fallbacks, steps=steps,
+                logits_rel_bound=LOGITS_REL_BOUND)
 
+
+def leg_latent(sz, rehearsal):
+    return block_leg(sz, rehearsal, sz.latent, sz.latent_batch,
+                     sz.latent_chunk, LATENT_KERNELS, latent_step_logits,
+                     seed=7, counters_agree=True)
 
 
 # ---------------------------------------------------------------------------
-# kernels: the shapes the two legs above use (tests/test_kernels_lower_tpu.py
+# afmoe: the grouped-query window/global block, its decode and chunk steps
+# over two kinds of page, kernel path against lax path
+# ---------------------------------------------------------------------------
+
+AFMOE_KERNELS = ("gmm", "gqa_decode", "gqa_chunk", "kv_page_write")
+
+
+def afmoe_step_logits(model, sz, chunked):
+    """Logits of one decode or one chunk step of the block over random
+    pages of both kinds (contexts longer than the window, so that the
+    walk starts past page 0), under whatever kernel policy is in force."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving import KVBlockPool
+
+    cfg = model.config
+    B, bs = sz.afmoe_batch, sz.block_size
+    Mb = sz.serve_ctx // bs
+    C = sz.afmoe_chunk if chunked else 1
+    rng = np.random.RandomState(17)
+    kinds = model.page_kinds()
+    pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim, bs,
+                       [B * Mb] * len(kinds), entry=model.cache_entry(),
+                       kinds=kinds)
+    arrays = [jnp.asarray(rng.randn(*a.shape).astype(np.float32) * 0.3,
+                          pool.dtype) for a in pool.arrays]
+    tables = np.stack([rng.permutation(np.arange(1, B * Mb + 1))
+                       .reshape(B, Mb).astype(np.int32) for _ in kinds])
+    pos = rng.randint(Mb * bs // 2, Mb * bs - C, B).astype(np.int32)
+    toks = rng.randint(0, cfg.vocab_size, (B, C)).astype(np.int32)
+    on = np.ones(B, bool)
+    if chunked:
+        lens = np.where(np.arange(B) % 2, 1, C).astype(np.int32)
+        out = model.make_prefill_step(B, Mb, C, return_logits=True)(
+            model.weights, *arrays, toks, lens > 1, np.zeros(B, np.int32),
+            pos, lens, tables, on)
+    else:
+        out = model.make_decode_step(B, Mb, return_logits=True)(
+            model.weights, *arrays, toks[:, 0], on, np.zeros(B, np.int32),
+            pos, tables, on)
+    # (*pools', tokens, counters, top_logit, logits)
+    return np.asarray(out[-1]), np.asarray(out[-3])
+
+
+def leg_afmoe(sz, rehearsal):
+    def released(stats):
+        # prompts longer than the window: pages slid out and came back
+        assert stats["window_blocks_released"] > 0, stats
+        return {"window_blocks_released": stats["window_blocks_released"]}
+
+    return block_leg(sz, rehearsal, sz.afmoe, sz.afmoe_batch,
+                     sz.afmoe_chunk, AFMOE_KERNELS, afmoe_step_logits,
+                     seed=11, after_serving=released)
+
+
+# ---------------------------------------------------------------------------
+# kernels: the shapes the legs above use (tests/test_kernels_lower_tpu.py
 # lowers the same cases for the TPU from the sandbox)
 # ---------------------------------------------------------------------------
 
@@ -754,6 +849,65 @@ def kernel_cases(sz):
         [((n_tiles * bm, Dm), jnp.bfloat16), ((E, Dm, Fe), jnp.bfloat16),
          ((n_tiles,), i32), ((1,), i32)], {},
         dict(rows=n_tiles * bm, k=Dm, n=Fe), fill_gmm)
+
+    # the grouped-query block's three kernels at its widths: packed bf16
+    # pools of [bs, Hkv * Dh] pages (two layers, the second used), tiles
+    # deep in a context under a window, one-token tiles, an unused tile
+    ab = sz.afmoe["block"]
+    Ha, Hkv, Da = sz.afmoe["n_heads"], ab["n_kv_heads"], ab["head_dim"]
+    Ba, Ca = sz.afmoe_batch, min(128, sz.afmoe_chunk)
+    gpool = ((2, Ba * Mb + 1, bs, Hkv * Da), jnp.bfloat16)
+    win = ab["sliding_window"]
+
+    def gqa_layout(rng):
+        tables = rng.permutation(np.arange(1, Ba * Mb + 1)) \
+            .reshape(Ba, Mb).astype(i32)
+        room = Mb * bs
+        pos = rng.randint(win, room - Ca, Ba).astype(i32)
+        lens = np.where(np.arange(Ba) % 2, 1, Ca).astype(i32)
+        pos[0], lens[-1] = 0, 0
+        # entries the window has slid past may point anywhere
+        for b in range(1, Ba):
+            tables[b, :max(pos[b] - win + 1, 0) // bs] = 0
+        return tables, pos, lens
+
+    def gqa_pools(rng):
+        return [jnp.asarray(rng.randn(*gpool[0]).astype(f32), gpool[1])
+                for _ in range(2)]
+
+    def fill_gqa_chunk(rng):
+        tables, pos, lens = gqa_layout(rng)
+        return gqa_pools(rng) + [rng.randn(Ba, Ca, Ha, Da).astype(f32),
+                                 tables, pos, lens]
+
+    def fill_gqa_decode(rng):
+        tables, pos, _lens = gqa_layout(rng)
+        return gqa_pools(rng) + [rng.randn(Ba, Ha, Da).astype(f32),
+                                 tables, pos]
+
+    gq = dict(head_dim=Da, block_size=bs)
+    cases["gqa_chunk"] = (
+        [gpool, gpool, ((Ba, Ca, Ha, Da), f32), ((Ba, Mb), i32),
+         ((Ba,), i32), ((Ba,), i32)], {"layer": 1, "window": win}, gq,
+        fill_gqa_chunk)
+    cases["gqa_decode"] = (
+        [gpool, gpool, ((Ba, Ha, Da), f32), ((Ba, Mb), i32), ((Ba,), i32)],
+        {"layer": 1, "window": win}, gq, fill_gqa_decode)
+    Ua = 2 * Ba
+
+    def fill_page_write(rng):
+        ids = rng.permutation(np.arange(1, Ba * Mb + 1))[:Ua].astype(i32)
+        lo = rng.randint(0, bs, Ua).astype(i32)
+        hi = np.minimum(lo + rng.randint(0, bs + 1, Ua), bs).astype(i32)
+        ids[0], hi[0] = 0, lo[0]                   # an unused unit
+        rows = [jnp.asarray(rng.randn(Ua, bs, Hkv * Da).astype(f32),
+                            jnp.bfloat16) for _ in range(2)]
+        return gqa_pools(rng) + rows + [ids, lo, hi]
+
+    rows_spec = ((Ua, bs, Hkv * Da), jnp.bfloat16)
+    cases["kv_page_write"] = (
+        [gpool, gpool, rows_spec, rows_spec, ((Ua,), i32), ((Ua,), i32),
+         ((Ua,), i32)], {"layer": 1}, gq, fill_page_write)
 
     M, K, N = sz.int8_mkn
     cases["int8_matmul"] = (
@@ -965,6 +1119,8 @@ def main(argv=None):
         run_leg("serve", lambda: leg_serve(sz, rehearsal), clock, results)
         run_leg("latent", lambda: leg_latent(sz, rehearsal), clock,
                 results)
+        run_leg("afmoe", lambda: leg_afmoe(sz, rehearsal), clock,
+                results)
         run_leg("kernels", lambda: leg_kernels(sz, rehearsal), clock,
                 results)
         run_leg("rec", lambda: leg_rec(sz, rehearsal), clock, results)
@@ -979,8 +1135,8 @@ def main(argv=None):
     except Exception:
         traceback.print_exc()
     ok = (all(r["ok"] for r in results.values())
-          and set(results) >= {"device", "train", "serve", "latent", "kernels",
-                               "rec"})
+          and set(results) >= {"device", "train", "serve", "latent", "afmoe",
+                               "kernels", "rec"})
     summary = {
         "ok": ok,
         "device": ident._asdict(),

@@ -69,6 +69,17 @@ paged_chunk_attention: the chunked-prefill window's attention over the
   ``[B, C, H, T]`` exists, and a one-token tile computes one sublane
   tile of query rows.
 
+gqa_paged_decode_attention / gqa_paged_chunk_attention: the same two
+  page walks for a GROUPED-QUERY block over a packed bfloat16 pool
+  (``kernels 'gqa_decode'``, ``'gqa_chunk'``; one kernel body): fewer
+  cache heads than query heads, a page ``[block_size, Hkv * Dh]`` whose
+  lanes hold the cache heads side by side, the query heads of a group
+  one operand against their cache head's keys. A layer states the
+  WINDOW of positions it sees (a traced scalar: window and global
+  layers share a lowering); the walk starts at the first page the
+  tile's earliest query still sees. ``kv_page_write`` puts a step's new
+  K and V rows into such a pool in place, a whole page a grid step.
+
 Whether a kernel compiles or runs in the Pallas interpreter is decided in
 one place, ``core.device.pallas_interpret()``: compiled on TPU (a kernel
 Mosaic refuses raises), interpreted everywhere else so the CPU test mesh
@@ -95,7 +106,11 @@ __all__ = ["flash_attention", "flash_attention_portable",
            "int8_matmul_reference", "gmm", "gmm_reference",
            "latent_paged_attention", "latent_paged_attention_reference",
            "latent_write", "latent_write_reference",
-           "paged_chunk_attention", "paged_chunk_attention_reference"]
+           "paged_chunk_attention", "paged_chunk_attention_reference",
+           "gqa_paged_chunk_attention", "gqa_paged_decode_attention",
+           "gqa_paged_attention_reference",
+           "gqa_paged_decode_attention_reference", "kv_page_write",
+           "kv_page_write_reference"]
 
 _NEG_INF = -1e30
 
@@ -1388,6 +1403,417 @@ def latent_paged_attention_reference(pool, q, block_tables, positions,
 # table; importing this module is what populates the registry)
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# grouped-query paged attention (a window of positions or all of them) and
+# the in-place page write of a packed bf16 K/V pool
+# ---------------------------------------------------------------------------
+
+GQA_PAGES_PER_STEP = 4
+GQA_ALL_POSITIONS = 2 ** 30       # a `window` no context reaches
+
+
+def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
+                     v_hbm, o_ref, kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
+                     *, sm_scale, block_size, pages, n_kv, head_dim):
+    """Grid (tiles,): one query tile a grid step, as
+    ``_chunk_attn_kernel``, over a pool whose page is ``[block_size,
+    Hkv * Dh]`` (a cache head is a whole-lane-tile slice of the page's
+    lanes). A chunk tile arrives as the step has it, ``[Cq, H * Dh]``;
+    for each cache head the ``H // Hkv`` query heads of its group are
+    stacked into ONE operand of ``group * nq`` rows (head-major: row
+    ``r`` is the tile's token ``r % nq``) against that head's keys, so a
+    key tile is loaded into the MXU once a group and not once a query
+    head. ``nq`` is the tile's ``Cq`` slots, or one sublane tile of 16
+    where the tile holds one token. A ONE-TOKEN tile may also arrive
+    already grouped, ``[Hkv, R, Dh]`` (the decode call: the group's
+    query heads are the ``R`` rows of their cache head, all at the
+    tile's one position): its operands are ``R`` rows, not ``group *
+    16``, which is what keeps a decode row's arithmetic under its page
+    reads.
+
+    ``scal_ref`` holds the layer (its index in this pool's arrays) and
+    the WINDOW: a query at position ``p`` sees ``p - window < t <= p``.
+    The page walk starts at the first page the tile's earliest query
+    still sees (``max(pos0 - window + 1, 0) // block_size``: table
+    entries before it may be released, and are never read) and ends at
+    the page of the tile's last token. With ``window`` past every
+    context (:data:`GQA_ALL_POSITIONS`) it is plain causal attention
+    from page 0. Arithmetic as ``_chunk_attn_kernel``: operands of both
+    products bfloat16, softmax statistics and accumulations fp32; a
+    masked entry contributes exactly zero, so a run that lies wholly
+    outside a query's window leaves that query's statistics alone."""
+    t = pl.program_id(0)
+    bs, P, Dh = block_size, pages, head_dim
+    span = P * bs
+    grouped = len(q_ref.shape) == 4    # [1, Hkv, R, Dh]: one token
+    if grouped:
+        Cq = one = q_ref.shape[2]
+        G = 1
+    else:
+        Cq = q_ref.shape[1]
+        G = q_ref.shape[2] // (n_kv * Dh)
+        one = min(Cq, 16)              # a bf16 sublane tile of query rows
+    n_tok = len_ref[t]
+    pos0 = pos_ref[t]
+    layer, window = scal_ref[0], scal_ref[1]
+    first_page = jnp.maximum(pos0 - window + 1, 0) // bs
+    n_pages = (pos0 + jnp.maximum(n_tok, 1) - 1) // bs + 1 - first_page
+    n_runs = (n_pages + P - 1) // P
+
+    @pl.when(t == 0)
+    def _zero():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def copies(run, half, start):
+        def page(p, carry):
+            blk = tables_ref[t, first_page + run * P + p]
+            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
+            for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                copy = pltpu.make_async_copy(
+                    pool.at[layer, blk], buf.at[half, dst],
+                    sems.at[which, half])
+                copy.start() if start else copy.wait()
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+
+    def attend(run, half, nq):
+        R = G * nq
+        t_pos = (first_page + run * P) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (R, span), 1)
+        if grouped:                    # every row is the one token
+            q_pos = pos0
+        else:          # nq is a power of two: row r is token r % nq
+            q_pos = pos0 + (jax.lax.broadcasted_iota(
+                jnp.int32, (R, span), 0) & (nq - 1))
+        mask = (t_pos <= q_pos) & (t_pos > q_pos - window)
+        for g in range(n_kv):
+            q = q_ref[0, g] if grouped else jnp.concatenate(
+                [q_ref[0, :nq, (g * G + j) * Dh:(g * G + j + 1) * Dh]
+                 for j in range(G)], axis=0)               # [R, Dh] bf16
+            k = kbuf[half, :, g * Dh:(g + 1) * Dh]         # [span, Dh]
+            v = vbuf[half, :, g * Dh:(g + 1) * Dh]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_scr[g, :R, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[g, :R, :1] * alpha \
+                + p.sum(axis=-1, keepdims=True)
+            acc_scr[g, :R, :] = acc_scr[g, :R, :] * alpha \
+                + jax.lax.dot_general(
+                    p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            m_scr[g, :R, :] = jnp.broadcast_to(m_new, (R, m_scr.shape[2]))
+            l_scr[g, :R, :] = jnp.broadcast_to(l_new, (R, l_scr.shape[2]))
+
+    def finish(nq):
+        R = G * nq
+        live = (jax.lax.broadcasted_iota(jnp.int32, (R, Dh), 0)
+                & (nq - 1)) < n_tok
+        for g in range(n_kv):
+            if grouped:
+                o_ref[0, g] = acc_scr[g] / jnp.maximum(l_scr[g, :, :1],
+                                                       1e-30)
+                continue
+            out = jnp.where(live, acc_scr[g, :R, :]
+                            / jnp.maximum(l_scr[g, :R, :1], 1e-30), 0.0)
+            for j in range(G):
+                o_ref[0, :nq, (g * G + j) * Dh:(g * G + j + 1) * Dh] = \
+                    out[j * nq:(j + 1) * nq, :]
+
+    def by_size(fn):
+        """`fn(nq)` for the tile's size: one token, or a window."""
+        if one == Cq:
+            fn(Cq)
+        else:
+            pl.when(n_tok == 1)(lambda: fn(one))
+            pl.when(n_tok > 1)(lambda: fn(Cq))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tok > 0)
+    def _tile():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        copies(0, 0, True)
+
+        def one_run(run, carry):
+            half = run % 2
+
+            @pl.when(run + 1 < n_runs)
+            def _next():
+                copies(run + 1, 1 - half, True)
+
+            copies(run, half, False)
+            by_size(lambda nq: attend(run, half, nq))
+            return carry
+
+        jax.lax.fori_loop(0, n_runs, one_run, 0)
+        by_size(finish)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sm_scale", "pages", "n_kv", "name", "interpret"))
+def _gqa_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
+              window, *, sm_scale, pages, n_kv, name, interpret):
+    """q ``[N, Cq, H * Dh]`` (``Cq`` a power of two), or ``[N, Hkv, R,
+    Dh]`` (one token a tile, its query heads grouped by cache head) ->
+    the same shape, float32. Layer and window are traced scalars: one
+    lowering serves every layer of a pool, whichever positions they
+    keep."""
+    N = q.shape[0]
+    bs = k_pool.shape[2]
+    Dh = k_pool.shape[3] // n_kv
+    if q.ndim == 4:
+        rows = q.shape[2]
+    else:
+        Cq, HD = q.shape[1:]
+        rows = HD // (n_kv * Dh) * Cq   # a cache head's stacked query rows
+        if Cq & (Cq - 1):
+            raise ValueError("a query tile holds a power of two of "
+                             "slots, got %d" % Cq)
+    block = (1,) + q.shape[1:]
+
+    def tile(n, *_):
+        return (n,) + (0,) * (q.ndim - 1)
+
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    return pl.pallas_call(
+        functools.partial(_gqa_attn_kernel, sm_scale=sm_scale,
+                          block_size=bs, pages=pages, n_kv=n_kv,
+                          head_dim=Dh),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(N,),
+            in_specs=[pl.BlockSpec(block, tile), hbm, hbm],
+            out_specs=pl.BlockSpec(block, tile),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, n_kv * Dh), k_pool.dtype),
+                pltpu.VMEM((2, pages * bs, n_kv * Dh), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_kv, rows, 128), jnp.float32),
+                pltpu.VMEM((n_kv, rows, 128), jnp.float32),
+                pltpu.VMEM((n_kv, rows, Dh), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=96 * 1024 * 1024),
+        interpret=interpret,
+        name=name,
+    )(block_tables.astype(jnp.int32),
+      jnp.maximum(positions, 0).astype(jnp.int32),
+      lengths.astype(jnp.int32),
+      jnp.stack([layer, window]).astype(jnp.int32),
+      q.astype(jnp.bfloat16), k_pool, v_pool)
+
+
+def _gqa_window(window):
+    return jnp.asarray(GQA_ALL_POSITIONS if window is None else window,
+                       jnp.int32)
+
+
+def gqa_paged_chunk_attention(k_pool, v_pool, q, block_tables, positions,
+                              lengths, *, layer, window=None,
+                              sm_scale=None,
+                              pages_per_step=GQA_PAGES_PER_STEP):
+    """The chunk window's attention of a GROUPED-QUERY block over a
+    paged pool, the window cut into query tiles
+    (:func:`paged_chunk_attention`'s contract) with two differences.
+    The pools are ``[n_layers, num_blocks+1, block_size, Hkv * Dh]``
+    (bfloat16: a page is a packed 2-D tile whose lanes hold the cache
+    heads side by side), fewer cache heads than the ``H`` query heads of
+    q ``[N, Cq, H, Dh]`` (``Cq`` a power of two): query head ``n`` reads
+    cache head ``n // (H // Hkv)``. And a position sees the last
+    ``window`` positions only, its own among them (``None``: all): the
+    walk starts at the first page the tile's first query sees, so
+    block-table entries before it may point anywhere. ``layer`` and
+    ``window`` may be traced. Returns ``[N, Cq, H, Dh]`` float32, zero at
+    slots past a tile's length."""
+    N, Cq, H, Dh = q.shape
+    if sm_scale is None:
+        sm_scale = Dh ** -0.5
+    out = _gqa_call(k_pool, v_pool, q.reshape(N, Cq, H * Dh), block_tables,
+                    positions, lengths, jnp.asarray(layer, jnp.int32),
+                    _gqa_window(window), sm_scale=float(sm_scale),
+                    pages=int(min(pages_per_step, block_tables.shape[1])),
+                    n_kv=k_pool.shape[3] // Dh,
+                    name="gqa_paged_chunk_attention",
+                    interpret=_device.pallas_interpret())
+    return out.reshape(N, Cq, H, Dh)
+
+
+def gqa_paged_decode_attention(k_pool, v_pool, q, block_tables, positions,
+                               *, layer, window=None, active=None,
+                               sm_scale=None,
+                               pages_per_step=2 * GQA_PAGES_PER_STEP):
+    """One-token decode attention of a grouped-query block: q ``[B, H,
+    Dh]``, positions ``[B]``, over the pools of
+    :func:`gqa_paged_chunk_attention`. One grid step a row; the row's
+    live pages (from the first its position still sees, on a window
+    layer) come by DMA, and the query heads of a group, padded to one
+    sublane tile of 16 rows, are one operand against their cache head's
+    keys: the chunk kernel's body over one-token tiles that arrive
+    grouped by cache head. An inactive row is skipped and comes out
+    zero. Returns ``[B, H, Dh]`` float32."""
+    B, H, Dh = q.shape
+    n_kv = k_pool.shape[3] // Dh
+    G = H // n_kv
+    rows = -(-G // 16) * 16
+    if sm_scale is None:
+        sm_scale = Dh ** -0.5
+    if active is None:
+        active = jnp.ones((B,), jnp.int32)
+    qg = jnp.pad(q.reshape(B, n_kv, G, Dh),
+                 ((0, 0), (0, 0), (0, rows - G), (0, 0)))
+    out = _gqa_call(k_pool, v_pool, qg, block_tables, positions,
+                    active.astype(jnp.int32),
+                    jnp.asarray(layer, jnp.int32), _gqa_window(window),
+                    sm_scale=float(sm_scale),
+                    pages=int(min(pages_per_step, block_tables.shape[1])),
+                    n_kv=n_kv, name="gqa_paged_decode_attention",
+                    interpret=_device.pallas_interpret())
+    return out[:, :, :G].reshape(B, H, Dh)
+
+
+def gqa_paged_attention_reference(k_pool, v_pool, q, block_tables,
+                                  positions, lengths, *, layer,
+                                  window=None, sm_scale=None,
+                                  pages_per_step=None):
+    """The lax fallback of both grouped-query kernels over query tiles
+    ``[N, Cq, H, Dh]``: the tile's block-table line gathered, cache
+    heads repeated over their groups, softmax under the causal band.
+    Released table entries gather the null page, which the band masks."""
+    N, Cq, H, Dh = q.shape
+    bs = k_pool.shape[2]
+    n_kv = k_pool.shape[3] // Dh
+    if sm_scale is None:
+        sm_scale = Dh ** -0.5
+    window = _gqa_window(window)
+
+    def ctx(pool):
+        g = _gathered_context(pool, layer, block_tables) \
+            .astype(jnp.float32).reshape(N, -1, n_kv, Dh)
+        return jnp.repeat(g, H // n_kv, axis=2)            # [N, T, H, Dh]
+
+    k_ctx, v_ctx = ctx(k_pool), ctx(v_pool)
+    slots = jnp.arange(Cq, dtype=jnp.int32)[None, :]
+    q_pos = (jnp.maximum(positions, 0)[:, None] + slots)[:, :, None]
+    t_ids = jnp.arange(block_tables.shape[1] * bs)[None, None, :]
+    seen = (t_ids <= q_pos) & (t_ids > q_pos - window)     # [N, Cq, T]
+    s = jnp.einsum("nchd,nthd->ncht", q.astype(jnp.float32), k_ctx) \
+        * sm_scale
+    s = jnp.where(seen[:, :, None, :], s, -jnp.inf)
+    w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    out = jnp.einsum("ncht,nthd->nchd", w, v_ctx)
+    return jnp.where((slots < lengths[:, None])[:, :, None, None], out, 0.0)
+
+
+def gqa_paged_decode_attention_reference(k_pool, v_pool, q, block_tables,
+                                         positions, *, layer, window=None,
+                                         active=None, sm_scale=None,
+                                         pages_per_step=None):
+    B = q.shape[0]
+    lengths = (jnp.ones((B,), jnp.int32) if active is None
+               else active.astype(jnp.int32))
+    return gqa_paged_attention_reference(
+        k_pool, v_pool, q[:, None], block_tables, positions, lengths,
+        layer=layer, window=window, sm_scale=sm_scale)[:, 0]
+
+
+def _kv_page_write_kernel(blk_ref, lo_ref, hi_ref, layer_ref, krows_ref,
+                          vrows_ref, kpage_ref, vpage_ref, ko_ref, vo_ref):
+    """Grid (units,): one page of K and one of V a grid step, found by
+    the index map (the null page for an unused unit). The page is read,
+    its rows ``lo <= r < hi`` take the unit's new rows, and it is
+    written back over itself."""
+    u = pl.program_id(0)
+    shape = kpage_ref.shape[2:]
+    r = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    hit = (r >= lo_ref[u]) & (r < hi_ref[u])
+    for rows_ref, page_ref, o_ref in ((krows_ref, kpage_ref, ko_ref),
+                                      (vrows_ref, vpage_ref, vo_ref)):
+        rows = jnp.broadcast_to(rows_ref[0].astype(jnp.float32), shape)
+        o_ref[0, 0] = jnp.where(hit, rows, page_ref[0, 0].astype(
+            jnp.float32)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_page_write_call(k_pool, v_pool, k_rows, v_rows, page_ids, lo, hi,
+                        layer, *, interpret):
+    U, n_rows, W = k_rows.shape
+    bs = k_pool.shape[2]
+
+    def page(u, blk, lo, hi, layer):
+        return (layer[0], blk[u], 0, 0)
+
+    rows = pl.BlockSpec((1, n_rows, W), lambda u, *_: (u, 0, 0))
+    pool = pl.BlockSpec((1, 1, bs, W), page)
+    return pl.pallas_call(
+        _kv_page_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(U,),
+            in_specs=[rows, rows, pool, pool],
+            out_specs=[pool, pool]),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        # operands 6 and 7 (after four prefetched scalars and the rows)
+        # are the pools: each result is the same buffer
+        input_output_aliases={6: 0, 7: 1},
+        interpret=interpret,
+        name="kv_page_write",
+    )(page_ids.astype(jnp.int32), lo.astype(jnp.int32),
+      hi.astype(jnp.int32), layer.reshape(1),
+      k_rows.astype(k_pool.dtype), v_rows.astype(v_pool.dtype),
+      k_pool, v_pool)
+
+
+def kv_page_write(k_pool, v_pool, k_rows, v_rows, page_ids, lo, hi, *,
+                  layer):
+    """Write new K and V rows into a paged pool IN PLACE, whole pages at
+    a time (the pools are aliased to the results).
+
+    k_pool/v_pool: ``[n_layers, num_blocks+1, block_size, W]`` WHOLE.
+    The write comes as UNITS, one page each: unit ``u`` rewrites rows
+    ``lo[u] <= r < hi[u]`` of page ``page_ids[u]`` of ``layer`` with
+    ``k_rows[u, r]`` / ``v_rows[u, r]`` (``[U, block_size, W]``; or
+    ``[U, 1, W]``: the one new row of a decode step, which goes where
+    ``lo`` says). No two units of a call may name the same page, the
+    null page (``lo == hi``: an unused unit) excepted. As
+    :func:`latent_write`: XLA's own scatter into a packed bfloat16 pool
+    changes the pool's layout and copies it; this reads and rewrites
+    the touched pages only (docs/KERNELS.md)."""
+    return _kv_page_write_call(k_pool, v_pool, k_rows, v_rows, page_ids,
+                               lo, hi, jnp.asarray(layer, jnp.int32),
+                               interpret=_device.pallas_interpret())
+
+
+def kv_page_write_reference(k_pool, v_pool, k_rows, v_rows, page_ids, lo,
+                            hi, *, layer):
+    """The lax fallback: one scatter a pool; rows outside ``[lo, hi)``
+    are dropped (an index past the pool)."""
+    U, n_rows, _W = k_rows.shape
+    bs = k_pool.shape[2]
+    r = jnp.arange(bs, dtype=jnp.int32)[None, :]
+    hit = (r >= lo[:, None]) & (r < hi[:, None])
+    blk = jnp.where(hit, page_ids[:, None], k_pool.shape[1])
+    src = jnp.zeros((U, bs), jnp.int32) if n_rows == 1 else \
+        jnp.broadcast_to(r, (U, bs))
+    take = jnp.arange(U)[:, None]
+
+    def put(pool, rows):
+        return pool.at[layer, blk, jnp.broadcast_to(r, (U, bs))].set(
+            rows[take, src].astype(pool.dtype), mode="drop")
+
+    return put(k_pool, k_rows), put(v_pool, v_rows)
+
+
 
 def _flash_qualify(T=None, Tk=None, head_dim=None, causal=False):
     """The compat_ops.py gate, promoted and FIXED: the historical check
@@ -1465,6 +1891,17 @@ def _latent_qualify(width=None, v_width=None, block_size=None,
     return True, None
 
 
+def _gqa_qualify(head_dim=None, block_size=None, window=None):
+    """Pages are packed bfloat16 tiles ``[block_size, Hkv * head_dim]``:
+    whole sublane tiles of 16 rows, a cache head a whole lane tile."""
+    if head_dim is not None and head_dim % 128:
+        return False, "head_dim not a multiple of 128 (a cache head is " \
+                      "a lane slice of the page)"
+    if block_size is not None and block_size % 16:
+        return False, "block_size not a multiple of 16 (packed bf16 pages)"
+    return True, None
+
+
 def _register_all():
     from .kernel_registry import register_kernel
 
@@ -1527,6 +1964,26 @@ def _register_all():
         doc="a window's rows written into the paged latent pool page by "
             "page, in place (XLA's scatter into a packed bf16 pool "
             "copies the pool); default: TPU only")
+    register_kernel(
+        "gqa_decode", gqa_paged_decode_attention,
+        gqa_paged_decode_attention_reference,
+        qualify=_gqa_qualify, default_on=_device.on_tpu,
+        doc="one-token grouped-query attention over packed bf16 pages, "
+            "a group's query heads one operand against its cache head, "
+            "the walk from the row's first live page on a window layer; "
+            "default: TPU only")
+    register_kernel(
+        "gqa_chunk", gqa_paged_chunk_attention,
+        gqa_paged_attention_reference,
+        qualify=_gqa_qualify, default_on=_device.on_tpu,
+        doc="the chunk window's grouped-query attention over query "
+            "tiles under the causal band of a window layer (or plain "
+            "causal); default: TPU only")
+    register_kernel(
+        "kv_page_write", kv_page_write, kv_page_write_reference,
+        qualify=_gqa_qualify, default_on=_device.on_tpu,
+        doc="new K and V rows written into a packed bf16 paged pool a "
+            "whole page at a time, in place; default: TPU only")
     register_kernel(
         "int8_matmul", int8_matmul, int8_matmul_reference,
         qualify=_int8_qualify, default_on=_device.on_tpu,

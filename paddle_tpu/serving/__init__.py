@@ -15,7 +15,11 @@ or a pluggable draft model — verified in one batched target step,
 ``spec_k=`` / ``$PTPU_SERVE_SPEC_K``). A second decoder block,
 latent attention over a paged latent cache with sigmoid-routed experts
 (``GenerationConfig(block=LatentMoEBlock(...))``, ``latent_moe.py``),
-runs through the same engine, scheduler and pool accounting.
+runs through the same engine, scheduler and pool accounting; so does a
+third, grouped-query attention over window and global layers that keep
+their cache in two kinds of page (``AfmoeBlock``, ``afmoe.py``: found
+by ``block.kind`` and imported when first named, so a server of another
+block never loads it).
 ``native_serve`` remains the
 Python-free deployment backend for the same exported artifact
 directory.
@@ -27,7 +31,7 @@ directory.
 """
 
 from .engine import ServingEngine  # noqa: F401
-from .kv_cache import (CacheEntry, KVBlockPool,  # noqa: F401
+from .kv_cache import (CacheEntry, KVBlockPool, PageKind,  # noqa: F401
                        blocks_needed, prefix_chain_keys)
 from .latent_moe import LatentMoEBlock  # noqa: F401
 from .loadgen import PoissonLoadGenerator  # noqa: F401
@@ -45,8 +49,21 @@ from .scheduler import (AdmissionError,  # noqa: F401
                         RequestQueue, StepScheduler,
                         spec_tree_acceptance)
 
+
+
+def __getattr__(name):
+    # the third block, lazily: `serving.AfmoeBlock` loads its module
+    if name == "AfmoeBlock":
+        from .afmoe import AfmoeBlock
+
+        return AfmoeBlock
+    raise AttributeError("module %r has no attribute %r"
+                         % (__name__, name))
+
+
 __all__ = ["ServingEngine", "ServingRouter", "RouterRequest",
-           "KVBlockPool", "CacheEntry", "LatentMoEBlock", "blocks_needed",
+           "KVBlockPool", "CacheEntry", "PageKind", "LatentMoEBlock",
+           "AfmoeBlock", "blocks_needed",
            "prefix_chain_keys",
            "PoissonLoadGenerator", "GenerationConfig", "GenerationModel",
            "GenerationArtifactError", "ModelDrafter", "NGramDrafter",

@@ -136,16 +136,28 @@ class _ModelWorker:
         self.engine = engine
         cfg = model.config
         max_seq_len = min(int(max_seq_len), cfg.max_seq_len)
-        if num_blocks is None:
+        kinds = model.page_kinds()
+        if kinds and (prefix_cache or spec_k or spec_tree
+                      or drafter is not None):
+            raise NotImplementedError(
+                "model %r keeps pages of %d kinds (%s): the prefix cache "
+                "and speculative, tree and draft windows are not built "
+                "over released window pages (ROADMAP Queue 2a)"
+                % (name, len(kinds), ", ".join(k.name for k in kinds)))
+        if not isinstance(num_blocks, dict):
             # default: enough cache for every slot to run a full-length
             # sequence concurrently (no admission stalls from the pool)
-            num_blocks = max_batch * blocks_needed(max_seq_len,
-                                                   block_size)
+            full = max_batch * blocks_needed(max_seq_len, block_size)
+            first = full if num_blocks is None else num_blocks
+            # an int (or nothing) sizes the kind that keeps every
+            # position; a window kind then never gates admission
+            num_blocks = {k.name: first if k.window is None else full
+                          for k in kinds} if kinds else first
         # the model says what one token's cache entry is; the pool's
         # accounting is the same for every entry
         self.pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
                                 block_size, num_blocks,
-                                entry=model.cache_entry())
+                                entry=model.cache_entry(), kinds=kinds)
         self.prefix_cache = bool(prefix_cache)
         # speculative decoding: the verify window is a compiled shape,
         # clamped so a full window always fits the context. A tree
@@ -287,11 +299,11 @@ class _ModelWorker:
         # overhang) — delegating keeps the two checks mirrored, so a
         # submittable request can never deadlock the head of the queue
         worst = self.scheduler._budget_for(request)
-        if worst > self.pool.blocks_total:
+        if not self.pool.could_hold(worst):
             raise AdmissionError(
-                "request needs %d KV blocks but the pool holds %d — "
+                "request needs %s KV blocks but the pool holds %s — "
                 "shorten the request or grow num_blocks"
-                % (worst, self.pool.blocks_total))
+                % (worst, self.pool.kind_totals()))
         # the liveness checks and the enqueue are one atomic region
         # under the worker's condition lock: the worker only exits (or
         # drains the queue on death) while holding the same lock, so a
@@ -690,21 +702,29 @@ class _ModelWorker:
             # array) and returns them updated, then its tokens, then
             # whatever counters its block reduces on the device
             arrays = self.pool.arrays
+            # one block table, or where the pool keeps pages of several
+            # kinds the stack of them, a table a kind
+            tables = (sched.block_tables if len(self.pool.kinds) == 1
+                      else sched.kind_tables).copy()
             if mixed:
                 out = self._chunk_step(
                     weights, *arrays,
                     sched.chunk_feed.copy(), sched.use_prompt.copy(),
                     self._prev_tokens, sched.positions.copy(),
-                    sched.chunk_lens.copy(), sched.block_tables.copy(),
-                    sched.active.copy())
+                    sched.chunk_lens.copy(), tables, sched.active.copy())
             else:
                 out = self._step(
                     weights, *arrays, *self._no_prompt,
-                    self._prev_tokens, sched.positions.copy(),
-                    sched.block_tables.copy(), sched.active.copy())
+                    self._prev_tokens, sched.positions.copy(), tables,
+                    sched.active.copy())
             self.pool.arrays = tuple(out[:len(arrays)])
             next_tokens = out[len(arrays)]
-            counters = out[len(arrays) + 1:]
+            # then what the block's steps hand back beside their
+            # tokens: its device counters, each token's own logit
+            extras = list(out[len(arrays) + 1:])
+            counters = extras.pop(0) if self.model.step_counters else None
+            top_logits = (extras.pop(0) if self.model.returns_top_logit
+                          else None)
         self._steps_dispatched += 1
         rec = None
         if tick is not None:
@@ -726,7 +746,9 @@ class _ModelWorker:
             rec["weight_params"] = self.model.dot_operand_params
             if mixed:
                 rec["rows_computed"] = self._chunk_rows
-            else:
+            if len(self.pool.kinds) > 1:
+                rec.update(self._pages_walked_by_kind())
+            elif not mixed:
                 # the pages a decode kernel that walks each row's own
                 # pages copies, beside the grid steps of one that visits
                 # every table slot of every row
@@ -735,8 +757,8 @@ class _ModelWorker:
                      // self.pool.block_size + 1).sum())
                 rec["pages_grid"] = (self.max_batch
                                      * sched.max_blocks_per_seq)
-            if counters:
-                rec["_counters"] = counters[0]   # read when consumed
+            if counters is not None:
+                rec["_counters"] = counters      # read when consumed
             if _tracing.enabled():
                 # request-scoped view of the same step: one window event
                 # per traced request riding this dispatch, so a
@@ -754,7 +776,7 @@ class _ModelWorker:
                         t0, t1, trace_id=tid, request=seq.request.id,
                         model=self.name)
         self._prev_tokens = next_tokens
-        self._inflight.append((next_tokens, plan, rec))
+        self._inflight.append((next_tokens, plan, rec, top_logits))
         _metrics.gauge("serving/inflight_steps").set(len(self._inflight))
         now = time.perf_counter()
         if self._t_first_step is None:
@@ -772,6 +794,47 @@ class _ModelWorker:
             reg.counter("serving/prefill_tokens").inc(
                 rec["prefill_tokens"])
             reg.counter("serving/decode_tokens").inc(rec["decode_tokens"])
+
+    def _pages_walked_by_kind(self):
+        """What the step just planned makes the attention kernels walk,
+        by page kind (the step log's ``<kind>_pages_walked``, summed
+        over rows and the kind's layers): each active row's pages from the
+        first its earliest query still sees to the page of its last
+        token. ``window_pages_full`` is what the window kinds' walk
+        would be from position 0. ``<kind>_keys_attended``: the keys
+        the rows' queries see between them, again over the kind's
+        layers, which is the attention's arithmetic. ``chunk_pages_walked``
+        and ``chunk_keys_attended``: the same two over every kind, of the
+        rows that hold more than one token alone (a mixed step's chunk
+        kernel; its one-token rows go through the decode kernel)."""
+        sched, bs = self.scheduler, self.pool.block_size
+        on = sched.active
+        pos0 = sched.positions[on].astype(np.int64)
+        n = np.maximum(sched.chunk_lens[on].astype(np.int64), 1)
+        last_page = (pos0 + n - 1) // bs
+        full_keys = n * pos0 + n * (n + 1) // 2
+        out = {"window_pages_full": 0, "chunk_pages_walked": 0,
+               "chunk_keys_attended": 0}
+        chunk = n > 1
+        for kind in self.pool.kinds:
+            layers = len(kind.layers)
+            if kind.window is None:
+                first, keys = 0, full_keys
+            else:
+                w = kind.window
+                first = np.maximum(pos0 - w + 1, 0) // bs
+                # query c sees min(pos0 + c + 1, w) keys
+                ramp = np.clip(w - pos0, 0, n)   # queries that see all
+                keys = (ramp * pos0 + ramp * (ramp + 1) // 2
+                        + (n - ramp) * w)
+                out["window_pages_full"] += layers * int(
+                    (last_page + 1).sum())
+            pages = last_page - first + 1
+            out[kind.name + "_pages_walked"] = layers * int(pages.sum())
+            out[kind.name + "_keys_attended"] = layers * int(keys.sum())
+            out["chunk_pages_walked"] += layers * int(pages[chunk].sum())
+            out["chunk_keys_attended"] += layers * int(keys[chunk].sum())
+        return out
 
     def _dispatch_spec(self, plan):
         """Dispatch one speculative verify window and fold it back
@@ -904,16 +967,20 @@ class _ModelWorker:
         return n_emitted
 
     def _process_oldest(self):
-        handle, plan, rec = self._inflight.pop(0)
+        handle, plan, rec, top_logits = self._inflight.pop(0)
         _metrics.gauge("serving/inflight_steps").set(len(self._inflight))
         tick = self._tick_log if rec is not None else None
         tokens, waited = self._materialize(tick, handle)
+        if top_logits is not None:
+            # the same step made them: there once its tokens are
+            top_logits = np.asarray(top_logits)
         with _phase(tick, "stream"):
             for seq, gen_idx in plan:
                 was_done = seq.request.finished
                 had_first = seq.request.first_token_time is not None
-                self.scheduler.record_token(seq, gen_idx,
-                                            tokens[seq.slot])
+                self.scheduler.record_token(
+                    seq, gen_idx, tokens[seq.slot],
+                    None if top_logits is None else top_logits[seq.slot])
                 if (not had_first
                         and seq.request.first_token_time is not None):
                     self._note_first_token(seq.request)

@@ -64,6 +64,23 @@ Prefix caching (docs/SERVING.md) makes the pool *content-addressed*:
     revive them without recomputation. ``alloc_block`` evicts from the
     LRU (dropping the index entry) only once the free list is empty.
 
+Pages of more than one KIND (docs/SERVING.md, "Two kinds of page"). A
+model whose layers do not all keep the same positions states its kinds
+(:class:`PageKind`): a ``window`` kind keeps only the last ``window``
+positions of a sequence, the others keep all. Each kind has device
+arrays of its own (its layers, its block count) and a free list of its
+own, under the ONE lock, owner set, ``reserve`` / ``alloc_block`` /
+``free_owner`` / ``truncate_owner`` / ``check_invariants`` / ``stats``
+of this pool: a reservation covers every kind or none, and
+``release_head`` hands a window kind's pages back as a sequence's
+positions slide out of the window (the owner's reservation of that kind
+grows back by as many, the inverse of ``alloc_block`` from the other
+end). A block table of a window kind is indexed by the logical page
+(``position // block_size``) like any other; released entries point at
+the null block. Kinds past the first are plain: no sharing, no content
+index (the engine refuses the prefix cache with them). A pool of one
+kind is the pool described above, attribute for attribute.
+
 Reservation conservation survives sharing (pinned by test):
 ``blocks_free(+cached) - reserved >= 0`` at every point, and
 ``free + cached + owned + shared == total`` — reviving a cached block
@@ -75,7 +92,7 @@ allocation, so outstanding reservations can never be left unbacked
 import hashlib
 from collections import OrderedDict
 
-__all__ = ["CacheEntry", "KVBlockPool", "blocks_needed",
+__all__ = ["CacheEntry", "KVBlockPool", "PageKind", "blocks_needed",
            "prefix_chain_keys"]
 
 
@@ -102,6 +119,59 @@ class CacheEntry:
 
     def __repr__(self):
         return "CacheEntry(%r, %r)" % (self.parts, self.dtype)
+
+
+class PageKind:
+    """One kind of page: its name, the layers (indices into the model's
+    layers) whose cache lives in it, and ``window``: how many positions
+    back a position of those layers sees, itself included (``None``:
+    all of them, so nothing is ever released)."""
+
+    __slots__ = ("name", "layers", "window")
+
+    def __init__(self, name, layers, window=None):
+        self.name = str(name)
+        self.layers = tuple(int(i) for i in layers)
+        self.window = None if window is None else int(window)
+        if not self.layers:
+            raise ValueError("page kind %r holds no layer" % self.name)
+
+    def first_live_page(self, position, block_size):
+        """The first logical page a query at ``position`` still reads."""
+        if self.window is None:
+            return 0
+        return max(int(position) - self.window + 1, 0) // int(block_size)
+
+    def __repr__(self):
+        return "PageKind(%r, %r, %r)" % (self.name, self.layers,
+                                         self.window)
+
+
+class _KindPages:
+    """The accounting of one kind of page past the first: a free list,
+    each owner's reservation and table. ``owned[owner]`` holds the LIVE
+    block ids in table order; ``head[owner]`` counts the logical pages
+    released in front of them, so entry ``i`` is logical page
+    ``head + i``. Mutated under the pool's lock only."""
+
+    def __init__(self, kind, num_blocks):
+        if num_blocks < 1:
+            raise ValueError("page kind %r needs at least one usable "
+                             "block" % kind.name)
+        self.kind = kind
+        self.num_blocks = int(num_blocks)
+        self.free = list(range(self.num_blocks, 0, -1))
+        self.reserved = {}
+        self.owned = {}
+        self.head = {}
+        self.ceiling = {}
+        self.released = 0        # cumulative, by release_head
+
+    def available(self):
+        return len(self.free) - sum(self.reserved.values())
+
+    def in_use(self):
+        return self.num_blocks - len(self.free)
 
 
 def blocks_needed(num_tokens, block_size):
@@ -144,10 +214,37 @@ class KVBlockPool:
     NULL_BLOCK = 0
 
     def __init__(self, n_layers, n_heads, head_dim, block_size,
-                 num_blocks, dtype=None, device=None, entry=None):
+                 num_blocks, dtype=None, device=None, entry=None,
+                 kinds=None):
         """``entry`` is the model's :class:`CacheEntry`; without one the
         pool holds K and V ``[n_heads, head_dim]`` a token. ``dtype``,
-        when given, overrides the entry's."""
+        when given, overrides the entry's. ``kinds`` (a sequence of
+        :class:`PageKind`, the model's) splits the layers over kinds of
+        page; ``num_blocks`` is then ``{kind name: usable blocks}`` or a
+        sequence in the kinds' order."""
+        kinds = tuple(kinds) if kinds else (
+            PageKind("all", range(int(n_layers))),)
+        if len(kinds) > 1:
+            if isinstance(num_blocks, dict):
+                num_blocks = [num_blocks[k.name] for k in kinds]
+            num_blocks = [int(n) for n in num_blocks]
+            if len(num_blocks) != len(kinds):
+                raise ValueError("one block count a page kind: %r for %r"
+                                 % (num_blocks, kinds))
+            if sorted(i for k in kinds for i in k.layers) \
+                    != list(range(int(n_layers))):
+                raise ValueError("page kinds %r do not partition %d "
+                                 "layers" % (kinds, n_layers))
+            if kinds[0].window is not None:
+                raise ValueError("the first page kind keeps every "
+                                 "position (window None)")
+            extra = [_KindPages(k, n)
+                     for k, n in zip(kinds[1:], num_blocks[1:])]
+            num_blocks = num_blocks[0]
+        else:
+            extra = []
+        self.kinds = kinds
+        self._extra = extra
         if num_blocks < 1:
             raise ValueError("KVBlockPool needs at least one usable block")
         if block_size < 1:
@@ -168,13 +265,17 @@ class KVBlockPool:
 
         # via jnp so that bfloat16 (no numpy type of its own) is a name
         self.dtype = jnp.dtype(dtype if dtype is not None else entry.dtype)
-        lead = (self.n_layers, self.num_blocks + 1, self.block_size)
         with (jax.default_device(device) if device is not None
               else contextlib.nullcontext()):
-            # one device array per part, in the entry's order; the steps
-            # take and return them as a tuple
-            self.arrays = tuple(jnp.zeros(lead + shape, self.dtype)
-                                for _name, shape in entry.parts)
+            # one device array per part, in the entry's order (kind by
+            # kind where there are several); the steps take and return
+            # them as a tuple
+            self.arrays = tuple(
+                jnp.zeros((len(kind.layers), n + 1, self.block_size)
+                          + shape, self.dtype)
+                for kind, n in zip(kinds, [self.num_blocks]
+                                   + [x.num_blocks for x in extra])
+                for _name, shape in entry.parts)
 
         from ..analysis.concurrency import make_lock
 
@@ -252,7 +353,13 @@ class KVBlockPool:
             reserved = sum(self._reserved.values())
             owned = sum(1 for r in self._refs.values() if r == 1)
             shared = len(self._refs) - owned
-        return {
+            by_kind = {x.kind.name: {"blocks_total": x.num_blocks,
+                                     "blocks_in_use": x.in_use(),
+                                     "blocks_reserved":
+                                         sum(x.reserved.values()),
+                                     "blocks_released": x.released}
+                       for x in self._extra}
+        out = {
             "blocks_total": self.num_blocks,
             "blocks_in_use": owned + shared,
             "blocks_owned": owned,
@@ -264,10 +371,47 @@ class KVBlockPool:
             "truncate_calls": self.truncate_calls,
             "blocks_truncated": self.blocks_truncated,
         }
+        if by_kind:
+            # the figures above are the first kind's; every kind's own
+            # are under its name, the first among them
+            by_kind = {self.kinds[0].name: {
+                "blocks_total": self.num_blocks,
+                "blocks_in_use": owned + shared,
+                "blocks_reserved": reserved, "blocks_released": 0},
+                **by_kind}
+            out["kinds"] = by_kind
+            out["window_blocks_released"] = sum(
+                k["blocks_released"] for k in by_kind.values())
+        return out
 
     # -- admission-side API --------------------------------------------
+    def _per_kind(self, n):
+        """``n`` as one count a kind (an int: the first kind's)."""
+        if not hasattr(n, "__len__"):
+            n = (int(n),) + (0,) * len(self._extra)
+        n = tuple(int(c) for c in n)
+        if len(n) != len(self.kinds):
+            raise ValueError("%d counts for %d page kinds"
+                             % (len(n), len(self.kinds)))
+        return n
+
+    def kind_totals(self):
+        """Usable blocks, a count a page kind."""
+        return (self.num_blocks,) + tuple(x.num_blocks
+                                          for x in self._extra)
+
+    def could_hold(self, n):
+        """Whether an EMPTY pool could cover the reservation ``n``."""
+        return all(c <= total for c, total in
+                   zip(self._per_kind(n), self.kind_totals()))
+
     def can_reserve(self, n):
-        return self.blocks_free >= int(n)
+        n = self._per_kind(n)
+        with self._lock:
+            return all(x.available() >= c
+                       for x, c in zip(self._extra, n[1:])) and (
+                len(self._free) + len(self._cached)
+                - sum(self._reserved.values()) >= n[0])
 
     def reserve(self, owner, n, prefix_keys=None):
         """Reserve ``n`` worst-case blocks for ``owner``. Returns False
@@ -280,8 +424,11 @@ class KVBlockPool:
         only ``n - matched`` blocks are actually reserved. Reviving a
         refcount-zero cached block is charged against availability like
         an allocation, so reservations already outstanding stay backed.
+
+        With several page kinds ``n`` is one count a kind, and every
+        kind's count is reserved or none is.
         """
-        n = int(n)
+        n, *n_extra = self._per_kind(n)
         with self._lock:
             if owner in self._reserved or owner in self._owned:
                 raise ValueError("owner %r already holds a reservation"
@@ -298,8 +445,14 @@ class KVBlockPool:
             need = max(n - len(matched), 0)
             avail = (len(self._free) + len(self._cached)
                      - sum(self._reserved.values()))
-            if avail < need + revive:
+            if avail < need + revive or any(
+                    x.available() < c
+                    for x, c in zip(self._extra, n_extra)):
                 return False
+            for x, c in zip(self._extra, n_extra):
+                x.reserved[owner] = x.ceiling[owner] = c
+                x.owned[owner] = []
+                x.head[owner] = 0
             for bid in matched:
                 r = self._refs.get(bid, 0)
                 if r == 0:
@@ -310,12 +463,24 @@ class KVBlockPool:
             self._reserve_ceiling[owner] = need + len(matched)
             return True
 
-    def alloc_block(self, owner):
+    def alloc_block(self, owner, kind=0):
         """Hand one physical block id to ``owner``, drawn from its
         reservation (appends to the owner's block table). Evicts the
         least-recently-freed cached prefix block when the free list is
-        empty (its content-index entry is dropped)."""
+        empty (its content-index entry is dropped). ``kind`` indexes
+        ``self.kinds``; each kind's ids are its own arrays' pages."""
         with self._lock:
+            if kind:
+                x = self._extra[kind - 1]
+                if x.reserved.get(owner, 0) <= 0:
+                    raise RuntimeError(
+                        "owner %r has no remaining reservation of %s "
+                        "pages (release_head comes before alloc_block)"
+                        % (owner, x.kind.name))
+                bid = x.free.pop()
+                x.reserved[owner] -= 1
+                x.owned[owner].append(bid)
+                return bid
             if self._reserved.get(owner, 0) <= 0:
                 raise RuntimeError(
                     "owner %r has no remaining reservation — the "
@@ -332,9 +497,42 @@ class KVBlockPool:
             self._owned[owner].append(bid)
             return bid
 
-    def block_table(self, owner):
+    def block_table(self, owner, kind=0):
+        """The owner's block ids in table order; of a window kind the
+        LIVE ones (``pages_released`` logical pages lie before them)."""
         with self._lock:
+            if kind:
+                return list(self._extra[kind - 1].owned.get(owner, ()))
             return list(self._owned.get(owner, ()))
+
+    def pages_released(self, owner, kind):
+        with self._lock:
+            return self._extra[kind - 1].head.get(owner, 0) if kind else 0
+
+    def release_head(self, owner, kind, first_live):
+        """Hand back ``owner``'s pages of a window kind whose logical
+        page number is below ``first_live``: the positions they hold
+        have slid out of the window of every query still to come. The
+        owner's reservation of the kind grows back by as many (the
+        inverse of ``alloc_block``), so a row's live pages plus its
+        reservation never exceed what ``reserve`` gave it. Returns the
+        released block ids, oldest first."""
+        if not kind or self.kinds[kind].window is None:
+            raise ValueError("page kind %r keeps every position"
+                             % self.kinds[kind].name)
+        with self._lock:
+            x = self._extra[kind - 1]
+            blocks = x.owned.get(owner)
+            if blocks is None:
+                raise KeyError("owner %r holds no block table" % (owner,))
+            n = min(max(int(first_live) - x.head[owner], 0), len(blocks))
+            dropped = blocks[:n]
+            del blocks[:n]
+            x.head[owner] += n
+            x.free.extend(reversed(dropped))
+            x.reserved[owner] += n
+            x.released += n
+            return dropped
 
     def free_owner(self, owner):
         """Drop ``owner``'s references and release the unused part of
@@ -347,6 +545,10 @@ class KVBlockPool:
         as unmatchable dead index entries. Idempotent. Returns the
         number of blocks the owner's table held."""
         with self._lock:
+            for x in self._extra:
+                x.free.extend(reversed(x.owned.pop(owner, [])))
+                for d in (x.reserved, x.head, x.ceiling):
+                    d.pop(owner, None)
             blocks = self._owned.pop(owner, [])
             self._reserved.pop(owner, None)
             self._reserve_ceiling.pop(owner, None)
@@ -388,6 +590,13 @@ class KVBlockPool:
             blocks = self._owned.get(owner)
             if blocks is None:
                 raise KeyError("owner %r holds no block table" % (owner,))
+            for x in self._extra:
+                # the same logical pages, of every kind
+                tail = x.owned[owner]
+                keep = max(n_keep - x.head[owner], 0)
+                x.free.extend(reversed(tail[keep:]))
+                x.reserved[owner] += len(tail[keep:])
+                del tail[keep:]
             if n_keep >= len(blocks):
                 return []
             dropped = blocks[n_keep:]
@@ -442,6 +651,8 @@ class KVBlockPool:
         """
         problems = []
         with self._lock:
+            for x in self._extra:
+                problems.extend(self._check_kind(x))
             free = list(self._free)
             cached = list(self._cached)
             refs = dict(self._refs)
@@ -526,6 +737,36 @@ class KVBlockPool:
                     "free-list block %d still carries content-index "
                     "key %s.. (truncated/flushed blocks must leave "
                     "the index)" % (bid, block_key[bid][:8]))
+        return problems
+
+    @staticmethod
+    def _check_kind(x):
+        """A plain kind's audit (under the lock): conservation, backed
+        reservations, ``reserved + owned == ceiling`` an owner, no block
+        twice."""
+        name, problems = x.kind.name, []
+        held = [b for blocks in x.owned.values() for b in blocks]
+        if len(x.free) + len(held) != x.num_blocks:
+            problems.append(
+                "%s pages: conservation broken: free %d + in-table %d != "
+                "total %d" % (name, len(x.free), len(held), x.num_blocks))
+        ids = x.free + held
+        if len(set(ids)) != len(ids) or KVBlockPool.NULL_BLOCK in ids:
+            problems.append("%s pages: a block twice, or the null block, "
+                            "across the free list and the tables" % name)
+        if x.available() < 0:
+            problems.append(
+                "%s pages: reservations unbacked: free %d < reserved %d"
+                % (name, len(x.free), sum(x.reserved.values())))
+        for owner, blocks in x.owned.items():
+            have = x.reserved.get(owner, 0) + len(blocks)
+            if x.reserved.get(owner, 0) < 0 \
+                    or have != x.ceiling.get(owner):
+                problems.append(
+                    "%s pages: owner %r reserved %d + owned %d != "
+                    "ceiling %r" % (name, owner,
+                                    x.reserved.get(owner, 0), len(blocks),
+                                    x.ceiling.get(owner)))
         return problems
 
     # -- content index (radix prefix caching) --------------------------

@@ -38,7 +38,7 @@ import numpy as np
 
 from .kv_cache import CacheEntry
 
-__all__ = ["LatentMoEBlock", "weight_names", "random_weights",
+__all__ = ["LatentMoEBlock", "BlockDescription", "weight_names", "random_weights",
            "make_decode_step", "make_window_step", "route",
            "expert_layer", "mla_absorbed", "mla_expanded",
            "rope_interleaved", "COUNTERS"]
@@ -50,7 +50,66 @@ COUNTERS = ("expert_pairs", "experts_touched", "expert_rows_max",
             "expert_slots")
 
 
-class LatentMoEBlock:
+def held_experts(n_routed_experts, experts_held):
+    """The experts a chip holds as a tuple of global ids (``None``: all
+    of them), checked: ascending, distinct, below the router's width."""
+    held = tuple(int(e) for e in (range(n_routed_experts)
+                                  if experts_held is None
+                                  else experts_held))
+    if (sorted(set(held)) != list(held) or not held
+            or held[-1] >= n_routed_experts):
+        raise ValueError("experts_held must be ascending, distinct "
+                         "ids below n_routed_experts")
+    return held
+
+
+class BlockDescription:
+    """What every decoder block's description shares: the round trip
+    through a plain dict (``FIELDS`` names the constructor's arguments;
+    tuples go out as lists) and the answers ``serving.model`` asks a
+    block for (model.BLOCK_KINDS), given by the functions of the
+    module the block's class lives in."""
+
+    kind = None
+    FIELDS = ()
+
+    def to_dict(self):
+        d = {k: getattr(self, k) for k in self.FIELDS}
+        d = {k: list(v) if isinstance(v, tuple) else v
+             for k, v in d.items()}
+        return dict(d, kind=self.kind)
+
+    @classmethod
+    def from_dict(cls, d):
+        d = dict(d)
+        if d.pop("kind", cls.kind) != cls.kind:
+            raise ValueError("not a %s block description" % cls.kind)
+        return cls(**d)
+
+    def replace(self, **changes):
+        return type(self).from_dict(dict(self.to_dict(), **changes))
+
+    def _module(self):
+        import importlib
+
+        return importlib.import_module(type(self).__module__)
+
+    def leaf_shapes(self, config):
+        return self._module().leaf_shapes(config)
+
+    def random_weights(self, config, seed=0, scale=0.1):
+        return random_weights(config, seed, scale)
+
+    def make_decode_step(self, model, return_logits=False):
+        return self._module().make_decode_step(model, return_logits)
+
+    def make_window_step(self, model, window, return_logits=False,
+                         max_tokens=None):
+        return self._module().make_window_step(model, window,
+                                               return_logits, max_tokens)
+
+
+class LatentMoEBlock(BlockDescription):
     """The block's description, carried by ``GenerationConfig.block``
     (``d_model``, ``n_heads``, ``n_layers``, ``vocab_size`` and the
     dense width ``d_ff`` stay on the configuration)."""
@@ -85,14 +144,8 @@ class LatentMoEBlock:
         self.moe_d_ff = int(moe_d_ff)
         self.routed_scaling_factor = float(routed_scaling_factor)
         # the experts this chip holds (global ids, ascending); None: all
-        held = (range(self.n_routed_experts) if experts_held is None
-                else experts_held)
-        self.experts_held = tuple(int(e) for e in held)
-        if (sorted(set(self.experts_held)) != list(self.experts_held)
-                or not self.experts_held
-                or self.experts_held[-1] >= self.n_routed_experts):
-            raise ValueError("experts_held must be ascending, distinct "
-                             "ids below n_routed_experts")
+        self.experts_held = held_experts(self.n_routed_experts,
+                                         experts_held)
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
         self.weight_dtype = str(weight_dtype)
@@ -122,21 +175,6 @@ class LatentMoEBlock:
     def cache_entry(self):
         return CacheEntry((("latent", (self.cache_row,)),),
                           self.cache_dtype)
-
-    def to_dict(self):
-        d = {k: getattr(self, k) for k in self.FIELDS}
-        d["experts_held"] = list(self.experts_held)
-        return dict(d, kind=self.kind)
-
-    @classmethod
-    def from_dict(cls, d):
-        d = dict(d)
-        if d.pop("kind", cls.kind) != cls.kind:
-            raise ValueError("not a %s block description" % cls.kind)
-        return cls(**d)
-
-    def replace(self, **changes):
-        return type(self).from_dict(dict(self.to_dict(), **changes))
 
 
 def _is_expert_layer(block, i):
@@ -189,7 +227,9 @@ def weight_names(config):
 
 def random_weights(config, seed=0, scale=0.1):
     """Deterministic random weights in the serving layout (tests, the
-    chip smoke): N(0, scale) matrices, gains 1, router bias 0. Made on
+    chip smoke) of any block whose gains end in ``norm`` and whose
+    router bias is ``router_bias``: N(0, scale) matrices, gains 1,
+    router bias 0. Made on
     the default device, a leaf at a time: at published widths an expert
     layer is a gigabyte."""
     import jax
@@ -197,7 +237,8 @@ def random_weights(config, seed=0, scale=0.1):
 
     key = jax.random.PRNGKey(seed)
     out = {}
-    for n, (name, (shape, dtype)) in enumerate(leaf_shapes(config).items()):
+    shapes = config.block.leaf_shapes(config)   # this block's, or another's
+    for n, (name, (shape, dtype)) in enumerate(shapes.items()):
         if name.endswith("norm"):
             out[name] = jnp.ones(shape, dtype)
         elif name.endswith("router_bias"):
@@ -356,7 +397,12 @@ def expert_layer(block, x, valid, idx, w, we_gate, we_up, we_down,
     # whatever the tokens are: a step's time then does not follow the
     # routing (nor, on seeded weights, the seed: PERF.md, PR 27), for at
     # most that share of the weights read in vain. Below that, an expert
-    # without a row has no tile and is never read.
+    # without a row has no tile and is never read. (A layer that holds a
+    # share of the router's experts gets that share of the pairs: with 32
+    # of 256 held, a decode step of 48 rows x 4 passes the rule on 192
+    # pairs and places 24, so about half of the held bytes it streams
+    # reach no row. Without the floor the step's time followed the seed's
+    # routing skew and the cell's runs spread 0.86 %: PERF.md, PR 37.)
     floor = 1 if P >= 4 * Eh else 0
     tiles = jnp.maximum(-(-sizes // tm), floor)
     tile_end = jnp.cumsum(tiles)

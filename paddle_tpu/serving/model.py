@@ -229,6 +229,32 @@ def _chunk_layout(jnp, positions, lengths, active, block_tables, window,
                          0, n_tiles * Cq - 1)}
 
 
+# The decoder blocks beside XGLM's own, by ``block.kind``: the module
+# (imported when a configuration first names the kind, so a server of
+# another block never loads it) and the class that describes one. A
+# block's description answers for everything that differs by block:
+# ``leaf_shapes(config)``, ``random_weights(config, seed, scale)``,
+# ``cache_entry()``, ``page_kinds(config)``, ``step_counters``,
+# ``make_decode_step(model, return_logits)`` and
+# ``make_window_step(model, window, return_logits, max_tokens)``.
+BLOCK_KINDS = {"latent_moe": ("latent_moe", "LatentMoEBlock"),
+               "afmoe": ("afmoe", "AfmoeBlock")}
+
+
+def block_from_dict(d):
+    """A block description from its ``to_dict()`` (``kind`` names the
+    class; a dict without one is the latent block's, as first written)."""
+    import importlib
+
+    kind = d.get("kind", "latent_moe")
+    if kind not in BLOCK_KINDS:
+        raise ValueError("unknown decoder block kind %r (have %s)"
+                         % (kind, sorted(BLOCK_KINDS)))
+    module, name = BLOCK_KINDS[kind]
+    return getattr(importlib.import_module("." + module, __package__),
+                   name).from_dict(d)
+
+
 class GenerationConfig:
     """Decoder-only LM hyperparameters (transformer_fluid.build shape).
 
@@ -243,11 +269,9 @@ class GenerationConfig:
     def __init__(self, vocab_size, d_model, n_heads, n_layers, d_ff,
                  max_seq_len=512, pe_alpha=1.0, pe_beta=1.0, block=None):
         if isinstance(block, dict):
-            from .latent_moe import LatentMoEBlock
-
-            block = LatentMoEBlock.from_dict(block)
+            block = block_from_dict(block)
         self.block = block
-        if d_model % n_heads:
+        if d_model % n_heads and getattr(block, "head_dim", None) is None:
             raise ValueError("n_heads must divide d_model")
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
@@ -260,7 +284,10 @@ class GenerationConfig:
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        """A block that states its own head (grouped-query attention:
+        ``n_heads * head_dim`` need not be ``d_model``) is asked."""
+        own = getattr(self.block, "head_dim", None)
+        return own if own is not None else self.d_model // self.n_heads
 
     def to_dict(self):
         d = {k: getattr(self, k) for k in
@@ -322,9 +349,7 @@ def leaf_shapes(config):
     embedding is gathered and scaled, never multiplied on the MXU;
     LayerNorm gains and every bias are added in float32)."""
     if config.block is not None:
-        from . import latent_moe
-
-        return latent_moe.leaf_shapes(config)
+        return config.block.leaf_shapes(config)
     D, F, V = config.d_model, config.d_ff, config.vocab_size
     shape = {"wqkv": (D, 3 * D), "bqkv": (3 * D,), "wproj": (D, D),
              "wff1": (D, F), "bff1": (F,), "wff2": (F, D)}
@@ -377,9 +402,7 @@ def random_weights(config, seed=0, scale=0.1):
     """Deterministic random weights (tests/bench: a servable model with
     no training program behind it)."""
     if config.block is not None:
-        from . import latent_moe
-
-        return latent_moe.random_weights(config, seed, scale)
+        return config.block.random_weights(config, seed, scale)
     rng = np.random.RandomState(seed)
     D, F, V = config.d_model, config.d_ff, config.vocab_size
 
@@ -833,6 +856,13 @@ class GenerationModel:
         return CacheEntry.per_head(self.config.n_heads,
                                    self.config.head_dim)
 
+    def page_kinds(self):
+        """The kinds of page this model's layers keep their cache in
+        (``kv_cache.PageKind``), or None: every layer keeps every
+        position, one kind."""
+        kinds = getattr(self.config.block, "page_kinds", None)
+        return kinds(self.config) if kinds is not None else None
+
     @property
     def step_counters(self):
         """Names of the integers a compiled step of this model returns
@@ -841,11 +871,20 @@ class GenerationModel:
         block = self.config.block
         return () if block is None else block.step_counters
 
+    @property
+    def returns_top_logit(self):
+        """Whether this model's decode and chunk steps return, after
+        their tokens and counters, each row's chosen token's own logit
+        (float32 ``[max_batch]``): the engine then keeps it beside the
+        token (``GenerationRequest.top_logits``), which lets a caller
+        hold the served arithmetic against a reference token by token."""
+        return bool(getattr(self.config.block, "returns_top_logit", False))
+
     def _no_such_step(self, what):
         if self.config.block is not None:
             raise NotImplementedError(
                 "%s is not built for the %s block: speculative, tree and "
-                "draft steps over a latent cache are ROADMAP Queue 2a"
+                "draft steps over its cache are ROADMAP Queue 2a"
                 % (what, self.config.block.kind))
 
     # -- weight-only int8 ---------------------------------------------------
@@ -1066,10 +1105,8 @@ class GenerationModel:
 
         cfg = self.config
         if cfg.block is not None:
-            from . import latent_moe
-
             jitted = self._instrument_step(
-                "decode", latent_moe.make_decode_step(self, return_logits))
+                "decode", cfg.block.make_decode_step(self, return_logits))
             self._steps[key] = jitted
             return jitted
         pe = jnp.asarray(_position_encoding_table(cfg))
@@ -1315,14 +1352,12 @@ class GenerationModel:
         docs/SERVING.md "Chunked prefill"), never over
         ``[B, C, H, T]``."""
         if self.config.block is not None:
-            from . import latent_moe
-
             key = ("chunk", int(max_batch), int(max_blocks_per_seq),
                    int(chunk), bool(return_logits),
                    max_tokens) + _kernel_key_suffix()
             if key not in self._steps:
                 self._steps[key] = self._instrument_step(
-                    "chunk", latent_moe.make_window_step(
+                    "chunk", self.config.block.make_window_step(
                         self, int(chunk), return_logits, max_tokens))
             return self._steps[key]
         return self._make_window_step("chunk", max_batch,
@@ -2089,9 +2124,9 @@ def reference_decode(model, prompt, max_new_tokens, eos_id=None):
     if cfg.block is not None:
         raise NotImplementedError(
             "reference_decode is the XGLM block's oracle; the %s block's "
-            "plain reference is perfbench/reference/kanana.py "
-            "(tests/test_latent_moe.py compares against it)"
-            % cfg.block.kind)
+            "plain reference is under perfbench/reference/ "
+            "(tests/test_latent_moe.py, tests/test_afmoe.py compare "
+            "against theirs)" % cfg.block.kind)
     w = model.dequantized_weights()
     pe = _position_encoding_table(cfg)
     emb_scale = float(cfg.d_model) ** 0.5

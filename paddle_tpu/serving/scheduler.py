@@ -181,6 +181,10 @@ class GenerationRequest:
         self.first_token_time = None  # first generated token materialized
         self.finish_time = None
         self.tokens = []            # generated ids (truncated at EOS)
+        # each generated token's own logit as the step computed it, one
+        # a token, where the model's steps hand it back
+        # (`GenerationModel.returns_top_logit`); empty otherwise
+        self.top_logits = []
         self.error = None
         self._done = threading.Event()
 
@@ -311,7 +315,12 @@ class StepScheduler:
         self._np = np
         mb = blocks_needed(self.max_seq_len, pool.block_size)
         self.max_blocks_per_seq = mb
-        self.block_tables = np.zeros((self.max_batch, mb), np.int32)
+        # one block table a page KIND (kv_cache.PageKind), each indexed
+        # by the logical page; `block_tables` is the first kind's (the
+        # only one of most models), a view of the stack
+        self.kind_tables = np.zeros(
+            (len(pool.kinds), self.max_batch, mb), np.int32)
+        self.block_tables = self.kind_tables[0]
         self.use_prompt = np.zeros(self.max_batch, bool)
         self.positions = np.zeros(self.max_batch, np.int32)
         self.active = np.zeros(self.max_batch, bool)
@@ -328,6 +337,13 @@ class StepScheduler:
         self.chunk_feed = np.zeros(
             (self.max_batch, self.prefill_chunk), np.int32)
         self.chunk_lens = np.zeros(self.max_batch, np.int32)
+        # a window kind's pages are released as a row's positions slide
+        # out, so a row never holds more of them than the window, the
+        # chunk in flight and one block's slack
+        self.kind_max_blocks = [
+            mb if k.window is None else min(mb, blocks_needed(
+                k.window - 1 + self.prefill_chunk, pool.block_size) + 1)
+            for k in pool.kinds]
         self.prefix_cache = bool(prefix_cache)
         self.cache_namespace = str(cache_namespace)
         # host-side reuse telemetry (live even with metrics disabled —
@@ -390,7 +406,10 @@ class StepScheduler:
             # the cap here matches it.
             total = min(total + self.spec_tree[0] * self.spec_tree[1],
                         self.max_seq_len)
-        return blocks_needed(total, self.pool.block_size)
+        n = blocks_needed(total, self.pool.block_size)
+        if len(self.pool.kinds) == 1:
+            return n
+        return tuple(min(n, cap) for cap in self.kind_max_blocks)
 
     def admit(self, queue):
         """Move queued requests into free slots while the KV pool can
@@ -436,7 +455,7 @@ class StepScheduler:
                 _tracing.instant("admit", trace_id=request.trace_id,
                                  request=request.id, slot=slot)
             self.slots[slot] = seq
-            self.block_tables[slot, :] = self.pool.NULL_BLOCK
+            self.kind_tables[:, slot, :] = self.pool.NULL_BLOCK
             seq.prefix_keys = tuple(keys)
             matched = self.pool.block_table(seq)
             if matched:
@@ -515,13 +534,16 @@ class StepScheduler:
             else:
                 gen_idx = seq.n_dispatched
             self.use_prompt[slot] = prefill
+            if len(self.pool.kinds) > 1:
+                self._release_slid_out(slot, seq, pos)
             # lazy block allocation for EVERY boundary the window
             # crosses (drawn from the admission-time reservation, so it
-            # cannot fail)
+            # cannot fail), a page of every kind
             for p in range(pos, pos + n):
                 if p % bs == 0:
-                    bid = self.pool.alloc_block(seq)
-                    self.block_tables[slot, p // bs] = bid
+                    for k, table in enumerate(self.kind_tables):
+                        table[slot, p // bs] = self.pool.alloc_block(
+                            seq, k)
             self.positions[slot] = pos
             self.chunk_lens[slot] = n
             self.active[slot] = True
@@ -536,6 +558,26 @@ class StepScheduler:
             if seq.prefix_keys:
                 self._seal_ready(slot, seq)
         return plan, kind
+
+    def _release_slid_out(self, slot, seq, pos):
+        """Before a step whose earliest query of this row is at ``pos``:
+        hand back the row's window-kind pages no query at or past
+        ``pos`` reads, and point their table entries at the null block.
+        Steps already dispatched carry the tables they were planned
+        with, and the device runs steps in order, so a page released
+        here is rewritten only after its last reader."""
+        bs = self.pool.block_size
+        for kind, page_kind in enumerate(self.pool.kinds):
+            if page_kind.window is None:
+                continue
+            head = self.pool.pages_released(seq, kind)
+            live = page_kind.first_live_page(pos, bs)
+            if live <= head:
+                continue
+            with _tracing.annotation("ptpu/scheduler.release_window_pages"):
+                n = len(self.pool.release_head(seq, kind, live))
+                self.kind_tables[kind, slot, head:head + n] = \
+                    self.pool.NULL_BLOCK
 
     def plan_spec(self):
         """Speculative verify-window planning (docs/SERVING.md).
@@ -826,10 +868,11 @@ class StepScheduler:
         return n_emit
 
     # -- lagged result processing --------------------------------------
-    def record_token(self, seq, gen_idx, token):
+    def record_token(self, seq, gen_idx, token, top_logit=None):
         """Fold one materialized decode output back into its sequence
         (called in dispatch order — possibly several steps after the
-        dispatch, under the async window)."""
+        dispatch, under the async window). ``top_logit``: the token's
+        logit, where the step returned it."""
         seq.pending -= 1
         if gen_idx is None or seq.finished:
             return
@@ -839,6 +882,8 @@ class StepScheduler:
             # overshoot tokens are dropped
             return
         request.tokens.append(int(token))
+        if top_logit is not None:
+            request.top_logits.append(float(top_logit))
         if len(request.tokens) == 1:
             request.first_token_time = time.perf_counter()
         hit_eos = (request.eos_id is not None

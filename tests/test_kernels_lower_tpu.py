@@ -157,8 +157,8 @@ def test_one_row_page_is_disqualified_with_a_reason(v5e_chip):
 
 def test_decode_page_walk_compiles_at_the_served_geometry(v5e_chip):
     """The decode step's attention call as `xglm-1.7b-serve` makes it:
-    24 layers of 896 blocks of 16 tokens, 16 heads of 128, 16 rows of
-    128 blocks, the layer a traced scalar. At that head width
+    24 layers of `engine.num_blocks` blocks (1,760 since PR 36) of 16
+    tokens, 16 heads of 128, 16 rows of 128 blocks, the layer a traced scalar. At that head width
     `paged_decode` is the kernel that walks each row's own pages."""
     import json
 
@@ -448,4 +448,128 @@ def test_latent_step_updates_the_bf16_pool_in_place(kind, v5e_chip):
     assert large and all(op == "custom-call" for op, _, _ in large), large
     # workspace: far under the pool (84 MB here), whatever the step holds
     assert compiled.memory_analysis().temp_size_in_bytes < latent.size, \
+        compiled.memory_analysis()
+
+
+# ---------------------------------------------------------------------------
+# the grouped-query window/global block (PR 37): its kernels at the served
+# geometry, and its steps over two kinds of packed bf16 page
+# ---------------------------------------------------------------------------
+
+def _trinity():
+    import json
+
+    with open(os.path.join(
+            os.path.dirname(chip_smoke.__file__),
+            "perfbench/configs/trinity-large-preview-serve.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("kernel", ["gqa_decode", "gqa_chunk",
+                                    "kv_page_write"])
+def test_gqa_kernels_compile_at_the_served_geometry(kernel, v5e_chip):
+    """The three kernels as `trinity-large-preview-serve` calls them on
+    its window pool: 4 layers of 3,888 pages `[64, 1024]` bf16 (8 cache
+    heads of 128 side by side), 48 query heads, 48 rows of 544 table
+    slots, a mixed step's 56 tiles of 128 tokens and 112 page units; the
+    layer and the window traced scalars."""
+    c = _trinity()
+    e = c["engine"]
+    H, Hkv, Dh = (c["num_attention_heads"], c["num_key_value_heads"],
+                  c["head_dim"])
+    bs, B = e["block_size"], e["max_batch"]
+    Mb = e["max_seq_len"] // bs
+    assert (H, Hkv, Dh, bs, B, Mb) == (48, 8, 128, 64, 48, 544)
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    pool = ((4, e["window_blocks"] + 1, bs, Hkv * Dh), bf)
+    spec = registered_kernels()[kernel]
+    assert spec.qualify(head_dim=Dh, block_size=bs)[0]
+    if kernel == "gqa_decode":
+        specs = [pool, pool, ((B, H, Dh), f32), ((B, Mb), i32), ((B,), i32),
+                 ((), i32), ((), i32), ((B,), jnp.bool_)]
+        fn = lambda k, v, q, t, p, layer, w, on: spec.pallas(  # noqa: E731
+            k, v, q, t, p, layer=layer, window=w, active=on)
+    elif kernel == "gqa_chunk":
+        N = 56
+        specs = [pool, pool, ((N, 128, H, Dh), f32), ((N, Mb), i32),
+                 ((N,), i32), ((N,), i32), ((), i32), ((), i32)]
+        fn = lambda k, v, q, t, p, n, layer, w: spec.pallas(  # noqa: E731
+            k, v, q, t, p, n, layer=layer, window=w)
+    else:
+        U = 112
+        rows = ((U, bs, Hkv * Dh), bf)
+        specs = [pool, pool, rows, rows, ((U,), i32), ((U,), i32),
+                 ((U,), i32), ((), i32)]
+        fn = lambda k, v, kr, vr, ids, lo, hi, layer: spec.pallas(  # noqa: E731,E501
+            k, v, kr, vr, ids, lo, hi, layer=layer)
+    compiled = _compile(fn, specs, v5e_chip)
+    assert spec.pallas.__name__ in compiled.as_text()
+    # the pools stay where they are: nothing of a pool's size is made
+    pool_bytes = 4 * (e["window_blocks"] + 1) * bs * Hkv * Dh * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+# pools larger than the chip's 128 MiB of VMEM: the compiler prefetches
+# an argument that fits there, and that copy is not the one looked for
+AFMOE_STEP = dict(batch=8, blocks_per_seq=16, block_size=64, chunk=256,
+                  global_blocks=2048, window_blocks=1024)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_afmoe_step_updates_both_kinds_of_page_in_place(kind, v5e_chip):
+    """The third block's steps at its published widths (a dense window
+    layer, a global and a window expert layer, 8 experts held): each
+    kind's K and V pools are packed bf16 and are rewritten by
+    `kv_page_write` in place and read by the grouped-query kernels from
+    HBM; the compiled steps hold nothing of a pool's size but the
+    kernels' own (aliased) results."""
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    KVBlockPool)
+
+    g = AFMOE_STEP
+    cfg = GenerationConfig(
+        **dict(chip_smoke.FULL.afmoe, vocab_size=1024),
+        max_seq_len=g["blocks_per_seq"] * g["block_size"])
+    model = GenerationModel.__new__(GenerationModel)
+    model.config, model.trace_count = cfg, 0
+    kinds = model.page_kinds()
+    assert [(k.name, k.layers) for k in kinds] == [("global", (1,)),
+                                                   ("window", (0, 2))]
+    sharding = jax.sharding.SingleDeviceSharding(v5e_chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=sharding)
+
+    weights = {k: arg(s, d) for k, (s, d) in
+               cfg.block.leaf_shapes(cfg).items()}
+    B, Mb = g["batch"], g["blocks_per_seq"]
+    arrays = jax.eval_shape(lambda: KVBlockPool(
+        cfg.n_layers, cfg.n_heads, cfg.head_dim, g["block_size"],
+        [g["global_blocks"], g["window_blocks"]],
+        entry=cfg.block.cache_entry(), kinds=kinds).arrays)
+    assert [a.shape for a in arrays] == [(1, 2049, 64, 1024)] * 2 \
+        + [(2, 1025, 64, 1024)] * 2
+    assert all(a.dtype == jnp.bfloat16 for a in arrays)
+    pools = tuple(arg(a.shape, a.dtype) for a in arrays)
+    row, on = arg((B,)), arg((B,), jnp.bool_)
+    tables = arg((2, B, Mb))
+    with device.compiling_for(v5e_chip):
+        if kind == "decode":
+            compiled = cfg.block.make_decode_step(model).lower(
+                weights, *pools, row, on, row, row, tables, on).compile()
+        else:
+            compiled = cfg.block.make_window_step(
+                model, g["chunk"], max_tokens=B + g["chunk"]).lower(
+                weights, *pools, arg((B, g["chunk"])), on, row, row, row,
+                tables, on).compile()
+    hlo = compiled.as_text()
+    names = ["gmm", "kv_page_write", "gqa_paged_decode_attention"
+             if kind == "decode" else "gqa_paged_chunk_attention"]
+    for name in names:
+        assert name in hlo, name
+    smallest = min(a.size for a in arrays)
+    large = _large_results(hlo, smallest)
+    assert large and all(op == "custom-call" for op, _, _ in large), large
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * smallest, \
         compiled.memory_analysis()

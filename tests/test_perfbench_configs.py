@@ -1,0 +1,153 @@
+"""The benchmark's serving configurations fill the chip, by their own
+numbers, and every cell's traffic parses. CPU arithmetic on shapes only:
+nothing is built and nothing runs. (What ``perfbench/tests/
+test_configs.py`` checks, which tier-1 never collects; that file
+stays.)"""
+
+import numpy as np
+import pytest
+
+from perfbench import loadgen, spec
+
+USABLE_HBM_BYTES = 15.75 * 2 ** 30   # what the v5e's compiler hands out
+BENCH = spec.load_benchmark()
+
+
+def _read(entry):
+    return spec.read_json(spec.os.path.join(spec.ROOT, entry["file"]))
+
+
+SERVING = [c["name"] for c in BENCH["configs"] if "engine" in _read(c)]
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def configuration(name):
+    return _read(next(c for c in BENCH["configs"] if c["name"] == name))
+
+
+def generation_config(config):
+    from paddle_tpu.serving import GenerationConfig
+
+    runner, e = spec.runner(config), config["engine"]
+    if hasattr(runner, "generation_config"):
+        return runner.generation_config(config, e["max_seq_len"])
+    return GenerationConfig(
+        vocab_size=config["vocab_size"], d_model=config["d_model"],
+        n_heads=config["attention_heads"], n_layers=config["num_layers"],
+        d_ff=config["ffn_dim"], max_seq_len=e["max_seq_len"])
+
+
+def itemsize(dtype):
+    return 2 if dtype == "bfloat16" else np.dtype(dtype).itemsize
+
+
+def held_bytes(config, monkeypatch):
+    """(weights, pools) bytes as the TPU's store holds them: the leaves
+    in the dtypes ``leaf_shapes`` states there, and every page kind's
+    arrays, the null block among them."""
+    from paddle_tpu.serving import GenerationModel, model
+
+    monkeypatch.setattr(model, "default_dot_rounds_to_bf16", lambda: True)
+    cfg = generation_config(config)
+    weights = sum(int(np.prod(shape)) * itemsize(dtype)
+                  for shape, dtype in model.leaf_shapes(cfg).values())
+    shell = GenerationModel.__new__(GenerationModel)
+    shell.config = cfg
+    entry, e = shell.cache_entry(), config["engine"]
+    token = sum(int(np.prod(shape)) for _n, shape in entry.parts) \
+        * itemsize(entry.dtype)
+    kinds = shell.page_kinds()
+    if kinds:
+        pools = sum(len(k.layers) * token * e["block_size"]
+                    * (e[k.name + "_blocks"] + 1) for k in kinds)
+        # the one number perfbench/tests/test_configs.py reckons with
+        assert cfg.n_layers * e["num_blocks"] == sum(
+            len(k.layers) * e[k.name + "_blocks"] for k in kinds)
+    else:
+        pools = cfg.n_layers * token * e["block_size"] \
+            * (e["num_blocks"] + 1)
+    return weights, pools
+
+
+def test_the_benchmark_has_the_serving_configurations():
+    assert set(SERVING) >= {"xglm-1.7b-serve", "kanana-2-30b-a3b-serve",
+                            "trinity-large-preview-serve"}
+
+
+@pytest.mark.parametrize("name", SERVING)
+def test_a_serving_configuration_fills_the_chip(name, monkeypatch):
+    config = configuration(name)
+    weights, pools = held_bytes(config, monkeypatch)
+    share = (weights + pools) / USABLE_HBM_BYTES
+    assert 0.75 <= share <= 0.995, (weights, pools, share)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_every_reduced_key_states_its_published_value(name):
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    config = _read(entry)
+    assert config["reduced"] == entry["reduced"]
+    assert config["source"] == entry["source"]
+    if "engine" in config:
+        for key in config["reduced"]:
+            assert key in config.get("published", {}), key
+            assert config["published"][key] != config[key], key
+
+
+def test_trinity_states_its_cut_and_its_assumed_equations():
+    config = configuration("trinity-large-preview-serve")
+    assert config["reduced"] == ["num_hidden_layers", "num_dense_layers",
+                                 "num_experts", "vocab_size"]
+    assert config["published"]["num_experts"] == 256 \
+        == config["router_experts"]
+    assert len(config["layer_types"]) == config["num_hidden_layers"] == 5
+    assert config["layer_types"].count("full_attention") == 1
+    for key in ("rotary_on_window_layers_only", "output_gate",
+                "qk_norm_over_head_dim", "mup_embedding_scale",
+                "sandwich_norms", "engine"):
+        assert key in config["assumed"], key
+    assert "eight chips" in config["deployment"]
+    assert [c["name"] for c in config["controls"]] == [
+        "bf16_router", "int8_expert_weights", "window_ignored"]
+    e = config["engine"]
+    assert config["controls"][2]["value"]["sliding_window"] \
+        > e["max_seq_len"]
+    # no width is cut
+    for key, want in (("hidden_size", 3072), ("head_dim", 128),
+                      ("num_attention_heads", 48),
+                      ("num_key_value_heads", 8),
+                      ("intermediate_size", 12288),
+                      ("moe_intermediate_size", 3072),
+                      ("num_experts_per_tok", 4), ("sliding_window", 4096)):
+        assert config[key] == want, key
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cells_traffic_parses(cell):
+    _w, config, mix = spec.cell(BENCH, cell)
+    vocab = config["vocab_size"]
+    if mix["kind"] == "open_loop":
+        requests = loadgen.open_loop(1, mix, float(BENCH["run_seconds"]),
+                                     vocab)
+        assert len(requests) >= 100
+    elif mix["kind"] == "closed_loop":
+        requests = loadgen.closed_loop(1, mix, vocab)
+        assert len(requests) == loadgen.CLOSED_LOOP_CYCLE
+        assert mix["clients"] > config["engine"]["max_batch"]
+    else:
+        assert mix["kind"] == "token_stream"
+        return
+    longest = max(len(r.prompt) + r.max_new_tokens for r in requests)
+    assert longest <= config["engine"]["max_seq_len"]
+    assert all(0 <= int(t) < vocab for r in requests[:8] for t in r.prompt)
+
+
+def test_the_new_cells_traffic_is_what_the_issue_gave():
+    _w, _c, mix = spec.cell(BENCH, "trinity-large-preview.long-closed")
+    assert mix == {
+        "prompt_len": {"dist": "lognormal", "median": 8192, "sigma": 0.9,
+                       "min": 512, "max": 32768},
+        "output_len": {"dist": "lognormal", "median": 1024, "sigma": 0.5,
+                       "min": 256, "max": 2048},
+        "kind": "closed_loop", "clients": 64, "ramp_s": 30.0,
+        "drain_s": 240.0, "trace_seconds": 5.0}

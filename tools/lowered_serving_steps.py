@@ -5,7 +5,8 @@
 
 ``write`` imports ``paddle_tpu`` from ``<checkout>`` (this tree or a
 ``git archive`` of another commit), builds the decode and chunk steps of
-both serving configurations of the benchmark at their engines' geometry
+the serving configurations of the benchmark (XGLM, kanana and, where the
+checkout has it, trinity with its two kinds of page) at their engines' geometry
 (``perfbench/configs/*-serve.json``; only shapes are made, no weights),
 lowers them for a described ``v5e:2x2`` device and writes the StableHLO
 text with debug locations stripped, and prints each step's dots counted
@@ -115,22 +116,26 @@ def write(root, out, compile_too):
                                 compiled.as_text()))
         print(json.dumps(info), flush=True)
 
-    def both_steps(name, model, e):
+    def both_steps(name, model, e, kinds=None, num_blocks=None):
         cfg = model.config
         B, bs, C = e["max_batch"], e["block_size"], e["prefill_chunk"]
         Mb = -(-e["max_seq_len"] // bs)
+        more = {"kinds": kinds} if kinds else {}
         pool = tuple(arg(a.shape, a.dtype) for a in jax.eval_shape(
             lambda: KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
-                                bs, e["num_blocks"],
-                                entry=model.cache_entry()).arrays))
-        row, on, tables = arg((B,)), arg((B,), jnp.bool_), arg((B, Mb))
+                                bs, num_blocks or e["num_blocks"],
+                                entry=model.cache_entry(), **more).arrays))
+        row, on = arg((B,)), arg((B,), jnp.bool_)
+        # one block table, or the stack of them, a table a page kind
+        tables = arg((len(kinds), B, Mb) if kinds else (B, Mb))
+        budget = e.get("prefill_token_budget", 4 * C)
         # the engine's calls: prompt_feed, use_prompt, prev_tokens,
         # positions, (lengths,) block_tables, active; its promise of
         # max_batch + four chunks of token rows
         emit(name + "_decode_step", model.make_decode_step(B, Mb),
              (model.weights,) + pool + (row, on, row, row, tables, on))
         emit(name + "_chunk_step",
-             model.make_prefill_step(B, Mb, C, max_tokens=B + 4 * C),
+             model.make_prefill_step(B, Mb, C, max_tokens=B + budget),
              (model.weights,) + pool
              + (arg((B, C)), on, row, row, row, tables, on))
 
@@ -162,6 +167,18 @@ def write(root, out, compile_too):
     cfg = serve_latent.generation_config(c, c["engine"]["max_seq_len"])
     both_steps("kanana", model_of(cfg, latent_moe.leaf_shapes(cfg)),
                c["engine"])
+
+    if not os.path.exists(os.path.join(
+            root, "perfbench/configs/trinity-large-preview-serve.json")):
+        return             # a checkout from before the third block
+    from perfbench.runners import serve_window
+
+    c = config("trinity-large-preview-serve.json")
+    cfg = serve_window.generation_config(c, c["engine"]["max_seq_len"])
+    model = model_of(cfg, cfg.block.leaf_shapes(cfg))
+    both_steps("trinity", model, c["engine"], kinds=model.page_kinds(),
+               num_blocks={"global": c["engine"]["global_blocks"],
+                           "window": c["engine"]["window_blocks"]})
 
 
 def compare(dir_a, dir_b):
