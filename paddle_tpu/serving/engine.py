@@ -30,9 +30,14 @@ Prefill is chunked (docs/SERVING.md): a tick with a row mid-prompt
 dispatches the second compiled step shape — a ``[max_batch, chunk]``
 window (``prefill_chunk`` / ``$PTPU_SERVE_PREFILL_CHUNK``) where prefill
 rows consume whole prompt spans while decode rows ride along as 1-token
-windows — with ``prefill_token_budget`` (default ``4 * chunk``) bounding
-the prompt tokens per mixed step so decode latency stays bounded; any
-other tick dispatches the decode step, every row a window of one.
+windows — with ``prefill_token_budget`` bounding the prompt tokens per
+mixed step so decode latency stays bounded (where none is stated: four
+chunks up to the 256 rows at which the weights' matmuls turn
+compute-bound, never under one chunk,
+``scheduler.default_prefill_token_budget``; handed to the prefilling
+rows in admission order) and with it the token rows the ONE chunk
+program is compiled for, ``max_batch`` + the budget; any other tick
+dispatches the decode step, every row a window of one.
 **Radix prefix caching** (opt-in: ``prefix_cache`` /
 ``$PTPU_SERVE_PREFIX_CACHE``) content-addresses the KV pool so requests
 sharing a prompt prefix skip its prefill compute and block allocations.
@@ -63,8 +68,9 @@ Telemetry (the autoscaling surface, docs/OBSERVABILITY.md):
 kv_blocks_in_use,tokens_per_sec,request_latency(_p50/_p99),
 ttft(_p50/_p99),steps,prefill_tokens,decode_tokens,prefill_chunk_steps,
 prefix_blocks_reused,prefix_tokens_skipped,spec_steps,spec_proposed,
-spec_accepted,spec_rejected,spec_accept_rate,requests_submitted,
-requests_completed,requests_rejected,requests_failed}``, and the step
+spec_accepted,spec_rejected,spec_accept_rate,prefill_rows_deferred,
+requests_submitted,requests_completed,requests_rejected,
+requests_failed}``, and the step
 log ``serving/step``: one record per dispatched step, written by the
 worker where the work happens (:class:`_TickLog`).
 """
@@ -91,7 +97,7 @@ __all__ = ["ServingEngine"]
 
 # the fields of a `serving/step` record that the registry dump and
 # /metrics summarise
-STEP_LOG_FIELDS = ("device_ms", "host_ms", "wait_ms")
+STEP_LOG_FIELDS = ("device_ms", "host_ms", "wait_ms", "rows_deferred")
 
 
 class _TickLog:
@@ -185,7 +191,8 @@ class _ModelWorker:
             # geometry once, up front
             self.drafter.bind(max_batch, self.spec_k)
         # the scheduler settles the chunk's size and the prefill budget
-        # of a mixed step (scheduler.DEFAULT_PREFILL_CHUNK, 4 chunks)
+        # of a mixed step (scheduler.DEFAULT_PREFILL_CHUNK,
+        # default_prefill_token_budget: four chunks up to the ridge)
         self.scheduler = StepScheduler(
             max_batch, self.pool, max_seq_len,
             prefill_chunk=prefill_chunk,
@@ -746,6 +753,8 @@ class _ModelWorker:
             rec["weight_params"] = self.model.dot_operand_params
             if mixed:
                 rec["rows_computed"] = self._chunk_rows
+                # prefilling rows the budget left without a token
+                rec["rows_deferred"] = sched.rows_deferred
             if len(self.pool.kinds) > 1:
                 rec.update(self._pages_walked_by_kind())
             elif not mixed:
@@ -791,6 +800,8 @@ class _ModelWorker:
                 peak.set(occupancy)
             if mixed:
                 reg.counter("serving/prefill_chunk_steps").inc()
+                reg.counter("serving/prefill_rows_deferred").inc(
+                    rec["rows_deferred"])
             reg.counter("serving/prefill_tokens").inc(
                 rec["prefill_tokens"])
             reg.counter("serving/decode_tokens").inc(rec["decode_tokens"])

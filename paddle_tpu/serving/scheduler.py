@@ -28,9 +28,17 @@ the boundary, still drawn from the admission reservation) and decode
 rows ride along as 1-token windows; when no row is mid-prompt it plans
 a DECODE step, every row a window of one whose input token chains
 on-device from the previous step's output. ``prefill_token_budget``
-caps the TOTAL prompt tokens per mixed step (rows past the budget sit
-the step out, in slot order), so decode rows' per-step latency stays
-bounded no matter how many prompts arrive at once.
+caps the TOTAL prompt tokens per mixed step, so decode rows' per-step
+latency stays bounded no matter how many prompts arrive at once. Where
+nobody states it, it is four chunks but no more than the rows at which
+a weight matmul turns compute-bound, and never under one chunk
+(:func:`default_prefill_token_budget`, :data:`PREFILL_BUDGET_RIDGE`):
+past the ridge every further row adds its full time to the step of
+every decode row in it and buys no prefill throughput. The budget goes
+to the prefilling rows in the order they were ADMITTED, first admitted
+first fed: a row past the budget sits the step out (``rows_deferred``
+counts them) and waits for the prompts ahead of it, never for a later
+arrival that landed in a lower slot.
 
 **Radix prefix caching** (``prefix_cache=True``, off by default):
 admission runs a longest-prefix-match of the prompt's chain keys
@@ -66,7 +74,7 @@ from .kv_cache import blocks_needed, prefix_chain_keys
 
 __all__ = ["AdmissionError", "DeadlineExceededError", "GenerationRequest",
            "RequestQueue", "StepScheduler", "check_request_args",
-           "spec_tree_acceptance"]
+           "default_prefill_token_budget", "spec_tree_acceptance"]
 
 _req_ids = itertools.count()
 
@@ -77,6 +85,28 @@ _req_ids = itertools.count()
 # (``model.CHUNK_TILE``, settled on the chip in PR 28), so a default
 # engine compiles the shape that was measured.
 DEFAULT_PREFILL_CHUNK = 256
+
+# The token rows at which a weight matmul on the served chip turns
+# compute-bound. A ``[rows, d] x [d, n]`` dot over bf16 weights does
+# ``rows`` FLOP a weight byte, and the v5e does 197 TFLOP/s over
+# 819 GB/s = 240 FLOP a byte (Google Cloud documentation, "TPU v5e";
+# observability/cost.py has the first): up to 240 rows ride on the
+# weights' stream, every row past it adds its full time to the step.
+# 240 rounded up to whole query tiles (``model.CHUNK_TILE``). PERF.md §5
+# has the mixed step measured on either side of it.
+PREFILL_BUDGET_RIDGE = 256
+
+
+def default_prefill_token_budget(prefill_chunk):
+    """The prompt tokens a mixed step may hold where the deployment
+    names no ``prefill_token_budget``: four chunks, but no more than
+    :data:`PREFILL_BUDGET_RIDGE` and never under one chunk. A budget
+    past the ridge buys no prefill throughput a token, only a longer
+    step for every decode row in it; what a wider step does amortise is
+    the step's fixed part, and only when it is full. A caller whose
+    prompts arrive in bursts that fill a wider window, or whose chunk
+    step is not bound by its token rows' matmuls, states the budget."""
+    return max(prefill_chunk, min(4 * prefill_chunk, PREFILL_BUDGET_RIDGE))
 
 
 def spec_tree_acceptance(window, outs, width):
@@ -274,13 +304,14 @@ class RequestQueue:
 class _Sequence:
     """Scheduler-internal per-slot decode state."""
 
-    __slots__ = ("request", "slot", "pos", "n_dispatched", "pending",
-                 "finished", "dispatch_done", "prefix_keys",
+    __slots__ = ("request", "slot", "admitted", "pos", "n_dispatched",
+                 "pending", "finished", "dispatch_done", "prefix_keys",
                  "sealed_upto")
 
-    def __init__(self, request, slot):
+    def __init__(self, request, slot, admitted):
         self.request = request
         self.slot = slot
+        self.admitted = admitted  # the scheduler's admission ordinal
         self.pos = 0             # position of the NEXT token to process
         self.n_dispatched = 0    # generated tokens dispatched so far
         self.pending = 0         # dispatched steps not yet processed
@@ -325,15 +356,21 @@ class StepScheduler:
         self.positions = np.zeros(self.max_batch, np.int32)
         self.active = np.zeros(self.max_batch, bool)
         # the chunk is a compiled shape, so it is clamped to the
-        # context; the per-step token budget (default 4 chunks) bounds
-        # how much prefill compute a MIXED step carries alongside decode
-        # rows: the decode-latency bound
+        # context; the per-step token budget (stated, or the rule's:
+        # four chunks up to the matmuls' ridge) bounds how much prefill
+        # compute a MIXED step carries alongside decode rows: the
+        # decode-latency bound, and the token rows the engine compiles
+        # the chunk step for
         self.prefill_chunk = min(
             max(0, int(prefill_chunk or 0)) or DEFAULT_PREFILL_CHUNK,
             self.max_seq_len)
         self.prefill_token_budget = max(1, int(
-            4 * self.prefill_chunk if prefill_token_budget is None
-            else prefill_token_budget))
+            default_prefill_token_budget(self.prefill_chunk)
+            if prefill_token_budget is None else prefill_token_budget))
+        self._admissions = itertools.count()
+        # prefilling rows the last planned step gave no token for want
+        # of budget (the step log's `rows_deferred`)
+        self.rows_deferred = 0
         self.chunk_feed = np.zeros(
             (self.max_batch, self.prefill_chunk), np.int32)
         self.chunk_lens = np.zeros(self.max_batch, np.int32)
@@ -429,7 +466,7 @@ class StepScheduler:
                     % (len(request.prompt), self.max_seq_len)))
                 _metrics.counter("serving/requests_failed").inc()
                 continue
-            seq = _Sequence(request, slot)
+            seq = _Sequence(request, slot, next(self._admissions))
             keys = ()
             if self.prefix_cache:
                 # longest-prefix-match candidates: every full prompt
@@ -500,33 +537,42 @@ class StepScheduler:
         otherwise (every row a window of one, no prompt token fed).
 
         Prefill rows consume up to ``prefill_chunk`` prompt tokens
-        (bounded further by ``prefill_token_budget`` across rows; rows
-        past the budget sit the step out), decode rows one token
-        chained on the device."""
-        kind = ("mixed" if any(
-            s is not None and not s.dispatch_done and s.in_prefill
-            for s in self.slots) else "decode")
+        (bounded further by ``prefill_token_budget`` across rows, in
+        the order the rows were admitted; rows past the budget sit the
+        step out and ``rows_deferred`` counts them), decode rows one
+        token chained on the device."""
+        prefilling = sorted(
+            (s for s in self.slots
+             if s is not None and not s.dispatch_done and s.in_prefill),
+            key=lambda s: s.admitted)
+        kind = "mixed" if prefilling else "decode"
         bs = self.pool.block_size
+        # a prefill row takes what the step's budget still holds once
+        # the rows admitted before it have taken theirs; where that is
+        # nothing it sits the step out, so that decode rows' latency
+        # stays bounded, and resumes once the prompts ahead of it are
+        # through
         budget = self.prefill_token_budget
+        granted = {}
+        for seq in prefilling:
+            n = min(self.prefill_chunk,
+                    len(seq.request.prompt) - seq.pos, budget)
+            granted[seq.slot] = n
+            budget -= n
+        self.rows_deferred = sum(not n for n in granted.values())
         plan = []
         for slot, seq in enumerate(self.slots):
             n = 0
             if seq is not None and not seq.dispatch_done:
                 prefill = seq.in_prefill
                 pos, prompt = seq.pos, seq.request.prompt
-                # a prefill row takes what the step's budget still
-                # holds; where that is nothing it sits the step out, so
-                # that decode rows' latency stays bounded, and resumes
-                # next step
-                n = (min(self.prefill_chunk, len(prompt) - pos, budget)
-                     if prefill else 1)
+                n = granted[slot] if prefill else 1
             if not n:
                 self.active[slot] = False
                 self.use_prompt[slot] = False
                 self.chunk_lens[slot] = 0
                 continue
             if prefill:
-                budget -= n
                 self.chunk_feed[slot, :n] = prompt[pos:pos + n]
                 # the window consuming the LAST prompt token emits the
                 # first generated token

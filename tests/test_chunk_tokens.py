@@ -10,7 +10,9 @@ runs over query tiles. Pinned here, CPU, toy widths:
     tightly, through the ``chunk_window`` kernel (interpreted) within
     the rounding of its bf16 operands;
   * the promise the step rests on: ``plan_step`` never plans more than
-    ``max_batch + prefill_token_budget`` tokens;
+    ``max_batch + prefill_token_budget`` tokens, hands the budget to
+    the prefilling rows in the order they were admitted, and counts the
+    rows it left without a token (``rows_deferred``);
   * the tiles: how many the step is compiled for, and that a window's
     tiles cover each of its tokens once.
 """
@@ -187,6 +189,18 @@ def test_plan_step_keeps_the_promise_of_max_tokens(seed):
             tiles = -(-sched.chunk_lens // TILE)
             assert tiles.sum() <= chunk_tile_count(
                 max_batch, chunk, max_batch + budget, TILE)
+            # the rows that sat out: still mid-prompt, given nothing,
+            # counted, and every one admitted after every row fed
+            sat_out = [s for s in sched.slots
+                       if s is not None and not s.dispatch_done
+                       and not sched.active[s.slot]]
+            fed = [s for s, _ in plan if sched.use_prompt[s.slot]]
+            assert all(s.in_prefill for s in sat_out)
+            assert sched.rows_deferred == len(sat_out)
+            assert not sat_out or (max(s.admitted for s in fed)
+                                   < min(s.admitted for s in sat_out))
+        else:
+            assert sched.rows_deferred == 0
         for seq, gen_idx in plan:
             sched.record_token(seq, gen_idx, int(rng.randint(1, 60)))
         sched.reap()
@@ -194,11 +208,14 @@ def test_plan_step_keeps_the_promise_of_max_tokens(seed):
             break
     assert planned_mixed > 10 and not sched.has_work()
 
-
 @pytest.mark.parametrize("max_batch,window,max_tokens,tile,want", [
     (16, 256, 16 + 1024, 64, 16 + 1024 // 64),      # the benchmark's engine
     # the chosen tile is the benchmark's whole chunk: a tile a row
     (16, 256, 16 + 1024, CHUNK_TILE, 16),
+    # ... and since the unstated budget stops at the ridge (one chunk
+    # there): fewer token rows, the same tiles
+    (16, 256, 16 + 256, CHUNK_TILE, 16),
+    (16, 256, 16 + 256, 64, 16 + 256 // 64),
     (16, 2 * CHUNK_TILE, 16 + 4 * 2 * CHUNK_TILE, CHUNK_TILE, 16 + 8),
     (16, 256, None, 64, 16 * 4),                    # every slot's tile
     (16, 256, 16 * 256, 64, 16 * 4),

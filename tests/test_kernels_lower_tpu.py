@@ -32,6 +32,7 @@ import chip_smoke
 from paddle_tpu.core import device
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.ops.kernel_registry import registered_kernels
+from paddle_tpu.serving.scheduler import default_prefill_token_budget
 
 V5E = device.DeviceIdentity("tpu", "TPU v5 lite", 1)
 SIZES = {"smoke": chip_smoke.FULL, "toy": chip_smoke.TOY}
@@ -438,7 +439,9 @@ def test_latent_step_updates_the_bf16_pool_in_place(kind, v5e_chip):
                 weights, pool, row, on, row, row, tables, on).compile()
         else:
             compiled = latent_moe.make_window_step(
-                model, g["chunk"], max_tokens=B + 4 * g["chunk"]).lower(
+                model, g["chunk"],
+                max_tokens=B + default_prefill_token_budget(
+                    g["chunk"])).lower(
                 weights, pool, arg((B, g["chunk"])), on, row, row, row,
                 tables, on).compile()
     hlo = compiled.as_text()

@@ -9,7 +9,10 @@ Covers the two tentpole legs and their satellites:
   * the chunked [max_batch, chunk] prefill step — staggered-arrival
     torture across chunk boundaries pinned token-identical to
     ``reference_decode`` with exactly TWO traces (one per step shape),
-    and the per-step prefill token budget (decode-latency bound);
+    and the per-step prefill token budget (decode-latency bound): what
+    it is where nobody states it (four chunks up to the matmuls' ridge),
+    the token rows the chunk step is compiled for at the benchmark's
+    three geometries, and who gets it (the row admitted first);
   * the one planner — its plan sequence (mixed windows, then decode
     windows of one) and pool accounting pinned against an in-test
     oracle; what a default engine builds; a default engine prefills a
@@ -17,6 +20,8 @@ Covers the two tentpole legs and their satellites:
   * TTFT telemetry (histogram + p50/p99 gauges).
 """
 
+import json
+import os
 import threading
 
 import numpy as np
@@ -27,6 +32,10 @@ from paddle_tpu.serving import (GenerationConfig, GenerationModel,
                                 GenerationRequest, KVBlockPool,
                                 RequestQueue, StepScheduler,
                                 prefix_chain_keys, reference_decode)
+from paddle_tpu.serving.scheduler import (PREFILL_BUDGET_RIDGE,
+                                          default_prefill_token_budget)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CFG = dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
            max_seq_len=64)
@@ -265,8 +274,8 @@ def test_chunked_eos_truncates_like_reference():
 
 def test_chunk_budget_bounds_prefill_per_step():
     """The engine's decode-latency bound: prefill rows past the
-    per-step token budget sit the step out (in slot order) and resume
-    next step; decode rows always ride."""
+    per-step token budget sit the step out (the row admitted first is
+    fed first) and resume next step; decode rows always ride."""
     pool = KVBlockPool(1, 1, 4, 4, num_blocks=32)
     sched = StepScheduler(2, pool, 32, prefill_chunk=4,
                           prefill_token_budget=4)
@@ -282,6 +291,7 @@ def test_chunk_budget_bounds_prefill_per_step():
     assert sched.chunk_lens.tolist() == [4, 0]
     assert sched.active.tolist() == [True, False]
     assert [g for _, g in plan] == [None]
+    assert sched.rows_deferred == 1
     for seq, g in plan:
         sched.record_token(seq, g, 1)
     plan, kind = sched.plan_step()
@@ -297,6 +307,101 @@ def test_chunk_budget_bounds_prefill_per_step():
     assert sched.chunk_lens.tolist() == [1, 4]
     assert sched.use_prompt.tolist() == [False, True]
     assert sched.active.tolist() == [True, True]
+    assert sched.rows_deferred == 0
+
+
+@pytest.mark.parametrize("chunk,max_batch,stated,want", [
+    (256, 16, None, 256),       # xglm-1.7b-serve: one chunk, the ridge
+    (16, 128, None, 64),        # kanana-2-30b-a3b-serve: four chunks
+    (1024, 48, 1024, 1024),     # trinity-large-preview-serve states it
+    (512, 16, None, 512),       # a chunk past the ridge: never under it
+    (256, 16, 1024, 1024),      # a stated budget beats the rule
+    (64, 2, None, 256),         # four chunks where they reach the ridge
+    (100, 4, None, 256),        # ... and are cut to it where they pass
+], ids=["xglm", "kanana", "trinity", "chunk_past_the_ridge",
+        "stated_beats_rule", "four_chunks_at_the_ridge",
+        "cut_to_the_ridge"])
+def test_default_budget_by_geometry(chunk, max_batch, stated, want):
+    """Where nobody states `prefill_token_budget` a mixed step holds
+    four chunks of prompt, but no more than the token rows at which a
+    weight matmul turns compute-bound, and never under one chunk: the
+    rule reads the chunk alone."""
+    assert PREFILL_BUDGET_RIDGE == 256
+    assert default_prefill_token_budget(chunk) == max(
+        chunk, min(4 * chunk, PREFILL_BUDGET_RIDGE))
+    pool = KVBlockPool(1, 1, 4, 16, num_blocks=8)
+    sched = StepScheduler(max_batch, pool, 4096, prefill_chunk=chunk,
+                          prefill_token_budget=stated)
+    assert sched.prefill_chunk == chunk
+    assert sched.prefill_token_budget == want
+
+
+def _served_geometry(config):
+    with open(os.path.join(REPO, "perfbench/configs", config)) as f:
+        return json.load(f)["engine"]
+
+
+def _xglm_toy(max_seq_len):
+    return tiny_model(max_seq_len=max_seq_len)
+
+
+def _latent_toy(max_seq_len):
+    return GenerationModel.random(GenerationConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+        max_seq_len=max_seq_len, block=serving.LatentMoEBlock(
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            kv_lora_rank=128, n_routed_experts=8, experts_per_token=2,
+            n_shared_experts=2, moe_d_ff=32)), seed=7)
+
+
+def _window_toy(max_seq_len):
+    return GenerationModel.random(GenerationConfig(
+        vocab_size=96, d_model=64, n_heads=4, n_layers=4, d_ff=96,
+        max_seq_len=max_seq_len, block=serving.AfmoeBlock(
+            n_kv_heads=2, head_dim=128,
+            layer_types=["sliding_attention"] * 2
+            + ["full_attention", "sliding_attention"],
+            sliding_window=16, n_routed_experts=8, experts_per_token=2,
+            n_shared_experts=1, moe_d_ff=32)), seed=7)
+
+
+@pytest.mark.parametrize("config,toy,num_blocks,rows,parent_rows", [
+    ("xglm-1.7b-serve.json", _xglm_toy, 8, 272, 1040),
+    ("kanana-2-30b-a3b-serve.json", _latent_toy, 8, 192, 192),
+    ("trinity-large-preview-serve.json", _window_toy,
+     {"global": 8, "window": 8}, 1072, 1072),
+], ids=["xglm", "kanana", "trinity"])
+def test_chunk_rows_of_the_served_geometries(config, toy, num_blocks,
+                                             rows, parent_rows):
+    """The token rows the ONE chunk program is compiled for at the
+    benchmark's three serving geometries (a toy model of the
+    configuration's block in the configuration's engine): XGLM's fall
+    from 1,040 to 272; kanana's and trinity's, and with them their
+    step-cache keys, are what they were under a default of four chunks
+    (`max_batch + 4 * chunk`, and trinity's stated 1,024)."""
+    e = _served_geometry(config)
+    assert parent_rows == e["max_batch"] + e.get(
+        "prefill_token_budget", 4 * e["prefill_chunk"])
+    model = toy(e["max_seq_len"])
+    with serving.ServingEngine(
+            model, max_batch=e["max_batch"], max_seq_len=e["max_seq_len"],
+            block_size=e["block_size"], num_blocks=num_blocks,
+            prefill_chunk=e["prefill_chunk"],
+            prefill_token_budget=e.get("prefill_token_budget")) as eng:
+        w = eng._workers["default"]
+        assert w._chunk_rows == rows
+        blocks_a_row = w.scheduler.max_blocks_per_seq
+    chunk_keys = [k for k in model._steps if k[0] == "chunk"]
+    assert len(chunk_keys) == 1 and len(model._steps) == 2
+    geometry = ("chunk", e["max_batch"], blocks_a_row,
+                e["prefill_chunk"], False)
+    if model.config.block is None:
+        # the XGLM step states its rows where they are fewer than slots
+        assert chunk_keys[0][:5] == geometry
+        assert chunk_keys[0][-1] == "rows:%d" % rows
+    else:
+        assert chunk_keys[0][:6] == geometry + (rows,)
+    assert model.trace_count == 0           # built, never traced
 
 
 def test_chunked_budgeted_engine_token_identical():
@@ -308,6 +413,79 @@ def test_chunked_budgeted_engine_token_identical():
                                prefill_token_budget=4) as eng:
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         assert [r.wait(120) for r in reqs] == refs
+
+
+def test_long_prompts_under_a_one_chunk_budget_in_admission_order():
+    """Three prompts several chunks long admitted together, one chunk
+    of budget a step (what a default engine gives a chunk of 256): the
+    tokens are `reference_decode`'s, the first tokens come in the order
+    the requests were admitted, and the engine traced two shapes."""
+    model = tiny_model(seed=11)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 64, size=n).tolist() for n in (26, 19, 23)]
+    refs = [reference_decode(model, p, 6) for p in prompts]
+    first, lock = [], threading.Lock()
+
+    def stream_of(i):
+        def on_token(*_):
+            with lock:
+                if i not in first:
+                    first.append(i)
+        return on_token
+
+    with serving.ServingEngine(model, max_batch=4, max_seq_len=64,
+                               block_size=4, prefill_chunk=4,
+                               prefill_token_budget=4) as eng:
+        gate = threading.Event()
+        # hold the worker on a request's stream until all three wait in
+        # the queue, so that one tick admits them together
+        held = eng.submit([1, 2], max_new_tokens=2,
+                          stream=lambda *_: gate.wait(60))
+        reqs = [eng.submit(p, max_new_tokens=6, stream=stream_of(i))
+                for i, p in enumerate(prompts)]
+        gate.set()
+        assert held.wait(120) == reference_decode(model, [1, 2], 2)
+        assert [r.wait(120) for r in reqs] == refs
+        st = eng.stats()["default"]
+    assert first == [0, 1, 2]
+    # four prompt tokens a step at most: 68 of them took 17 mixed steps
+    assert st["steps"] >= (26 + 19 + 23) // 4
+    assert model.trace_count == 2
+
+
+def test_late_arrival_in_a_lower_slot_does_not_overtake():
+    """The budget goes to the prefilling row admitted FIRST, not to the
+    lowest slot: a request that lands in a freed lower slot waits until
+    the half-prefilled row above it is through its prompt."""
+    pool = KVBlockPool(1, 1, 4, 4, num_blocks=64)
+    sched = StepScheduler(2, pool, 64, prefill_chunk=4,
+                          prefill_token_budget=4)
+    q = RequestQueue(8)
+
+    def step():
+        plan, kind = sched.plan_step()
+        for seq, g in plan:
+            sched.record_token(seq, g, 1)
+        sched.reap()
+        return kind, sched.chunk_lens.tolist(), sched.rows_deferred
+
+    short = GenerationRequest([1, 2], max_new_tokens=1)
+    long_ = GenerationRequest(list(range(1, 15)), max_new_tokens=2)
+    q.submit(short)
+    q.submit(long_)
+    assert [s.slot for s in sched.admit(q)] == [0, 1]
+    assert step() == ("mixed", [2, 2], 0)   # slot 0 done, slot 1 at 2/14
+    assert sched.slots[0] is None and sched.slots[1].pos == 2
+    late = GenerationRequest(list(range(21, 31)), max_new_tokens=2)
+    q.submit(late)
+    assert [s.slot for s in sched.admit(q)] == [0]   # the lower slot
+    # three more chunks of the older prompt first; the late arrival
+    # sits them out however low its slot
+    for fed in (4, 4, 4):
+        assert step() == ("mixed", [0, fed], 1)
+    assert not sched.slots[1].in_prefill and sched.slots[0].pos == 0
+    # then the late arrival gets the budget while the older row decodes
+    assert step() == ("mixed", [4, 1], 0)
 
 
 # ---------------------------------------------------------------------------
@@ -459,11 +637,12 @@ def test_defaults_build_decode_and_chunk_shapes_and_no_index(monkeypatch):
     with serving.ServingEngine(model, max_batch=2, max_seq_len=64,
                                block_size=4) as eng:
         w = eng._workers["default"]
-        # the default chunk, clamped to the context; four chunks of
-        # prefill budget a mixed step
+        # the default chunk, clamped to the context; the rule's
+        # prefill budget a mixed step (four chunks reach the ridge here)
         assert serving.scheduler.DEFAULT_PREFILL_CHUNK == 256
         assert w.prefill_chunk == 64 and w.prefix_cache is False
-        assert w.scheduler.prefill_token_budget == 256
+        assert w.scheduler.prefill_token_budget \
+            == default_prefill_token_budget(64) == 256
         assert w.scheduler.chunk_feed.shape == (2, 64)
         reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
         assert [r.wait(120) for r in reqs] == refs
@@ -502,7 +681,8 @@ def test_env_flags_set_chunk_size_and_prefix_cache(monkeypatch):
                                block_size=4) as eng:
         w = eng._workers["default"]
         assert w.prefill_chunk == 4 and w.prefix_cache is True
-        assert w.scheduler.prefill_token_budget == 16  # 4 * chunk
+        assert w.scheduler.prefill_token_budget \
+            == default_prefill_token_budget(4) == 16    # four chunks
         assert eng.generate(prompt, max_new_tokens=5, timeout=120) == ref
         # 14 prompt tokens in chunks of 4, then 4 decode steps
         assert eng.stats()["default"]["steps"] == 4 + 4

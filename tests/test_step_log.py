@@ -161,7 +161,8 @@ def logged_run():
             off_records=off_records, raw_step=raw,
             counters={k: reg.counter("serving/" + k).value
                       for k in ("steps", "prefill_chunk_steps",
-                                "prefill_tokens", "decode_tokens")})
+                                "prefill_tokens", "decode_tokens",
+                                "prefill_rows_deferred")})
     finally:
         metrics.disable()
         metrics.reset()
@@ -195,6 +196,18 @@ def check_mixed_records_carry_rows_computed(run):
             assert r["slots_used"] <= r["rows_computed"]
         else:
             assert "rows_computed" not in r
+
+
+def check_mixed_records_carry_rows_deferred(run):
+    # four rows of one chunk at most against a budget of four chunks:
+    # the budget never binds here, and the field says so on every mixed
+    # record and on no other
+    for r in run["records"]:
+        if r["kind"] == "mixed":
+            assert r["rows_deferred"] == 0
+        else:
+            assert "rows_deferred" not in r
+    assert run["counters"]["prefill_rows_deferred"] == 0
 
 
 def check_slots_used_is_what_the_scheduler_planned(run):
@@ -260,6 +273,7 @@ def check_metrics_off_writes_nothing_and_changes_no_token(run):
 @pytest.mark.parametrize("check", [
     check_one_record_per_step, check_mixed_steps_are_the_chunk_steps,
     check_mixed_records_carry_rows_computed,
+    check_mixed_records_carry_rows_deferred,
     check_slots_used_is_what_the_scheduler_planned,
     check_stamps_are_ordered, check_host_and_wait_fit_in_the_tick,
     check_cold_is_the_first_step_of_each_shape,
@@ -370,6 +384,39 @@ def test_rows_computed_is_the_promise_or_the_window(metrics_on, engine_kw,
     for r in mixed:
         assert r["rows_computed"] == rows <= r["slots_total"]
         assert r["slots_used"] <= r["rows_computed"]
+
+
+@pytest.mark.parametrize("engine_kw,binds", [
+    (dict(prefill_token_budget=8), True),     # one chunk a step
+    (dict(prefill_token_budget=12), True),    # a chunk and a half
+    (dict(), False),                          # the rule's: four chunks
+], ids=["one_chunk", "chunk_and_a_half", "default_budget"])
+def test_rows_deferred_counts_what_sat_out(metrics_on, engine_kw, binds):
+    """`rows_deferred` of a mixed record: the prefilling rows that took
+    no token in the step because the rows admitted before them had the
+    budget. Six prompts over four rows: with a chunk of budget a step
+    some row waits in most mixed steps, with the default never; the
+    counter is the records' sum and the registry summarises the field."""
+    tokens, _steps = serve(toy_model(), **engine_kw)
+    assert all(len(t) == NEW_TOKENS for t in tokens)
+    mixed = [r for r in metrics_on.samples("serving/step").records()
+             if r["kind"] == "mixed"]
+    budget = engine_kw.get("prefill_token_budget", 4 * 8)
+    for r in mixed:
+        assert 0 <= r["rows_deferred"] <= r["rows"] + r["rows_deferred"] \
+            <= 4
+        assert r["prefill_tokens"] <= budget
+        # a row sits out only where the budget was spent to the last
+        # token on the rows ahead of it
+        assert not r["rows_deferred"] or r["prefill_tokens"] == budget
+    total = sum(r["rows_deferred"] for r in mixed)
+    assert (total > 0) == binds
+    assert metrics_on.counter("serving/prefill_rows_deferred").value \
+        == total
+    summary = metrics_on.to_dict()["samples"]["serving/step"]["fields"]
+    assert summary["rows_deferred"]["count"] == len(mixed)
+    assert summary["rows_deferred"]["max"] == max(
+        r["rows_deferred"] for r in mixed)
 
 
 def test_a_speculative_window_is_logged_as_spec(metrics_on):

@@ -79,6 +79,12 @@ def write(root, out, compile_too):
                                     KVBlockPool, latent_moe)
     from paddle_tpu.serving.model import weight_names
     from perfbench.runners import serve_latent
+    try:
+        from paddle_tpu.serving.scheduler import \
+            default_prefill_token_budget
+    except ImportError:   # a checkout whose unstated budget is 4 chunks
+        def default_prefill_token_budget(chunk):
+            return 4 * chunk
 
     chip = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0]
@@ -128,10 +134,11 @@ def write(root, out, compile_too):
         row, on = arg((B,)), arg((B,), jnp.bool_)
         # one block table, or the stack of them, a table a page kind
         tables = arg((len(kinds), B, Mb) if kinds else (B, Mb))
-        budget = e.get("prefill_token_budget", 4 * C)
+        budget = e.get("prefill_token_budget",
+                       default_prefill_token_budget(C))
         # the engine's calls: prompt_feed, use_prompt, prev_tokens,
         # positions, (lengths,) block_tables, active; its promise of
-        # max_batch + four chunks of token rows
+        # max_batch + the scheduler's prefill budget of token rows
         emit(name + "_decode_step", model.make_decode_step(B, Mb),
              (model.weights,) + pool + (row, on, row, row, tables, on))
         emit(name + "_chunk_step",
