@@ -70,9 +70,12 @@ ttft(_p50/_p99),steps,prefill_tokens,decode_tokens,prefill_chunk_steps,
 prefix_blocks_reused,prefix_tokens_skipped,spec_steps,spec_proposed,
 spec_accepted,spec_rejected,spec_accept_rate,prefill_rows_deferred,
 requests_submitted,requests_completed,requests_rejected,
-requests_failed}``, and the step
-log ``serving/step``: one record per dispatched step, written by the
-worker where the work happens (:class:`_TickLog`).
+requests_failed}``, and the two logs
+the worker writes where the work happens (:class:`_TickLog`):
+``serving/step``, one record per dispatched step, and
+``serving/request``, one record per request that leaves the engine,
+whose time to first token is the sum of its queue, plan, prefill,
+in-flight and delivery phases (``_ModelWorker._request_record``).
 """
 
 import threading
@@ -98,6 +101,14 @@ __all__ = ["ServingEngine"]
 # the fields of a `serving/step` record that the registry dump and
 # /metrics summarise
 STEP_LOG_FIELDS = ("device_ms", "host_ms", "wait_ms", "rows_deferred")
+# and of a `serving/request` record
+REQUEST_LOG_FIELDS = ("ttft_ms", "queue_ms", "plan_ms", "prefill_ms",
+                      "inflight_ms", "ahead_ms", "deliver_ms", "latency_ms")
+
+
+def _ms(t0, t1):
+    """Milliseconds from one stamp to another; None where either is."""
+    return None if t0 is None or t1 is None else (t1 - t0) * 1e3
 
 
 class _TickLog:
@@ -456,6 +467,8 @@ class _ModelWorker:
                     break
                 req._finish(e)
                 _metrics.counter("serving/requests_failed").inc()
+                self.scheduler._note_departed(req, None, "failed")
+            self._close_requests()
         # black box: the uncaught-worker-death dump trigger — recorded
         # AFTER the cv region (dump does file I/O; the ring lock is the
         # only lock it takes)
@@ -533,6 +546,8 @@ class _ModelWorker:
             self._check_invariants()
         if tick is not None:
             self._close_tick(tick)
+        if sched.departed:
+            self._close_requests()
 
     def _check_invariants(self):
         """Step-boundary runtime audit (PTPU_LOCK_CHECK=1 only): the
@@ -697,6 +712,74 @@ class _ModelWorker:
                     int(rec["t_ready"] * 1e9), model=self.name,
                     step=rec["step"])
 
+    # -- the request log ------------------------------------------------
+    def _close_requests(self):
+        """Write one `serving/request` record for each request that left
+        since the last call (the scheduler's `departed`). Called once
+        the tick's step records are complete: the step that gave a
+        request's first token was consumed in this tick or an earlier
+        one, so its `t_ready` and `device_ms` are there to read."""
+        departed = self.scheduler.departed
+        log = _metrics.samples("serving/request", fields=REQUEST_LOG_FIELDS)
+        for request, noted, outcome in departed:
+            log.add(self._request_record(request, noted, outcome))
+        del departed[:]
+
+    def _request_record(self, request, noted, outcome):
+        """One request's record (docs/OBSERVABILITY.md, "The serving
+        request log"). Every stamp is one that was taken where the thing
+        happened: the request's own (`submit_time`, `start_time` in
+        `StepScheduler.admit`, `first_token_time` in `record_token`,
+        `finish_time`) and the `t_dispatched` / `t_ready` of the step
+        records that carried it (`noted`, its `_RequestLog`: an empty
+        one where it never reached a slot). The five phases are
+        differences of consecutive stamps and `ttft_ms` is their sum."""
+        first, token = noted.first_rec, noted.token_rec
+        t_submit, t_admit = request.submit_time, request.start_time
+        t_first = first and first["t_dispatched"]
+        t_last = token and token["t_dispatched"]
+        # None until the step's result has been taken
+        t_ready = token and token.get("t_ready")
+        t_token, t_finish = request.first_token_time, request.finish_time
+        phases = (_ms(t_submit, t_admit), _ms(t_admit, t_first),
+                  _ms(t_first, t_last), _ms(t_last, t_ready),
+                  _ms(t_ready, t_token))
+        queue_ms, plan_ms, prefill_ms, inflight_ms, deliver_ms = phases
+        device_ms = token and token.get("device_ms")
+        return {
+            "request": request.id, "model": self.name,
+            "trace_id": request.trace_id, "outcome": outcome,
+            "prompt_tokens": len(request.prompt),
+            "output_tokens": len(request.tokens),
+            # a step that carried it traced or compiled: its times are
+            # not a warm request's
+            "cold": noted.cold,
+            "t_submit": t_submit, "t_admit": t_admit,
+            "t_first_dispatch": t_first,
+            "t_last_prefill_dispatch": t_last, "t_first_ready": t_ready,
+            "t_first_token": t_token, "t_finish": t_finish,
+            "first_step": first and first["step"],
+            "first_token_step": token and token["step"],
+            "last_step": noted.last_step,
+            "prefill_steps": noted.prefill_steps,
+            "deferred_steps": noted.deferred_steps,
+            "queued_at_first_token": token and token["queued"],
+            "gaps": noted.gaps, "gaps_mixed": noted.gaps_mixed,
+            "gap_max_ms": noted.gap_max_ms,
+            "gap_max_kind": noted.gap_max_kind,
+            "queue_ms": queue_ms, "plan_ms": plan_ms,
+            "prefill_ms": prefill_ms, "inflight_ms": inflight_ms,
+            # what the first token's step stood behind steps queued
+            # ahead of it: its time in flight less the device's own
+            "ahead_ms": (None if inflight_ms is None or device_ms is None
+                         else inflight_ms - device_ms),
+            "deliver_ms": deliver_ms,
+            # added in this order, left to right (`sum()` compensates
+            # its rounding and can differ from it by an ulp)
+            "ttft_ms": (None if None in phases else queue_ms + plan_ms
+                        + prefill_ms + inflight_ms + deliver_ms),
+            "latency_ms": _ms(t_submit, t_finish)}
+
     def _dispatch(self, plan, kind):
         mixed = kind == "mixed"
         sched = self.scheduler
@@ -768,20 +851,23 @@ class _ModelWorker:
                                      * sched.max_blocks_per_seq)
             if counters is not None:
                 rec["_counters"] = counters      # read when consumed
-            if _tracing.enabled():
-                # request-scoped view of the same step: one window event
-                # per traced request riding this dispatch, so a
-                # request's trace shows ITS prefill/decode activity, not
-                # just engine steps
+            # request-scoped view of the same step, from the record's
+            # own stamps into two sinks: each row's request log notes
+            # the record, and a traced request gets one window event,
+            # so its trace shows ITS prefill/decode activity, not just
+            # engine steps
+            traced = _tracing.enabled()
+            if traced:
                 t0 = int(rec["t_planned"] * 1e9)
                 t1 = int(rec["t_dispatched"] * 1e9)
-                for seq, _gen_idx in plan:
-                    tid = seq.request.trace_id
-                    if tid is None:
-                        continue
+            for seq, gen_idx in plan:
+                prefill = bool(sched.use_prompt[seq.slot])
+                if seq.log is not None:
+                    seq.log.dispatched(rec, prefill, gen_idx)
+                tid = seq.request.trace_id
+                if traced and tid is not None:
                     _tracing.complete(
-                        "prefill_chunk" if sched.use_prompt[seq.slot]
-                        else "decode_window",
+                        "prefill_chunk" if prefill else "decode_window",
                         t0, t1, trace_id=tid, request=seq.request.id,
                         model=self.name)
         self._prev_tokens = next_tokens
@@ -874,11 +960,16 @@ class _ModelWorker:
                 self.max_batch * sched.spec_feed.shape[1], traces0)
         # materialize NOW (the sync contract)
         outs, waited = self._materialize(tick, out)
-        if rec is not None and _tracing.enabled():
+        if rec is not None:
+            # the same two sinks as `_dispatch`, a window's extent
+            # reaching to its synchronous result
+            traced = _tracing.enabled()
             t0, t1 = int(rec["t_planned"] * 1e9), int(waited[2] * 1e9)
             for seq, window in plan:
+                if seq.log is not None:
+                    seq.log.dispatched(rec, False, None)
                 tid = seq.request.trace_id
-                if tid is not None:
+                if traced and tid is not None:
                     _tracing.complete(
                         "spec_window", t0, t1, trace_id=tid,
                         request=seq.request.id, model=self.name,
@@ -888,7 +979,7 @@ class _ModelWorker:
             self._t_first_step = now
         self._t_last_step = now
         with _phase(tick, "stream"):
-            n_emitted = self._fold_spec(plan, outs)
+            n_emitted = self._fold_spec(plan, outs, rec)
         self._gen_tokens += n_emitted
         if (self._t_first_step is not None
                 and self._t_last_step > self._t_first_step):
@@ -911,10 +1002,11 @@ class _ModelWorker:
             reg.gauge("serving/spec_accept_rate").set(
                 sched.spec_accepted / max(1, sched.spec_proposed))
 
-    def _fold_spec(self, plan, outs):
+    def _fold_spec(self, plan, outs, rec):
         """Fold a materialized verify window back into its sequences
         (acceptance, rollback, stream callbacks); returns the tokens
-        emitted."""
+        emitted. `rec`: the window's step record while the log records,
+        which each row's request log notes its tokens against."""
         import jax.numpy as jnp
 
         sched = self.scheduler
@@ -957,23 +1049,21 @@ class _ModelWorker:
                     jnp.asarray(commit_active))
                 self.spec_tree_commits += 1
                 _metrics.counter("serving/spec_tree_commits").inc()
-            for seq, window, path, emitted in acc:
-                was_done = seq.request.finished
-                n_emitted += sched.record_spec_tree(seq, window, path,
-                                                    emitted)
-                if seq.request.tokens:
-                    prev[seq.slot] = seq.request.tokens[-1]
-                if seq.request.finished and not was_done:
-                    self._note_completion(seq.request)
+            record = sched.record_spec_tree
+            rows = [(seq, rest) for seq, *rest in acc]
         else:
-            for seq, window in plan:
-                was_done = seq.request.finished
-                n_emitted += sched.record_spec(seq, window,
-                                               outs[seq.slot])
-                if seq.request.tokens:
-                    prev[seq.slot] = seq.request.tokens[-1]
-                if seq.request.finished and not was_done:
-                    self._note_completion(seq.request)
+            record = sched.record_spec
+            rows = [(seq, (window, outs[seq.slot])) for seq, window in plan]
+        for seq, args in rows:
+            was_done = seq.request.finished
+            n = record(seq, *args)
+            n_emitted += n
+            if n and seq.log is not None and rec is not None:
+                seq.log.emitted(rec, time.perf_counter(), n)
+            if seq.request.tokens:
+                prev[seq.slot] = seq.request.tokens[-1]
+            if seq.request.finished and not was_done:
+                self._note_completion(seq.request)
         self._prev_tokens = jnp.asarray(prev)
         return n_emitted
 
@@ -987,16 +1077,22 @@ class _ModelWorker:
             top_logits = np.asarray(top_logits)
         with _phase(tick, "stream"):
             for seq, gen_idx in plan:
-                was_done = seq.request.finished
-                had_first = seq.request.first_token_time is not None
+                request = seq.request
+                was_done = request.finished
+                n_before = len(request.tokens)
                 self.scheduler.record_token(
                     seq, gen_idx, tokens[seq.slot],
                     None if top_logits is None else top_logits[seq.slot])
-                if (not had_first
-                        and seq.request.first_token_time is not None):
-                    self._note_first_token(seq.request)
-                if seq.request.finished and not was_done:
-                    self._note_completion(seq.request)
+                if len(request.tokens) != n_before:
+                    if not n_before:
+                        self._note_first_token(request)
+                    if seq.log is not None and rec is not None:
+                        # the first token's stamp is the request's own
+                        seq.log.emitted(
+                            rec, time.perf_counter() if n_before
+                            else request.first_token_time)
+                if request.finished and not was_done:
+                    self._note_completion(request)
         if tick is not None:
             self._consume_record(tick, rec, waited)
         if gen_tokens := sum(1 for _, g in plan if g is not None):

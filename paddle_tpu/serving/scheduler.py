@@ -301,12 +301,63 @@ class RequestQueue:
         return expired
 
 
+class _RequestLog:
+    """What the worker notes of ONE request while the request log
+    records (`serving/request`, docs/OBSERVABILITY.md "The serving
+    request log"): which step records carried it, and its counts. It
+    hangs off the request's `_Sequence` and holds no clock reading of
+    its own but the last emission's: every other stamp of the record is
+    the request's (`submit_time`, `start_time`, `first_token_time`,
+    `finish_time`) or a step record's (`t_dispatched`, `t_ready`)."""
+
+    __slots__ = ("first_rec", "token_rec", "last_step", "prefill_steps",
+                 "deferred_steps", "cold", "gaps", "gaps_mixed",
+                 "gap_max_ms", "gap_max_kind", "t_emitted")
+
+    def __init__(self):
+        self.first_rec = None    # record of the first step with its prompt
+        self.token_rec = None    # ... of the step that ends its prompt
+        self.last_step = None    # `step` of the one that gave its last token
+        self.prefill_steps = self.deferred_steps = 0
+        self.cold = False
+        self.gaps = self.gaps_mixed = 0
+        self.gap_max_ms = self.gap_max_kind = None
+        self.t_emitted = None
+
+    def dispatched(self, rec, prefill, gen_idx):
+        """The step of `rec` carries a row of this request: `prefill`,
+        prompt tokens of it; `gen_idx == 0`, its last one."""
+        if rec["cold"]:
+            self.cold = True
+        if prefill:
+            self.prefill_steps += 1
+            if self.first_rec is None:
+                self.first_rec = rec
+            if gen_idx == 0:
+                self.token_rec = rec
+
+    def emitted(self, rec, now, n=1):
+        """The step of `rec` gave `n` tokens of this request (a verify
+        window may give several), recorded at `now`."""
+        if self.t_emitted is None:
+            n -= 1                       # the first token ends no gap
+        else:
+            gap_ms = (now - self.t_emitted) * 1e3
+            if self.gap_max_ms is None or gap_ms > self.gap_max_ms:
+                self.gap_max_ms, self.gap_max_kind = gap_ms, rec["kind"]
+        self.gaps += n
+        if rec["kind"] == "mixed":
+            self.gaps_mixed += n
+        self.t_emitted = now
+        self.last_step = rec["step"]
+
+
 class _Sequence:
     """Scheduler-internal per-slot decode state."""
 
     __slots__ = ("request", "slot", "admitted", "pos", "n_dispatched",
                  "pending", "finished", "dispatch_done", "prefix_keys",
-                 "sealed_upto")
+                 "sealed_upto", "log")
 
     def __init__(self, request, slot, admitted):
         self.request = request
@@ -319,6 +370,7 @@ class _Sequence:
         self.dispatch_done = False  # no more steps will be dispatched
         self.prefix_keys = ()    # content keys of the prompt's full blocks
         self.sealed_upto = 0     # prompt blocks already in the pool index
+        self.log = None          # its _RequestLog while the log records
 
     @property
     def in_prefill(self):
@@ -371,6 +423,10 @@ class StepScheduler:
         # prefilling rows the last planned step gave no token for want
         # of budget (the step log's `rows_deferred`)
         self.rows_deferred = 0
+        # (request, its _RequestLog, outcome) of the requests that left
+        # since the worker last wrote the request log; filled only
+        # while that log records (`_note_departed`)
+        self.departed = []
         self.chunk_feed = np.zeros(
             (self.max_batch, self.prefill_chunk), np.int32)
         self.chunk_lens = np.zeros(self.max_batch, np.int32)
@@ -465,6 +521,7 @@ class StepScheduler:
                     "prompt length %d >= engine max_seq_len %d"
                     % (len(request.prompt), self.max_seq_len)))
                 _metrics.counter("serving/requests_failed").inc()
+                self._note_departed(request, None, "failed")
                 continue
             seq = _Sequence(request, slot, next(self._admissions))
             keys = ()
@@ -482,9 +539,12 @@ class StepScheduler:
                 break  # KV gate: head doesn't fit — keep queue order
             queue.pop()
             request.start_time = time.perf_counter()
+            if _metrics.enabled():
+                seq.log = _RequestLog()
             if request.trace_id is not None and _tracing.enabled():
-                # retroactive queue_wait span (submit -> admission) plus
-                # an admit marker carrying the slot the request landed in
+                # retroactive queue_wait span (submit -> admission: the
+                # request log's `t_submit`, `t_admit`) plus an admit
+                # marker carrying the slot the request landed in
                 _tracing.complete(
                     "queue_wait", int(request.submit_time * 1e9),
                     int(request.start_time * 1e9),
@@ -559,6 +619,8 @@ class StepScheduler:
                     len(seq.request.prompt) - seq.pos, budget)
             granted[seq.slot] = n
             budget -= n
+            if not n and seq.log is not None:
+                seq.log.deferred_steps += 1
         self.rows_deferred = sum(not n for n in granted.values())
         plan = []
         for slot, seq in enumerate(self.slots):
@@ -896,6 +958,7 @@ class StepScheduler:
                 seq.finished = True
                 seq.dispatch_done = True
                 request._finish()
+                self._note_departed(request, seq, "finished")
                 break
         seq.pos = pos + n_emit
         seq.n_dispatched = len(request.tokens)
@@ -947,6 +1010,20 @@ class StepScheduler:
             seq.finished = True
             seq.dispatch_done = True
             request._finish()
+            self._note_departed(request, seq, "finished")
+
+    def _note_departed(self, request, seq, outcome):
+        """`request` has left with `outcome` (``finished``, ``failed``,
+        ``expired``); `seq` is None where it never reached a slot, and
+        nothing was noted of it. While the request log records it is
+        kept in `departed`, with what the worker noted of it, until the
+        worker writes the log at the end of its tick; otherwise nothing
+        is."""
+        if seq is None:
+            if _metrics.enabled():
+                self.departed.append((request, _RequestLog(), outcome))
+        elif seq.log is not None:
+            self.departed.append((request, seq.log, outcome))
 
     def expire_deadlines(self, queue, now=None):
         """Fail every request whose deadline passed — queued requests
@@ -966,6 +1043,7 @@ class StepScheduler:
                 "(waited %.3fs)" % (request.id,
                                     now - request.submit_time)))
             self._note_expired(request, "queued")
+            self._note_departed(request, None, "expired")
             expired += 1
         for seq in self.slots:
             if seq is None or seq.finished:
@@ -981,6 +1059,7 @@ class StepScheduler:
                 % (seq.request.id, len(seq.request.tokens),
                    seq.request.max_new_tokens)))
             self._note_expired(seq.request, "mid_generation")
+            self._note_departed(seq.request, seq, "expired")
             expired += 1
         if expired:
             self.deadline_expired += expired
@@ -1010,6 +1089,7 @@ class StepScheduler:
                 # ran out of budget (max_new/max_seq) without EOS
                 seq.finished = True
                 seq.request._finish()
+                self._note_departed(seq.request, seq, "finished")
             if seq.finished:
                 self.pool.free_owner(seq)
                 self._release_draft_state(seq)
@@ -1035,5 +1115,6 @@ class StepScheduler:
             if not seq.request.finished:
                 seq.request._finish(error)
                 _metrics.counter("serving/requests_failed").inc()
+                self._note_departed(seq.request, seq, "failed")
             self.slots[slot] = None
             self.active[slot] = False
