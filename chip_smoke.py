@@ -942,6 +942,11 @@ def leg_kernels(sz, rehearsal):
             want = jax.jit(lambda *a: spec.fallback(*a, **kwargs))(*args)
         got = np.asarray(got, np.float32)
         want = np.asarray(want, np.float32)
+        if name == "chunk_window":
+            # an unused tile's slots are not written (the chunk step
+            # reads none of them): the tiles that hold a token compare
+            used = np.asarray(args[5]) > 0
+            got, want = got[used], want[used]
         scale = float(np.abs(want).max())
         err = float(np.abs(got - want).max())
         bound = (INT8_REL_BOUND if name == "int8_matmul"

@@ -66,8 +66,9 @@ paged_chunk_attention: the chunked-prefill window's attention over the
   row's block-table line; the pool stays in HBM and a tile's own pages,
   up to the page of its last token and no further, are copied in runs
   by manual DMA, as ``latent_paged_attention`` does. Nothing of
-  ``[B, C, H, T]`` exists, and a one-token tile computes one sublane
-  tile of query rows.
+  ``[B, C, H, T]`` exists. A tile of length 0 is skipped and moves
+  nothing: the chunk step hands its one-token tiles to
+  ``paged_decode_attention`` and marks them so here.
 
 gqa_paged_decode_attention / gqa_paged_chunk_attention: the same two
   page walks for a GROUPED-QUERY block over a packed bfloat16 pool
@@ -529,9 +530,10 @@ def _run_copies(tables_ref, row, run, n_pages, layer, k_hbm, v_hbm, kbuf,
     jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
 
 
-def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
-                       k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_scr, l_scr,
-                       acc_scr, *, sm_scale, block_size, pages, n_heads):
+def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, _blk_ref,
+                       q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_scr,
+                       l_scr, acc_scr, *, sm_scale, block_size, pages,
+                       n_heads):
     """Grid (tiles,): one query tile a grid step. Both pools stay in
     HBM; the pages of the tile's row, up to the page of the tile's LAST
     token and no further, are copied into one of two VMEM buffers in
@@ -543,20 +545,24 @@ def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
     softmax of every head is carried over the tile's runs.
 
     Tile slot c holds the token at logical position ``pos + c`` and sees
-    ``t <= pos + c``. A tile of one token (every decode row of a mixed
-    step) computes its first sublane tile of query rows only; an empty
-    tile computes nothing. Slots at or past the tile's length come out
-    zero. Lines of a buffer past the tile's last page keep an earlier
-    tile's values: finite (the buffers are zeroed once) and masked by
-    position. The operands of both products are rounded to bfloat16,
-    which is what a default-precision fp32 dot does on the chip; the
-    softmax statistics and both accumulations are fp32."""
+    ``t <= pos + c``. Every live tile computes all ``Cq`` query rows:
+    a tile of one token is right but costs a whole tile's arithmetic,
+    and the chunk step sends those to ``paged_decode_attention``
+    instead. Slots at or past the tile's length come out zero. An empty
+    tile computes nothing and MOVES nothing: its query and output
+    blocks are a live tile's (``_chunk_call``), so it must leave
+    ``o_ref`` alone, and its own slots of the output are never
+    written. Lines of a buffer past
+    the tile's last page keep an earlier tile's values: finite (the
+    buffers are zeroed once) and masked by position. The operands of
+    both products are rounded to bfloat16, which is what a
+    default-precision fp32 dot does on the chip; the softmax statistics
+    and both accumulations are fp32."""
     t = pl.program_id(0)
     bs, P = block_size, pages
     span = P * bs
     Cq = q_ref.shape[1]
     Dh = q_ref.shape[2] // n_heads
-    one = min(Cq, 16)                  # a bf16 sublane tile of query rows
     n_tok = len_ref[t]
     pos0 = pos_ref[t]
     n_pages = (pos0 + jnp.maximum(n_tok, 1) - 1) // bs + 1
@@ -572,51 +578,39 @@ def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
         _run_copies(tables_ref, t, run, n_pages, layer, k_hbm, v_hbm, kbuf,
                     vbuf, sems, half, pages=P, block_size=bs, start=start)
 
-    def attend(run, half, nq):
+    def attend(run, half):
         t_pos = run * span + jax.lax.broadcasted_iota(
-            jnp.int32, (nq, span), 1)
+            jnp.int32, (Cq, span), 1)
         mask = t_pos <= pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (nq, span), 0)
+            jnp.int32, (Cq, span), 0)
         for h in range(n_heads):
-            q = q_ref[0, :nq, h * Dh:(h + 1) * Dh]         # [nq, Dh] bf16
+            q = q_ref[0, :, h * Dh:(h + 1) * Dh]            # [Cq, Dh] bf16
             k = kbuf[half, :, h, :].astype(jnp.bfloat16)    # [span, Dh]
             v = vbuf[half, :, h, :].astype(jnp.bfloat16)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
             s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_scr[h, :nq, :1]
+            m_prev = m_scr[h, :, :1]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_new = l_scr[h, :nq, :1] * alpha \
-                + p.sum(axis=-1, keepdims=True)
-            acc_scr[h, :nq, :] = acc_scr[h, :nq, :] * alpha \
-                + jax.lax.dot_general(
-                    p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_scr[h, :nq, :] = jnp.broadcast_to(m_new, (nq, m_scr.shape[2]))
-            l_scr[h, :nq, :] = jnp.broadcast_to(l_new, (nq, l_scr.shape[2]))
+            l_new = l_scr[h, :, :1] * alpha + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    def finish(nq):
-        live = jax.lax.broadcasted_iota(jnp.int32, (nq, Dh), 0) < n_tok
+    def finish():
+        live = jax.lax.broadcasted_iota(jnp.int32, (Cq, Dh), 0) < n_tok
         for h in range(n_heads):
-            o_ref[0, :nq, h * Dh:(h + 1) * Dh] = jnp.where(
-                live, acc_scr[h, :nq, :]
-                / jnp.maximum(l_scr[h, :nq, :1], 1e-30), 0.0)
-
-    def by_size(fn):
-        """`fn(nq)` for the tile's size: one token, or a window."""
-        if one == Cq:
-            fn(Cq)
-        else:
-            pl.when(n_tok == 1)(lambda: fn(one))
-            pl.when(n_tok > 1)(lambda: fn(Cq))
-
-    o_ref[...] = jnp.zeros_like(o_ref)
+            o_ref[0, :, h * Dh:(h + 1) * Dh] = jnp.where(
+                live, acc_scr[h] / jnp.maximum(l_scr[h, :, :1], 1e-30), 0.0)
 
     @pl.when(n_tok > 0)
     def _tile():
+        o_ref[...] = jnp.zeros_like(o_ref)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -630,11 +624,11 @@ def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
                 copies(run + 1, 1 - half, True)
 
             copies(run, half, False)
-            by_size(lambda nq: attend(run, half, nq))
+            attend(run, half)
             return carry
 
         jax.lax.fori_loop(0, n_runs, one_run, 0)
-        by_size(finish)
+        finish()
 
 
 def paged_chunk_attention(k_pool, v_pool, q, block_tables, positions,
@@ -651,22 +645,26 @@ def paged_chunk_attention(k_pool, v_pool, q, block_tables, positions,
     sequence, slot c at logical position ``positions[n] + c``, and
     ``block_tables[n]`` (``[N, Mb]``) is that sequence's block-table
     line. A decode row is a tile of one token; a prefilling row's chunk
-    is ``ceil(tokens / Cq)`` tiles; a tile of length 0 is skipped. The
-    window's K and V are written before the call, so slot c sees
-    ``t <= positions[n] + c``: the committed prefix and the window's
-    earlier tokens.
+    is ``ceil(tokens / Cq)`` tiles. The window's K and V are written
+    before the call, so slot c sees ``t <= positions[n] + c``: the
+    committed prefix and the window's earlier tokens.
 
     Returns the ``[N, Cq, H, Dh]`` fp32 context, zero at slots at or
-    past a tile's length. Operands of both products rounded to bfloat16
+    past a LIVE tile's length. A tile of length 0 is skipped: nothing is
+    computed, copied in or written for it, and its slots of the result
+    hold whatever the buffer held, so the caller reads none of them (the
+    chunk step sends its one-token tiles to ``paged_decode_attention``
+    and marks them 0 here: 3 MB a skipped tile and layer that no longer
+    move). Operands of both products rounded to bfloat16
     (a default-precision fp32 dot on the chip), online softmax in fp32:
     token-identical to the gathered reference, not bitwise."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     # the layer goes in as a traced scalar, under one jitted function:
     # a step calls this once a layer, and tracing and lowering the
-    # kernel's body (two sizes of tile, every head unrolled) for each
-    # call cost 10 s of a 24-layer step's first call, compile cache or
-    # not (a cached program is found by its lowered text)
+    # kernel's body (every head unrolled) for each call cost 10 s of a
+    # 24-layer step's first call, compile cache or not (a cached
+    # program is found by its lowered text)
     return _chunk_call(k_pool, v_pool, q, block_tables, positions,
                        lengths, jnp.asarray(layer, jnp.int32),
                        sm_scale=float(sm_scale),
@@ -682,15 +680,15 @@ def _chunk_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
     N, Cq, H, Dh = q.shape
     bs = k_pool.shape[2]
 
-    def tile(n, *_):
-        return (n, 0, 0)
+    def tile(n, _tables, _pos, _len, _layer, blk):
+        return (blk[n], 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     out = pl.pallas_call(
         functools.partial(_chunk_attn_kernel, sm_scale=sm_scale,
                           block_size=bs, pages=pages, n_heads=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(N,),
             in_specs=[pl.BlockSpec((1, Cq, H * Dh), tile), hbm, hbm],
             out_specs=pl.BlockSpec((1, Cq, H * Dh), tile),
@@ -709,9 +707,21 @@ def _chunk_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
         name="paged_chunk_attention",
     )(block_tables.astype(jnp.int32),
       jnp.maximum(positions, 0).astype(jnp.int32),
-      lengths.astype(jnp.int32), layer.reshape(1),
+      lengths.astype(jnp.int32), layer.reshape(1), _tile_blocks(lengths),
       q.reshape(N, Cq, H * Dh).astype(jnp.bfloat16), k_pool, v_pool)
     return out.reshape(N, Cq, H, Dh)
+
+
+def _tile_blocks(lengths):
+    """The query and output block of each grid step of
+    ``_chunk_attn_kernel``: a live tile's own; a skipped tile's is the
+    last live tile's before it (the first live tile's where none came
+    before), so the block index does not change over a skipped tile and
+    Pallas copies nothing in or out for it."""
+    n = jnp.arange(lengths.shape[0], dtype=jnp.int32)
+    live = lengths > 0
+    last = jax.lax.cummax(jnp.where(live, n, -1))
+    return jnp.where(last < 0, jnp.argmax(live).astype(jnp.int32), last)
 
 
 def paged_chunk_attention_reference(k_pool, v_pool, q, block_tables,

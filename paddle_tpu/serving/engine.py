@@ -69,7 +69,7 @@ kv_blocks_in_use,tokens_per_sec,request_latency(_p50/_p99),
 ttft(_p50/_p99),steps,prefill_tokens,decode_tokens,prefill_chunk_steps,
 prefix_blocks_reused,prefix_tokens_skipped,spec_steps,spec_proposed,
 spec_accepted,spec_rejected,spec_accept_rate,prefill_rows_deferred,
-requests_submitted,requests_completed,requests_rejected,
+mixed_one_token_rows,requests_submitted,requests_completed,requests_rejected,
 requests_failed}``, and the two logs
 the worker writes where the work happens (:class:`_TickLog`):
 ``serving/step``, one record per dispatched step, and
@@ -838,6 +838,10 @@ class _ModelWorker:
                 rec["rows_computed"] = self._chunk_rows
                 # prefilling rows the budget left without a token
                 rec["rows_deferred"] = sched.rows_deferred
+                # rows holding ONE token (every decode row, a prompt's
+                # last token): the tiles a decode kernel takes
+                rec["one_token_rows"] = int(
+                    (sched.chunk_lens[sched.active] == 1).sum())
             if len(self.pool.kinds) > 1:
                 rec.update(self._pages_walked_by_kind())
             elif not mixed:
@@ -888,6 +892,8 @@ class _ModelWorker:
                 reg.counter("serving/prefill_chunk_steps").inc()
                 reg.counter("serving/prefill_rows_deferred").inc(
                     rec["rows_deferred"])
+                reg.counter("serving/mixed_one_token_rows").inc(
+                    rec["one_token_rows"])
             reg.counter("serving/prefill_tokens").inc(
                 rec["prefill_tokens"])
             reg.counter("serving/decode_tokens").inc(rec["decode_tokens"])

@@ -1219,18 +1219,51 @@ class GenerationModel:
             # invalid slots, into the null block), and the query tiles
             write_blk, slot_idx = layout["write_blk"], layout["slot_idx"]
             n_tiles, Cq = layout["tile_rows"].shape
-            tile_attention = (
-                _pk.paged_chunk_attention
-                if _choose_kernel("chunk_window", head_dim=Dh,
-                                  block_size=bs, window=Cq)
-                else _pk.paged_chunk_attention_reference)
+            use_chunk = _choose_kernel("chunk_window", head_dim=Dh,
+                                       block_size=bs, window=Cq)
+            tile_attention = (_pk.paged_chunk_attention if use_chunk
+                              else _pk.paged_chunk_attention_reference)
+            # A tile of ONE token (every decode row of a mixed step, a
+            # chunk's tail of one, a one-token prompt) is a decode
+            # query: where both kernels are chosen (heads of whole lane
+            # tiles, so `paged_decode_attention` walks the row's own
+            # pages) the decode kernel takes it and the chunk kernel
+            # skips it (docs/SERVING.md, "Chunked prefill": 0.18 ms a
+            # row against 0.47). A narrower head keeps the single call:
+            # its decode kernel is the grid over every table slot.
+            route = use_chunk and _choose_kernel(
+                "paged_decode", head_dim=Dh, block_size=bs)
+            tile_len = layout["tile_len"]
+            live = layout["live"][:, None]
+            if route:
+                one_token = tile_len == 1
+                tile_len = jnp.where(one_token, 0, tile_len)
+                first_rows = layout["tile_rows"][:, :1]
+                # a token row's tile, and whether the decode kernel
+                # had it
+                tile_of = layout["back"] // Cq
+                from_decode = live & one_token[tile_of][:, None]
 
             def attend(i, q, kv_k, kv_v):
                 ctx = tile_attention(
                     kv_k, kv_v, q[layout["tile_rows"]],
                     layout["tile_tables"], layout["tile_pos"],
-                    layout["tile_len"], layer=i, sm_scale=sm_scale)
-                return ctx.reshape(n_tiles * Cq, -1)[layout["back"]]
+                    tile_len, layer=i, sm_scale=sm_scale)
+                ctx = ctx.reshape(n_tiles * Cq, -1)[layout["back"]]
+                if not use_chunk:
+                    return ctx
+                # the kernel leaves a skipped or unused tile's slots
+                # unwritten, and a padding row's `back` may point into
+                # one: a padding row takes zero
+                ctx = jnp.where(live, ctx, 0.0)
+                if not route:
+                    return ctx
+                one = _pk.paged_decode_attention(
+                    kv_k, kv_v, q[first_rows],
+                    layout["tile_tables"], layout["tile_pos"][:, None],
+                    layer=i, sm_scale=sm_scale, active=one_token)
+                return jnp.where(
+                    from_decode, one.reshape(n_tiles, -1)[tile_of], ctx)
         else:
             B, C = lead
             # per-slot write targets: window slot j of row b lands at

@@ -14,7 +14,11 @@ runs over query tiles. Pinned here, CPU, toy widths:
     the prefilling rows in the order they were admitted, and counts the
     rows it left without a token (``rows_deferred``);
   * the tiles: how many the step is compiled for, and that a window's
-    tiles cover each of its tokens once.
+    tiles cover each of its tokens once;
+  * which kernel a tile goes to (ISSUE 40): at a head of whole lane
+    tiles the one-token tiles go to ``paged_decode_attention`` and the
+    chunk kernel is told to skip them; a narrower head keeps the single
+    call and traces no decode kernel inside the chunk step.
 """
 
 import numpy as np
@@ -46,6 +50,17 @@ WINDOWS = {
         [(6, 7, True), (3, 1, False), (11, 5, True), (2, 1, False)],
     "all_decode_rows_dispatched_as_chunk":
         [(9, 1, False), (1, 1, False), (17, 1, False), (40, 1, False)],
+    # windows the routing of one-token tiles splits (ISSUE 40): every
+    # tile the decode kernel's; a chunk whose tail tile holds one token;
+    # a one-token prompt at position 0; idle rows between live ones
+    "decode_rows_alone_at_both_ends_of_a_context":
+        [(62, 1, False), (1, 1, False), (33, 1, False), (16, 1, False)],
+    "chunk_tail_tile_of_one_token":
+        [(9, 1, False), (3, 5, True), (17, 1, False), (0, 5, True)],
+    "one_token_prompt_at_position_0":
+        [(0, 1, True), (12, 1, False), (0, 8, True), (5, 1, False)],
+    "idle_rows_between_live_ones":
+        [(9, 1, False), None, (4, 5, True), None],
 }
 
 
@@ -140,6 +155,96 @@ def test_chunk_step_over_token_rows_matches_every_slot(window, path,
             page = feed[5][b, p // BS] - 1
             assert not np.allclose(k0[page, p % BS],
                                    kv[0][0, page + 1, p % BS]), (b, p)
+
+
+@pytest.mark.parametrize("widths", ["kernel", "lax"])
+def test_one_token_tiles_go_to_the_decode_kernel_where_both_qualify(
+        widths, monkeypatch):
+    """At a head of 128 lanes every layer of the chunk step makes one
+    `paged_decode_attention` call over the window's tiles, active on the
+    one-token ones, and `paged_chunk_attention` is handed those tiles
+    at length 0. At a head of 16 (`_chunk_qualify` false: the decode
+    kernel would be the grid over every table slot) the step keeps its
+    single call over the lax tiles and traces no decode kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(serving_model, "CHUNK_TILE", TILE)
+    monkeypatch.setenv("PTPU_KERNELS", "1")
+    calls = {"decode": [], "chunk": [], "lax": []}
+    real = {"decode": pk.paged_decode_attention,
+            "chunk": pk.paged_chunk_attention,
+            "lax": pk.paged_chunk_attention_reference}
+
+    def decode(k, v, q, tables, pos, **kw):
+        calls["decode"].append(np.asarray(kw["active"]))
+        return real["decode"](k, v, q, tables, pos, **kw)
+
+    def tiles(which):
+        def call(k, v, q, tables, pos, lens, **kw):
+            calls[which].append(np.asarray(lens))
+            return real[which](k, v, q, tables, pos, lens, **kw)
+        return call
+
+    monkeypatch.setattr(pk, "paged_decode_attention", decode)
+    monkeypatch.setattr(pk, "paged_chunk_attention", tiles("chunk"))
+    monkeypatch.setattr(pk, "paged_chunk_attention_reference", tiles("lax"))
+    model = GenerationModel.random(GenerationConfig(
+        vocab_size=64, n_layers=2, d_ff=64, max_seq_len=MB * BS,
+        **WIDTHS[widths]), seed=5)
+    rows = WINDOWS["chunk_tail_tile_of_one_token"]
+    kv, feed = _feed(rows, model.config)
+    with jax.disable_jit():     # the calls see the window's own numbers
+        model.make_prefill_step(B, MB, C, max_tokens=B + C).__wrapped__(
+            model.weights, jnp.asarray(kv[0]), jnp.asarray(kv[1]),
+            *[jnp.asarray(a) for a in feed])
+    if widths == "lax":
+        assert not calls["decode"] and not calls["chunk"]
+        assert [c.tolist() for c in calls["lax"]] == [[1, 4, 1, 1, 4, 1]] * 2
+        return
+    assert not calls["lax"]
+    assert [c.tolist() for c in calls["decode"]] == [
+        [True, False, True, True, False, True]] * 2
+    assert [c.tolist() for c in calls["chunk"]] == [[0, 4, 0, 0, 4, 0]] * 2
+
+
+@pytest.mark.parametrize("disable", ["", "paged_decode"],
+                         ids=["routed", "decode_kernel_off"])
+def test_padding_rows_read_nothing_of_an_unwritten_tile(disable,
+                                                        monkeypatch):
+    """`paged_chunk_attention` writes nothing for a tile it skips or
+    that holds no token (the interpreter leaves NaN there), and a
+    padding token row's `back` may point into one: the step takes zero
+    for such a row, routed or (`PTPU_KERNELS_DISABLE=paged_decode`: the
+    chunk kernel alone) not, so the null page a padding row writes to
+    stays finite for a lax step that gathers it, and the real rows
+    match the lax path."""
+    import jax.numpy as jnp
+
+    monkeypatch.setattr(serving_model, "CHUNK_TILE", TILE)
+    model = _model("kernel")
+    rows = WINDOWS["chunk_tail_tile_of_one_token"]
+    kv, feed = _feed(rows, model.config)
+    monkeypatch.setenv("PTPU_KERNELS", "0")
+    want = _run(model, None, kv, feed)
+    monkeypatch.setenv("PTPU_KERNELS", "1")
+    monkeypatch.setenv("PTPU_KERNELS_DISABLE", disable)
+    # 20 token rows for the window's 12 tokens, 8 tiles for its 6
+    step = model.make_prefill_step(B, MB, C, return_logits=True,
+                                   max_tokens=B + 2 * C)
+    k, v, _nxt, logits = step(model.weights, jnp.asarray(kv[0]),
+                              jnp.asarray(kv[1]), *feed)
+    assert np.isfinite(np.asarray(k)).all()
+    assert np.isfinite(np.asarray(v)).all()
+    on = feed[-1]
+    np.testing.assert_allclose(
+        np.asarray(logits)[on], want[1][on],
+        atol=16 * 2.0 ** -9 * np.abs(want[1]).max(), rtol=0)
+    for g, w in zip((k, v), want[2:]):
+        np.testing.assert_allclose(np.asarray(g)[:, 1:], w,
+                                   atol=16 * 2.0 ** -9 * 2.0, rtol=0)
 
 
 def test_a_window_holding_fewer_tokens_than_rows_pads(monkeypatch):

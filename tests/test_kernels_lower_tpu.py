@@ -387,6 +387,70 @@ def test_xglm_step_multiplies_the_store_as_it_holds_it(kind, built_for,
     assert compiled.memory_analysis().temp_size_in_bytes < layer * 4
 
 
+def test_routed_chunk_step_compiles_at_the_served_geometry(v5e_chip):
+    """ISSUE 40. The engine's ONE chunk program of `xglm-1.7b-serve` as
+    it is built on the chip (16 rows of 128 blocks, a chunk of 256, 272
+    token rows, 24 layers of 16 heads of 128, the pool of
+    `engine.num_blocks`, the bf16 store; only shapes are made): every
+    layer calls `paged_chunk_attention` over the 16 query tiles (a tile
+    a row: no more than every slot's) AND `paged_decode_attention` over
+    the same 16 for the one-token ones,
+    the BlockSpec grid `paged_attention` is nowhere in it, it copies no
+    layer's pages out of the pool, and its workspace stays under one
+    layer's pages."""
+    import json
+
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    KVBlockPool)
+    from paddle_tpu.serving.model import chunk_tile_count, leaf_shapes
+
+    with open(os.path.join(os.path.dirname(chip_smoke.__file__),
+                           "perfbench/configs/xglm-1.7b-serve.json")) as f:
+        c = json.load(f)
+    e = c["engine"]
+    B, bs, C = e["max_batch"], e["block_size"], e["prefill_chunk"]
+    Mb = e["max_seq_len"] // bs
+    rows = B + default_prefill_token_budget(C)
+    assert (B, Mb, C, rows, chunk_tile_count(B, C, rows)) \
+        == (16, 128, 256, 272, 16)
+    cfg = GenerationConfig(
+        vocab_size=c["vocab_size"], d_model=c["d_model"],
+        n_heads=c["attention_heads"], n_layers=c["num_layers"],
+        d_ff=c["ffn_dim"], max_seq_len=e["max_seq_len"])
+    sharding = jax.sharding.SingleDeviceSharding(v5e_chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    # a model of shapes (`GenerationModel.__init__` wants arrays), its
+    # leaves as the store keeps them on the v5e
+    model = GenerationModel.__new__(GenerationModel)
+    model.config, model.name, model._steps = cfg, "shapes", {}
+    model.weight_only_int8, model.trace_count = False, 0
+    with device.compiling_for(v5e_chip):
+        model.weights = {k: arg(s, d)
+                         for k, (s, d) in leaf_shapes(cfg).items()}
+    pool = jax.eval_shape(lambda: KVBlockPool(
+        cfg.n_layers, cfg.n_heads, cfg.head_dim, bs, e["num_blocks"]).k)
+    pool = arg(pool.shape, pool.dtype)
+    row, on = arg((B,)), arg((B,), jnp.bool_)
+    step = model.make_prefill_step(B, Mb, C, max_tokens=rows)
+    with device.compiling_for(v5e_chip):
+        compiled = step.lower(model.weights, pool, pool, arg((B, C)), on,
+                              row, row, row, arg((B, Mb)), on).compile()
+    hlo = compiled.as_text()
+    import re
+
+    for kernel in ("paged_chunk_attention", "paged_decode_attention"):
+        assert len(re.findall(r"%%%s[.\d]* = " % kernel, hlo)) \
+            == cfg.n_layers
+    assert not re.findall(r"%paged_attention[.\d]* = ", hlo)
+    layer = pool.size // cfg.n_layers
+    large = _large_results(hlo, layer)
+    assert all(n == pool.size for _, _, n in large), large
+    assert compiled.memory_analysis().temp_size_in_bytes < layer * 4
+
+
 # ---------------------------------------------------------------------------
 # the latent block's steps update and read the bf16 latent pool in place
 # (PR 27)

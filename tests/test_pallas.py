@@ -384,7 +384,9 @@ def test_paged_chunk_attention_matches_gathered_reference(tile,
     pages end inside a run, on a run's edge and in a later run. The
     kernel rounds the operands of both products to bfloat16 (a
     default-precision dot on the chip): within 8 half-ulps of the
-    reference's largest value, slots past a tile's length zero."""
+    reference's largest value, slots past a live tile's length zero.
+    The unused tile's slots are not written (the interpreter leaves NaN
+    there): no caller reads them."""
     from paddle_tpu.ops.pallas_kernels import (
         paged_chunk_attention, paged_chunk_attention_reference)
 
@@ -404,11 +406,13 @@ def test_paged_chunk_attention_matches_gathered_reference(tile,
         pages_per_step=pages_per_step))
     want = np.asarray(paged_chunk_attention_reference(
         k_pool, v_pool, q, tables, pos, lens, layer=LAYER))
-    assert np.isfinite(got).all()
+    used = lens > 0
+    assert np.isfinite(got[used]).all()
     np.testing.assert_allclose(
-        got, want, atol=8 * 2.0 ** -9 * np.abs(want).max(), rtol=0)
+        got[used], want[used], atol=8 * 2.0 ** -9 * np.abs(want).max(),
+        rtol=0)
     past = np.arange(tile)[None, :] >= lens[:, None]
-    assert (got[past] == 0).all() and (want[past] == 0).all()
+    assert (got[past & used[:, None]] == 0).all() and (want[past] == 0).all()
     # and the fallback is the window's own lax attention, slot c of a
     # tile at position pos + c
     slots = np.minimum(np.arange(tile)[None, :], np.maximum(lens - 1, 0)
@@ -418,6 +422,39 @@ def test_paged_chunk_attention_matches_gathered_reference(tile,
     live = ~past
     live[:, 1:] &= (slots[:, 1:] == np.arange(1, tile)[None, :])
     np.testing.assert_allclose(want[live], dense[live], atol=1e-6, rtol=0)
+
+
+@pytest.mark.parametrize("lens, blocks", [
+    ((0, 0, 4, 0, 3, 0), [2, 2, 2, 2, 4, 4]),   # skipped first, between, last
+    ((2, 0, 0, 0, 0, 4), [0, 0, 0, 0, 0, 5]),
+    ((0, 0, 0, 0, 0, 0), [0] * 6),      # a window of one-token rows alone
+    ((0, 0, 0, 0, 0, 1), [5] * 6),
+])
+def test_paged_chunk_attention_moves_nothing_for_a_skipped_tile(lens,
+                                                                blocks):
+    """A tile of length 0 (the chunk step's one-token tiles, which the
+    decode kernel takes) shares the last live tile's query and output
+    block, or the first live tile's where none came before it, so no
+    block is copied in or out for it: the live tiles come out as they
+    do among live neighbours, whatever stands around them."""
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    lens = np.array(lens, np.int32)
+    assert np.asarray(pk._tile_blocks(jnp.asarray(lens))).tolist() == blocks
+    rng, k_pool, v_pool = _paged_setup(seed=9, NB=40, bs=4, H=2, Dh=16)
+    tile, Mb = 4, 10
+    q = jnp.asarray(rng.randn(6, tile, 2, 16).astype(np.float32))
+    tables = rng.permutation(np.arange(1, 41))[:Mb].astype(np.int32)
+    tables = np.tile(tables, (6, 1))
+    pos = np.array([0, 7, 12, 20, 30, 33], np.int32)
+    got = np.asarray(pk.paged_chunk_attention(
+        k_pool, v_pool, q, tables, pos, lens, layer=LAYER))
+    want = np.asarray(pk.paged_chunk_attention_reference(
+        k_pool, v_pool, q, tables, pos, lens, layer=LAYER))
+    used = lens > 0
+    np.testing.assert_allclose(
+        got[used], want[used], atol=8 * 2.0 ** -9 * max(
+            np.abs(want).max(), 1.0), rtol=0)
 
 
 # ---------------------------------------------------------------------------

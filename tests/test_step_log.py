@@ -162,7 +162,8 @@ def logged_run():
             counters={k: reg.counter("serving/" + k).value
                       for k in ("steps", "prefill_chunk_steps",
                                 "prefill_tokens", "decode_tokens",
-                                "prefill_rows_deferred")})
+                                "prefill_rows_deferred",
+                                "mixed_one_token_rows")})
     finally:
         metrics.disable()
         metrics.reset()
@@ -208,6 +209,24 @@ def check_mixed_records_carry_rows_deferred(run):
         else:
             assert "rows_deferred" not in r
     assert run["counters"]["prefill_rows_deferred"] == 0
+
+
+def check_mixed_records_carry_one_token_rows(run):
+    # the rows of a mixed step that hold ONE token, which a decode
+    # kernel takes (ISSUE 40): every decode row riding in it, and a
+    # prompt's last chunk where that is one token (the prompts of 9 and
+    # 17 tokens against a chunk of 8); on no decode record; the counter
+    # is the records' sum
+    mixed = [r for r in run["records"] if r["kind"] == "mixed"]
+    for r in mixed:
+        assert r["decode_tokens"] <= r["one_token_rows"] <= r["rows"]
+    assert all("one_token_rows" not in r for r in run["records"]
+               if r["kind"] != "mixed")
+    assert sum(r["one_token_rows"] - r["decode_tokens"]
+               for r in mixed) == 2
+    assert sum(r["decode_tokens"] for r in mixed) > 0
+    assert run["counters"]["mixed_one_token_rows"] == sum(
+        r["one_token_rows"] for r in mixed)
 
 
 def check_slots_used_is_what_the_scheduler_planned(run):
@@ -274,6 +293,7 @@ def check_metrics_off_writes_nothing_and_changes_no_token(run):
     check_one_record_per_step, check_mixed_steps_are_the_chunk_steps,
     check_mixed_records_carry_rows_computed,
     check_mixed_records_carry_rows_deferred,
+    check_mixed_records_carry_one_token_rows,
     check_slots_used_is_what_the_scheduler_planned,
     check_stamps_are_ordered, check_host_and_wait_fit_in_the_tick,
     check_cold_is_the_first_step_of_each_shape,
