@@ -54,7 +54,8 @@ Sizes = collections.namedtuple("Sizes", [
     "serve_ctx", "serve_batch", "block_size", "prefill_chunk",
     "prompt_lens", "new_tokens", "spec_k", "spec_tree",
     "int8_mkn", "latent", "latent_batch", "latent_chunk", "afmoe",
-    "afmoe_batch", "afmoe_chunk", "rec"])
+    "afmoe_batch", "afmoe_chunk", "zaya", "zaya_batch", "zaya_chunk",
+    "rec"])
 
 # the flagship at full width (bench_transformer_fluid's operating point)
 FULL = Sizes(
@@ -89,6 +90,15 @@ FULL = Sizes(
                    routed_scaling_factor=2.448,
                    experts_held=list(range(8)))),
     afmoe_batch=8, afmoe_chunk=256,
+    # the compressed-convolutional-attention / top-1 routed block at its
+    # published widths (Zyphra/ZAYA1-8B): two layers, all 16 experts of
+    # each, the whole tied vocabulary
+    zaya=dict(vocab_size=262272, d_model=2048, n_heads=8, n_layers=2,
+              d_ff=2048, block=dict(
+                  kind="zaya", n_kv_heads=2, head_dim=128,
+                  router_hidden=256, n_routed_experts=16, moe_d_ff=2048,
+                  rope_theta=5e6)),
+    zaya_batch=8, zaya_chunk=256,
     # bench.py --rec-only sizes
     rec=dict(n_shards=4, records_per_shard=320, batch_size=32, vocab=512,
              fields=6, embed_dim=16, cache_rows=128))
@@ -121,6 +131,12 @@ TOY = Sizes(
                    routed_scaling_factor=2.448,
                    experts_held=list(range(4)))),
     afmoe_batch=4, afmoe_chunk=32,
+    zaya=dict(vocab_size=512, d_model=64, n_heads=4, n_layers=2,
+              d_ff=64, block=dict(
+                  kind="zaya", n_kv_heads=2, head_dim=128,
+                  router_hidden=16, n_routed_experts=4, moe_d_ff=64,
+                  rope_theta=5e6)),
+    zaya_batch=4, zaya_chunk=32,
     rec=dict(n_shards=2, records_per_shard=64, batch_size=16, vocab=128,
              fields=4, embed_dim=8, cache_rows=32))
 
@@ -635,31 +651,38 @@ def leg_latent(sz, rehearsal):
 AFMOE_KERNELS = ("gmm", "gqa_decode", "gqa_chunk", "kv_page_write")
 
 
-def afmoe_step_logits(model, sz, chunked):
-    """Logits of one decode or one chunk step of the block over random
-    pages of both kinds (contexts longer than the window, so that the
-    walk starts past page 0), under whatever kernel policy is in force."""
+def paged_step_logits(model, batch, chunk, sz):
+    """Logits of one decode (``chunk`` 1) or one chunk step of a
+    grouped-query block over random pages of every kind it keeps
+    (contexts longer than a window, so that a walk starts past page 0)
+    and, where it has one, a random row state, under whatever kernel
+    policy is in force."""
     import jax.numpy as jnp
 
     from paddle_tpu.serving import KVBlockPool
 
     cfg = model.config
-    B, bs = sz.afmoe_batch, sz.block_size
+    B, bs, C = batch, sz.block_size, chunk
     Mb = sz.serve_ctx // bs
-    C = sz.afmoe_chunk if chunked else 1
     rng = np.random.RandomState(17)
     kinds = model.page_kinds()
     pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim, bs,
-                       [B * Mb] * len(kinds), entry=model.cache_entry(),
-                       kinds=kinds)
+                       [B * Mb] * len(kinds) if len(kinds) > 1 else B * Mb,
+                       entry=model.cache_entry(), kinds=kinds)
     arrays = [jnp.asarray(rng.randn(*a.shape).astype(np.float32) * 0.3,
                           pool.dtype) for a in pool.arrays]
     tables = np.stack([rng.permutation(np.arange(1, B * Mb + 1))
                        .reshape(B, Mb).astype(np.int32) for _ in kinds])
+    if len(kinds) == 1:
+        tables = tables[0]
+    state = model.row_state()
+    if state is not None:
+        arrays.append(jnp.asarray(
+            rng.randn(B, *state[0]).astype(np.float32) * 0.3, state[1]))
     pos = rng.randint(Mb * bs // 2, Mb * bs - C, B).astype(np.int32)
     toks = rng.randint(0, cfg.vocab_size, (B, C)).astype(np.int32)
     on = np.ones(B, bool)
-    if chunked:
+    if C > 1:
         lens = np.where(np.arange(B) % 2, 1, C).astype(np.int32)
         out = model.make_prefill_step(B, Mb, C, return_logits=True)(
             model.weights, *arrays, toks, lens > 1, np.zeros(B, np.int32),
@@ -668,8 +691,13 @@ def afmoe_step_logits(model, sz, chunked):
         out = model.make_decode_step(B, Mb, return_logits=True)(
             model.weights, *arrays, toks[:, 0], on, np.zeros(B, np.int32),
             pos, tables, on)
-    # (*pools', tokens, counters, top_logit, logits)
+    # (*pools', [row state',] tokens, counters, top_logit, logits)
     return np.asarray(out[-1]), np.asarray(out[-3])
+
+
+def afmoe_step_logits(model, sz, chunked):
+    return paged_step_logits(model, sz.afmoe_batch,
+                             sz.afmoe_chunk if chunked else 1, sz)
 
 
 def leg_afmoe(sz, rehearsal):
@@ -681,6 +709,18 @@ def leg_afmoe(sz, rehearsal):
     return block_leg(sz, rehearsal, sz.afmoe, sz.afmoe_batch,
                      sz.afmoe_chunk, AFMOE_KERNELS, afmoe_step_logits,
                      seed=11, after_serving=released)
+
+
+def zaya_step_logits(model, sz, chunked):
+    return paged_step_logits(model, sz.zaya_batch,
+                             sz.zaya_chunk if chunked else 1, sz)
+
+
+def leg_zaya(sz, rehearsal):
+    """The fourth block: the same kernels over one kind of page, a row
+    state carried through the engine's steps, a tied head."""
+    return block_leg(sz, rehearsal, sz.zaya, sz.zaya_batch, sz.zaya_chunk,
+                     AFMOE_KERNELS, zaya_step_logits, seed=13)
 
 
 # ---------------------------------------------------------------------------
@@ -1126,6 +1166,7 @@ def main(argv=None):
                 results)
         run_leg("afmoe", lambda: leg_afmoe(sz, rehearsal), clock,
                 results)
+        run_leg("zaya", lambda: leg_zaya(sz, rehearsal), clock, results)
         run_leg("kernels", lambda: leg_kernels(sz, rehearsal), clock,
                 results)
         run_leg("rec", lambda: leg_rec(sz, rehearsal), clock, results)
@@ -1141,7 +1182,7 @@ def main(argv=None):
         traceback.print_exc()
     ok = (all(r["ok"] for r in results.values())
           and set(results) >= {"device", "train", "serve", "latent", "afmoe",
-                               "kernels", "rec"})
+                               "zaya", "kernels", "rec"})
     summary = {
         "ok": ok,
         "device": ident._asdict(),

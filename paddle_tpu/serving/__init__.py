@@ -32,7 +32,7 @@ directory.
 
 from .engine import ServingEngine  # noqa: F401
 from .kv_cache import (CacheEntry, KVBlockPool, PageKind,  # noqa: F401
-                       blocks_needed, prefix_chain_keys)
+                       RowState, blocks_needed, prefix_chain_keys)
 from .latent_moe import LatentMoEBlock  # noqa: F401
 from .loadgen import PoissonLoadGenerator  # noqa: F401
 from .model import (GenerationArtifactError,  # noqa: F401
@@ -52,18 +52,23 @@ from .scheduler import (AdmissionError,  # noqa: F401
 
 
 def __getattr__(name):
-    # the third block, lazily: `serving.AfmoeBlock` loads its module
+    # the third and fourth blocks, lazily: `serving.AfmoeBlock` loads
+    # its module
     if name == "AfmoeBlock":
         from .afmoe import AfmoeBlock
 
         return AfmoeBlock
+    if name == "ZayaBlock":
+        from .zaya import ZayaBlock
+
+        return ZayaBlock
     raise AttributeError("module %r has no attribute %r"
                          % (__name__, name))
 
 
 __all__ = ["ServingEngine", "ServingRouter", "RouterRequest",
-           "KVBlockPool", "CacheEntry", "PageKind", "LatentMoEBlock",
-           "AfmoeBlock", "blocks_needed",
+           "KVBlockPool", "CacheEntry", "PageKind", "RowState",
+           "LatentMoEBlock", "AfmoeBlock", "ZayaBlock", "blocks_needed",
            "prefix_chain_keys",
            "PoissonLoadGenerator", "GenerationConfig", "GenerationModel",
            "GenerationArtifactError", "ModelDrafter", "NGramDrafter",

@@ -230,113 +230,168 @@ def _write_units(jnp, pos0, lens, first_row, bs, n_units, n_rows):
             "src": jnp.clip(src, 0, n_rows - 1)}
 
 
-def _forward(model, weights, tok, pos0, lengths, tables, active, pools,
-             max_tokens):
-    """``tok`` [B, C] through every layer: each layer's new K and V are
-    written into its kind's pages, then attended. ``pools`` is ``(k, v)``
-    a page kind, ``tables`` ``[kinds, B, Mb]`` (or ``[B, Mb]``: one
-    kind). Returns (pools, logits [B, V] at each row's last valid slot,
-    counters int32 [len(COUNTERS)]).
+class _PagedWindow:
+    """The paged grouped-query attention of one step over a ``[B, C]``
+    window of tokens, shared by every layer: where the window's token
+    rows come from, the pages their new K and V fall into, and the
+    kernels chosen. ``pools`` is ``(k, v)`` a page kind (kept as a list
+    and replaced by every :meth:`write`), ``tables`` ``[kinds, B, Mb]``
+    (or ``[B, Mb]``: one kind).
 
     C == 1 is the decode step: every row one token. Otherwise the
     window's real tokens are COMPACTED to ``max_tokens`` token rows
     (``model._chunk_layout``, the XGLM chunk step's) for everything a
     token does alone, and the attention sees them as query tiles of
-    :data:`ATTN_TILE` tokens."""
+    :data:`ATTN_TILE` tokens.
+
+    ``tok [Tc]``, ``pos [Tc]``, ``valid [Tc]``: the token rows; ``lens
+    [B]`` the tokens a window row holds and ``last [B]`` its last token
+    row; ``at [Tc]`` the window slot ``b * C + c`` a token row holds
+    (None: the decode step, row ``b``)."""
+
+    def __init__(self, model, tok, pos0, lengths, tables, active, pools,
+                 max_tokens):
+        import jax.numpy as jnp
+
+        from ..ops import pallas_kernels as pk
+        from ..ops.kernel_registry import choose
+        from .model import _chunk_layout, chunk_tile_count
+
+        cfg, blk = model.config, model.config.block
+        B, C = tok.shape
+        H, Dh, Hkv = cfg.n_heads, blk.head_dim, blk.n_kv_heads
+        self.pools = pools = list(pools)
+        bs = pools[0].shape[2]
+        if tables.ndim == 2:
+            tables = tables[None]
+        Mb = tables.shape[2]
+        self.kinds = kinds = blk.page_kinds(cfg) or (
+            PageKind("all", range(cfg.n_layers)),)
+        # layer -> (its kind, its index in the kind's arrays)
+        self.where = {layer: (k, j) for k, kind in enumerate(kinds)
+                      for j, layer in enumerate(kind.layers)}
+        self.lens = lens = jnp.where(
+            active, jnp.clip(lengths, 0, C), 0).astype(jnp.int32)
+        pos0 = jnp.maximum(pos0, 0).astype(jnp.int32)
+
+        use_write = choose("kv_page_write", head_dim=Dh, block_size=bs)
+        self._write = (pk.kv_page_write if use_write
+                       else pk.kv_page_write_reference)
+        if C == 1:
+            self.Tc, self.at = B, None
+            self.tok, self.pos, self.valid = tok.reshape(B), pos0, lens > 0
+            valid = self.valid
+            slot = jnp.clip(pos0 // bs, 0, Mb - 1)
+            rows = jnp.arange(B)
+            self._units = {"lo": jnp.where(valid, pos0 % bs, 0),
+                           "hi": jnp.where(valid, pos0 % bs + 1, 0)}
+            self._unit_pages = [jnp.where(valid, t[rows, slot], 0)
+                                for t in tables]
+            use_attn = choose("gqa_decode", head_dim=Dh, block_size=bs)
+
+            def new_rows(a):                    # [B, W] -> one row a unit
+                return a[:, None, :]
+
+            def attend(q, k, j, kind):
+                fn = (pk.gqa_paged_decode_attention if use_attn
+                      else pk.gqa_paged_decode_attention_reference)
+                return fn(pools[2 * k], pools[2 * k + 1], q, tables[k],
+                          pos0, layer=j, window=kind.window, active=valid)
+
+            self.last = rows
+        else:
+            T = B * C
+            self.Tc = Tc = T if max_tokens is None \
+                else min(int(max_tokens), T)
+            # a power of two of slots (the kernels find a stacked row's
+            # token by a bit mask)
+            Cq = 1 << (min(ATTN_TILE, C).bit_length() - 1)
+            n_tiles = chunk_tile_count(B, C, None if Tc == T else Tc,
+                                       tile=Cq)
+            layouts = [_chunk_layout(jnp, pos0, lens, active, t, C, Tc, Cq,
+                                     n_tiles, bs) for t in tables]
+            lay = layouts[0]
+            self.at = lay["at"]
+            self.tok, self.pos, self.valid = (tok.reshape(T)[lay["at"]],
+                                              lay["pos"], lay["live"])
+            first_row = (jnp.cumsum(lens) - lens if Tc < T
+                         else jnp.arange(B, dtype=jnp.int32) * C)
+            n_units = min(B * ((C - 1) // bs + 2), Tc // bs + 2 * B)
+            self._units = units = _write_units(jnp, pos0, lens, first_row,
+                                               bs, n_units, Tc)
+            self._unit_pages = [jnp.where(units["used"], t[
+                units["row"], jnp.clip(units["slot"], 0, Mb - 1)], 0)
+                for t in tables]
+            use_attn = choose("gqa_chunk", head_dim=Dh, block_size=bs,
+                              window=Cq)
+            use_one = choose("gqa_decode", head_dim=Dh, block_size=bs)
+            # a tile of ONE token (every decode row of a mixed step, and
+            # a chunk's tail of one) is a decode query: the decode
+            # kernel takes it, grouped by cache head, and the chunk
+            # kernel skips it
+            one_token = lay["tile_len"] == 1
+            chunk_len = jnp.where(one_token, 0, lay["tile_len"])
+
+            def new_rows(a):                    # [Tc, W] -> [U, bs, W]
+                return a[units["src"]]
+
+            def attend(q, k, j, kind):
+                fn = (pk.gqa_paged_chunk_attention if use_attn
+                      else pk.gqa_paged_attention_reference)
+                one = (pk.gqa_paged_decode_attention if use_one
+                       else pk.gqa_paged_decode_attention_reference)
+                ly = layouts[k]
+                tiles = q[ly["tile_rows"]]               # [n, Cq, H, Dh]
+                on = (pools[2 * k], pools[2 * k + 1])
+                ctx = fn(*on, tiles, ly["tile_tables"], ly["tile_pos"],
+                         chunk_len, layer=j, window=kind.window)
+                first = one(*on, tiles[:, 0], ly["tile_tables"],
+                            ly["tile_pos"], layer=j, window=kind.window,
+                            active=one_token)
+                ctx = ctx.at[:, 0].set(jnp.where(
+                    one_token[:, None, None], first, ctx[:, 0]))
+                return ctx.reshape(n_tiles * Cq, H, Dh)[ly["back"]]
+
+            self.last = lay["last"]
+        self._new_rows, self._attend = new_rows, attend
+
+    def write(self, layer, k, v):
+        """Layer ``layer``'s new ``k`` and ``v`` ``[Tc, Hkv * Dh]`` into
+        its kind's pages (the pools go to the kernels whole, never
+        ``pool[j]``)."""
+        pools, (k_i, j_i) = self.pools, self.where[layer]
+        dt = pools[2 * k_i].dtype
+        pools[2 * k_i], pools[2 * k_i + 1] = self._write(
+            pools[2 * k_i], pools[2 * k_i + 1],
+            self._new_rows(k.astype(dt)), self._new_rows(v.astype(dt)),
+            self._unit_pages[k_i], self._units["lo"], self._units["hi"],
+            layer=j_i)
+
+    def attend(self, layer, q):
+        """``q [Tc, H, Dh]`` against layer ``layer``'s pages ->
+        ``[Tc, H, Dh]`` float32."""
+        k_i, j_i = self.where[layer]
+        return self._attend(q, k_i, j_i, self.kinds[k_i])
+
+
+def _forward(model, weights, tok, pos0, lengths, tables, active, pools,
+             max_tokens):
+    """``tok`` [B, C] through every layer: each layer's new K and V are
+    written into its kind's pages, then attended (:class:`_PagedWindow`).
+    Returns (pools, logits [B, V] at each row's last valid slot,
+    counters int32 [len(COUNTERS)])."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops import pallas_kernels as pk
     from ..ops.kernel_registry import choose
-    from .model import _chunk_layout, chunk_tile_count
 
     cfg, blk = model.config, model.config.block
     act = jnp.dtype(blk.activation_dtype)
-    B, C = tok.shape
     H, D, Dh, Hkv = cfg.n_heads, cfg.d_model, blk.head_dim, blk.n_kv_heads
     eps = blk.rms_norm_eps
-    pools = list(pools)
-    bs = pools[0].shape[2]
-    if tables.ndim == 2:
-        tables = tables[None]
-    Mb = tables.shape[2]
-    kinds = blk.page_kinds(cfg) or (PageKind("all", range(cfg.n_layers)),)
-    # layer -> (its kind, its index in the kind's arrays)
-    where = {layer: (k, j) for k, kind in enumerate(kinds)
-             for j, layer in enumerate(kind.layers)}
-    lens = jnp.where(active, jnp.clip(lengths, 0, C), 0).astype(jnp.int32)
-    pos0 = jnp.maximum(pos0, 0).astype(jnp.int32)
-
-    use_write = choose("kv_page_write", head_dim=Dh, block_size=bs)
-    write = pk.kv_page_write if use_write else pk.kv_page_write_reference
-    if C == 1:
-        Tc = B
-        tok, pos, valid = tok.reshape(B), pos0, lens > 0
-        slot = jnp.clip(pos0 // bs, 0, Mb - 1)
-        rows = jnp.arange(B)
-        units = {"lo": jnp.where(valid, pos0 % bs, 0),
-                 "hi": jnp.where(valid, pos0 % bs + 1, 0)}
-        unit_pages = [jnp.where(valid, t[rows, slot], 0) for t in tables]
-        use_attn = choose("gqa_decode", head_dim=Dh, block_size=bs)
-
-        def new_rows(a):                    # [B, W] -> one row a unit
-            return a[:, None, :]
-
-        def attend(q, k, j, kind):
-            fn = (pk.gqa_paged_decode_attention if use_attn
-                  else pk.gqa_paged_decode_attention_reference)
-            return fn(pools[2 * k], pools[2 * k + 1], q, tables[k], pos0,
-                      layer=j, window=kind.window, active=valid)
-
-        last = rows
-    else:
-        T = B * C
-        Tc = T if max_tokens is None else min(int(max_tokens), T)
-        # a power of two of slots (the kernels find a stacked row's
-        # token by a bit mask)
-        Cq = 1 << (min(ATTN_TILE, C).bit_length() - 1)
-        n_tiles = chunk_tile_count(B, C, None if Tc == T else Tc, tile=Cq)
-        layouts = [_chunk_layout(jnp, pos0, lens, active, t, C, Tc, Cq,
-                                 n_tiles, bs) for t in tables]
-        lay = layouts[0]
-        tok, pos, valid = tok.reshape(T)[lay["at"]], lay["pos"], lay["live"]
-        first_row = (jnp.cumsum(lens) - lens if Tc < T
-                     else jnp.arange(B, dtype=jnp.int32) * C)
-        n_units = min(B * ((C - 1) // bs + 2), Tc // bs + 2 * B)
-        units = _write_units(jnp, pos0, lens, first_row, bs, n_units, Tc)
-        unit_pages = [jnp.where(units["used"], t[
-            units["row"], jnp.clip(units["slot"], 0, Mb - 1)], 0)
-            for t in tables]
-        use_attn = choose("gqa_chunk", head_dim=Dh, block_size=bs,
-                          window=Cq)
-        use_one = choose("gqa_decode", head_dim=Dh, block_size=bs)
-        # a tile of ONE token (every decode row of a mixed step, and a
-        # chunk's tail of one) is a decode query: the decode kernel
-        # takes it, grouped by cache head, and the chunk kernel skips it
-        one_token = lay["tile_len"] == 1
-        chunk_len = jnp.where(one_token, 0, lay["tile_len"])
-
-        def new_rows(a):                    # [Tc, W] -> [U, bs, W]
-            return a[units["src"]]
-
-        def attend(q, k, j, kind):
-            fn = (pk.gqa_paged_chunk_attention if use_attn
-                  else pk.gqa_paged_attention_reference)
-            one = (pk.gqa_paged_decode_attention if use_one
-                   else pk.gqa_paged_decode_attention_reference)
-            ly = layouts[k]
-            tiles = q[ly["tile_rows"]]               # [n, Cq, H, Dh]
-            on = (pools[2 * k], pools[2 * k + 1])
-            ctx = fn(*on, tiles, ly["tile_tables"], ly["tile_pos"],
-                     chunk_len, layer=j, window=kind.window)
-            first = one(*on, tiles[:, 0], ly["tile_tables"],
-                        ly["tile_pos"], layer=j, window=kind.window,
-                        active=one_token)
-            ctx = ctx.at[:, 0].set(jnp.where(
-                one_token[:, None, None], first, ctx[:, 0]))
-            return ctx.reshape(n_tiles * Cq, H, Dh)[ly["back"]]
-
-        last = lay["last"]
+    win = _PagedWindow(model, tok, pos0, lengths, tables, active, pools,
+                       max_tokens)
+    Tc, tok, pos, valid = win.Tc, win.tok, win.pos, win.valid
     use_gmm = (cfg.n_layers > blk.n_dense_layers
                and choose("gmm", k=D, n=blk.moe_d_ff))
 
@@ -346,7 +401,6 @@ def _forward(model, weights, tok, pos0, lengths, tables, active, pools,
     counters = jnp.zeros((len(COUNTERS),), jnp.int32)
     for i in range(cfg.n_layers):
         p = "l%d/" % i
-        k_i, j_i = where[i]
         a = _rms_norm(x, weights[p + "attn_norm"], eps)
         q = _rms_norm(_dot(a, weights[p + "wq"], act).reshape(Tc, H, Dh),
                       weights[p + "q_norm"], eps)
@@ -358,15 +412,9 @@ def _forward(model, weights, tok, pos0, lengths, tables, active, pools,
             q = rope_half_split(q, pos[:, None], blk.rope_theta)
             k = rope_half_split(k, pos[:, None], blk.rope_theta)
         with jax.named_scope("kv_write"):
-            # the pools go to the kernels whole, never `pool[j]`
-            dt = pools[2 * k_i].dtype
-            pools[2 * k_i], pools[2 * k_i + 1] = write(
-                pools[2 * k_i], pools[2 * k_i + 1],
-                new_rows(k.reshape(Tc, Hkv * Dh).astype(dt)),
-                new_rows(v.astype(dt)), unit_pages[k_i], units["lo"],
-                units["hi"], layer=j_i)
+            win.write(i, k.reshape(Tc, Hkv * Dh), v)
         with jax.named_scope("gqa_attention"):
-            o = attend(q, k_i, j_i, kinds[k_i]).reshape(Tc, H * Dh) * gate
+            o = win.attend(i, q).reshape(Tc, H * Dh) * gate
             x = x + _rms_norm(_dot(o, weights[p + "wo"], act),
                               weights[p + "attn_post_norm"], eps)
         f = _rms_norm(x, weights[p + "ffn_norm"], eps)
@@ -392,8 +440,8 @@ def _forward(model, weights, tok, pos0, lengths, tables, active, pools,
         x = x + _rms_norm(y, weights[p + "ffn_post_norm"], eps)
 
     with jax.named_scope("head"):
-        x_last = _rms_norm(x[last], weights["final_norm"], eps)
-        return (tuple(pools), _dot(x_last, weights["lm_head"], act),
+        x_last = _rms_norm(x[win.last], weights["final_norm"], eps)
+        return (tuple(win.pools), _dot(x_last, weights["lm_head"], act),
                 counters)
 
 

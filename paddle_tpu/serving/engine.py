@@ -90,7 +90,7 @@ from ..observability import flight_recorder as _blackbox
 from ..observability import metrics as _metrics
 from ..observability import tracing as _tracing
 from ..quant import weight_store_bytes as _weight_store_bytes
-from .kv_cache import KVBlockPool, blocks_needed
+from .kv_cache import KVBlockPool, RowState, blocks_needed
 from .model import GenerationModel, load_generation_artifact, store_leaf
 from .scheduler import (AdmissionError, GenerationRequest, RequestQueue,
                         StepScheduler)
@@ -154,13 +154,22 @@ class _ModelWorker:
         cfg = model.config
         max_seq_len = min(int(max_seq_len), cfg.max_seq_len)
         kinds = model.page_kinds()
-        if kinds and (prefix_cache or spec_k or spec_tree
-                      or drafter is not None):
-            raise NotImplementedError(
-                "model %r keeps pages of %d kinds (%s): the prefix cache "
-                "and speculative, tree and draft windows are not built "
-                "over released window pages (ROADMAP Queue 2a)"
-                % (name, len(kinds), ", ".join(k.name for k in kinds)))
+        row_state = model.row_state()
+        if (prefix_cache or spec_k or spec_tree or drafter is not None):
+            if row_state is not None:
+                raise NotImplementedError(
+                    "model %r carries a row state beside its pages: the "
+                    "prefix cache and speculative, tree and draft "
+                    "windows are not built with it (the carry at an "
+                    "adopted page boundary, and a roll-back of it, do "
+                    "not exist yet: ROADMAP R7)" % name)
+            if kinds:
+                raise NotImplementedError(
+                    "model %r keeps pages of %d kinds (%s): the prefix "
+                    "cache and speculative, tree and draft windows are "
+                    "not built over released window pages (ROADMAP "
+                    "Queue 2a)"
+                    % (name, len(kinds), ", ".join(k.name for k in kinds)))
         if not isinstance(num_blocks, dict):
             # default: enough cache for every slot to run a full-length
             # sequence concurrently (no admission stalls from the pool)
@@ -170,11 +179,16 @@ class _ModelWorker:
             # position; a window kind then never gates admission
             num_blocks = {k.name: first if k.window is None else full
                           for k in kinds} if kinds else first
-        # the model says what one token's cache entry is; the pool's
-        # accounting is the same for every entry
-        self.pool = KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
-                                block_size, num_blocks,
-                                entry=model.cache_entry(), kinds=kinds)
+        # the model says what one token's cache entry is, and what a
+        # batch row carries beside its pages; the pool's accounting is
+        # the same for every entry
+        self.pool = KVBlockPool(
+            cfg.n_layers, cfg.n_heads, cfg.head_dim, block_size,
+            num_blocks, entry=model.cache_entry(), kinds=kinds,
+            row_state=row_state and RowState(max_batch, *row_state))
+        # a model that NAMES its page kinds, be it one, gets the step
+        # log's fields by kind (`_pages_walked_by_kind`)
+        self._kinds_named = bool(kinds)
         self.prefix_cache = bool(prefix_cache)
         # speculative decoding: the verify window is a compiled shape,
         # clamped so a full window always fits the context. A tree
@@ -789,9 +803,10 @@ class _ModelWorker:
         with _phase(tick, "dispatch"):
             weights = {n: self.scope.get(n) for n in self._weight_names}
             # a step takes the pool's arrays (K and V, or one latent
-            # array) and returns them updated, then its tokens, then
-            # whatever counters its block reduces on the device
-            arrays = self.pool.arrays
+            # array; then the row state, of a block that has one) and
+            # returns them updated, then its tokens, then whatever
+            # counters its block reduces on the device
+            arrays = self.pool.step_arrays
             # one block table, or where the pool keeps pages of several
             # kinds the stack of them, a table a kind
             tables = (sched.block_tables if len(self.pool.kinds) == 1
@@ -807,7 +822,7 @@ class _ModelWorker:
                     weights, *arrays, *self._no_prompt,
                     self._prev_tokens, sched.positions.copy(), tables,
                     sched.active.copy())
-            self.pool.arrays = tuple(out[:len(arrays)])
+            self.pool.step_arrays = out[:len(arrays)]
             next_tokens = out[len(arrays)]
             # then what the block's steps hand back beside their
             # tokens: its device counters, each token's own logit
@@ -842,7 +857,7 @@ class _ModelWorker:
                 # last token): the tiles a decode kernel takes
                 rec["one_token_rows"] = int(
                     (sched.chunk_lens[sched.active] == 1).sum())
-            if len(self.pool.kinds) > 1:
+            if self._kinds_named:
                 rec.update(self._pages_walked_by_kind())
             elif not mixed:
                 # the pages a decode kernel that walks each row's own
@@ -904,7 +919,8 @@ class _ModelWorker:
         over rows and the kind's layers): each active row's pages from the
         first its earliest query still sees to the page of its last
         token. ``window_pages_full`` is what the window kinds' walk
-        would be from position 0. ``<kind>_keys_attended``: the keys
+        would be from position 0 (it and ``window_keys_attended`` are 0
+        where no kind has a window). ``<kind>_keys_attended``: the keys
         the rows' queries see between them, again over the kind's
         layers, which is the attention's arithmetic. ``chunk_pages_walked``
         and ``chunk_keys_attended``: the same two over every kind, of the
@@ -916,8 +932,8 @@ class _ModelWorker:
         n = np.maximum(sched.chunk_lens[on].astype(np.int64), 1)
         last_page = (pos0 + n - 1) // bs
         full_keys = n * pos0 + n * (n + 1) // 2
-        out = {"window_pages_full": 0, "chunk_pages_walked": 0,
-               "chunk_keys_attended": 0}
+        out = {"window_pages_full": 0, "window_keys_attended": 0,
+               "chunk_pages_walked": 0, "chunk_keys_attended": 0}
         chunk = n > 1
         for kind in self.pool.kinds:
             layers = len(kind.layers)

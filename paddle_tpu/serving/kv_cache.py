@@ -81,6 +81,14 @@ the null block. Kinds past the first are plain: no sharing, no content
 index (the engine refuses the prefix cache with them). A pool of one
 kind is the pool described above, attribute for attribute.
 
+A ROW STATE beside the pages (:class:`RowState`; docs/SERVING.md, "A
+fourth block"): a block whose token needs something of its predecessor
+that no page holds states one small array a batch row; the pool carries
+it through every step with its own arrays (``step_arrays``) and audits
+it in ``check_invariants``. It has no accounting (a slot's entry is
+whoever sits there's; position 0 ignores it), which is why the engine
+refuses the prefix cache and speculation with it.
+
 Reservation conservation survives sharing (pinned by test):
 ``blocks_free(+cached) - reserved >= 0`` at every point, and
 ``free + cached + owned + shared == total`` — reviving a cached block
@@ -92,8 +100,8 @@ allocation, so outstanding reservations can never be left unbacked
 import hashlib
 from collections import OrderedDict
 
-__all__ = ["CacheEntry", "KVBlockPool", "PageKind", "blocks_needed",
-           "prefix_chain_keys"]
+__all__ = ["CacheEntry", "KVBlockPool", "PageKind", "RowState",
+           "blocks_needed", "prefix_chain_keys"]
 
 
 class CacheEntry:
@@ -145,6 +153,50 @@ class PageKind:
     def __repr__(self):
         return "PageKind(%r, %r, %r)" % (self.name, self.layers,
                                          self.window)
+
+
+class RowState:
+    """A second kind of state beside the pages: one small array a BATCH
+    ROW (``[max_batch] + shape``), for a block whose token needs
+    something of its predecessor that no page holds (a convolution's
+    last inputs, a shifted value). It has no accounting: a row's entry
+    belongs to whatever sequence sits in the slot, every step rewrites
+    the entries of the rows it computed, and a step ignores the entry of
+    a row whose first token is at position 0, so admission and retirement
+    leave it alone. The steps take ``array`` after the pool's arrays,
+    donated, and return it (``KVBlockPool.step_arrays``).
+
+    What is NOT kept, and why the engine refuses the features that would
+    need it: the state at a page boundary of an adopted prefix (the
+    prefix cache), and the state before a window that is rolled back
+    (speculation)."""
+
+    __slots__ = ("max_batch", "shape", "dtype", "array")
+
+    def __init__(self, max_batch, shape, dtype="float32"):
+        import jax.numpy as jnp
+
+        self.max_batch = int(max_batch)
+        self.shape = tuple(int(d) for d in shape)
+        self.dtype = jnp.dtype(dtype)
+        self.array = jnp.zeros((self.max_batch,) + self.shape, self.dtype)
+
+    def check_invariants(self):
+        """Problem strings (empty: clean): the array a step handed back
+        is the one stated, and still there (a donated array that no
+        step's result replaced is deleted)."""
+        a, want = self.array, (self.max_batch,) + self.shape
+        if a.shape != want or a.dtype != self.dtype:
+            return ["row state: %s %s where %s %s was stated" % (
+                a.dtype, a.shape, self.dtype, want)]
+        if a.is_deleted():
+            return ["row state: the array was donated to a step and not "
+                    "replaced by its result"]
+        return []
+
+    def __repr__(self):
+        return "RowState(%d, %r, %r)" % (self.max_batch, self.shape,
+                                         str(self.dtype))
 
 
 class _KindPages:
@@ -215,18 +267,22 @@ class KVBlockPool:
 
     def __init__(self, n_layers, n_heads, head_dim, block_size,
                  num_blocks, dtype=None, device=None, entry=None,
-                 kinds=None):
+                 kinds=None, row_state=None):
         """``entry`` is the model's :class:`CacheEntry`; without one the
         pool holds K and V ``[n_heads, head_dim]`` a token. ``dtype``,
         when given, overrides the entry's. ``kinds`` (a sequence of
         :class:`PageKind`, the model's) splits the layers over kinds of
         page; ``num_blocks`` is then ``{kind name: usable blocks}`` or a
-        sequence in the kinds' order."""
+        sequence in the kinds' order. ``row_state`` (a
+        :class:`RowState`, or None) rides with the pool's arrays
+        through every step."""
         kinds = tuple(kinds) if kinds else (
             PageKind("all", range(int(n_layers))),)
+        if isinstance(num_blocks, dict):
+            num_blocks = [num_blocks[k.name] for k in kinds]
+            if len(kinds) == 1:
+                num_blocks, = num_blocks
         if len(kinds) > 1:
-            if isinstance(num_blocks, dict):
-                num_blocks = [num_blocks[k.name] for k in kinds]
             num_blocks = [int(n) for n in num_blocks]
             if len(num_blocks) != len(kinds):
                 raise ValueError("one block count a page kind: %r for %r"
@@ -245,6 +301,7 @@ class KVBlockPool:
             extra = []
         self.kinds = kinds
         self._extra = extra
+        self.row_state = row_state
         if num_blocks < 1:
             raise ValueError("KVBlockPool needs at least one usable block")
         if block_size < 1:
@@ -314,6 +371,22 @@ class KVBlockPool:
     def _set_part(self, name, value):
         i = self._part(name)
         self.arrays = self.arrays[:i] + (value,) + self.arrays[i + 1:]
+
+    @property
+    def step_arrays(self):
+        """What a step takes after the weights and hands back first:
+        the pages' arrays, then the row state's where there is one."""
+        if self.row_state is None:
+            return self.arrays
+        return self.arrays + (self.row_state.array,)
+
+    @step_arrays.setter
+    def step_arrays(self, value):
+        value = tuple(value)
+        if self.row_state is not None:
+            self.row_state.array = value[-1]
+            value = value[:-1]
+        self.arrays = value
 
     k = property(lambda self: self.arrays[self._part("k")],
                  lambda self, v: self._set_part("k", v))
@@ -648,8 +721,11 @@ class KVBlockPool:
             one way, ``truncate_owner`` moves it back), and no
             free-list block retains a content-index entry (a truncated
             or flushed block must leave the index)
+          * the row state, where the pool carries one
+            (:meth:`RowState.check_invariants`)
         """
-        problems = []
+        problems = ([] if self.row_state is None
+                    else self.row_state.check_invariants())
         with self._lock:
             for x in self._extra:
                 problems.extend(self._check_kind(x))

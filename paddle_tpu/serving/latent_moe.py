@@ -324,6 +324,20 @@ def _swiglu(x, w_gate, w_up, w_down, act_dtype):
     return _dot(h, w_down, act_dtype)
 
 
+def narrowed(dtype):
+    """``a -> a`` rounded to a router's ``dtype`` where that is narrower
+    than float32, and kept so: left to itself the chip's compiler
+    carries a value it has in float32 through a bfloat16 intermediate
+    unrounded (its excess precision)."""
+    import jax
+    import jax.numpy as jnp
+
+    if dtype == jnp.float32:
+        return lambda a: a
+    info = jnp.finfo(dtype)
+    return lambda a: jax.lax.reduce_precision(a, info.nexp, info.nmant)
+
+
 def route(block, x, router_w, router_bias):
     """The router: ``(idx [T, k] int32, w [T, k] float32)``. Sigmoid
     scores in ``router_dtype`` (float32: the dot at the highest
@@ -335,16 +349,7 @@ def route(block, x, router_w, router_bias):
     import jax.numpy as jnp
 
     rd = jnp.dtype(block.router_dtype)
-
-    def stored(a):
-        # a narrower router_dtype is kept narrow: left to itself the
-        # chip's compiler carries a value it has in float32 through a
-        # bfloat16 intermediate unrounded (its excess precision)
-        if rd == jnp.float32:
-            return a
-        info = jnp.finfo(rd)
-        return jax.lax.reduce_precision(a, info.nexp, info.nmant)
-
+    stored = narrowed(rd)
     logits = stored(jnp.dot(x.astype(rd), router_w.astype(rd),
                             precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=rd))

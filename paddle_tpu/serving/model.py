@@ -238,7 +238,8 @@ def _chunk_layout(jnp, positions, lengths, active, block_tables, window,
 # ``make_decode_step(model, return_logits)`` and
 # ``make_window_step(model, window, return_logits, max_tokens)``.
 BLOCK_KINDS = {"latent_moe": ("latent_moe", "LatentMoEBlock"),
-               "afmoe": ("afmoe", "AfmoeBlock")}
+               "afmoe": ("afmoe", "AfmoeBlock"),
+               "zaya": ("zaya", "ZayaBlock")}
 
 
 def block_from_dict(d):
@@ -365,9 +366,11 @@ def leaf_shapes(config):
 
 def dot_operand_names(config):
     """The leaves a step multiplies on the MXU (its weight stream), for
-    either block: every matrix but the gathered embedding."""
+    any block: every matrix but the gathered embedding, which a block
+    whose head is TIED to it multiplies too."""
+    tied = getattr(config.block, "tied_head", False)
     return [n for n, (shape, _d) in leaf_shapes(config).items()
-            if len(shape) > 1 and n != "embedding"]
+            if len(shape) > 1 and (tied or n != "embedding")]
 
 
 def weight_names(config):
@@ -862,6 +865,14 @@ class GenerationModel:
         position, one kind."""
         kinds = getattr(self.config.block, "page_kinds", None)
         return kinds(self.config) if kinds is not None else None
+
+    def row_state(self):
+        """What a batch row of this model carries from step to step
+        beside its pages, ``(shape a row, dtype)``
+        (``kv_cache.RowState``), or None: every block but one keeps all
+        it needs of the past in its pages."""
+        state = getattr(self.config.block, "row_state", None)
+        return state(self.config) if state is not None else None
 
     @property
     def step_counters(self):

@@ -640,3 +640,72 @@ def test_afmoe_step_updates_both_kinds_of_page_in_place(kind, v5e_chip):
     assert large and all(op == "custom-call" for op, _, _ in large), large
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * smallest, \
         compiled.memory_analysis()
+
+
+# the fourth block: one kind of page and a row state beside it
+ZAYA_STEP = dict(batch=8, blocks_per_seq=16, block_size=64, chunk=256,
+                 blocks=2048)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_zaya_step_updates_its_pages_and_its_carry_in_place(kind, v5e_chip):
+    """The fourth block's steps at its published widths (two layers, all
+    16 experts of each, heads of 128 on 2 cache heads: pages 256 lanes
+    wide, 4 query heads a group): the grouped-query kernels and
+    `kv_page_write` qualify as they are, K, V and the row state are
+    donated and rewritten in place, and the compiled steps hold nothing
+    of a pool's size but the kernels' own (aliased) results."""
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    KVBlockPool)
+
+    g = ZAYA_STEP
+    cfg = GenerationConfig(
+        **dict(chip_smoke.FULL.zaya, vocab_size=1024),
+        max_seq_len=g["blocks_per_seq"] * g["block_size"])
+    model = GenerationModel.__new__(GenerationModel)
+    model.config, model.trace_count = cfg, 0
+    kinds = model.page_kinds()
+    assert [(k.name, k.window, k.layers) for k in kinds] \
+        == [("global", None, (0, 1))]
+    sharding = jax.sharding.SingleDeviceSharding(v5e_chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=sharding)
+
+    weights = {k: arg(s, d) for k, (s, d) in
+               cfg.block.leaf_shapes(cfg).items()}
+    B, Mb = g["batch"], g["blocks_per_seq"]
+    arrays = jax.eval_shape(lambda: KVBlockPool(
+        cfg.n_layers, cfg.n_heads, cfg.head_dim, g["block_size"],
+        g["blocks"], entry=cfg.block.cache_entry(), kinds=kinds).arrays)
+    assert [a.shape for a in arrays] == [(2, 2049, 64, 256)] * 2
+    assert all(a.dtype == jnp.bfloat16 for a in arrays)
+    shape, dtype = model.row_state()
+    assert shape == (2, 2 * 1280 + 128) and dtype == "float32"
+    state = arg((B,) + shape, dtype)
+    pools = tuple(arg(a.shape, a.dtype) for a in arrays) + (state,)
+    row, on = arg((B,)), arg((B,), jnp.bool_)
+    tables = arg((B, Mb))
+    with device.compiling_for(v5e_chip):
+        if kind == "decode":
+            compiled = cfg.block.make_decode_step(model).lower(
+                weights, *pools, row, on, row, row, tables, on).compile()
+        else:
+            compiled = cfg.block.make_window_step(
+                model, g["chunk"], max_tokens=B + g["chunk"]).lower(
+                weights, *pools, arg((B, g["chunk"])), on, row, row, row,
+                tables, on).compile()
+    hlo = compiled.as_text()
+    names = ["gmm", "kv_page_write", "gqa_paged_decode_attention"
+             if kind == "decode" else "gqa_paged_chunk_attention"]
+    for name in names:
+        assert name in hlo, name
+    smallest = min(a.size for a in arrays)
+    large = _large_results(hlo, smallest)
+    assert large and all(op == "custom-call" for op, _, _ in large), large
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 * smallest, m
+    # the pools and the carry come back in the arguments' own buffers
+    donated = sum(a.size * 2 for a in arrays) + B * shape[0] * shape[1] * 4
+    assert m.alias_size_in_bytes >= donated, m

@@ -57,7 +57,7 @@ def held_bytes(config, monkeypatch):
     token = sum(int(np.prod(shape)) for _n, shape in entry.parts) \
         * itemsize(entry.dtype)
     kinds = shell.page_kinds()
-    if kinds:
+    if kinds and len(kinds) > 1:
         pools = sum(len(k.layers) * token * e["block_size"]
                     * (e[k.name + "_blocks"] + 1) for k in kinds)
         # the one number perfbench/tests/test_configs.py reckons with
@@ -66,12 +66,15 @@ def held_bytes(config, monkeypatch):
     else:
         pools = cfg.n_layers * token * e["block_size"] \
             * (e["num_blocks"] + 1)
+    state = shell.row_state()
+    if state is not None:          # a row's carry beside its pages
+        pools += e["max_batch"] * int(np.prod(state[0])) * itemsize(state[1])
     return weights, pools
 
 
 def test_the_benchmark_has_the_serving_configurations():
     assert set(SERVING) >= {"xglm-1.7b-serve", "kanana-2-30b-a3b-serve",
-                            "trinity-large-preview-serve"}
+                            "trinity-large-preview-serve", "zaya1-8b-serve"}
 
 
 @pytest.mark.parametrize("name", SERVING)
@@ -122,6 +125,46 @@ def test_trinity_states_its_cut_and_its_assumed_equations():
         assert config[key] == want, key
 
 
+def test_zaya_states_its_cut_and_its_assumed_equations():
+    config = configuration("zaya1-8b-serve")
+    assert config["reduced"] == ["num_hidden_layers"]
+    assert config["published"] == {"num_hidden_layers": 40,
+                                   "layers_held": [0, 19]}
+    assert config["num_hidden_layers"] == 20
+    # copied whole from the source: the held layers are its first 20
+    assert config["layer_types"] == ["hybrid"] * 40
+    for key in ("sources", "layers_held", "embedding", "cca_latent",
+                "conv_taps", "qk_mean", "value_shift",
+                "qk_norm_and_temperature", "partial_rotary",
+                "residual_scaling", "router", "init", "engine"):
+        assert key in config["assumed"], key
+    assert "two pipeline stages" in config["deployment"]
+    assert any("skip expert" in d for d in config["departures"])
+    assert [c["name"] for c in config["controls"]] == [
+        "bf16_router", "int8_expert_weights", "carry_ignored"]
+    # no width, no expert and no vocabulary row is cut
+    for key, want in (("hidden_size", 2048), ("head_dim", 128),
+                      ("num_attention_heads", 8),
+                      ("num_key_value_heads", 2),
+                      ("moe_intermediate_size", 2048),
+                      ("num_experts", 16), ("num_experts_per_tok", 1),
+                      ("router_hidden_size", 256), ("vocab_size", 262272),
+                      ("cca_time0", 2), ("cca_time1", 2),
+                      ("partial_rotary_factor", 0.5),
+                      ("tie_word_embeddings", True)):
+        assert config[key] == want, key
+    e = config["engine"]
+    assert (e["max_batch"], e["max_seq_len"], e["block_size"]) \
+        == (96, 12288, 64)
+    assert e["prefill_chunk"] == e["prefill_token_budget"] == 1024
+    from perfbench.flops import zaya as flops
+    from perfbench.reference import zaya as ref
+
+    # a cached token is 512 values a layer, 20,480 B over the 20 layers
+    assert flops.cache_bytes_per_token(config) == 20480
+    assert ref.n_params(config) == 4688800104       # 9.38 GB in bf16
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_a_cells_traffic_parses(cell):
     _w, config, mix = spec.cell(BENCH, cell)
@@ -142,12 +185,40 @@ def test_a_cells_traffic_parses(cell):
     assert all(0 <= int(t) < vocab for r in requests[:8] for t in r.prompt)
 
 
-def test_the_new_cells_traffic_is_what_the_issue_gave():
-    _w, _c, mix = spec.cell(BENCH, "trinity-large-preview.long-closed")
-    assert mix == {
+@pytest.mark.parametrize("cell,want", [
+    ("trinity-large-preview.long-closed", {
         "prompt_len": {"dist": "lognormal", "median": 8192, "sigma": 0.9,
                        "min": 512, "max": 32768},
         "output_len": {"dist": "lognormal", "median": 1024, "sigma": 0.5,
                        "min": 256, "max": 2048},
         "kind": "closed_loop", "clients": 64, "ramp_s": 30.0,
-        "drain_s": 240.0, "trace_seconds": 5.0}
+        "drain_s": 240.0, "trace_seconds": 5.0}),
+    ("zaya1-8b.reason-closed", {
+        "prompt_len": {"dist": "lognormal", "median": 1024, "sigma": 1.0,
+                       "min": 128, "max": 8192},
+        "output_len": {"dist": "lognormal", "median": 2048, "sigma": 0.5,
+                       "min": 512, "max": 4096},
+        "kind": "closed_loop", "clients": 120, "ramp_s": 45.0,
+        "drain_s": 240.0, "trace_seconds": 5.0})])
+def test_the_new_cells_traffic_is_what_the_issue_gave(cell, want):
+    w, _c, mix = spec.cell(BENCH, cell)
+    assert mix == want and w["chips"] == 1
+
+
+def test_the_zaya_cell_reports_the_kernels_it_shares():
+    """Appended to the readings of the kernels it reuses, not to the
+    window ones; its own three metrics are its alone."""
+    cell = "zaya1-8b.reason-closed"
+    mine = {m["name"] for m in spec.metrics_of(BENCH, "per_layer", cell)}
+    assert {"gmm_roofline_pct.decode", "gqa_decode_attn_roofline_pct.decode",
+            "gqa_chunk_attn_roofline_pct.batch", "mfu_pct.batch",
+            "kernels_device_share_pct.batch", "expert_top_load_pct.decode",
+            "carry_rows_pct.batch", "reach_chip_s"} <= mine
+    assert not mine & {"window_pages_walked_pct.decode",
+                       "global_pool_used_pct.batch",
+                       "window_pool_used_pct.batch",
+                       "expert_load_max_over_mean.decode"}
+    for name in mine:
+        spec.layer_metric(name)              # every one has its file
+    e2e = {m["name"] for m in spec.metrics_of(BENCH, "end_to_end", cell)}
+    assert e2e == {"serve_tokens_per_s", "setup_s"}

@@ -6,7 +6,8 @@
 ``write`` imports ``paddle_tpu`` from ``<checkout>`` (this tree or a
 ``git archive`` of another commit), builds the decode and chunk steps of
 the serving configurations of the benchmark (XGLM, kanana and, where the
-checkout has it, trinity with its two kinds of page) at their engines' geometry
+checkout has them, trinity with its two kinds of page and zaya with its
+row state) at their engines' geometry
 (``perfbench/configs/*-serve.json``; only shapes are made, no weights),
 lowers them for a described ``v5e:2x2`` device and writes the StableHLO
 text with debug locations stripped, and prints each step's dots counted
@@ -131,9 +132,14 @@ def write(root, out, compile_too):
             lambda: KVBlockPool(cfg.n_layers, cfg.n_heads, cfg.head_dim,
                                 bs, num_blocks or e["num_blocks"],
                                 entry=model.cache_entry(), **more).arrays))
+        # a block with a row state hands it over after the pool's arrays
+        state = getattr(model, "row_state", lambda: None)()
+        if state is not None:
+            pool += (arg((B,) + state[0], state[1]),)
         row, on = arg((B,)), arg((B,), jnp.bool_)
         # one block table, or the stack of them, a table a page kind
-        tables = arg((len(kinds), B, Mb) if kinds else (B, Mb))
+        tables = arg((len(kinds), B, Mb) if kinds and len(kinds) > 1
+                     else (B, Mb))
         budget = e.get("prefill_token_budget",
                        default_prefill_token_budget(C))
         # the engine's calls: prompt_feed, use_prompt, prev_tokens,
@@ -186,6 +192,16 @@ def write(root, out, compile_too):
     both_steps("trinity", model, c["engine"], kinds=model.page_kinds(),
                num_blocks={"global": c["engine"]["global_blocks"],
                            "window": c["engine"]["window_blocks"]})
+
+    if not os.path.exists(os.path.join(
+            root, "perfbench/configs/zaya1-8b-serve.json")):
+        return             # a checkout from before the fourth block
+    from perfbench.runners import serve_zaya
+
+    c = config("zaya1-8b-serve.json")
+    cfg = serve_zaya.generation_config(c, c["engine"]["max_seq_len"])
+    model = model_of(cfg, cfg.block.leaf_shapes(cfg))
+    both_steps("zaya", model, c["engine"], kinds=model.page_kinds())
 
 
 def compare(dir_a, dir_b):
