@@ -249,8 +249,12 @@ _declare("PTPU_DATA_EXCHANGE_TIMEOUT", "float", 300.0,
          "(listener not up — startup skew or a crashed machine) is "
          "only confirmed dead at this deadline, the legacy tolerance")
 # -- serving (docs/SERVING.md) ----------------------------------------------
-_declare("PTPU_SERVE_ASYNC_STEPS", "int", 4,
-         "decode steps kept in flight ahead of EOS/stream materialization")
+_declare("PTPU_SERVE_ASYNC_STEPS", "int", 2,
+         "serving steps the worker keeps dispatched ahead of the one "
+         "whose result it takes (depth): a tick of the host longer than "
+         "depth - 1 steps leaves the device idle, and a new request's "
+         "first step stands behind depth - 1 steps of other rows "
+         "(1 = synchronous)")
 _declare("PTPU_SERVE_PREFILL_CHUNK", "int", 0,
          "prompt tokens a prefill row consumes per mixed serving step "
          "(0 = the server's default, 256, clamped to the context)")

@@ -13,13 +13,20 @@ Decode steps ride the PR-2 async machinery: the step's input token
 vector chains on *device* from the previous step's output, so the worker
 dispatches step ``k+1`` without materializing step ``k`` — an
 ``InflightWindow`` (``async_depth``, default ``$PTPU_SERVE_ASYNC_STEPS``
-or 4) bounds the lag, and EOS detection/streaming callbacks process the
-materialized tokens a few steps behind dispatch. Deterministic finishes
+or 2: ONE step queued behind the running one) bounds the lag, and EOS
+detection/streaming callbacks process the materialized tokens
+``async_depth - 1`` steps behind dispatch. The queued steps hide the
+host's tick from the token gap: a tick shorter than ``async_depth - 1``
+steps never leaves the device idle (the step log's ``ran_dry`` says
+where one did). They cost a new request's first step its place: it
+stands behind the ``async_depth - 1`` steps dispatched before it (the
+request log's ``ahead_ms``). Deterministic finishes
 (``max_new_tokens``, the sequence-length cap) are known at dispatch
-time, so the only cost of the lag is a handful of discarded
-speculative steps after an EOS — whose tokens are never emitted
-(``record_token`` drops post-EOS outputs) and whose KV writes land in
-blocks the retiring sequence still owns until ``reap``. With
+time, so the other cost of the lag is ``async_depth - 1`` discarded
+speculative steps after an EOS (one, by default) — whose tokens are
+never emitted (``record_token`` drops post-EOS outputs) and whose KV
+writes land in blocks the retiring sequence still owns until ``reap``;
+``swap_weights`` waits for as many to drain. With
 speculative DECODING on (``spec_k`` below) the window collapses to one
 step: every verify window is materialized before the next is planned,
 so nothing is ever dispatched for a finished sequence, and rejected
@@ -117,15 +124,23 @@ class _TickLog:
     A tick dispatches at most one step (``opened``: its record, which
     gets this tick's host time) and consumes the result of at most one,
     dispatched ``async_depth - 1`` ticks earlier (``done``; ``waited``
-    is the seconds this tick spent blocked on it)."""
+    is the seconds this tick spent blocked on it): the tick before, by
+    default. ``follows_dispatch``: the tick before this one (``before``,
+    its log: None after an idle stretch, or where it did not record)
+    dispatched a step too, so a device with nothing to run at this
+    tick's dispatch ran dry under a working host (the record's
+    ``ran_dry``)."""
 
-    __slots__ = ("t_tick", "t_planned", "waited", "opened", "done")
+    __slots__ = ("t_tick", "t_planned", "waited", "opened", "done",
+                 "follows_dispatch")
 
-    def __init__(self):
+    def __init__(self, before):
         self.t_tick = self.t_planned = time.perf_counter()
         self.waited = 0.0
         self.opened = None
         self.done = []
+        self.follows_dispatch = (before is not None
+                                 and before.opened is not None)
 
 
 def _phase(tick, name):
@@ -234,7 +249,9 @@ class _ModelWorker:
         self.async_depth = max(1, int(async_depth))
         # [(next_tokens_handle, plan, step-log record or None)], FIFO
         self._inflight = []
-        self._tick_log = None      # this tick's _TickLog while recording
+        # this tick's _TickLog while recording (between ticks the last
+        # tick's; None again once the worker idles)
+        self._tick_log = None
         # (step, t_ready, t_ready is its completion) of the last record
         self._last_consumed = None
 
@@ -377,6 +394,8 @@ class _ModelWorker:
                            and not len(self.queue)
                            and not self.scheduler.has_work()
                            and not self._inflight):
+                        # an idle stretch: the next step follows no tick
+                        self._tick_log = None
                         self._cv.wait(timeout=0.1)
                     abort = self._abort_error
                     if (abort is None and self._closing
@@ -514,8 +533,8 @@ class _ModelWorker:
         # in place by _run (the fault-injection sites fire here — BEFORE
         # any mutation — for exactly that reason)
         tick = self._tick_log = (
-            _TickLog() if _metrics.enabled() or _tracing.enabled()
-            else None)
+            _TickLog(self._tick_log)
+            if _metrics.enabled() or _tracing.enabled() else None)
         sched = self.scheduler
         plan = kind = None
         with _phase(tick, "plan"):
@@ -636,6 +655,12 @@ class _ModelWorker:
             # steps dispatched before it whose results the host had not
             # consumed yet: what may still be ahead of it on the device
             "queued": len(self._inflight),
+            # the device had nothing left to run although the host was
+            # working: the tick before dispatched too, and the newest
+            # step in flight was done already (the device runs them in
+            # order, so that one says it for all), or none was in flight
+            "ran_dry": tick.follows_dispatch and (
+                not self._inflight or self._inflight[-1][0].is_ready()),
             # the dispatch call traced or compiled (the first step of
             # each shape): its times are not a warm step's
             "cold": self.model.trace_count != traces0,

@@ -14,6 +14,7 @@ import time
 import numpy as np
 import pytest
 
+from paddle_tpu import flags
 from paddle_tpu.observability import metrics, tracing
 from paddle_tpu.serving import (GenerationConfig, GenerationModel,
                                 ServingEngine)
@@ -124,7 +125,7 @@ PROMPT_LENS = (5, 11, 17, 3, 9, 20)
 NEW_TOKENS = 12
 
 
-def serve(model, stream=None, lens=PROMPT_LENS, **engine_kw):
+def serve(model, stream=None, lens=PROMPT_LENS, eos_id=None, **engine_kw):
     """One engine, every request to its end, the engine closed: the
     worker has written its last record. Returns (tokens, Δsteps)."""
     kw = dict(max_batch=4, max_seq_len=64, block_size=4, prefill_chunk=8)
@@ -133,7 +134,8 @@ def serve(model, stream=None, lens=PROMPT_LENS, **engine_kw):
     with ServingEngine(model, **kw) as engine:
         steps0 = engine.stats()["default"]["steps"]
         reqs = [engine.submit(rng.randint(0, 64, size=n).tolist(),
-                              max_new_tokens=NEW_TOKENS, stream=stream)
+                              max_new_tokens=NEW_TOKENS, eos_id=eos_id,
+                              stream=stream)
                 for n in lens]
         tokens = [r.wait(300) for r in reqs]
         steps = engine.stats()["default"]["steps"] - steps0
@@ -142,9 +144,15 @@ def serve(model, stream=None, lens=PROMPT_LENS, **engine_kw):
 
 @pytest.fixture(scope="module")
 def logged_run():
-    """A chunked run with metrics on: its records, its step count, the
-    registry's counters, and the same requests served with metrics off
-    by a model of the same weights."""
+    """A chunked run with metrics on and no `async_depth` stated: its
+    records, its step count, the registry's counters, and the same
+    requests served with metrics off by a model of the same weights."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.delenv("PTPU_SERVE_ASYNC_STEPS", raising=False)
+        return _logged_run()
+
+
+def _logged_run():
     metrics.reset()
     assert not metrics.enabled()
     off_model = toy_model()
@@ -157,6 +165,8 @@ def logged_run():
         reg = metrics.registry()
         out = dict(
             records=reg.samples("serving/step").records(), steps=steps,
+            requests=reg.samples("serving/request").records(),
+            async_depth=flags.env("PTPU_SERVE_ASYNC_STEPS"),
             tokens=tokens, off_tokens=off_tokens, off_steps=off_steps,
             off_records=off_records, raw_step=raw,
             counters={k: reg.counter("serving/" + k).value
@@ -268,8 +278,30 @@ def check_host_and_wait_fit_in_the_tick(run):
             assert r["t_dispatched"] <= taken["t_wait"] \
                 and taken["t_done"] <= r["t_end"]
             consumed.add(r["consumed"])
-    # steps queue async_depth deep, so a result is taken a few ticks on
-    assert consumed and all(by_step[s]["queued"] <= 3 for s in consumed)
+    # steps queue async_depth deep, so a result is taken
+    # async_depth - 1 ticks on
+    assert consumed and all(by_step[s]["queued"] <= run["async_depth"] - 1
+                            for s in consumed)
+
+
+def check_one_step_is_queued_where_no_depth_is_stated(run):
+    # the program's default (ISSUE 43): one step behind the running one,
+    # so a new request's first-token step stands behind one step at most
+    assert run["async_depth"] == 2
+    warm = [r for r in run["records"] if not r["cold"]]
+    assert warm and all(r["queued"] <= 1 for r in warm)
+    assert any(r["queued"] == 1 for r in warm)
+    assert len(run["requests"]) == len(PROMPT_LENS)
+    assert all(q["queued_at_first_token"] <= 1 for q in run["requests"])
+
+
+def check_ran_dry_is_a_bool_and_false_on_the_first_step(run):
+    recs = run["records"]
+    assert all(type(r["ran_dry"]) is bool for r in recs)
+    # the first step of the run follows no tick that dispatched
+    assert recs[0]["ran_dry"] is False and recs[0]["queued"] == 0
+    # a step with another still running ahead of it is not dry
+    assert all(r["queued"] for r in recs if r["ran_dry"])
 
 
 def check_cold_is_the_first_step_of_each_shape(run):
@@ -296,6 +328,8 @@ def check_metrics_off_writes_nothing_and_changes_no_token(run):
     check_mixed_records_carry_one_token_rows,
     check_slots_used_is_what_the_scheduler_planned,
     check_stamps_are_ordered, check_host_and_wait_fit_in_the_tick,
+    check_one_step_is_queued_where_no_depth_is_stated,
+    check_ran_dry_is_a_bool_and_false_on_the_first_step,
     check_cold_is_the_first_step_of_each_shape,
     check_metrics_off_writes_nothing_and_changes_no_token,
 ], ids=lambda f: f.__name__[len("check_"):])
@@ -303,16 +337,23 @@ def test_engine_step_log(logged_run, check):
     check(logged_run)
 
 
-@pytest.mark.parametrize("async_depth", [1, 4])
-def test_a_slow_stream_callback_is_host_time_not_wait(metrics_on,
-                                                     async_depth):
+def sleepy_stream(seconds):
+    """A stream callback that sleeps once, at a request's fifth token,
+    and the list that gets the moment it fell asleep."""
     slept = []
 
     def stream(request, _token, _final):
         if len(request.tokens) == 5 and not slept:
             slept.append(time.perf_counter())
-            time.sleep(0.05)
+            time.sleep(seconds)
 
+    return stream, slept
+
+
+@pytest.mark.parametrize("async_depth", [1, 4])
+def test_a_slow_stream_callback_is_host_time_not_wait(metrics_on,
+                                                     async_depth):
+    stream, slept = sleepy_stream(0.05)
     serve(toy_model(), stream=stream, lens=(6,),
           async_depth=async_depth)
     recs = metrics_on.samples("serving/step").records()
@@ -328,6 +369,72 @@ def test_a_slow_stream_callback_is_host_time_not_wait(metrics_on,
     assert all(r["wait_ms"] < 50 for r in recs if not r["cold"])
     assert all(r["host_ms"] < 50 for r in recs
                if r is not tick and not r["cold"])
+
+
+@pytest.mark.parametrize("eos", [False, True], ids=["to_length", "eos"])
+@pytest.mark.parametrize("async_depth", [1, 2, 4])
+def test_served_tokens_do_not_depend_on_the_depth(logged_run, async_depth,
+                                                  eos):
+    """The depth orders the same steps: their inputs chain on the
+    device, so every request's tokens are those of the run with no
+    depth stated, to the int. With an EOS the `async_depth - 1` steps
+    dispatched for a finished row are discarded at every depth."""
+    want = logged_run["off_tokens"]
+    eos_id = None
+    if eos:
+        # a token some request emits mid-way: it stops there, the
+        # others (which never emit it, or later) run on
+        eos_id = want[0][4]
+        want = [t[:t.index(eos_id) + 1] if eos_id in t else t
+                for t in want]
+        assert len(want[0]) < NEW_TOKENS
+    tokens, _steps = serve(toy_model(), eos_id=eos_id,
+                           async_depth=async_depth)
+    assert tokens == want
+
+
+@pytest.mark.parametrize("async_depth", [1, 2])
+def test_an_arrival_into_an_idle_engine_is_not_a_dry_queue(metrics_on,
+                                                           async_depth):
+    """`ran_dry` counts a device left idle by a WORKING host. A request
+    that finds the worker idle finds the device idle too, and its first
+    step reads false: at depth 1, where the tick before the idle
+    stretch dispatched the last step of the request before it, as at
+    depth 2, where that tick only drained."""
+    kw = dict(max_batch=4, max_seq_len=64, block_size=4, prefill_chunk=8,
+              async_depth=async_depth)
+    with ServingEngine(toy_model(), **kw) as engine:
+        for _ in range(3):
+            engine.submit([5, 9, 2, 7, 1], max_new_tokens=6).wait(300)
+            time.sleep(0.25)          # the worker waits on its condition
+    recs = metrics_on.samples("serving/step").records()
+    reqs = metrics_on.samples("serving/request").records()
+    assert len(reqs) == 3
+    assert all(type(r["ran_dry"]) is bool for r in recs)
+    by_step = {r["step"]: r for r in recs}
+    for q in reqs:
+        first = by_step[q["first_step"]]
+        assert first["queued"] == 0 and first["ran_dry"] is False
+    if async_depth == 1:
+        # synchronous: the device waits out every tick of a busy stretch
+        firsts = {q["first_step"] for q in reqs}
+        assert all(r["ran_dry"] for r in recs if r["step"] not in firsts)
+
+
+@pytest.mark.parametrize("async_depth", [2, 4])
+def test_a_tick_longer_than_the_queued_steps_runs_the_queue_dry(
+        metrics_on, async_depth):
+    """A stream callback that sleeps longer than the queued steps run:
+    they are done when the next tick dispatches, and that step's record
+    says the device had nothing left (`ran_dry`)."""
+    stream, slept = sleepy_stream(0.1)
+    serve(toy_model(), stream=stream, lens=(6,), async_depth=async_depth)
+    recs = metrics_on.samples("serving/step").records()
+    (t_cb,) = slept
+    tick = next(r for r in recs if r["t_tick"] <= t_cb <= r["t_end"])
+    after = next(r for r in recs if r["step"] == tick["step"] + 1)
+    assert tick["host_ms"] >= 100
+    assert after["queued"] == async_depth - 1 and after["ran_dry"] is True
 
 
 def latent_toy_model():
