@@ -55,7 +55,7 @@ Sizes = collections.namedtuple("Sizes", [
     "prompt_lens", "new_tokens", "spec_k", "spec_tree",
     "int8_mkn", "latent", "latent_batch", "latent_chunk", "afmoe",
     "afmoe_batch", "afmoe_chunk", "zaya", "zaya_batch", "zaya_chunk",
-    "rec"])
+    "scan", "rec"])
 
 # the flagship at full width (bench_transformer_fluid's operating point)
 FULL = Sizes(
@@ -99,6 +99,12 @@ FULL = Sizes(
                   router_hidden=256, n_routed_experts=16, moe_d_ff=2048,
                   rope_theta=5e6)),
     zaya_batch=8, zaya_chunk=256,
+    # the delta-rule scan's two kernels at the published head shape
+    # (inclusionAI/Ling-3.0-flash: 32 heads of 128 x 128 float32 a row
+    # a layer): batch rows, layers (the second used), the prefill
+    # tokens of the chunk kernel's rows
+    scan=dict(n_heads=32, head_dim=128, layers=2, batch=8,
+              chunk_rows=(150, 64, 2, 1000)),
     # bench.py --rec-only sizes
     rec=dict(n_shards=4, records_per_shard=320, batch_size=32, vocab=512,
              fields=6, embed_dim=16, cache_rows=128))
@@ -137,6 +143,8 @@ TOY = Sizes(
                   router_hidden=16, n_routed_experts=4, moe_d_ff=64,
                   rope_theta=5e6)),
     zaya_batch=4, zaya_chunk=32,
+    scan=dict(n_heads=8, head_dim=128, layers=2, batch=4,
+              chunk_rows=(70, 3)),
     rec=dict(n_shards=2, records_per_shard=64, batch_size=16, vocab=128,
              fields=4, embed_dim=8, cache_rows=32))
 
@@ -949,6 +957,56 @@ def kernel_cases(sz):
         [gpool, gpool, rows_spec, rows_spec, ((Ua,), i32), ((Ua,), i32),
          ((Ua,), i32)], {"layer": 1}, gq, fill_page_write)
 
+    # the scan's two kernels: states of every row and layer in one
+    # array (the second layer used); the one-token step with idle rows
+    # at both ends and one that starts its sequence (decay 0); the
+    # chunked one over rows of several tiles, one tile and two tokens,
+    # from their stored state and from zero, decays down to the gate's
+    # bound of -5
+    from paddle_tpu.ops.pallas_kernels import KDA_TILE
+
+    sc = sz.scan
+    Hs, ds, Bs = sc["n_heads"], sc["head_dim"], sc["batch"]
+    state_spec = ((Bs, sc["layers"], Hs, ds, ds), f32)
+
+    def unit(x):
+        return (x / np.linalg.norm(x, axis=-1, keepdims=True)).astype(f32)
+
+    def fill_scan(rng, T):
+        return [rng.randn(*state_spec[0]).astype(f32),
+                unit(rng.randn(T, Hs, ds)) * f32(ds ** -0.5),
+                unit(rng.randn(T, Hs, ds)), rng.randn(T, Hs, ds).astype(f32)]
+
+    def fill_kda_decode(rng):
+        alpha = np.exp(-5.0 * rng.rand(Bs, Hs, ds)).astype(f32)
+        alpha[1] = 0.0
+        on = np.ones(Bs, bool)
+        on[0] = on[-1] = False
+        return fill_scan(rng, Bs) + [alpha, rng.rand(Bs, Hs).astype(f32),
+                                     on]
+
+    cases["kda_decode"] = (
+        [state_spec] + [((Bs, Hs, ds), f32)] * 4
+        + [((Bs, Hs), f32), ((Bs,), np.bool_)], {"layer": 1},
+        dict(head_dim=ds, n_heads=Hs), fill_kda_decode)
+    rows = sc["chunk_rows"]
+    Ts = sum(rows)
+    tiles = [(sum(rows[:b]) + j, min(KDA_TILE, n - j), b,
+              (1 + b % 2) if j == 0 else 0, int(j + KDA_TILE >= n))
+             for b, n in enumerate(rows) for j in range(0, n, KDA_TILE)]
+    tiles.insert(1, (0, 0, 0, 0, 0))                # a tile that sits out
+
+    def fill_kda_chunk(rng):
+        g = (-5.0 * rng.rand(Ts, Hs, ds)).astype(f32)
+        g[:KDA_TILE] = -5.0
+        return fill_scan(rng, Ts) + [g, rng.rand(Ts, Hs).astype(f32)] \
+            + [np.array(col, i32) for col in zip(*tiles)]
+
+    cases["kda_chunk"] = (
+        [state_spec] + [((Ts, Hs, ds), f32)] * 4 + [((Ts, Hs), f32)]
+        + [((len(tiles),), i32)] * 5, {"layer": 1},
+        dict(head_dim=ds, n_heads=Hs), fill_kda_chunk)
+
     M, K, N = sz.int8_mkn
     cases["int8_matmul"] = (
         [((M, K), f32), ((K, N), np.int8), ((N,), f32)],
@@ -980,6 +1038,11 @@ def leg_kernels(sz, rehearsal):
         got = jax.jit(lambda *a: spec.pallas(*a, **kwargs))(*args)
         with jax.default_matmul_precision("highest"):
             want = jax.jit(lambda *a: spec.fallback(*a, **kwargs))(*args)
+        if name.startswith("kda_"):
+            # (states, read-outs): two results of different shapes
+            got, want = (np.concatenate([np.asarray(a, np.float32).ravel()
+                                         for a in pair])
+                         for pair in (got, want))
         got = np.asarray(got, np.float32)
         want = np.asarray(want, np.float32)
         if name == "chunk_window":

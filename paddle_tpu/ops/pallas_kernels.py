@@ -81,6 +81,20 @@ gqa_paged_decode_attention / gqa_paged_chunk_attention: the same two
   tile's earliest query still sees. ``kv_page_write`` puts a step's new
   K and V rows into such a pool in place, a whole page a grid step.
 
+kda_decode / kda_chunk: the SCAN of a linear-attention layer (the delta
+  rule with a decay a channel; ``kernels 'kda_decode'``, ``'kda_chunk'``),
+  whose state is a ``[dk, dv]`` float32 matrix a head that a batch row
+  carries beside its pages (``kv_cache.RowState``). Both take every
+  row's, layer's and head's matrices as ONE array, aliased to the
+  result, with the layer a traced scalar. ``kda_decode`` is one token a
+  row: the active rows' states of one layer read, decayed, updated by
+  the rank-one delta and read out on the VPU, written back in place, an
+  inactive row neither fetched nor written. ``kda_chunk`` is a step's
+  prefill tokens in tiles of ``KDA_TILE``: the recurrence in closed form
+  (the WY / UT transform), the decays factored over sub-chunks of
+  ``KDA_SUB`` tokens so that no ``exp`` of a whole tile's decay is ever
+  formed, a row's state carried in VMEM over its tiles.
+
 Whether a kernel compiles or runs in the Pallas interpreter is decided in
 one place, ``core.device.pallas_interpret()``: compiled on TPU (a kernel
 Mosaic refuses raises), interpreted everywhere else so the CPU test mesh
@@ -111,7 +125,8 @@ __all__ = ["flash_attention", "flash_attention_portable",
            "gqa_paged_chunk_attention", "gqa_paged_decode_attention",
            "gqa_paged_attention_reference",
            "gqa_paged_decode_attention_reference", "kv_page_write",
-           "kv_page_write_reference"]
+           "kv_page_write_reference", "kda_decode", "kda_decode_reference",
+           "kda_chunk", "kda_chunk_reference"]
 
 _NEG_INF = -1e30
 
@@ -1409,6 +1424,403 @@ def latent_paged_attention_reference(pool, q, block_tables, positions,
 
 
 # ---------------------------------------------------------------------------
+# KDA: the delta rule with a per-channel decay (linear attention), whose
+# state a batch row carries beside its pages (kv_cache.RowState)
+# ---------------------------------------------------------------------------
+#
+#   S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T,  o_t = S_t^T q_t
+#
+# a head's S is ``[dk, dv]`` float32; the steps keep every row's, every
+# layer's and every head's in ONE array ``[B, L, H, dk, dv]`` that both
+# kernels take whole and update in place (the layer a traced scalar).
+
+KDA_TILE = 64        # tokens a grid step of kda_chunk carries the state over
+KDA_SUB = 16         # tokens whose decays are factored against one reference
+_HIGHEST = jax.lax.Precision.HIGHEST
+# the most half a sub-chunk's log-decay can be: KDA_SUB / 2 tokens at the
+# gate's lower bound of -5 a token
+_KDA_HALF_SPAN = 40.0
+
+
+def _kda_decode_kernel(src_ref, act_ref, layer_ref, n_act_ref, cols_ref,
+                       rows_ref, s_ref, so_ref, o_ref, *, n_heads):
+    """Grid (B,): one batch row a grid step, its layer's ``[H, dk, dv]``
+    states one block (found by the index map; an INACTIVE row's index
+    repeats its active neighbour's, so nothing is fetched or written
+    back for it). ``cols [dk, 3H..]`` holds the row's decay, key and
+    query a head as COLUMNS (``dk`` on sublanes, as the state's rows
+    are), ``rows [2H, dv]`` its value and beta as lane rows."""
+    b = pl.program_id(0)
+    H = n_heads
+
+    @pl.when(act_ref[b] > 0)
+    def _row():
+        cols = cols_ref[0]
+        for h in range(H):
+            s = s_ref[0, 0, h].astype(jnp.float32)         # [dk, dv]
+            kc = cols[:, H + h:H + h + 1]
+            s = cols[:, h:h + 1] * s                       # the decay
+            u = rows_ref[0, H + h:H + h + 1, :] * (
+                rows_ref[0, h:h + 1, :]
+                - jnp.sum(kc * s, axis=0, keepdims=True))  # [1, dv]
+            s = s + kc * u                                 # the delta rule
+            so_ref[0, 0, h] = s.astype(so_ref.dtype)
+            s = so_ref[0, 0, h].astype(jnp.float32)        # as stored
+            o_ref[0, h:h + 1, :] = jnp.sum(
+                cols[:, 2 * H + h:2 * H + h + 1] * s, axis=0, keepdims=True)
+
+    @pl.when(act_ref[b] == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    # no row active: the one block every step names is handed back as read
+    @pl.when((b == 0) & (n_act_ref[0] == 0))
+    def _untouched():
+        so_ref[...] = s_ref[...]
+
+
+def kda_decode(state, q, k, v, alpha, beta, active, *, layer):
+    """One token a row through the delta rule, the rows' states updated
+    IN PLACE (``state`` is aliased to the first result).
+
+    state: ``[B, L, H, dk, dv]`` float32 WHOLE; ``layer`` (an int or a
+    traced scalar) picks ``state[:, layer]``. q, k, alpha: ``[B, H,
+    dk]`` (the query scaled, ``alpha = exp(g)`` the decay a channel, 0
+    where a row starts its sequence: its stored state is then ignored),
+    v: ``[B, H, dv]``, beta: ``[B, H]``, active: ``[B]`` bool. A row that
+    is not active is skipped: its state is neither read nor written and
+    its output is zero.
+
+    Returns ``(state', o [B, H, dv] float32)``. Memory-bound: every
+    active row's ``H * dk * dv`` floats are read and written once."""
+    B, L, H, dk, dv = state.shape
+    f32 = jnp.float32
+    lanes = -(-3 * H // 128) * 128
+    cols = jnp.concatenate([alpha.astype(f32), k.astype(f32),
+                            q.astype(f32)], axis=1)        # [B, 3H, dk]
+    cols = jnp.pad(jnp.swapaxes(cols, 1, 2),
+                   ((0, 0), (0, 0), (0, lanes - 3 * H)))   # [B, dk, lanes]
+    rows = jnp.concatenate(
+        [v.astype(f32), jnp.broadcast_to(beta.astype(f32)[:, :, None],
+                                         (B, H, dv))], axis=1)
+    on = active.astype(jnp.int32)
+    idx = jnp.arange(B, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(active, idx, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(active).astype(jnp.int32))
+
+    def of_row(b, src, on, layer, n):
+        return (src[b], layer[0], 0, 0, 0)
+
+    blk = pl.BlockSpec((1, 1, H, dk, dv), of_row)
+    new_state, o = pl.pallas_call(
+        functools.partial(_kda_decode_kernel, n_heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, dk, lanes), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((1, 2 * H, dv), lambda b, *_: (b, 0, 0)),
+                      blk],
+            out_specs=[blk,
+                       pl.BlockSpec((1, H, dv), lambda b, *_: (b, 0, 0))]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((B, H, dv), f32)],
+        # operand 6 (after the four prefetched scalars, the columns and
+        # the rows) is the state: the first result is the same buffer
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_device.pallas_interpret(),
+        name="kda_decode",
+    )(src, on, jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.sum(on).reshape(1), cols, rows, state)
+    return new_state, o
+
+
+def kda_decode_reference(state, q, k, v, alpha, beta, active, *, layer):
+    """The lax fallback: the same step on ``state[:, layer]`` whole."""
+    f32 = jnp.float32
+    s0 = jnp.take(state, layer, axis=1).astype(f32)        # [B, H, dk, dv]
+    s = alpha.astype(f32)[..., None] * s0
+    u = beta.astype(f32)[..., None] * (v.astype(f32) - jnp.einsum(
+        "bhk,bhkv->bhv", k.astype(f32), s, precision=_HIGHEST))
+    s = s + k.astype(f32)[..., None] * u[..., None, :]
+    o = jnp.einsum("bhk,bhkv->bhv", q.astype(f32), s, precision=_HIGHEST)
+    on = active[:, None, None]
+    s = jnp.where(on[..., None], s, s0).astype(state.dtype)
+    new_state = jax.lax.dynamic_update_index_in_dim(
+        state, s[:, None], jnp.asarray(layer, jnp.int32), axis=1)
+    return new_state, jnp.where(on, o, 0.0)
+
+
+def _kda_chunk_kernel(start_ref, len_ref, row_ref, load_ref, store_ref,
+                      layer_ref, q_hbm, k_hbm, kb_hbm, vb_hbm, g_hbm, s_hbm,
+                      so_hbm, o_hbm, qbuf, kbuf, kbbuf, vbbuf, gbuf, obuf,
+                      sbuf, sems, *stage, tile, sub, n_heads, group):
+    """Grid (tiles,), in order: a tile is up to ``tile`` consecutive
+    tokens of ONE batch row, a row's tiles following one another. The
+    row's ``[H, dk, dv]`` states live in ``sbuf`` from its first tile
+    (copied from HBM, or zeroed where the row starts its sequence) to
+    its last (copied back). The tokens' operands lie in HBM head-group
+    major, ``[H / group, tokens * group, d]``, so that a tile's window of
+    any start is a whole number of (8, 128) tiles; a head's tokens are
+    every ``group``-th line of the window.
+
+    Inside a tile the recurrence is solved in closed form (the WY / UT
+    transform): with ``G`` the running sum of the log-decays, ``A[t, s]
+    = sum_c kb_t k_s exp(G_t - G_s)`` (s < t) and ``B`` the same of q (s
+    <= t), ``U = (I + A)^-1 (vb - (kb exp G) S0)``, ``O = (q exp G) S0 +
+    B U``, ``S' = Diag(exp G_end) S0 + (k exp(G_end - G))^T U``. The
+    decays never meet as ``exp(G_t) * exp(-G_s)``: a row block of ``sub``
+    tokens is taken against the sum at ITS MIDDLE token, so each factor
+    is ``exp`` of at most ``sub / 2`` tokens' decay either way (e^40 and
+    e^-40 at the gate's bound of -5: neither an overflow nor a flushed
+    denormal, and their product, of entries above the diagonal that the
+    mask drops, still a float32) and of anything further down."""
+    n = pl.program_id(0)
+    C, H, hg = tile, n_heads, group
+    n_tok = len_ref[n]
+    f32 = jnp.float32
+
+    @pl.when(n_tok > 0)
+    def _tile():
+        layer, row = layer_ref[0], row_ref[n]
+        first = pl.multiple_of(start_ref[n] * hg, hg)
+        window = pl.ds(first, C * hg)
+        copies = [pltpu.make_async_copy(hbm.at[:, window], buf, sems.at[i])
+                  for i, (hbm, buf) in enumerate(
+                      ((q_hbm, qbuf), (k_hbm, kbuf), (kb_hbm, kbbuf),
+                       (vb_hbm, vbbuf), (g_hbm, gbuf)))]
+        for c in copies:
+            c.start()
+        # a state stored narrower than float32 passes through a buffer
+        # of its own type (a DMA converts nothing)
+        held = stage[0] if stage else sbuf
+        state_in = pltpu.make_async_copy(s_hbm.at[row, layer], held,
+                                         sems.at[5])
+
+        @pl.when(load_ref[n] == 1)
+        def _load():
+            state_in.start()
+            state_in.wait()
+            if stage:
+                sbuf[...] = held[...].astype(f32)
+
+        @pl.when(load_ref[n] == 2)
+        def _fresh():
+            sbuf[...] = jnp.zeros_like(sbuf)
+
+        for c in copies:
+            c.wait()
+
+        t_col = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+        live = t_col < n_tok
+        tt = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+        ss = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+        tri = (ss <= tt).astype(f32)
+        eye = (ss == tt).astype(f32)
+        same = (ss // sub) == (tt // sub)
+        dk = sbuf.shape[1]
+        on_diag = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+
+        def mm(a, b, dims=(((1,), (0,)), ((), ()))):
+            return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                                       preferred_element_type=f32)
+
+        def nt(a, b):                       # a @ b^T
+            return mm(a, b, (((1,), (1,)), ((), ())))
+
+        def head(gi, hh):
+            lines = pl.ds(hh, C, stride=hg)
+            q = jnp.where(live, qbuf[gi, lines, :], 0.0)
+            k = jnp.where(live, kbuf[gi, lines, :], 0.0)
+            kb = jnp.where(live, kbbuf[gi, lines, :], 0.0)
+            vb = jnp.where(live, vbbuf[gi, lines, :], 0.0)
+            g = jnp.where(live, gbuf[gi, lines, :], 0.0)
+            h = gi * hg + hh
+            s0 = sbuf[h]                                   # [dk, dv]
+            G = mm(tri, g)                                 # running sums
+            a_rows, b_rows = [], []
+            for i in range(C // sub):
+                r0 = i * sub
+                mid = r0 + sub // 2
+                ref = G[mid - 1:mid, :]
+                down = jnp.exp(G[r0:r0 + sub] - ref)
+                up = k * jnp.exp(jnp.minimum(ref - G, _KDA_HALF_SPAN))
+                ab = nt(jnp.concatenate(
+                    [kb[r0:r0 + sub] * down, q[r0:r0 + sub] * down]), up)
+                a_rows.append(ab[:sub])
+                b_rows.append(ab[sub:])
+            A = jnp.where(ss < tt, jnp.concatenate(a_rows), 0.0)
+            Bm = jnp.where(ss <= tt, jnp.concatenate(b_rows), 0.0)
+            eg = jnp.exp(G)
+            ks = mm(jnp.concatenate([kb * eg, q * eg]), s0)
+            W, O = vb - ks[:C], ks[C:]
+            # (I + A)^-1: the diagonal blocks by the nilpotent product
+            # (N^sub = 0), the blocks below them by (I + M)^-1 with
+            # M^(C / sub) = 0
+            N = -jnp.where(same, A, 0.0)
+            T = eye + N
+            power = N
+            for _ in range(max(sub - 1, 1).bit_length() - 1):
+                power = mm(power, power)
+                T = T + mm(T, power)
+            X = mm(T, W)
+            M = mm(T, jnp.where(same, 0.0, A))
+            U = X
+            power, sign = M, -1.0
+            for _ in range(C // sub - 1):
+                U = U + sign * mm(power, X)
+                power, sign = mm(power, M), -sign
+            O = O + mm(Bm, U)
+            g_end = G[C - 1:C, :]
+            khat = k * jnp.exp(g_end - G)
+            # Diag(exp G_end) S0 as a product: the decay is a lane row
+            # and the state's rows are the channels
+            decay = jnp.where(on_diag, jnp.exp(g_end), 0.0)
+            sbuf[h] = mm(decay, s0) + mm(khat, U, (((0,), (0,)), ((), ())))
+            obuf[gi, lines, :] = O
+
+        def groups(gi, carry):
+            for hh in range(hg):
+                head(gi, hh)
+            return carry
+
+        jax.lax.fori_loop(0, H // hg, groups, 0)
+        out = pltpu.make_async_copy(obuf, o_hbm.at[:, window], sems.at[6])
+        out.start()
+        state_out = pltpu.make_async_copy(held, so_hbm.at[row, layer],
+                                          sems.at[5])
+
+        @pl.when(store_ref[n] == 1)
+        def _store():
+            if stage:
+                held[...] = sbuf[...].astype(held.dtype)
+            state_out.start()
+            state_out.wait()
+
+        out.wait()
+
+
+def kda_chunk(state, q, k, v, g, beta, tile_start, tile_len, tile_row,
+              tile_load, tile_store, *, layer, tile=KDA_TILE, sub=KDA_SUB):
+    """A step's prefill tokens through the delta rule in TILES, each
+    row's state read from ``state`` at its first tile, carried over its
+    tiles and written back IN PLACE after its last (``state`` is aliased
+    to the first result).
+
+    state: ``[B, L, H, dk, dv]`` float32 WHOLE; ``layer`` picks
+    ``state[:, layer]``. q, k, g: ``[T, H, dk]`` token rows (the query
+    scaled, ``g`` the LOG of the decay a channel, <= 0), v: ``[T, H,
+    dv]``, beta: ``[T, H]``; a batch row's tokens are consecutive rows.
+    Tile ``n`` is the ``tile_len[n] <= tile`` token rows from
+    ``tile_start[n]`` of batch row ``tile_row[n]`` (0: no tile, or one
+    the caller computes otherwise: nothing is read or written);
+    ``tile_load[n]``: 1 the tile starts from the row's stored state, 2
+    from zero (the row starts its sequence), 0 from where the tile
+    before left it (the same row's); ``tile_store[n]``: 1 the row's
+    state is written back after it. Tiles come in token order, a row's
+    one after another.
+
+    Returns ``(state', o [T, H, dv] float32)``; token rows of no tile
+    come out unspecified."""
+    B, L, H, dk, dv = state.shape
+    T = q.shape[0]
+    hg = 8 if H % 8 == 0 else 1
+    f32 = jnp.float32
+    n_tiles = tile_start.shape[0]
+
+    def lines(a):
+        """``[T, H, d]`` -> ``[H / hg, (T + tile) * hg, d]``."""
+        d = a.shape[-1]
+        a = jnp.pad(a.astype(f32), ((0, tile), (0, 0), (0, 0)))
+        return a.reshape(T + tile, H // hg, hg, d).transpose(1, 0, 2, 3) \
+            .reshape(H // hg, (T + tile) * hg, d)
+
+    b32 = beta.astype(f32)[..., None]
+    operands = [lines(q), lines(k), lines(k.astype(f32) * b32),
+                lines(v.astype(f32) * b32), lines(g)]
+    anywhere = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    token_buf = pltpu.VMEM((H // hg, tile * hg, dk), f32)
+    value_buf = pltpu.VMEM((H // hg, tile * hg, dv), f32)
+    new_state, o = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, tile=tile, sub=sub, n_heads=H,
+                          group=hg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(n_tiles,),
+            in_specs=[anywhere] * 6,
+            out_specs=[anywhere, anywhere],
+            scratch_shapes=[token_buf, token_buf, token_buf, value_buf,
+                            token_buf, value_buf,
+                            pltpu.VMEM((H, dk, dv), f32),
+                            pltpu.SemaphoreType.DMA((7,))]
+            + ([] if state.dtype == f32
+               else [pltpu.VMEM((H, dk, dv), state.dtype)])),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((H // hg, (T + tile) * hg, dv),
+                                        f32)],
+        # operand 11 (after the six prefetched scalars and the five token
+        # operands) is the state: the first result is the same buffer
+        input_output_aliases={11: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=96 * 1024 * 1024),
+        interpret=_device.pallas_interpret(),
+        name="kda_chunk",
+    )(tile_start.astype(jnp.int32), tile_len.astype(jnp.int32),
+      tile_row.astype(jnp.int32), tile_load.astype(jnp.int32),
+      tile_store.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands, state)
+    o = o.reshape(H // hg, T + tile, hg, dv).transpose(1, 0, 2, 3)
+    return new_state, o.reshape(T + tile, H, dv)[:T]
+
+
+def kda_chunk_reference(state, q, k, v, g, beta, tile_start, tile_len,
+                        tile_row, tile_load, tile_store, *, layer,
+                        tile=KDA_TILE, sub=KDA_SUB):
+    """The lax fallback: the recurrence itself, a token at a time, over
+    the same tiles in the same order (nothing of the closed form)."""
+    f32 = jnp.float32
+    B, L, H, dk, dv = state.shape
+    T = q.shape[0]
+    layer = jnp.asarray(layer, jnp.int32)
+
+    def token(carry, t):
+        s, o, start, n_tok = carry
+        at = jnp.minimum(start + t, T - 1)
+        live = t < n_tok
+        kt, qt = k[at].astype(f32), q[at].astype(f32)
+        s1 = jnp.exp(g[at].astype(f32))[..., None] * s
+        u = beta[at].astype(f32)[:, None] * (v[at].astype(f32) - jnp.einsum(
+            "hk,hkv->hv", kt, s1, precision=_HIGHEST))
+        s1 = s1 + kt[..., None] * u[:, None, :]
+        ot = jnp.einsum("hk,hkv->hv", qt, s1, precision=_HIGHEST)
+        o = jnp.where(live, o.at[at].set(ot), o)
+        return (jnp.where(live, s1, s), o, start, n_tok), None
+
+    def one_tile(n, carry):
+        state, s, o = carry
+        row = tile_row[n]
+        stored = jax.lax.dynamic_index_in_dim(
+            jax.lax.dynamic_index_in_dim(state, row, 0, keepdims=False),
+            layer, 0, keepdims=False).astype(f32)
+        s = jnp.where(tile_load[n] == 1, stored,
+                      jnp.where(tile_load[n] == 2, 0.0, s))
+        (s, o, _, _), _ = jax.lax.scan(
+            token, (s, o, tile_start[n], tile_len[n]), jnp.arange(tile))
+        write = (tile_store[n] == 1) & (tile_len[n] > 0)
+        state = jnp.where(write, jax.lax.dynamic_update_slice(
+            state, s.astype(state.dtype)[None, None],
+            (row, layer, 0, 0, 0)), state)
+        return state, s, o
+
+    state, _s, o = jax.lax.fori_loop(
+        0, tile_start.shape[0], one_tile,
+        (state, jnp.zeros((H, dk, dv), f32), jnp.zeros((T, H, dv), f32)))
+    return state, o
+
+
+# ---------------------------------------------------------------------------
 # registry entries (ops/kernel_registry — docs/KERNELS.md qualification
 # table; importing this module is what populates the registry)
 # ---------------------------------------------------------------------------
@@ -1912,6 +2324,19 @@ def _gqa_qualify(head_dim=None, block_size=None, window=None):
     return True, None
 
 
+def _kda_qualify(head_dim=None, n_heads=None):
+    """A head's state is ``[dk, dv]`` float32 tiles: whole lane tiles of
+    values, whole sublane tiles of channels; the chunk kernel reads a
+    head's tokens as every eighth line of a group of eight heads."""
+    if head_dim is not None and head_dim % 128:
+        return False, "head_dim not a multiple of 128 (a state's rows " \
+                      "are lane tiles)"
+    if n_heads is not None and n_heads % 8:
+        return False, "n_heads not a multiple of 8 (a token's heads " \
+                      "are stored in groups of eight lines)"
+    return True, None
+
+
 def _register_all():
     from .kernel_registry import register_kernel
 
@@ -1994,6 +2419,20 @@ def _register_all():
         qualify=_gqa_qualify, default_on=_device.on_tpu,
         doc="new K and V rows written into a packed bf16 paged pool a "
             "whole page at a time, in place; default: TPU only")
+    register_kernel(
+        "kda_decode", kda_decode, kda_decode_reference,
+        qualify=_kda_qualify, default_on=_device.on_tpu,
+        doc="one token a row through the delta rule with a per-channel "
+            "decay: the row's [H, dk, dv] float32 states of one layer "
+            "read, decayed, updated and read out on the VPU and written "
+            "back in place, an inactive row skipped; default: TPU only")
+    register_kernel(
+        "kda_chunk", kda_chunk, kda_chunk_reference,
+        qualify=_kda_qualify, default_on=_device.on_tpu,
+        doc="a step's prefill tokens through the delta rule in tiles of "
+            "64 (the WY form, decays factored over sub-chunks of 16), a "
+            "row's state carried in VMEM over its tiles and written back "
+            "in place; default: TPU only")
     register_kernel(
         "int8_matmul", int8_matmul, int8_matmul_reference,
         qualify=_int8_qualify, default_on=_device.on_tpu,

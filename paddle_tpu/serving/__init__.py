@@ -52,8 +52,8 @@ from .scheduler import (AdmissionError,  # noqa: F401
 
 
 def __getattr__(name):
-    # the third and fourth blocks, lazily: `serving.AfmoeBlock` loads
-    # its module
+    # the third, fourth and fifth blocks, lazily: `serving.AfmoeBlock`
+    # loads its module
     if name == "AfmoeBlock":
         from .afmoe import AfmoeBlock
 
@@ -62,13 +62,18 @@ def __getattr__(name):
         from .zaya import ZayaBlock
 
         return ZayaBlock
+    if name == "LingBlock":
+        from .ling import LingBlock
+
+        return LingBlock
     raise AttributeError("module %r has no attribute %r"
                          % (__name__, name))
 
 
 __all__ = ["ServingEngine", "ServingRouter", "RouterRequest",
            "KVBlockPool", "CacheEntry", "PageKind", "RowState",
-           "LatentMoEBlock", "AfmoeBlock", "ZayaBlock", "blocks_needed",
+           "LatentMoEBlock", "AfmoeBlock", "ZayaBlock", "LingBlock",
+           "blocks_needed",
            "prefix_chain_keys",
            "PoissonLoadGenerator", "GenerationConfig", "GenerationModel",
            "GenerationArtifactError", "ModelDrafter", "NGramDrafter",

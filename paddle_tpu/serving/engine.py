@@ -1311,6 +1311,16 @@ class ServingEngine:
         """The named model's isolated weight scope."""
         return self._workers[model or self._default].scope
 
+    def row_state(self, model=None):
+        """The named model's :class:`~paddle_tpu.serving.kv_cache.
+        RowState` (None where its block carries none): what each batch
+        row holds beside its pages. A row keeps its last occupant's
+        entry (``GenerationRequest.slot`` names the row) until another
+        sequence is admitted to it; the arrays are donated to every
+        step, so read them only while no step is in flight (after
+        :meth:`close`)."""
+        return self._workers[model or self._default].pool.row_state
+
     def weight_version(self, model=None):
         """The named model's current weight version: 0 for the weights
         the engine was built with, bumped by every applied

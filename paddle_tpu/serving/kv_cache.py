@@ -82,12 +82,17 @@ index (the engine refuses the prefix cache with them). A pool of one
 kind is the pool described above, attribute for attribute.
 
 A ROW STATE beside the pages (:class:`RowState`; docs/SERVING.md, "A
-fourth block"): a block whose token needs something of its predecessor
-that no page holds states one small array a batch row; the pool carries
-it through every step with its own arrays (``step_arrays``) and audits
-it in ``check_invariants``. It has no accounting (a slot's entry is
-whoever sits there's; position 0 ignores it), which is why the engine
-refuses the prefix cache and speculation with it.
+fourth block", "A fifth block"): a block whose token needs something of
+its predecessors that no page holds states arrays a batch row, one a
+named part with its own dtype (a convolution's last inputs; the matrix
+a linear-attention scan carries, which is LARGER than the row's pages);
+the pool carries them through every step with its own arrays
+(``step_arrays``), audits them in ``check_invariants`` and reports their
+bytes beside the pages' in ``stats()``. It has no accounting (a slot's
+entry is whoever sits there's; position 0 ignores it): the batch rows
+bound it as the blocks bound the pages, and admission needs both a free
+row and its pages. That is why the engine refuses the prefix cache and
+speculation with it.
 
 Reservation conservation survives sharing (pinned by test):
 ``blocks_free(+cached) - reserved >= 0`` at every point, and
@@ -100,8 +105,10 @@ allocation, so outstanding reservations can never be left unbacked
 import hashlib
 from collections import OrderedDict
 
+import numpy as np
+
 __all__ = ["CacheEntry", "KVBlockPool", "PageKind", "RowState",
-           "blocks_needed", "prefix_chain_keys"]
+           "row_state_parts", "blocks_needed", "prefix_chain_keys"]
 
 
 class CacheEntry:
@@ -155,48 +162,117 @@ class PageKind:
                                          self.window)
 
 
+def row_state_parts(shape, dtype="float32"):
+    """:class:`RowState`'s arguments after ``max_batch`` (what a block's
+    ``row_state(config)`` returns) as ``(name, shape, dtype)`` parts: a
+    sequence of such parts as it is, one shape and dtype as the one part
+    ``state``."""
+    named = bool(shape) and not isinstance(shape[0], int)
+    return tuple(shape) if named else (("state", tuple(shape), dtype),)
+
+
 class RowState:
-    """A second kind of state beside the pages: one small array a BATCH
-    ROW (``[max_batch] + shape``), for a block whose token needs
-    something of its predecessor that no page holds (a convolution's
-    last inputs, a shifted value). It has no accounting: a row's entry
-    belongs to whatever sequence sits in the slot, every step rewrites
-    the entries of the rows it computed, and a step ignores the entry of
-    a row whose first token is at position 0, so admission and retirement
-    leave it alone. The steps take ``array`` after the pool's arrays,
-    donated, and return it (``KVBlockPool.step_arrays``).
+    """A second kind of state beside the pages: arrays a BATCH ROW
+    (``[max_batch] + shape`` each), for a block whose token needs
+    something of its predecessors that no page holds (a convolution's
+    last inputs, a shifted value, the matrix a linear-attention scan
+    carries over the whole sequence). It has no accounting: a row's
+    entry belongs to whatever sequence sits in the slot, every step
+    rewrites the entries of the rows it computed, and a step ignores the
+    entry of a row whose first token is at position 0, so admission and
+    retirement leave it alone; what bounds it is the number of batch
+    rows, as blocks bound the pages. The steps take the arrays after the
+    pool's, donated, and return them (``KVBlockPool.step_arrays``).
+
+    ``RowState(max_batch, shape, dtype)`` is one array (``shape``,
+    ``dtype``, ``array``); ``RowState(max_batch, parts)`` with ``parts``
+    a sequence of ``(name, shape, dtype)`` is one array a NAMED PART,
+    each in its own dtype, as :class:`CacheEntry` names the parts of a
+    page (``parts``, ``arrays``, ``part(name)``): a scan's float32
+    matrices beside a convolution's narrower inputs.
 
     What is NOT kept, and why the engine refuses the features that would
     need it: the state at a page boundary of an adopted prefix (the
     prefix cache), and the state before a window that is rolled back
     (speculation)."""
 
-    __slots__ = ("max_batch", "shape", "dtype", "array")
+    __slots__ = ("max_batch", "parts", "arrays")
 
     def __init__(self, max_batch, shape, dtype="float32"):
         import jax.numpy as jnp
 
         self.max_batch = int(max_batch)
-        self.shape = tuple(int(d) for d in shape)
-        self.dtype = jnp.dtype(dtype)
-        self.array = jnp.zeros((self.max_batch,) + self.shape, self.dtype)
+        self.parts = tuple((str(n), tuple(int(d) for d in s), jnp.dtype(t))
+                           for n, s, t in row_state_parts(shape, dtype))
+        if len({n for n, _s, _t in self.parts}) != len(self.parts):
+            raise ValueError("row state parts need distinct names: %r"
+                             % (self.parts,))
+        self.arrays = tuple(jnp.zeros((self.max_batch,) + s, t)
+                            for _n, s, t in self.parts)
+
+    # -- a row state of one part, as first written ------------------------
+    def _only(self):
+        if len(self.parts) != 1:
+            raise AttributeError(
+                "this row state has %d parts (%s): ask for one by name"
+                % (len(self.parts), ", ".join(n for n, _s, _t in self.parts)))
+        return self.parts[0]
+
+    shape = property(lambda self: self._only()[1])
+    dtype = property(lambda self: self._only()[2])
+
+    @property
+    def array(self):
+        self._only()
+        return self.arrays[0]
+
+    @array.setter
+    def array(self, value):
+        self._only()
+        self.arrays = (value,)
+
+    def part(self, name):
+        """The array of the part called ``name``."""
+        for (n, _s, _t), a in zip(self.parts, self.arrays):
+            if n == name:
+                return a
+        raise KeyError("row state has no part %r" % (name,))
+
+    def part_bytes(self):
+        """{part: bytes it holds on the device, all rows}."""
+        return {n: self.max_batch * int(np.prod(s, dtype=np.int64))
+                * t.itemsize for n, s, t in self.parts}
+
+    @property
+    def nbytes(self):
+        return sum(self.part_bytes().values())
 
     def check_invariants(self):
-        """Problem strings (empty: clean): the array a step handed back
+        """Problem strings (empty: clean): each array a step handed back
         is the one stated, and still there (a donated array that no
         step's result replaced is deleted)."""
-        a, want = self.array, (self.max_batch,) + self.shape
-        if a.shape != want or a.dtype != self.dtype:
-            return ["row state: %s %s where %s %s was stated" % (
-                a.dtype, a.shape, self.dtype, want)]
-        if a.is_deleted():
-            return ["row state: the array was donated to a step and not "
-                    "replaced by its result"]
-        return []
+        problems = []
+        if len(self.arrays) != len(self.parts):
+            return ["row state: %d arrays for %d parts"
+                    % (len(self.arrays), len(self.parts))]
+        for (n, s, t), a in zip(self.parts, self.arrays):
+            want = (self.max_batch,) + s
+            what = "row state" if len(self.parts) == 1 \
+                else "row state part %r" % n
+            if a.shape != want or a.dtype != t:
+                problems.append("%s: %s %s where %s %s was stated" % (
+                    what, a.dtype, a.shape, t, want))
+            elif a.is_deleted():
+                problems.append("%s: the array was donated to a step and "
+                                "not replaced by its result" % what)
+        return problems
 
     def __repr__(self):
-        return "RowState(%d, %r, %r)" % (self.max_batch, self.shape,
-                                         str(self.dtype))
+        if len(self.parts) == 1:
+            return "RowState(%d, %r, %r)" % (self.max_batch, self.shape,
+                                             str(self.dtype))
+        return "RowState(%d, %r)" % (self.max_batch, tuple(
+            (n, s, str(t)) for n, s, t in self.parts))
 
 
 class _KindPages:
@@ -375,17 +451,19 @@ class KVBlockPool:
     @property
     def step_arrays(self):
         """What a step takes after the weights and hands back first:
-        the pages' arrays, then the row state's where there is one."""
+        the pages' arrays, then the row state's, one a part, where there
+        is one."""
         if self.row_state is None:
             return self.arrays
-        return self.arrays + (self.row_state.array,)
+        return self.arrays + self.row_state.arrays
 
     @step_arrays.setter
     def step_arrays(self, value):
         value = tuple(value)
         if self.row_state is not None:
-            self.row_state.array = value[-1]
-            value = value[:-1]
+            n = len(self.row_state.parts)
+            self.row_state.arrays = value[-n:]
+            value = value[:-n]
         self.arrays = value
 
     k = property(lambda self: self.arrays[self._part("k")],
@@ -455,6 +533,13 @@ class KVBlockPool:
             out["kinds"] = by_kind
             out["window_blocks_released"] = sum(
                 k["blocks_released"] for k in by_kind.values())
+        # the two kinds of state side by side: what the pages hold (all
+        # kinds, the null blocks too) and what the batch rows carry
+        out["page_bytes"] = sum(int(a.size) * a.dtype.itemsize
+                                for a in self.arrays)
+        if self.row_state is not None:
+            out["row_state_bytes"] = self.row_state.nbytes
+            out["row_state_parts"] = self.row_state.part_bytes()
         return out
 
     # -- admission-side API --------------------------------------------

@@ -40,7 +40,8 @@ from .kv_cache import CacheEntry
 
 __all__ = ["LatentMoEBlock", "BlockDescription", "weight_names", "random_weights",
            "make_decode_step", "make_window_step", "route",
-           "expert_layer", "mla_absorbed", "mla_expanded",
+           "group_limited", "expert_layer", "mla_absorbed", "mla_expanded",
+           "latent_rows", "absorbed_queries", "context_to_heads",
            "rope_interleaved", "COUNTERS"]
 
 # what a step returns beside its tokens, reduced over the expert layers
@@ -344,7 +345,9 @@ def route(block, x, router_w, router_bias):
     precision, so a near-tie falls the way the reference's does; a
     narrower type: logits and scores rounded to it); the
     bias takes part in the choice only; the chosen scores are
-    renormalised and scaled."""
+    renormalised and scaled. A block that states ``n_group`` > 1
+    chooses among the experts of each token's best ``topk_group``
+    groups only (:func:`group_limited`)."""
     import jax
     import jax.numpy as jnp
 
@@ -354,11 +357,32 @@ def route(block, x, router_w, router_bias):
                             precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=rd))
     scores = stored(jax.nn.sigmoid(logits)).astype(jnp.float32)
-    _top, idx = jax.lax.top_k(scores + router_bias[None, :],
-                              block.experts_per_token)
+    choice = scores + router_bias[None, :]
+    n_group = getattr(block, "n_group", 1)
+    if n_group > 1:
+        choice = group_limited(choice, n_group, block.topk_group)
+    _top, idx = jax.lax.top_k(choice, block.experts_per_token)
     picked = jnp.take_along_axis(scores, idx, axis=1)
     w = picked / (jnp.sum(picked, axis=1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), w * block.routed_scaling_factor
+
+
+def group_limited(choice, n_group, topk_group):
+    """``choice [T, E]`` (score + bias) with every expert outside a
+    token's best ``topk_group`` of ``n_group`` groups set to ``-inf``.
+    The experts are grouped in order (``E / n_group`` consecutive ids a
+    group); a group's score is the sum of its two largest entries."""
+    import jax
+    import jax.numpy as jnp
+
+    T, E = choice.shape
+    grouped = choice.reshape(T, n_group, E // n_group)
+    best2, _ = jax.lax.top_k(grouped, 2)
+    _top, keep = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+    kept = jnp.any(keep[:, :, None]
+                   == jnp.arange(n_group, dtype=keep.dtype)[None, None, :],
+                   axis=1)                                  # [T, n_group]
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(T, E)
 
 
 def expert_layer(block, x, valid, idx, w, we_gate, we_up, we_down,
@@ -483,6 +507,45 @@ def mla_expanded(q_nope, q_pe, c_ctx, kpe_ctx, w_uk, w_uv, mask,
     return jnp.einsum("cht,thv->chv", p, v)
 
 
+def latent_rows(block, c_new, kpe_new):
+    """The cache rows of ``[T]`` tokens, float32 ``[T, cache_row]``: the
+    normalised latent, the rotated shared key, zeros up to whole lane
+    tiles."""
+    import jax.numpy as jnp
+
+    pad = block.cache_row - block.cache_width
+    return jnp.concatenate(
+        [c_new, kpe_new, jnp.zeros((c_new.shape[0], pad), jnp.float32)],
+        axis=-1)
+
+
+def absorbed_queries(block, q_nope, q_pe, w_uk, act_dtype):
+    """The queries of the absorbed form in the cache's own space,
+    ``[T, H, cache_row]`` float32, scaled: ``q_nope @ W_UK`` beside the
+    rotated ``q_pe``, zeros over the row's padding."""
+    import jax.numpy as jnp
+
+    T, H = q_pe.shape[:2]
+    sm_scale = float(block.qk_head_dim) ** -0.5
+    q_lat = jnp.einsum(
+        "thn,hnr->thr", *_operands(act_dtype, q_nope, w_uk),
+        preferred_element_type=jnp.float32)
+    return jnp.concatenate(
+        [q_lat * sm_scale, q_pe * sm_scale,
+         jnp.zeros((T, H, block.cache_row - block.cache_width),
+                   jnp.float32)], axis=-1)
+
+
+def context_to_heads(ctx, w_uv, act_dtype):
+    """The latent-space context ``[T, H, r]`` brought back to value
+    heads ``[T, H, dv]`` (``@ W_UV``)."""
+    import jax.numpy as jnp
+
+    return jnp.einsum(
+        "thr,hrv->thv", *_operands(act_dtype, ctx, w_uv),
+        preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # the forward over a [B, C] window (C = 1: the decode step)
 # ---------------------------------------------------------------------------
@@ -568,7 +631,6 @@ def _forward(model, weights, tok, pos0, lengths, block_tables, active,
     use_gmm = (cfg.n_layers > blk.first_k_dense
                and choose("gmm", k=D, n=blk.moe_d_ff))
 
-    pad = blk.cache_row - blk.cache_width
     x = jnp.take(weights["embedding"], tok, axis=0).astype(jnp.float32)
     counters = jnp.zeros((len(COUNTERS),), jnp.int32)
     for i in range(cfg.n_layers):
@@ -582,28 +644,20 @@ def _forward(model, weights, tok, pos0, lengths, block_tables, active,
         q_pe = rope_interleaved(q[..., dn:], pos[:, None], blk.rope_theta)
         with jax.named_scope("latent_write"):
             # the pool goes to the kernels whole, never `latent[i]`
-            row = jnp.concatenate(
-                [c_new, kpe_new, jnp.zeros((Tc, pad), jnp.float32)], axis=-1)
+            row = latent_rows(blk, c_new, kpe_new)
             latent = write(latent, to_window(row.astype(latent.dtype)),
                            block_tables, pos0, lengths, layer=i)
         w_uk, w_uv = weights[p + "w_uk"], weights[p + "w_uv"]
         with jax.named_scope("mla_attention"):
             if use_attn or C == 1:
                 # absorbed: queries into latent space, scaled here
-                q_lat = jnp.einsum(
-                    "thn,hnr->thr", *_operands(act, q_nope, w_uk),
-                    preferred_element_type=jnp.float32)
-                q_abs = jnp.concatenate(
-                    [q_lat * sm_scale, q_pe * sm_scale,
-                     jnp.zeros((Tc, H, pad), jnp.float32)], axis=-1)
+                q_abs = absorbed_queries(blk, q_nope, q_pe, w_uk, act)
                 attend = (latent_paged_attention if use_attn
                           else latent_paged_attention_reference)
                 ctx = attend(latent, to_window(q_abs.astype(latent.dtype)),
                              block_tables, pos0, lengths, layer=i,
                              v_width=r)                    # [B, C, H, r]
-                o = jnp.einsum(
-                    "thr,hrv->thv", *_operands(act, from_window(ctx), w_uv),
-                    preferred_element_type=jnp.float32)
+                o = context_to_heads(from_window(ctx), w_uv, act)
             else:
                 # lax chunk path: expanded over the gathered rows
                 rows = _gathered_context(latent, i, block_tables) \

@@ -239,7 +239,8 @@ def _chunk_layout(jnp, positions, lengths, active, block_tables, window,
 # ``make_window_step(model, window, return_logits, max_tokens)``.
 BLOCK_KINDS = {"latent_moe": ("latent_moe", "LatentMoEBlock"),
                "afmoe": ("afmoe", "AfmoeBlock"),
-               "zaya": ("zaya", "ZayaBlock")}
+               "zaya": ("zaya", "ZayaBlock"),
+               "ling": ("ling", "LingBlock")}
 
 
 def block_from_dict(d):

@@ -208,6 +208,7 @@ class GenerationRequest:
         self.deadline = (self.submit_time + float(deadline_s)
                          if deadline_s is not None else None)
         self.start_time = None      # admitted to the batch
+        self.slot = None            # the batch row it was admitted to
         self.first_token_time = None  # first generated token materialized
         self.finish_time = None
         self.tokens = []            # generated ids (truncated at EOS)
@@ -539,6 +540,7 @@ class StepScheduler:
                 break  # KV gate: head doesn't fit — keep queue order
             queue.pop()
             request.start_time = time.perf_counter()
+            request.slot = slot
             if _metrics.enabled():
                 seq.log = _RequestLog()
             if request.trace_id is not None and _tracing.enabled():

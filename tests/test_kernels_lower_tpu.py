@@ -709,3 +709,85 @@ def test_zaya_step_updates_its_pages_and_its_carry_in_place(kind, v5e_chip):
     # the pools and the carry come back in the arguments' own buffers
     donated = sum(a.size * 2 for a in arrays) + B * shape[0] * shape[1] * 4
     assert m.alias_size_in_bytes >= donated, m
+
+
+# the fifth block: the MLA layers' latent pages and a row state of two
+# parts beside them; a KDA dense layer, an MLA and a KDA expert layer
+LING_STEP = dict(batch=8, blocks_per_seq=32, block_size=64, chunk=256,
+                 blocks=2048)
+LING = dict(vocab_size=1024, d_model=2560, n_heads=32, n_layers=3,
+            d_ff=6144, block=dict(
+                kind="ling", head_dim=128,
+                layer_types=["kda", "mla", "kda"], conv_kernel=4,
+                kda_lower_bound=-5.0, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+                rope_theta=6e6, first_k_dense=1, n_routed_experts=512,
+                experts_per_token=8, n_shared_experts=1, moe_d_ff=768,
+                routed_scaling_factor=2.5, n_group=8, topk_group=4,
+                experts_held=list(range(8))))
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_ling_step_updates_its_pages_and_its_scan_state_in_place(
+        kind, v5e_chip):
+    """The fifth block's steps at its published widths (32 scan heads of
+    128 x 128 float32, a latent row of 576 values, 8 of 512 experts in 8
+    groups held): the two scan kernels and the latent block's three
+    qualify as they are; the latent pool and both parts of the row state
+    are donated and rewritten in place, and the compiled steps hold
+    nothing of the pool's or the scan state's size but the kernels' own
+    (aliased) results."""
+    from paddle_tpu.serving import (GenerationConfig, GenerationModel,
+                                    KVBlockPool, RowState)
+
+    g = LING_STEP
+    cfg = GenerationConfig(
+        **LING, max_seq_len=g["blocks_per_seq"] * g["block_size"])
+    model = GenerationModel.__new__(GenerationModel)
+    model.config, model.trace_count = cfg, 0
+    kinds = model.page_kinds()
+    assert [(k.name, k.window, k.layers) for k in kinds] \
+        == [("global", None, (1,))]
+    sharding = jax.sharding.SingleDeviceSharding(v5e_chip)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=sharding)
+
+    weights = {k: arg(s, d) for k, (s, d) in
+               cfg.block.leaf_shapes(cfg).items()}
+    B, Mb = g["batch"], g["blocks_per_seq"]
+    arrays = jax.eval_shape(lambda: KVBlockPool(
+        cfg.n_layers, cfg.n_heads, cfg.head_dim, g["block_size"],
+        g["blocks"], entry=cfg.block.cache_entry(), kinds=kinds,
+        row_state=RowState(B, *model.row_state())).step_arrays)
+    assert [(a.shape, str(a.dtype)) for a in arrays] == [
+        ((1, 2049, 64, 640), "bfloat16"),
+        ((B, 2, 32, 128, 128), "float32"),
+        ((B, 2 * 3 * 3 * 32 * 128), "bfloat16")]
+    pools = tuple(arg(a.shape, a.dtype) for a in arrays)
+    row, on = arg((B,)), arg((B,), jnp.bool_)
+    tables = arg((B, Mb))
+    with device.compiling_for(v5e_chip):
+        if kind == "decode":
+            compiled = cfg.block.make_decode_step(model).lower(
+                weights, *pools, row, on, row, row, tables, on).compile()
+        else:
+            compiled = cfg.block.make_window_step(
+                model, g["chunk"], max_tokens=B + g["chunk"]).lower(
+                weights, *pools, arg((B, g["chunk"])), on, row, row, row,
+                tables, on).compile()
+    hlo = compiled.as_text()
+    names = ["gmm", "latent_write", "latent_paged_attention", "kda_decode"]
+    for name in names + ["kda_chunk"] * (kind == "chunk"):
+        assert name in hlo, name
+    # (a weight is as large as these few rows' scan state, and the
+    # compiler stages weights through VMEM: only results of exactly the
+    # pool's or the scan state's size are looked at)
+    sizes = {a.size for a in arrays[:2]}
+    large = [r for r in _large_results(hlo, min(sizes)) if r[2] in sizes]
+    assert large and all(op == "custom-call" for op, _, _ in large), large
+    m = compiled.memory_analysis()
+    # the pool and the row state come back in the arguments' own buffers
+    donated = sum(a.size * a.dtype.itemsize for a in arrays)
+    assert m.alias_size_in_bytes >= donated, m

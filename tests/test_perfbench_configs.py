@@ -64,17 +64,26 @@ def held_bytes(config, monkeypatch):
         assert cfg.n_layers * e["num_blocks"] == sum(
             len(k.layers) * e[k.name + "_blocks"] for k in kinds)
     else:
-        pools = cfg.n_layers * token * e["block_size"] \
-            * (e["num_blocks"] + 1)
+        # one kind of page: every layer's, or the layers it names
+        # (where they are fewer the file states their blocks beside
+        # the number `perfbench/tests/test_configs.py` reckons with)
+        paged = len(kinds[0].layers) if kinds else cfg.n_layers
+        pools = paged * token * e["block_size"] \
+            * (e.get("latent_blocks", e["num_blocks"]) + 1)
     state = shell.row_state()
-    if state is not None:          # a row's carry beside its pages
-        pools += e["max_batch"] * int(np.prod(state[0])) * itemsize(state[1])
+    if state is not None:          # a row's state beside its pages
+        from paddle_tpu.serving.kv_cache import row_state_parts
+
+        pools += e["max_batch"] * sum(
+            int(np.prod(shape)) * itemsize(dtype)
+            for _n, shape, dtype in row_state_parts(*state))
     return weights, pools
 
 
 def test_the_benchmark_has_the_serving_configurations():
     assert set(SERVING) >= {"xglm-1.7b-serve", "kanana-2-30b-a3b-serve",
-                            "trinity-large-preview-serve", "zaya1-8b-serve"}
+                            "trinity-large-preview-serve", "zaya1-8b-serve",
+                            "ling-3.0-flash-serve"}
 
 
 @pytest.mark.parametrize("name", SERVING)
@@ -165,6 +174,66 @@ def test_zaya_states_its_cut_and_its_assumed_equations():
     assert ref.n_params(config) == 4688800104       # 9.38 GB in bf16
 
 
+def test_ling_states_its_cut_and_its_assumed_equations(monkeypatch):
+    config = configuration("ling-3.0-flash-serve")
+    assert config["reduced"] == ["num_hidden_layers",
+                                 "first_k_dense_replace", "num_experts",
+                                 "vocab_size"]
+    assert config["published"] == {
+        "num_hidden_layers": 42, "first_k_dense_replace": 2,
+        "num_experts": 512, "vocab_size": 157184, "layers_held": [1, 7]}
+    assert config["router_experts"] == 512
+    for key in ("sources", "layer_kinds", "kda_inputs", "kda_safe_gate",
+                "kda_seeds", "kda_beta_and_gate", "group_norm_size", "mla",
+                "use_qk_norm", "router", "init", "conv_state_dtype",
+                "engine"):
+        assert key in config["assumed"], key
+    assert "four chips share each layer" in config["deployment"]
+    assert any("MTP" in d for d in config["departures"])
+    assert [c["name"] for c in config["controls"]] == [
+        "bf16_router", "int8_expert_weights", "bf16_scan_state",
+        "scan_restarts_each_chunk"]
+    # no width is cut
+    for key, want in (("hidden_size", 2560), ("head_dim", 128),
+                      ("num_attention_heads", 32),
+                      ("short_conv_kernel_size", 4),
+                      ("kv_lora_rank", 512), ("qk_rope_head_dim", 64),
+                      ("qk_nope_head_dim", 128), ("v_head_dim", 128),
+                      ("moe_intermediate_size", 768),
+                      ("moe_shared_expert_intermediate_size", 768),
+                      ("intermediate_size", 6144),
+                      ("num_experts_per_tok", 8), ("n_group", 8),
+                      ("topk_group", 4), ("layer_group_size", 6),
+                      ("kda_lower_bound", -5)):
+        assert config[key] == want, key
+    # the floors: a whole period and four layers after the dense one,
+    # eight experts, an eighth of the vocabulary
+    assert (config["num_hidden_layers"], config["num_experts"],
+            config["vocab_size"]) == (7, 128, 39296)
+    from perfbench.flops import ling as flops
+    from perfbench.reference import ling as ref
+
+    assert ref.layer_types(config) == ["kda"] * 4 + ["mla"] + ["kda"] * 2
+    assert ref.held(config) == list(range(128))
+    assert ref.n_params(config) == 5169367232        # 10.34 GB in bf16
+    e = config["engine"]
+    assert (e["max_batch"], e["max_seq_len"], e["block_size"],
+            e["async_depth"]) == (256, 40960, 64, 2)
+    assert e["prefill_chunk"] == e["prefill_token_budget"] == 1024
+    assert e["max_queue"] >= 384
+    assert config["dtypes"]["state"] == "float32"
+    # the two kinds of state: the row state is the larger, and with the
+    # weights they are 88-96 % of what the runtime gives
+    weights, pools = held_bytes(config, monkeypatch)
+    state = e["max_batch"] * flops.row_state_bytes(config)
+    pages = pools - state
+    assert pages == (e["latent_blocks"] + 1) * 64 * 640 * 2 < state
+    # the one number the harness's own test multiplies by every layer
+    assert abs(7 * 64 * 1280 * e["num_blocks"]
+               - (pages + state)) < 7 * 64 * 1280
+    assert 0.88 <= (weights + pools) / USABLE_HBM_BYTES <= 0.96
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_a_cells_traffic_parses(cell):
     _w, config, mix = spec.cell(BENCH, cell)
@@ -199,7 +268,14 @@ def test_a_cells_traffic_parses(cell):
         "output_len": {"dist": "lognormal", "median": 2048, "sigma": 0.5,
                        "min": 512, "max": 4096},
         "kind": "closed_loop", "clients": 120, "ramp_s": 45.0,
-        "drain_s": 240.0, "trace_seconds": 5.0})])
+        "drain_s": 240.0, "trace_seconds": 5.0}),
+    ("ling-3.0-flash.reason-long-closed", {
+        "prompt_len": {"dist": "lognormal", "median": 1024, "sigma": 1.0,
+                       "min": 128, "max": 32768},
+        "output_len": {"dist": "lognormal", "median": 4096, "sigma": 0.5,
+                       "min": 1024, "max": 8192},
+        "kind": "closed_loop", "clients": 320, "ramp_s": 60.0,
+        "drain_s": 300.0, "trace_seconds": 5.0})])
 def test_the_new_cells_traffic_is_what_the_issue_gave(cell, want):
     w, _c, mix = spec.cell(BENCH, cell)
     assert mix == want and w["chips"] == 1
@@ -218,6 +294,36 @@ def test_the_zaya_cell_reports_the_kernels_it_shares():
                        "global_pool_used_pct.batch",
                        "window_pool_used_pct.batch",
                        "expert_load_max_over_mean.decode"}
+    for name in mine:
+        spec.layer_metric(name)              # every one has its file
+    e2e = {m["name"] for m in spec.metrics_of(BENCH, "end_to_end", cell)}
+    assert e2e == {"serve_tokens_per_s", "setup_s"}
+
+
+def test_the_ling_cell_reports_the_kernels_it_shares():
+    """Appended to the readings of the kernels it reuses (the expert
+    layer's and the latent attention's), not to the grouped-query ones;
+    the scan's four metrics are its alone."""
+    cell = "ling-3.0-flash.reason-long-closed"
+    mine = {m["name"] for m in spec.metrics_of(BENCH, "per_layer", cell)}
+    own = {"kda_decode_roofline_pct.decode", "kda_chunk_roofline_pct.batch",
+           "kda_device_share_pct.batch",
+           "scan_kernels_device_share_pct.batch"}
+    assert own | {"gmm_roofline_pct.decode", "mfu_pct.batch",
+                  "latent_attn_roofline_pct.decode",
+                  "latent_attn_device_share_pct.decode",
+                  "experts_touched_pct.decode", "expert_top_load_pct.decode",
+                  "kv_pool_used_pct.batch", "dry_dispatch_pct.batch",
+                  "steps_queued_ahead.batch", "reach_chip_s"} <= mine
+    assert not mine & {"gqa_decode_attn_roofline_pct.decode",
+                       "gqa_chunk_attn_roofline_pct.batch",
+                       "carry_rows_pct.batch",
+                       "kernels_device_share_pct.batch"}
+    for name in own:
+        entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+        assert entry["workloads"] == [cell]
+        assert (entry["unit"], entry["moves"]) == ("%",
+                                                   "serve_tokens_per_s")
     for name in mine:
         spec.layer_metric(name)              # every one has its file
     e2e = {m["name"] for m in spec.metrics_of(BENCH, "end_to_end", cell)}

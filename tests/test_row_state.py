@@ -136,3 +136,72 @@ def test_ignoring_the_carry_changes_what_is_served(monkeypatch):
         # the carry is WRITTEN either way; the control only reads zeros
         assert np.abs(np.asarray(pool.row_state.array)).max() > 0
     assert np.abs(logits[0] - logits[1]).max() > 1e-3
+
+
+# -- a row state of named parts, each with its own dtype ---------------------
+
+PARTS = (("scan", (2, 4, 4), "float32"), ("conv", (6,), "bfloat16"))
+
+
+def test_a_row_state_of_two_parts_is_an_array_a_part():
+    state = RowState(3, PARTS)
+    assert [a.shape for a in state.arrays] == [(3, 2, 4, 4), (3, 6)]
+    assert [str(a.dtype) for a in state.arrays] == ["float32", "bfloat16"]
+    assert state.part("conv") is state.arrays[1]
+    assert state.nbytes == 3 * (32 * 4 + 6 * 2)
+    assert state.check_invariants() == []
+    assert "('conv', (6,), 'bfloat16')" in repr(state)
+    # no ONE array, shape or dtype to ask for
+    for name in ("array", "shape", "dtype"):
+        with pytest.raises(AttributeError, match="2 parts"):
+            getattr(state, name)
+    with pytest.raises(KeyError):
+        state.part("carry")
+    with pytest.raises(ValueError, match="distinct names"):
+        RowState(3, (PARTS[0], PARTS[0]))
+    # one part is what the one-array state is, attribute for attribute
+    one, named = RowState(3, (2, 5)), RowState(3, (("state", (2, 5),
+                                                    "float32"),))
+    assert (one.shape, one.dtype, one.array.shape, one.parts) \
+        == (named.shape, named.dtype, named.array.shape, named.parts)
+
+
+@pytest.mark.parametrize("wrong,says", [
+    (lambda a: (a[0], a[1][:2]), "part 'conv': bfloat16 (2, 6)"),
+    (lambda a: (a[0].astype(jnp.bfloat16), a[1]), "part 'scan': bfloat16"),
+    (lambda a: a[:1], "1 arrays for 2 parts")])
+def test_a_part_of_another_shape_or_type_is_a_problem(wrong, says):
+    state = RowState(3, PARTS)
+    state.arrays = tuple(wrong(state.arrays))
+    problems = state.check_invariants()
+    assert len(problems) == 1 and says in problems[0], problems
+
+
+def test_the_pool_donates_and_takes_back_every_part():
+    pool = KVBlockPool(2, 4, 8, 4, 6, row_state=RowState(3, PARTS))
+    k, v, scan, conv = pool.step_arrays
+    assert scan is pool.row_state.part("scan")
+    step = jax.jit(lambda k, v, s, c: (k + 1, v, s + 2, c + 3),
+                   donate_argnums=(0, 1, 2, 3))
+    out = step(*pool.step_arrays)
+    if scan.is_deleted():
+        assert any("part 'scan'" in p and "donated" in p
+                   for p in pool.check_invariants())
+    pool.step_arrays = out
+    assert len(pool.arrays) == 2 and float(pool.k.max()) == 1.0
+    assert float(pool.row_state.part("scan").min()) == 2.0
+    assert float(pool.row_state.part("conv").min()) == 3.0
+    assert pool.check_invariants() == []
+
+
+def test_stats_report_the_row_states_bytes_beside_the_pages():
+    plain = KVBlockPool(2, 4, 8, 4, 6).stats()
+    assert plain["page_bytes"] == 2 * 2 * 7 * 4 * 4 * 8 * 4
+    assert "row_state_bytes" not in plain
+    stats = KVBlockPool(2, 4, 8, 4, 6,
+                        row_state=RowState(3, PARTS)).stats()
+    assert stats["page_bytes"] == plain["page_bytes"]
+    assert stats["row_state_parts"] == {"scan": 3 * 32 * 4, "conv": 3 * 12}
+    assert stats["row_state_bytes"] == 3 * (32 * 4 + 12)
+    one = KVBlockPool(2, 4, 8, 4, 6, row_state=RowState(3, (2, 7))).stats()
+    assert one["row_state_parts"] == {"state": 3 * 14 * 4}

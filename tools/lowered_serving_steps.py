@@ -1,13 +1,15 @@
 """Did a refactor change a compiled serving step? Answered without a chip.
 
     python tools/lowered_serving_steps.py write <checkout> <out-dir> [--compile]
+                                          [--only=<configuration> ...]
     python tools/lowered_serving_steps.py compare <out-dir-a> <out-dir-b>
 
 ``write`` imports ``paddle_tpu`` from ``<checkout>`` (this tree or a
 ``git archive`` of another commit), builds the decode and chunk steps of
 the serving configurations of the benchmark (XGLM, kanana and, where the
-checkout has them, trinity with its two kinds of page and zaya with its
-row state) at their engines' geometry
+checkout has them, trinity with its two kinds of page, zaya with its
+row state and ling with its latent pages beside a row state of two
+parts) at their engines' geometry
 (``perfbench/configs/*-serve.json``; only shapes are made, no weights),
 lowers them for a described ``v5e:2x2`` device and writes the StableHLO
 text with debug locations stripped, and prints each step's dots counted
@@ -15,8 +17,9 @@ by operand dtypes (``dots``: XGLM's read ``bf16 x bf16``, activations
 rounded as the dot rounds them against a weight the store keeps in
 bfloat16 for the v5e, with no convert between the step's weight
 argument and the dot). ``--compile``
-also compiles each for the v5e and prints its argument, output and
-workspace bytes and the same count over the compiled program's
+(``--only=ling``: that configuration's two steps alone, which is how
+an engine is sized) also compiles each for the v5e and prints its
+argument, output and workspace bytes and the same count over the compiled program's
 convolutions (``compiled_dots``: the dtypes XLA really reads; a bf16
 weight there and a workspace smaller than a weight say no float32 copy
 of a weight is written to HBM).
@@ -61,7 +64,7 @@ def compiled_dots(text):
     return dict(dots)
 
 
-def write(root, out, compile_too):
+def write(root, out, compile_too, only=None):
     root = os.path.realpath(root)
     sys.path.insert(0, root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -119,11 +122,15 @@ def write(root, out, compile_too):
                 info.update(argument_bytes=m.argument_size_in_bytes,
                             output_bytes=m.output_size_in_bytes,
                             workspace_bytes=m.temp_size_in_bytes,
+                            bytes_accessed=(compiled.cost_analysis()
+                                            or {}).get("bytes accessed"),
                             compiled_dots=compiled_dots(
                                 compiled.as_text()))
         print(json.dumps(info), flush=True)
 
     def both_steps(name, model, e, kinds=None, num_blocks=None):
+        if only and name not in only:
+            return
         cfg = model.config
         B, bs, C = e["max_batch"], e["block_size"], e["prefill_chunk"]
         Mb = -(-e["max_seq_len"] // bs)
@@ -133,9 +140,12 @@ def write(root, out, compile_too):
                                 bs, num_blocks or e["num_blocks"],
                                 entry=model.cache_entry(), **more).arrays))
         # a block with a row state hands it over after the pool's arrays
+        # (one array, or one a named part)
         state = getattr(model, "row_state", lambda: None)()
         if state is not None:
-            pool += (arg((B,) + state[0], state[1]),)
+            parts = state[0] if not isinstance(state[0][0], int) \
+                else (("state",) + tuple(state),)
+            pool += tuple(arg((B,) + tuple(s), d) for _n, s, d in parts)
         row, on = arg((B,)), arg((B,), jnp.bool_)
         # one block table, or the stack of them, a table a page kind
         tables = arg((len(kinds), B, Mb) if kinds and len(kinds) > 1
@@ -203,6 +213,17 @@ def write(root, out, compile_too):
     model = model_of(cfg, cfg.block.leaf_shapes(cfg))
     both_steps("zaya", model, c["engine"], kinds=model.page_kinds())
 
+    if not os.path.exists(os.path.join(
+            root, "perfbench/configs/ling-3.0-flash-serve.json")):
+        return             # a checkout from before the fifth block
+    from perfbench.runners import serve_ling
+
+    c = config("ling-3.0-flash-serve.json")
+    cfg = serve_ling.generation_config(c, c["engine"]["max_seq_len"])
+    model = model_of(cfg, cfg.block.leaf_shapes(cfg))
+    both_steps("ling", model, c["engine"], kinds=model.page_kinds(),
+               num_blocks=c["engine"]["latent_blocks"])
+
 
 def compare(dir_a, dir_b):
     from jax._src.lib import tpu
@@ -246,7 +267,8 @@ def compare(dir_a, dir_b):
 
 if __name__ == "__main__":
     if len(sys.argv) >= 4 and sys.argv[1] == "write":
-        write(sys.argv[2], sys.argv[3], "--compile" in sys.argv[4:])
+        write(sys.argv[2], sys.argv[3], "--compile" in sys.argv[4:],
+              [a[7:] for a in sys.argv[4:] if a.startswith("--only=")])
     elif len(sys.argv) == 4 and sys.argv[1] == "compare":
         sys.exit(compare(sys.argv[2], sys.argv[3]))
     else:
