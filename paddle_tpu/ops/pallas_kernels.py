@@ -72,13 +72,17 @@ paged_chunk_attention: the chunked-prefill window's attention over the
 
 gqa_paged_decode_attention / gqa_paged_chunk_attention: the same two
   page walks for a GROUPED-QUERY block over a packed bfloat16 pool
-  (``kernels 'gqa_decode'``, ``'gqa_chunk'``; one kernel body): fewer
+  (``kernels 'gqa_decode'``, ``'gqa_chunk'``; one walk, mask and
+  online softmax under two page pipes): fewer
   cache heads than query heads, a page ``[block_size, Hkv * Dh]`` whose
   lanes hold the cache heads side by side, the query heads of a group
   one operand against their cache head's keys. A layer states the
   WINDOW of positions it sees (a traced scalar: window and global
   layers share a lowering); the walk starts at the first page the
-  tile's earliest query still sees. ``kv_page_write`` puts a step's new
+  tile's earliest query still sees. The decode kernel's pipe never
+  runs empty inside a call: a run is sized by its bytes
+  (``gqa_pages_per_run``) and a row's last run starts the next live
+  row's first. ``kv_page_write`` puts a step's new
   K and V rows into such a pool in place, a whole page a grid step.
 
 kda_decode / kda_chunk: the SCAN of a linear-attention layer (the delta
@@ -102,6 +106,7 @@ exercises the same kernel bodies (tests/test_pallas.py).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -525,16 +530,19 @@ CHUNK_PAGES_PER_STEP = 8
 
 
 def _run_copies(tables_ref, row, run, n_pages, layer, k_hbm, v_hbm, kbuf,
-                vbuf, sems, half, *, pages, block_size, start):
+                vbuf, sems, half, *, pages, block_size, start,
+                first_page=None):
     """Start, or wait for, the copies of run ``run`` of ``row``'s pages
-    (table slots ``run * pages`` on, up to the row's ``n_pages``) from
+    (table slots ``run * pages`` on, counted from ``first_page`` where a
+    walk does not start at slot 0, up to the walk's ``n_pages``) from
     the pools in HBM into half ``half`` of the two VMEM buffers: one DMA
     a page and pool, a run's copies on one semaphore a pool. Shared by
     the kernels that walk a row's own pages."""
     P, bs = pages, block_size
 
     def page(p, carry):
-        blk = tables_ref[row, run * P + p]
+        blk = tables_ref[row, (run * P if first_page is None
+                               else first_page + run * P) + p]
         dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
         for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
             copy = pltpu.make_async_copy(
@@ -1830,8 +1838,50 @@ def kda_chunk_reference(state, q, k, v, g, beta, tile_start, tile_len,
 # the in-place page write of a packed bf16 K/V pool
 # ---------------------------------------------------------------------------
 
-GQA_PAGES_PER_STEP = 4
+GQA_PAGES_PER_STEP = 4            # the chunk kernel's run
+GQA_RUN_BYTES = 1 << 20           # the decode kernel's run, a pool
 GQA_ALL_POSITIONS = 2 ** 30       # a `window` no context reaches
+
+
+def gqa_pages_per_run(pool, table_len):
+    """The pages of one run of ``gqa_paged_decode_attention`` over
+    ``pool`` (anything with the shape and dtype of a ``[layers, pages,
+    block_size, Hkv * Dh]`` pool): as many as make
+    :data:`GQA_RUN_BYTES` (a buffer half), at least one and no more than
+    a block-table line holds. A run is sized by what it MOVES: it has a
+    fixed cost (a turn of the loop, a rescale of the online softmax, two
+    small products a cache head), and eight pages of 32 KiB carried it
+    for a quarter of the bytes that eight pages of 128 KiB do. The
+    kernel and the step log (``engine._pages_walked_by_kind``) both ask
+    here."""
+    page_bytes = math.prod(pool.shape[2:]) * jnp.dtype(pool.dtype).itemsize
+    return int(max(1, min(GQA_RUN_BYTES // page_bytes, int(table_len))))
+
+
+def _gqa_head_run(q, kbuf, vbuf, half, g, mask, m_scr, l_scr, acc_scr, *,
+                  sm_scale, head_dim):
+    """Cache head ``g``'s keys and values of the run in buffer ``half``
+    against its ``R`` stacked query rows ``q``: the online softmax's
+    update of rows ``:R`` of the head's statistics and accumulator."""
+    R, Dh = q.shape[0], head_dim
+    k = kbuf[half, :, g * Dh:(g + 1) * Dh]         # [span, Dh]
+    v = vbuf[half, :, g * Dh:(g + 1) * Dh]
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(mask, s, _NEG_INF)
+    m_prev = m_scr[g, :R, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_scr[g, :R, :1] * alpha \
+        + p.sum(axis=-1, keepdims=True)
+    acc_scr[g, :R, :] = acc_scr[g, :R, :] * alpha \
+        + jax.lax.dot_general(
+            p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    m_scr[g, :R, :] = jnp.broadcast_to(m_new, (R, m_scr.shape[2]))
+    l_scr[g, :R, :] = jnp.broadcast_to(l_new, (R, l_scr.shape[2]))
 
 
 def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
@@ -1840,18 +1890,14 @@ def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
     """Grid (tiles,): one query tile a grid step, as
     ``_chunk_attn_kernel``, over a pool whose page is ``[block_size,
     Hkv * Dh]`` (a cache head is a whole-lane-tile slice of the page's
-    lanes). A chunk tile arrives as the step has it, ``[Cq, H * Dh]``;
+    lanes). A tile arrives as the step has it, ``[Cq, H * Dh]``;
     for each cache head the ``H // Hkv`` query heads of its group are
     stacked into ONE operand of ``group * nq`` rows (head-major: row
     ``r`` is the tile's token ``r % nq``) against that head's keys, so a
     key tile is loaded into the MXU once a group and not once a query
     head. ``nq`` is the tile's ``Cq`` slots, or one sublane tile of 16
-    where the tile holds one token. A ONE-TOKEN tile may also arrive
-    already grouped, ``[Hkv, R, Dh]`` (the decode call: the group's
-    query heads are the ``R`` rows of their cache head, all at the
-    tile's one position): its operands are ``R`` rows, not ``group *
-    16``, which is what keeps a decode row's arithmetic under its page
-    reads.
+    where the tile holds one token (the steps send those to
+    ``_gqa_decode_kernel``).
 
     ``scal_ref`` holds the layer (its index in this pool's arrays) and
     the WINDOW: a query at position ``p`` sees ``p - window < t <= p``.
@@ -1867,14 +1913,9 @@ def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
     t = pl.program_id(0)
     bs, P, Dh = block_size, pages, head_dim
     span = P * bs
-    grouped = len(q_ref.shape) == 4    # [1, Hkv, R, Dh]: one token
-    if grouped:
-        Cq = one = q_ref.shape[2]
-        G = 1
-    else:
-        Cq = q_ref.shape[1]
-        G = q_ref.shape[2] // (n_kv * Dh)
-        one = min(Cq, 16)              # a bf16 sublane tile of query rows
+    Cq = q_ref.shape[1]
+    G = q_ref.shape[2] // (n_kv * Dh)
+    one = min(Cq, 16)                  # a bf16 sublane tile of query rows
     n_tok = len_ref[t]
     pos0 = pos_ref[t]
     layer, window = scal_ref[0], scal_ref[1]
@@ -1888,59 +1929,30 @@ def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     def copies(run, half, start):
-        def page(p, carry):
-            blk = tables_ref[t, first_page + run * P + p]
-            dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-            for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                copy = pltpu.make_async_copy(
-                    pool.at[layer, blk], buf.at[half, dst],
-                    sems.at[which, half])
-                copy.start() if start else copy.wait()
-            return carry
-        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+        _run_copies(tables_ref, t, run, n_pages, layer, k_hbm, v_hbm,
+                    kbuf, vbuf, sems, half, pages=P, block_size=bs,
+                    start=start, first_page=first_page)
 
     def attend(run, half, nq):
         R = G * nq
         t_pos = (first_page + run * P) * bs + jax.lax.broadcasted_iota(
             jnp.int32, (R, span), 1)
-        if grouped:                    # every row is the one token
-            q_pos = pos0
-        else:          # nq is a power of two: row r is token r % nq
-            q_pos = pos0 + (jax.lax.broadcasted_iota(
-                jnp.int32, (R, span), 0) & (nq - 1))
+        # nq is a power of two: row r is token r % nq
+        q_pos = pos0 + (jax.lax.broadcasted_iota(
+            jnp.int32, (R, span), 0) & (nq - 1))
         mask = (t_pos <= q_pos) & (t_pos > q_pos - window)
         for g in range(n_kv):
-            q = q_ref[0, g] if grouped else jnp.concatenate(
+            q = jnp.concatenate(
                 [q_ref[0, :nq, (g * G + j) * Dh:(g * G + j + 1) * Dh]
                  for j in range(G)], axis=0)               # [R, Dh] bf16
-            k = kbuf[half, :, g * Dh:(g + 1) * Dh]         # [span, Dh]
-            v = vbuf[half, :, g * Dh:(g + 1) * Dh]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            s = jnp.where(mask, s, _NEG_INF)
-            m_prev = m_scr[g, :R, :1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_scr[g, :R, :1] * alpha \
-                + p.sum(axis=-1, keepdims=True)
-            acc_scr[g, :R, :] = acc_scr[g, :R, :] * alpha \
-                + jax.lax.dot_general(
-                    p.astype(jnp.bfloat16), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_scr[g, :R, :] = jnp.broadcast_to(m_new, (R, m_scr.shape[2]))
-            l_scr[g, :R, :] = jnp.broadcast_to(l_new, (R, l_scr.shape[2]))
+            _gqa_head_run(q, kbuf, vbuf, half, g, mask, m_scr, l_scr,
+                          acc_scr, sm_scale=sm_scale, head_dim=Dh)
 
     def finish(nq):
         R = G * nq
         live = (jax.lax.broadcasted_iota(jnp.int32, (R, Dh), 0)
                 & (nq - 1)) < n_tok
         for g in range(n_kv):
-            if grouped:
-                o_ref[0, g] = acc_scr[g] / jnp.maximum(l_scr[g, :, :1],
-                                                       1e-30)
-                continue
             out = jnp.where(live, acc_scr[g, :R, :]
                             / jnp.maximum(l_scr[g, :R, :1], 1e-30), 0.0)
             for j in range(G):
@@ -1979,19 +1991,123 @@ def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
         by_size(finish)
 
 
+def _gqa_decode_kernel(tables_ref, pos_ref, act_ref, scal_ref, next_ref,
+                       q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
+                       hand_ref, m_scr, l_scr, acc_scr, *, sm_scale,
+                       block_size, pages, n_kv, head_dim):
+    """Grid (rows,): one ONE-TOKEN row a grid step over the pools of
+    ``_gqa_attn_kernel``, the row's query heads arriving grouped by
+    cache head, ``[Hkv, R, Dh]`` (the group's heads are the ``R`` rows
+    of their cache head, all at the row's one position): operands of
+    ``R`` rows, which is what keeps a decode row's arithmetic under its
+    page reads. Window, walk, mask and arithmetic as there.
+
+    What differs is the PAGE PIPE, which never runs empty between the
+    first live row and the last. A run is ``pages`` pages
+    (:func:`gqa_pages_per_run`: sized by its bytes), the next in flight
+    while this one is attended; and a row's LAST run starts the first
+    run of the next live row, ``next_ref[t]`` (the row count where none
+    follows; inactive rows may lie between), into the other buffer
+    half. ``hand_ref`` (SMEM) hands over which half that was and that
+    it was done, so only the first live row of a call opens its own
+    pipe and waits for it with nothing to attend. A row's walk is at
+    least one page, so every live row but the last hands over."""
+    t = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    bs, P, Dh = block_size, pages, head_dim
+    R = q_ref.shape[2]
+    layer, window = scal_ref[0], scal_ref[1]
+
+    def walk(row):
+        """`row`'s first live page and how many it walks."""
+        first = jnp.maximum(pos_ref[row] - window + 1, 0) // bs
+        return first, pos_ref[row] // bs + 1 - first
+
+    pos0 = pos_ref[t]
+    first_page, n_pages = walk(t)
+    n_runs = (n_pages + P - 1) // P
+
+    @pl.when(t == 0)
+    def _first():
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        hand_ref[1] = 0
+
+    def copies(row, run, half, start):
+        first, n = walk(row)
+        _run_copies(tables_ref, row, run, n, layer, k_hbm, v_hbm, kbuf,
+                    vbuf, sems, half, pages=P, block_size=bs, start=start,
+                    first_page=first)
+
+    def attend(run, half):
+        t_pos = (first_page + run * P) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (R, P * bs), 1)
+        mask = (t_pos <= pos0) & (t_pos > pos0 - window)
+        for g in range(n_kv):
+            _gqa_head_run(q_ref[0, g], kbuf, vbuf, half, g, mask, m_scr,
+                          l_scr, acc_scr, sm_scale=sm_scale, head_dim=Dh)
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(act_ref[t] > 0)
+    def _row():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # the live row before, if there was one, has started this row's
+        # first run and left word of the buffer half
+        opened = hand_ref[1] == 1
+        half0 = jnp.where(opened, hand_ref[0], 0)
+        hand_ref[1] = 0
+        follows = next_ref[t] < n_rows
+        nxt = jnp.minimum(next_ref[t], n_rows - 1)
+        pl.when(jnp.logical_not(opened))(lambda: copies(t, 0, half0, True))
+
+        def one_run(run, carry):
+            half = (half0 + run) % 2
+
+            @pl.when(run + 1 < n_runs)
+            def _next():
+                copies(t, run + 1, 1 - half, True)
+
+            @pl.when((run + 1 == n_runs) & follows)
+            def _next_row():
+                hand_ref[0] = 1 - half
+                hand_ref[1] = 1
+                copies(nxt, 0, 1 - half, True)
+
+            copies(t, run, half, False)
+            attend(run, half)
+            return carry
+
+        jax.lax.fori_loop(0, n_runs, one_run, 0)
+        for g in range(n_kv):
+            o_ref[0, g] = acc_scr[g] / jnp.maximum(l_scr[g, :, :1], 1e-30)
+
+
+def _next_live(live):
+    """For each row the next row after it with ``live`` set, or the row
+    count where none follows."""
+    n = live.shape[0]
+    at = jnp.where(live, jnp.arange(n, dtype=jnp.int32), n)
+    after = jax.lax.cummin(at, reverse=True)
+    return jnp.concatenate([after[1:], jnp.full((1,), n, jnp.int32)])
+
+
 @functools.partial(jax.jit, static_argnames=(
     "sm_scale", "pages", "n_kv", "name", "interpret"))
 def _gqa_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
               window, *, sm_scale, pages, n_kv, name, interpret):
-    """q ``[N, Cq, H * Dh]`` (``Cq`` a power of two), or ``[N, Hkv, R,
-    Dh]`` (one token a tile, its query heads grouped by cache head) ->
-    the same shape, float32. Layer and window are traced scalars: one
-    lowering serves every layer of a pool, whichever positions they
-    keep."""
+    """q ``[N, Cq, H * Dh]`` (``Cq`` a power of two: the chunk kernel),
+    or ``[N, Hkv, R, Dh]`` (one token a tile, its query heads grouped by
+    cache head: the decode kernel) -> the same shape, float32. Layer and
+    window are traced scalars: one lowering serves every layer of a
+    pool, whichever positions they keep."""
     N = q.shape[0]
     bs = k_pool.shape[2]
     Dh = k_pool.shape[3] // n_kv
-    if q.ndim == 4:
+    decode = q.ndim == 4
+    if decode:
         rows = q.shape[2]
     else:
         Cq, HD = q.shape[1:]
@@ -2000,24 +2116,32 @@ def _gqa_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
             raise ValueError("a query tile holds a power of two of "
                              "slots, got %d" % Cq)
     block = (1,) + q.shape[1:]
+    lengths = lengths.astype(jnp.int32)
+    scalars = [block_tables.astype(jnp.int32),
+               jnp.maximum(positions, 0).astype(jnp.int32), lengths,
+               jnp.stack([layer, window]).astype(jnp.int32)]
+    buffers = [pltpu.VMEM((2, pages * bs, n_kv * Dh), k_pool.dtype),
+               pltpu.VMEM((2, pages * bs, n_kv * Dh), v_pool.dtype),
+               pltpu.SemaphoreType.DMA((2, 2))]
+    if decode:
+        scalars.append(_next_live(lengths > 0))
+        buffers.append(pltpu.SMEM((2,), jnp.int32))
 
     def tile(n, *_):
         return (n,) + (0,) * (q.ndim - 1)
 
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     return pl.pallas_call(
-        functools.partial(_gqa_attn_kernel, sm_scale=sm_scale,
+        functools.partial(_gqa_decode_kernel if decode
+                          else _gqa_attn_kernel, sm_scale=sm_scale,
                           block_size=bs, pages=pages, n_kv=n_kv,
                           head_dim=Dh),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(scalars),
             grid=(N,),
             in_specs=[pl.BlockSpec(block, tile), hbm, hbm],
             out_specs=pl.BlockSpec(block, tile),
-            scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, n_kv * Dh), k_pool.dtype),
-                pltpu.VMEM((2, pages * bs, n_kv * Dh), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+            scratch_shapes=buffers + [
                 pltpu.VMEM((n_kv, rows, 128), jnp.float32),
                 pltpu.VMEM((n_kv, rows, 128), jnp.float32),
                 pltpu.VMEM((n_kv, rows, Dh), jnp.float32),
@@ -2027,11 +2151,7 @@ def _gqa_call(k_pool, v_pool, q, block_tables, positions, lengths, layer,
             vmem_limit_bytes=96 * 1024 * 1024),
         interpret=interpret,
         name=name,
-    )(block_tables.astype(jnp.int32),
-      jnp.maximum(positions, 0).astype(jnp.int32),
-      lengths.astype(jnp.int32),
-      jnp.stack([layer, window]).astype(jnp.int32),
-      q.astype(jnp.bfloat16), k_pool, v_pool)
+    )(*scalars, q.astype(jnp.bfloat16), k_pool, v_pool)
 
 
 def _gqa_window(window):
@@ -2071,17 +2191,17 @@ def gqa_paged_chunk_attention(k_pool, v_pool, q, block_tables, positions,
 
 def gqa_paged_decode_attention(k_pool, v_pool, q, block_tables, positions,
                                *, layer, window=None, active=None,
-                               sm_scale=None,
-                               pages_per_step=2 * GQA_PAGES_PER_STEP):
+                               sm_scale=None, pages_per_step=None):
     """One-token decode attention of a grouped-query block: q ``[B, H,
     Dh]``, positions ``[B]``, over the pools of
     :func:`gqa_paged_chunk_attention`. One grid step a row; the row's
     live pages (from the first its position still sees, on a window
-    layer) come by DMA, and the query heads of a group, padded to one
-    sublane tile of 16 rows, are one operand against their cache head's
-    keys: the chunk kernel's body over one-token tiles that arrive
-    grouped by cache head. An inactive row is skipped and comes out
-    zero. Returns ``[B, H, Dh]`` float32."""
+    layer) come by DMA in runs of :func:`gqa_pages_per_run` pages (by
+    the pool's own page: 8 of 128 KiB, 32 of 32 KiB), a row's last run
+    starting the next active row's first (``_gqa_decode_kernel``), and
+    the query heads of a group, padded to one sublane tile of 16 rows,
+    are one operand against their cache head's keys. An inactive row is
+    skipped and comes out zero. Returns ``[B, H, Dh]`` float32."""
     B, H, Dh = q.shape
     n_kv = k_pool.shape[3] // Dh
     G = H // n_kv
@@ -2090,6 +2210,8 @@ def gqa_paged_decode_attention(k_pool, v_pool, q, block_tables, positions,
         sm_scale = Dh ** -0.5
     if active is None:
         active = jnp.ones((B,), jnp.int32)
+    if pages_per_step is None:
+        pages_per_step = gqa_pages_per_run(k_pool, block_tables.shape[1])
     qg = jnp.pad(q.reshape(B, n_kv, G, Dh),
                  ((0, 0), (0, 0), (0, rows - G), (0, 0)))
     out = _gqa_call(k_pool, v_pool, qg, block_tables, positions,

@@ -62,6 +62,18 @@ ATTN_TILE = 128
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
+def decode_pages_per_run(pool, table_len):
+    """The pages of one run of the page pipe of the decode kernel that
+    :class:`_PagedWindow` calls, over ``pool``
+    (``pk.gqa_pages_per_run``, which the kernel asks too). A block that
+    attends through that class states it as its own
+    ``decode_pages_per_run``, and the step log counts the runs by it
+    (``engine._pages_walked_by_kind``)."""
+    from ..ops.pallas_kernels import gqa_pages_per_run
+
+    return gqa_pages_per_run(pool, table_len)
+
+
 class AfmoeBlock(BlockDescription):
     """The block's description, carried by ``GenerationConfig.block``
     (``d_model``, ``n_heads``, ``n_layers``, ``vocab_size`` and the
@@ -119,6 +131,8 @@ class AfmoeBlock(BlockDescription):
     def cache_entry(self):
         return CacheEntry((("k", (self.cache_width,)),
                            ("v", (self.cache_width,))), self.cache_dtype)
+
+    decode_pages_per_run = staticmethod(decode_pages_per_run)
 
     def page_kinds(self, config):
         """The global layers' pages first (they keep every position),

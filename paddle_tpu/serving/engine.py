@@ -950,7 +950,18 @@ class _ModelWorker:
         layers, which is the attention's arithmetic. ``chunk_pages_walked``
         and ``chunk_keys_attended``: the same two over every kind, of the
         rows that hold more than one token alone (a mixed step's chunk
-        kernel; its one-token rows go through the decode kernel)."""
+        kernel; its one-token rows go through the decode kernel).
+
+        Where the block's decode kernel takes a row's pages in runs it
+        sizes itself (``block.decode_pages_per_run``: the grouped-query
+        blocks), also what the step's one-token rows make THAT kernel's
+        page pipe do, over the kinds' layers: ``decode_rows_walked``
+        (rows x layers: the kernel's live grid steps),
+        ``decode_runs_walked`` (their runs, ``ceil(pages / run)`` by the
+        kernel's own rule) and ``decode_rows_opened_warm`` (those whose
+        first run a live row before them started: every live row of a
+        call but its first; a chunk's tail tile of one token, which the
+        kernel also takes, is not counted)."""
         sched, bs = self.scheduler, self.pool.block_size
         on = sched.active
         pos0 = sched.positions[on].astype(np.int64)
@@ -960,6 +971,15 @@ class _ModelWorker:
         out = {"window_pages_full": 0, "window_keys_attended": 0,
                "chunk_pages_walked": 0, "chunk_keys_attended": 0}
         chunk = n > 1
+        run_rule = getattr(self.model.config.block, "decode_pages_per_run",
+                           None)
+        if run_rule is not None:
+            # every kind's pool has the entry's page: one rule a step
+            per_run = run_rule(self.pool.arrays[0],
+                               sched.max_blocks_per_seq)
+            one_token = int((~chunk).sum())
+            out.update(decode_rows_walked=0, decode_runs_walked=0,
+                       decode_rows_opened_warm=0)
         for kind in self.pool.kinds:
             layers = len(kind.layers)
             if kind.window is None:
@@ -978,6 +998,12 @@ class _ModelWorker:
             out[kind.name + "_keys_attended"] = layers * int(keys.sum())
             out["chunk_pages_walked"] += layers * int(pages[chunk].sum())
             out["chunk_keys_attended"] += layers * int(keys[chunk].sum())
+            if run_rule is not None:
+                out["decode_rows_walked"] += layers * one_token
+                out["decode_runs_walked"] += layers * int(
+                    (-(-pages[~chunk] // per_run)).sum())
+                out["decode_rows_opened_warm"] += layers * max(
+                    one_token - 1, 0)
         return out
 
     def _dispatch_spec(self, plan):

@@ -50,7 +50,7 @@ windows (``GenerationModel._no_such_step``), the prefix cache (the
 engine: no carry exists at an adopted page boundary), ``quantized()``.
 """
 
-from .afmoe import _PagedWindow, rope_half_split
+from .afmoe import _PagedWindow, decode_pages_per_run, rope_half_split
 from .kv_cache import CacheEntry, PageKind
 from .latent_moe import (COUNTERS, BlockDescription, _dot, _normal,
                          _operands, _rms_norm, expert_layer, held_experts,
@@ -134,6 +134,8 @@ class ZayaBlock(BlockDescription):
     def cache_entry(self):
         return CacheEntry((("k", (self.cache_width,)),
                            ("v", (self.cache_width,))), self.cache_dtype)
+
+    decode_pages_per_run = staticmethod(decode_pages_per_run)
 
     def page_kinds(self, config):
         """One kind, every layer, every position; named, so that the

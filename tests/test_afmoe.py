@@ -348,8 +348,16 @@ def test_step_log_carries_the_page_walks(monkeypatch):
                   "window_pages_full", "global_keys_attended",
                   "window_keys_attended", "chunk_pages_walked",
                   "chunk_keys_attended", "cached_tokens", "weight_bytes",
-                  "expert_pairs", "experts_touched"):
+                  "expert_pairs", "experts_touched", "decode_rows_walked",
+                  "decode_runs_walked", "decode_rows_opened_warm"):
             assert f in r, (f, r)
+        # the decode kernel's pipe over the one-token rows: at least a
+        # run a row and layer, and one row a call opens cold
+        assert r["decode_rows_walked"] <= r["decode_runs_walked"]
+        assert r["decode_rows_walked"] in (0, 4 * (
+            r["decode_tokens"] + (r["prefill_tokens"] == 1)))
+        assert r["decode_rows_opened_warm"] == max(
+            r["decode_rows_walked"] - 4, 0)
         assert r["window_pages_walked"] <= r["window_pages_full"]
         assert r["chunk_keys_attended"] <= (r["global_keys_attended"]
                                             + r["window_keys_attended"])
@@ -360,3 +368,5 @@ def test_step_log_carries_the_page_walks(monkeypatch):
     assert last["window_pages_full"] == 3 * 4
     assert last["window_pages_walked"] == 3 * 2
     assert last["global_pages_walked"] == 4
+    # pages of 8 KiB and a table of 6: a run takes the whole walk
+    assert last["decode_rows_walked"] == last["decode_runs_walked"] == 4

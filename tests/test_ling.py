@@ -290,6 +290,8 @@ def test_the_engine_serves_what_the_reference_decodes(monkeypatch):
                   "scan_fresh_rows", "global_pages_walked",
                   "global_keys_attended", "window_keys_attended"):
             assert f in r, (f, r)
+        # its pages go through the latent kernel, whose runs are its own
+        assert "decode_runs_walked" not in r
         # two expert layers hold half of the router's experts
         assert r["expert_slots"] == 2 * 8
         assert r["expert_pairs"] <= 2 * 4 * r["slots_used"]

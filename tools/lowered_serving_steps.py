@@ -29,6 +29,9 @@ Mosaic kernel's body rides in its custom call as base64 MLIR bytecode,
 the Python call stack of its trace included, so each body is parsed and
 reprinted without locations before the lines are compared, first in
 order and then sorted (set-up lines ahead of the layer loop may move).
+Of two steps that differ it names the functions they do not share (by
+name and body, the counters of private names taken off), so that "only
+the decode kernel's call changed" can be read off.
 
 Keep JAX_PLATFORMS=cpu set: nothing here runs, and nothing it prints is
 a device number.
@@ -262,7 +265,31 @@ def compare(dir_a, dir_b):
         print("%-24s %6d / %6d lines: %s" % (name[:-5], len(a), len(b),
                                              verdict))
         different |= verdict == "DIFFERENT"
+        if verdict == "DIFFERENT":
+            in_a, in_b = functions(a), functions(b)
+            for side, fns in (("a", in_a - in_b), ("b", in_b - in_a)):
+                print("    only in %s: %s" % (side, ", ".join(
+                    "%s[%s] x%d (%d lines)" % (
+                        fn[0], " ".join(re.findall(
+                            r'kernel_name = "(\w+)"', fn[1])),
+                        n, fn[1].count("\n"))
+                    for fn, n in sorted(fns.items())) or "nothing"))
     return 1 if different else 0
+
+
+def functions(lines):
+    """A lowered module's functions as a multiset of (name, body), the
+    counters jax appends to private names (``@_where_228``) taken off
+    both: a function added anywhere renumbers every later one, and what
+    a reader wants to know is WHICH functions two programs do not
+    share."""
+    text = re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", "".join(lines))
+    found = collections.Counter()
+    for body in re.split(r"\n(?=  func\.func )", text):
+        name = re.match(r"\s*func\.func (?:\w+ )?@([\w.]+)", body)
+        if name:
+            found[(name.group(1), body)] += 1
+    return found
 
 
 if __name__ == "__main__":
