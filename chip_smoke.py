@@ -830,7 +830,8 @@ def kernel_cases(sz):
 
     # the latent block's three kernels at its widths: the latent pool
     # whole (two layers, the second used), rows at both ends of the
-    # context, one-token rows beside full windows
+    # context, one-token rows beside full windows, a row that is not
+    # live between two that hand the page pipe over it
     lb = sz.latent["block"]
     Hl, Bl = sz.latent["n_heads"], sz.latent_batch
     r = lb["kv_lora_rank"]
@@ -845,6 +846,7 @@ def kernel_cases(sz):
 
         def fill_attn(rng):
             tables, pos0, lens = layout(rng)
+            lens[1] = 0           # not live, between two rows that are
             return [jnp.asarray(rng.randn(*lpool[0]).astype(f32), lpool[1]),
                     (rng.randn(Bl, C, Hl, W) * 0.1).astype(f32),
                     tables, pos0, lens]

@@ -54,10 +54,12 @@ latent_paged_attention: attention over a paged LATENT cache (MLA,
   takes the pool whole like ``paged_attention``, leaves it in HBM and
   copies a row's own pages itself, ``pages_per_step`` to a matmul, so
   a row of hundreds of cached tokens costs a few matmuls and no page
-  it does not own. One kernel serves the
-  one-token decode step (``kernel 'latent_decode'``) and the chunk
-  window (``kernel 'latent_window'``). ``latent_write`` puts a window's
-  new rows into that pool in place, a page at a time.
+  it does not own; a row's last run starts the next live row's first
+  (``_row_pipe``, the hand-over the grouped-query decode kernel shares),
+  so the page pipe runs empty once a call, not once a row. One kernel
+  serves the one-token decode step (``kernel 'latent_decode'``) and the
+  chunk window (``kernel 'latent_window'``). ``latent_write`` puts a
+  window's new rows into that pool in place, a page at a time.
 
 paged_chunk_attention: the chunked-prefill window's attention over the
   same fp32 ``KVBlockPool`` (``kernel 'chunk_window'``). The window
@@ -82,7 +84,7 @@ gqa_paged_decode_attention / gqa_paged_chunk_attention: the same two
   tile's earliest query still sees. The decode kernel's pipe never
   runs empty inside a call: a run is sized by its bytes
   (``gqa_pages_per_run``) and a row's last run starts the next live
-  row's first. ``kv_page_write`` puts a step's new
+  row's first (``_row_pipe``). ``kv_page_write`` puts a step's new
   K and V rows into such a pool in place, a whole page a grid step.
 
 kda_decode / kda_chunk: the SCAN of a linear-attention layer (the delta
@@ -529,28 +531,99 @@ def paged_attention_reference(k_pool, v_pool, q, block_tables,
 CHUNK_PAGES_PER_STEP = 8
 
 
-def _run_copies(tables_ref, row, run, n_pages, layer, k_hbm, v_hbm, kbuf,
-                vbuf, sems, half, *, pages, block_size, start,
-                first_page=None):
+def _run_copies(tables_ref, row, run, n_pages, layer, pools, bufs, sems,
+                half, *, pages, block_size, start, first_page=None,
+                unroll=1):
     """Start, or wait for, the copies of run ``run`` of ``row``'s pages
     (table slots ``run * pages`` on, counted from ``first_page`` where a
     walk does not start at slot 0, up to the walk's ``n_pages``) from
-    the pools in HBM into half ``half`` of the two VMEM buffers: one DMA
-    a page and pool, a run's copies on one semaphore a pool. Shared by
-    the kernels that walk a row's own pages."""
+    ``pools`` in HBM (one latent pool, or K and V) into half ``half`` of
+    each pool's two VMEM buffers ``bufs``: one DMA a page and pool, a
+    run's copies on one semaphore a pool (``sems[pool, half]``). The one
+    page walk of every kernel that copies a row's own pages. The scalar
+    core issues a descriptor a page in the kernel's one instruction
+    stream; ``unroll`` pages a turn of the loop (the rest a page a turn)
+    takes the loop's own cost off small pages."""
     P, bs = pages, block_size
+    count = jnp.minimum(P, n_pages - run * P)
 
     def page(p, carry):
         blk = tables_ref[row, (run * P if first_page is None
                                else first_page + run * P) + p]
         dst = pl.ds(pl.multiple_of(p * bs, bs), bs)
-        for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+        for which, (pool, buf) in enumerate(zip(pools, bufs)):
             copy = pltpu.make_async_copy(
                 pool.at[layer, blk], buf.at[half, dst],
                 sems.at[which, half])
             copy.start() if start else copy.wait()
         return carry
-    jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
+
+    def group(i, carry):
+        for j in range(unroll):
+            page(i * unroll + j, carry)
+        return carry
+    whole = count // unroll
+    jax.lax.fori_loop(0, whole, group, 0)
+    jax.lax.fori_loop(whole * unroll, count, page, 0)
+
+
+def _pages_in(run_bytes, pool, table_len):
+    """The pages of ``pool`` (its shape and dtype: ``[layers, pages,
+    block_size, ...]``) that make a run of ``run_bytes``: at least one,
+    and no more than a block-table line holds."""
+    page_bytes = math.prod(pool.shape[2:]) * jnp.dtype(pool.dtype).itemsize
+    return int(max(1, min(run_bytes // page_bytes, int(table_len))))
+
+
+def _row_pipe(copies, attend, row, n_runs, next_row, n_rows, hand_ref):
+    """One LIVE row's walk through a page pipe that does not run empty
+    between a call's first live row and its last: the row's runs in
+    turn, the next in flight (``copies(row, run, half, start)`` starts
+    or waits for a run's copies into a buffer half) while this one is
+    attended (``attend(run, half)``), and the row's LAST run starts the
+    first run of the next live row, ``next_row`` (``n_rows`` where none
+    follows; rows that are not live may lie between), into the other
+    half. ``hand_ref`` (SMEM ``[2]``) hands over which half that was
+    and that it was done, so only the first live row of a call opens
+    its own pipe and waits for it with nothing to attend; the kernel
+    clears ``hand_ref[1]`` in its first grid step. A walk is at least
+    one run, so every live row but the last hands over."""
+    # the live row before, if there was one, has started this row's
+    # first run and left word of the buffer half
+    opened = hand_ref[1] == 1
+    half0 = jnp.where(opened, hand_ref[0], 0)
+    hand_ref[1] = 0
+    follows = next_row < n_rows
+    nxt = jnp.minimum(next_row, n_rows - 1)
+    pl.when(jnp.logical_not(opened))(lambda: copies(row, 0, half0, True))
+
+    def one_run(run, carry):
+        half = (half0 + run) % 2
+
+        @pl.when(run + 1 < n_runs)
+        def _next():
+            copies(row, run + 1, 1 - half, True)
+
+        @pl.when((run + 1 == n_runs) & follows)
+        def _next_row():
+            hand_ref[0] = 1 - half
+            hand_ref[1] = 1
+            copies(nxt, 0, 1 - half, True)
+
+        copies(row, run, half, False)
+        attend(run, half)
+        return carry
+
+    jax.lax.fori_loop(0, n_runs, one_run, 0)
+
+
+def _next_live(live):
+    """For each row the next row after it with ``live`` set, or the row
+    count where none follows."""
+    n = live.shape[0]
+    at = jnp.where(live, jnp.arange(n, dtype=jnp.int32), n)
+    after = jax.lax.cummin(at, reverse=True)
+    return jnp.concatenate([after[1:], jnp.full((1,), n, jnp.int32)])
 
 
 def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, _blk_ref,
@@ -598,8 +671,9 @@ def _chunk_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, _blk_ref,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     def copies(run, half, start):
-        _run_copies(tables_ref, t, run, n_pages, layer, k_hbm, v_hbm, kbuf,
-                    vbuf, sems, half, pages=P, block_size=bs, start=start)
+        _run_copies(tables_ref, t, run, n_pages, layer, (k_hbm, v_hbm),
+                    (kbuf, vbuf), sems, half, pages=P, block_size=bs,
+                    start=start)
 
     def attend(run, half):
         t_pos = run * span + jax.lax.broadcasted_iota(
@@ -812,9 +886,9 @@ def _decode_attn_kernel(tables_ref, pos_ref, act_ref, layer_ref, q_ref,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     def copies(row, run, half, start):
-        _run_copies(tables_ref, row, run, pages_of(row), layer, k_hbm,
-                    v_hbm, kbuf, vbuf, sems, half, pages=P, block_size=bs,
-                    start=start)
+        _run_copies(tables_ref, row, run, pages_of(row), layer,
+                    (k_hbm, v_hbm), (kbuf, vbuf), sems, half, pages=P,
+                    block_size=bs, start=start)
 
     def attend(run, half):
         n = P * bs * H
@@ -1174,7 +1248,9 @@ def gmm_reference(lhs, rhs, tile_expert, n_used, *, block_m=GMM_BLOCK_M):
 # latent paged attention: MLA's absorbed form over a paged latent cache
 # ---------------------------------------------------------------------------
 
-LATENT_PAGES_PER_STEP = 32
+LATENT_RUN_BYTES = 5 << 19        # a run of the attention kernel: 2.5 MiB
+LATENT_COPIES_UNROLL = 8          # its page loop: descriptors a turn
+LATENT_PRODUCT_SPANS = 4          # its products: the buffer, or a halving
 
 def _latent_write_kernel(tables_ref, pos_ref, len_ref, layer_ref, rows_ref,
                          page_ref, o_ref, *, block_size, window):
@@ -1260,117 +1336,202 @@ def latent_write_reference(pool, rows, block_tables, positions, lengths,
 
 
 
-def _latent_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, q_ref,
-                        pool_ref, o_ref, buf, sems, m_scr, l_scr, acc_scr,
-                        *, block_size, pages, window, n_heads, v_width):
-    """Grid (B,): one row a grid step. The pool stays in HBM; the row's
-    own pages, and no others, are copied into one of two VMEM buffers
-    in runs of ``pages`` (one DMA a page, all of a run on one
-    semaphore, the next run in flight while this one is attended), each
-    run being one ``[pages * block_size, width]`` key block whose first
-    ``v_width`` lanes are the value. The online-softmax state is
-    carried over the row's runs.
+def latent_pages_per_run(pool, table_len):
+    """The pages of one run of ``latent_paged_attention``'s page pipe
+    over ``pool`` (anything with the shape and dtype of a ``[layers,
+    pages, block_size, width]`` latent pool): as many as make
+    :data:`LATENT_RUN_BYTES` (a buffer half), at least one and no more
+    than a block-table line holds. A run is sized by what it MOVES, as
+    :func:`gqa_pages_per_run` sizes the grouped-query kernel's: it has
+    a fixed cost (a turn of the loop, the waits, a rescale of the
+    online softmax and two products), which 32 pages of 20 KB carried
+    for a quarter of the bytes that 32 pages of 80 KB do. The kernel's
+    call and the step log (``engine._decode_pipe_walked``) both ask
+    here."""
+    return _pages_in(LATENT_RUN_BYTES, pool, table_len)
+
+
+def _run_wait(count, bufs, sems, half, *, pages, block_size):
+    """Wait for the ``count`` page copies a pool that
+    :func:`_run_copies` started into half ``half``, by SIZE: a DMA
+    semaphore counts what has arrived, so one wait on a descriptor of
+    ``k`` pages takes ``k`` page copies off it, and ``count`` (at most
+    ``pages``) is waited for by its bits: at most ``log2(pages) + 1``
+    waits a pool where a wait a page is ``count``."""
+    k = 1 << (int(pages).bit_length() - 1)
+    while k:
+        def wait(k=k):
+            for which, buf in enumerate(bufs):
+                whole = buf.at[half, pl.ds(0, k * block_size)]
+                pltpu.make_async_copy(whole, whole,
+                                      sems.at[which, half]).wait()
+        pl.when((count & k) != 0)(wait)
+        k //= 2
+
+
+def _latent_attend(q_ref, buf, half, run, pos0, nq, m_scr, l_scr, acc_scr,
+                   *, n_heads, v_width, span):
+    """The first ``span`` tokens of the run in buffer ``half`` (one
+    ``[span, width]`` key block whose first ``v_width`` lanes are the
+    value) against the row's first ``nq`` query rows: the online
+    softmax's update of rows ``:nq`` of the statistics and the
+    accumulator."""
+    k = buf[half, :span]
+    t0 = run * buf.shape[1]
+    s = jax.lax.dot_general(
+        q_ref[0, :nq, :], k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                 # [nq, span]
+    t_pos = t0 + jax.lax.broadcasted_iota(jnp.int32, (nq, span), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (nq, span), 0) // n_heads
+    s = jnp.where(t_pos <= pos0 + slot, s, _NEG_INF)
+    m_prev = m_scr[:nq, :1]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_scr[:nq, :1] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_scr[:nq, :] = acc_scr[:nq, :] * alpha + jax.lax.dot_general(
+        p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[:nq, :] = jnp.broadcast_to(m_new, (nq, m_scr.shape[1]))
+    l_scr[:nq, :] = jnp.broadcast_to(l_new, (nq, l_scr.shape[1]))
+
+
+def _latent_attn_kernel(tables_ref, pos_ref, len_ref, layer_ref, next_ref,
+                        q_ref, pool_ref, o_ref, buf, sems, hand_ref, m_scr,
+                        l_scr, acc_scr, *, block_size, pages, window,
+                        n_heads, v_width):
+    """Grid (B,): one row a grid step. The pool stays in HBM; a LIVE
+    row's own pages (``len_ref[b] > 0``), and no others, are copied into
+    one of two VMEM buffers in runs of ``pages`` (one DMA a page, all of
+    a run on one semaphore), each run being one ``[pages * block_size,
+    width]`` key block whose first ``v_width`` lanes are the value. The
+    page pipe is :func:`_row_pipe`'s: the next run in flight while this
+    one is attended, and a row's LAST run starts the first run of the
+    next live row, ``next_ref[b]`` (:func:`_next_live`), so only the
+    first live row of a call opens on an empty pipe. The online-softmax
+    state is carried over the row's runs. A row that is not live starts
+    no copy, computes nothing and comes out zero.
 
     Query rows are ``[window * n_heads, width]``, slot-major: row r is
     head ``r % n_heads`` of window slot ``r // n_heads``, which sees
     logical positions ``t <= pos + slot``. A row whose window holds one
     token (every decode row of a mixed step) computes its first
-    ``n_heads`` query rows only. Lines of a buffer past the row's last
-    page keep an earlier row's values: finite (the buffers are zeroed
-    once) and masked by position."""
+    ``n_heads`` query rows only; it and the window rows go through the
+    same grid and the same hand-over. Lines of a buffer past the row's
+    last page keep an earlier row's values: finite (the buffers are
+    zeroed once) and masked by position."""
     b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
     bs, P = block_size, pages
-    span = P * bs
-    n_tok = jnp.maximum(len_ref[b], 1)
+    n_tok = len_ref[b]
     pos0 = pos_ref[b]
-    n_pages = (pos0 + n_tok - 1) // bs + 1
-    n_runs = (n_pages + P - 1) // P
     layer = layer_ref[0]
 
+    def pages_of(row):
+        return (pos_ref[row] + len_ref[row] - 1) // bs + 1
+
+    n_runs = (pages_of(b) + P - 1) // P
+
     @pl.when(b == 0)
-    def _zero():
+    def _first():
         buf[...] = jnp.zeros_like(buf)
+        hand_ref[1] = 0
 
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
+    def held(row, run):
+        """The pages run ``run`` of ``row`` holds."""
+        return jnp.minimum(P, pages_of(row) - run * P)
 
-    def copies(run, half, start):
-        def page(p, carry):
-            copy = pltpu.make_async_copy(
-                pool_ref.at[layer, tables_ref[b, run * P + p]],
-                buf.at[half, pl.ds(pl.multiple_of(p * bs, bs), bs)],
-                sems.at[half])
-            copy.start() if start else copy.wait()
-            return carry
-        jax.lax.fori_loop(0, jnp.minimum(P, n_pages - run * P), page, 0)
-
-    def attend(run, half, nq):
-        k = buf[half]
-        s = jax.lax.dot_general(
-            q_ref[0, :nq, :], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [nq, span]
-        t_pos = run * span + jax.lax.broadcasted_iota(
-            jnp.int32, (nq, span), 1)
-        slot = jax.lax.broadcasted_iota(
-            jnp.int32, (nq, span), 0) // n_heads
-        s = jnp.where(t_pos <= pos0 + slot, s, _NEG_INF)
-        m_prev = m_scr[:nq, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_scr[:nq, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[:nq, :] = acc_scr[:nq, :] * alpha + jax.lax.dot_general(
-            p.astype(k.dtype), k[:, :v_width], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:nq, :] = jnp.broadcast_to(m_new, (nq, m_scr.shape[1]))
-        l_scr[:nq, :] = jnp.broadcast_to(l_new, (nq, l_scr.shape[1]))
-
-    copies(0, 0, True)
-
-    def one_run(run, carry):
-        half = run % 2
-
-        @pl.when(run + 1 < n_runs)
-        def _next():
-            copies(run + 1, 1 - half, True)
-
-        copies(run, half, False)
-        if window == 1:
-            attend(run, half, n_heads)
+    def copies(row, run, half, start):
+        if start:
+            _run_copies(tables_ref, row, run, pages_of(row), layer,
+                        (pool_ref,), (buf,), sems, half, pages=P,
+                        block_size=bs, start=True,
+                        unroll=LATENT_COPIES_UNROLL)
         else:
-            pl.when(n_tok == 1)(lambda: attend(run, half, n_heads))
-            pl.when(n_tok > 1)(lambda: attend(run, half, window * n_heads))
-        return carry
+            _run_wait(held(row, run), (buf,), sems, half, pages=P,
+                      block_size=bs)
 
-    jax.lax.fori_loop(0, n_runs, one_run, 0)
-    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
-                ).astype(o_ref.dtype)
+    # the products span the smallest of a few halvings of the buffer
+    # that holds the run: a row's last run is rarely full
+    spans = [P * bs]
+    while len(spans) < LATENT_PRODUCT_SPANS and spans[-1] % (2 * bs) == 0:
+        spans.append(spans[-1] // 2)
+
+    def attend(run, half):
+        tokens = held(b, run) * bs
+
+        def rows(nq):
+            for i, span in enumerate(spans):
+                fits = tokens <= span
+                if i + 1 < len(spans):
+                    fits = fits & (tokens > spans[i + 1])
+                pl.when(fits)(functools.partial(
+                    _latent_attend, q_ref, buf, half, run, pos0, nq, m_scr,
+                    l_scr, acc_scr, n_heads=n_heads, v_width=v_width,
+                    span=span))
+        if window == 1:
+            rows(n_heads)
+        else:
+            pl.when(n_tok == 1)(lambda: rows(n_heads))
+            pl.when(n_tok > 1)(lambda: rows(window * n_heads))
+
+    @pl.when(n_tok <= 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_tok > 0)
+    def _row():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _row_pipe(copies, attend, b, n_runs, next_ref[b], n_rows, hand_ref)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def latent_paged_attention(pool, q, block_tables, positions, lengths, *,
-                           layer, v_width,
-                           pages_per_step=LATENT_PAGES_PER_STEP):
+                           layer, v_width, pages_per_step=None):
     """Absorbed-form latent attention over a paged latent cache.
 
     pool: ``[n_layers, num_blocks+1, block_size, width]``, the latent
     ``KVBlockPool`` array WHOLE (one row a token a layer: the
     normalised latent, then the rotated shared key); it is left in HBM
-    and ``layer`` and the block table pick the pages the kernel copies.
+    and ``layer`` and the block table pick the pages the kernel copies,
+    in runs of :func:`latent_pages_per_run` pages where
+    ``pages_per_step`` is not given.
     q: ``[B, C, H, width]`` queries in the cache's own space
     (``q_nope @ W_UK`` beside the rotated ``q_pe``), ALREADY scaled.
     positions: ``[B]`` int32, each row's first window position;
     lengths: ``[B]`` int32, tokens in its window (window slot c sees
     ``t <= positions[b] + c``; the window's own rows are written before
-    the call). Slots at or past a row's length are meaningless; for a
-    row of at most one token they are not computed and come out zero.
+    the call). A row of length 0 is not live: none of its pages is
+    read and it comes out zero. Slots at or past a live row's length
+    are meaningless; for a row of one token they are not computed and
+    come out zero.
 
     Returns the ``[B, C, H, v_width]`` fp32 context in latent space
     (``@ W_UV`` follows). The query is rounded to the pool's dtype for
     the MXU; softmax and both accumulations are fp32."""
+    if pages_per_step is None:
+        pages_per_step = latent_pages_per_run(pool, block_tables.shape[1])
+    # under one jitted function with the layer a traced scalar, as
+    # `_chunk_call`: a step calls this once a latent layer, and the
+    # kernel's body (a product a span, a wait a bit) is traced and
+    # lowered once, not once a layer
+    return _latent_call(pool, q, block_tables, positions, lengths,
+                        jnp.asarray(layer, jnp.int32), v_width=int(v_width),
+                        pages=int(min(pages_per_step,
+                                      block_tables.shape[1])),
+                        interpret=_device.pallas_interpret())
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("v_width", "pages", "interpret"))
+def _latent_call(pool, q, block_tables, positions, lengths, layer, *,
+                 v_width, pages, interpret):
     B, C, H, W = q.shape
-    bs = pool.shape[2]
-    P = int(min(pages_per_step, block_tables.shape[1]))
+    bs, P = pool.shape[2], pages
+    lengths = lengths.astype(jnp.int32)
 
     def row(b, *_):
         return (b, 0, 0)
@@ -1379,14 +1540,15 @@ def latent_paged_attention(pool, q, block_tables, positions, lengths, *,
         functools.partial(_latent_attn_kernel, block_size=bs, pages=P,
                           window=C, n_heads=H, v_width=v_width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=5,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, C * H, W), row),
                       pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
             out_specs=pl.BlockSpec((1, C * H, v_width), row),
             scratch_shapes=[
                 pltpu.VMEM((2, P * bs, W), pool.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.SMEM((2,), jnp.int32),
                 pltpu.VMEM((C * H, 128), jnp.float32),
                 pltpu.VMEM((C * H, 128), jnp.float32),
                 pltpu.VMEM((C * H, v_width), jnp.float32),
@@ -1394,11 +1556,11 @@ def latent_paged_attention(pool, q, block_tables, positions, lengths, *,
         out_shape=jax.ShapeDtypeStruct((B, C * H, v_width), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
-        interpret=_device.pallas_interpret(),
+        interpret=interpret,
         name="latent_paged_attention",
     )(block_tables.astype(jnp.int32),
-      jnp.maximum(positions, 0).astype(jnp.int32),
-      lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.maximum(positions, 0).astype(jnp.int32), lengths,
+      layer.reshape(1), _next_live(lengths > 0),
       q.reshape(B, C * H, W).astype(pool.dtype), pool)
     return out.reshape(B, C, H, v_width)
 
@@ -1425,9 +1587,10 @@ def latent_paged_attention_reference(pool, q, block_tables, positions,
     out = jnp.einsum("bcht,btv->bchv",
                      w.astype(pool.dtype).astype(jnp.float32),
                      ctx[..., :v_width])
-    # as the kernel: a one-token row computes its first slot only
-    skipped = ((lengths <= 1)[:, None]
-               & (jnp.arange(C, dtype=jnp.int32)[None, :] >= 1))
+    # as the kernel: a one-token row computes its first slot only, and
+    # a row that is not live (no token) nothing
+    slots = jnp.arange(C, dtype=jnp.int32)[None, :]
+    skipped = (lengths <= 1)[:, None] & (slots >= lengths[:, None])
     return jnp.where(skipped[:, :, None, None], 0.0, out)
 
 
@@ -1852,10 +2015,9 @@ def gqa_pages_per_run(pool, table_len):
     fixed cost (a turn of the loop, a rescale of the online softmax, two
     small products a cache head), and eight pages of 32 KiB carried it
     for a quarter of the bytes that eight pages of 128 KiB do. The
-    kernel and the step log (``engine._pages_walked_by_kind``) both ask
+    kernel and the step log (``engine._decode_pipe_walked``) both ask
     here."""
-    page_bytes = math.prod(pool.shape[2:]) * jnp.dtype(pool.dtype).itemsize
-    return int(max(1, min(GQA_RUN_BYTES // page_bytes, int(table_len))))
+    return _pages_in(GQA_RUN_BYTES, pool, table_len)
 
 
 def _gqa_head_run(q, kbuf, vbuf, half, g, mask, m_scr, l_scr, acc_scr, *,
@@ -1929,8 +2091,8 @@ def _gqa_attn_kernel(tables_ref, pos_ref, len_ref, scal_ref, q_ref, k_hbm,
         vbuf[...] = jnp.zeros_like(vbuf)
 
     def copies(run, half, start):
-        _run_copies(tables_ref, t, run, n_pages, layer, k_hbm, v_hbm,
-                    kbuf, vbuf, sems, half, pages=P, block_size=bs,
+        _run_copies(tables_ref, t, run, n_pages, layer, (k_hbm, v_hbm),
+                    (kbuf, vbuf), sems, half, pages=P, block_size=bs,
                     start=start, first_page=first_page)
 
     def attend(run, half, nq):
@@ -2003,15 +2165,12 @@ def _gqa_decode_kernel(tables_ref, pos_ref, act_ref, scal_ref, next_ref,
     page reads. Window, walk, mask and arithmetic as there.
 
     What differs is the PAGE PIPE, which never runs empty between the
-    first live row and the last. A run is ``pages`` pages
-    (:func:`gqa_pages_per_run`: sized by its bytes), the next in flight
-    while this one is attended; and a row's LAST run starts the first
-    run of the next live row, ``next_ref[t]`` (the row count where none
-    follows; inactive rows may lie between), into the other buffer
-    half. ``hand_ref`` (SMEM) hands over which half that was and that
-    it was done, so only the first live row of a call opens its own
-    pipe and waits for it with nothing to attend. A row's walk is at
-    least one page, so every live row but the last hands over."""
+    first live row and the last (:func:`_row_pipe`): a run is ``pages``
+    pages (:func:`gqa_pages_per_run`: sized by its bytes), the next in
+    flight while this one is attended, and a row's LAST run starts the
+    first run of the next live row, ``next_ref[t]``
+    (:func:`_next_live` of the active rows), ``hand_ref`` (SMEM)
+    handing the buffer half over."""
     t = pl.program_id(0)
     n_rows = pl.num_programs(0)
     bs, P, Dh = block_size, pages, head_dim
@@ -2035,9 +2194,9 @@ def _gqa_decode_kernel(tables_ref, pos_ref, act_ref, scal_ref, next_ref,
 
     def copies(row, run, half, start):
         first, n = walk(row)
-        _run_copies(tables_ref, row, run, n, layer, k_hbm, v_hbm, kbuf,
-                    vbuf, sems, half, pages=P, block_size=bs, start=start,
-                    first_page=first)
+        _run_copies(tables_ref, row, run, n, layer, (k_hbm, v_hbm),
+                    (kbuf, vbuf), sems, half, pages=P, block_size=bs,
+                    start=start, first_page=first)
 
     def attend(run, half):
         t_pos = (first_page + run * P) * bs + jax.lax.broadcasted_iota(
@@ -2054,44 +2213,9 @@ def _gqa_decode_kernel(tables_ref, pos_ref, act_ref, scal_ref, next_ref,
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        # the live row before, if there was one, has started this row's
-        # first run and left word of the buffer half
-        opened = hand_ref[1] == 1
-        half0 = jnp.where(opened, hand_ref[0], 0)
-        hand_ref[1] = 0
-        follows = next_ref[t] < n_rows
-        nxt = jnp.minimum(next_ref[t], n_rows - 1)
-        pl.when(jnp.logical_not(opened))(lambda: copies(t, 0, half0, True))
-
-        def one_run(run, carry):
-            half = (half0 + run) % 2
-
-            @pl.when(run + 1 < n_runs)
-            def _next():
-                copies(t, run + 1, 1 - half, True)
-
-            @pl.when((run + 1 == n_runs) & follows)
-            def _next_row():
-                hand_ref[0] = 1 - half
-                hand_ref[1] = 1
-                copies(nxt, 0, 1 - half, True)
-
-            copies(t, run, half, False)
-            attend(run, half)
-            return carry
-
-        jax.lax.fori_loop(0, n_runs, one_run, 0)
+        _row_pipe(copies, attend, t, n_runs, next_ref[t], n_rows, hand_ref)
         for g in range(n_kv):
             o_ref[0, g] = acc_scr[g] / jnp.maximum(l_scr[g, :, :1], 1e-30)
-
-
-def _next_live(live):
-    """For each row the next row after it with ``live`` set, or the row
-    count where none follows."""
-    n = live.shape[0]
-    at = jnp.where(live, jnp.arange(n, dtype=jnp.int32), n)
-    after = jax.lax.cummin(at, reverse=True)
-    return jnp.concatenate([after[1:], jnp.full((1,), n, jnp.int32)])
 
 
 @functools.partial(jax.jit, static_argnames=(

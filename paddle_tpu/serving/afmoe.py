@@ -68,7 +68,7 @@ def decode_pages_per_run(pool, table_len):
     (``pk.gqa_pages_per_run``, which the kernel asks too). A block that
     attends through that class states it as its own
     ``decode_pages_per_run``, and the step log counts the runs by it
-    (``engine._pages_walked_by_kind``)."""
+    (``engine._decode_pipe_walked``)."""
     from ..ops.pallas_kernels import gqa_pages_per_run
 
     return gqa_pages_per_run(pool, table_len)

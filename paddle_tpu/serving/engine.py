@@ -152,6 +152,43 @@ def _phase(tick, name):
     return _tracing.annotation("ptpu/engine." + name)
 
 
+def _decode_pipe_walked(block, sched, pool):
+    """What the step just planned makes the page pipe of ``block``'s
+    decode attention kernel do on its ONE-TOKEN rows, where that kernel
+    takes a row's pages in runs it sizes itself and hands the pipe from
+    row to row (``block.decode_pages_per_run``: the grouped-query
+    blocks' ``gqa_paged_decode_attention``, the latent blocks'
+    ``latent_paged_attention``; nothing for any other block), over
+    every page kind's layers: ``decode_rows_walked`` (rows x layers: the
+    kernel's live grid steps on such rows), ``decode_runs_walked``
+    (their runs, ``ceil(pages / run)`` by the kernel's own rule) and
+    ``decode_rows_opened_warm`` (those whose first run a one-token row
+    before them started: every such row of a call but its first. A
+    chunk's tail tile of one token, which the kernels also take, is not
+    counted; nor is it that in the latent kernel, whose window rows
+    share the grid, a window row opens the one-token row after it: a
+    floor by at most one row a layer of a mixed step)."""
+    run_rule = getattr(block, "decode_pages_per_run", None)
+    if run_rule is None:
+        return {}
+    bs = pool.block_size
+    on = sched.active & (sched.chunk_lens <= 1)
+    pos = sched.positions[on].astype(np.int64)
+    rows = int(on.sum())
+    # every kind's pool has the entry's page: one rule a step
+    per_run = run_rule(pool.arrays[0], sched.max_blocks_per_seq)
+    layers = runs = 0
+    for kind in pool.kinds:
+        first = (0 if kind.window is None
+                 else np.maximum(pos - kind.window + 1, 0) // bs)
+        pages = pos // bs + 1 - first
+        layers += len(kind.layers)
+        runs += len(kind.layers) * int((-(-pages // per_run)).sum())
+    return {"decode_rows_walked": layers * rows,
+            "decode_runs_walked": runs,
+            "decode_rows_opened_warm": layers * max(rows - 1, 0)}
+
+
 class _ModelWorker:
     """Per-model serving state: isolated scope + pool + scheduler +
     decode loop thread."""
@@ -884,15 +921,20 @@ class _ModelWorker:
                     (sched.chunk_lens[sched.active] == 1).sum())
             if self._kinds_named:
                 rec.update(self._pages_walked_by_kind())
-            elif not mixed:
-                # the pages a decode kernel that walks each row's own
-                # pages copies, beside the grid steps of one that visits
-                # every table slot of every row
-                rec["pages_walked"] = int(
-                    (sched.positions[sched.active]
-                     // self.pool.block_size + 1).sum())
-                rec["pages_grid"] = (self.max_batch
-                                     * sched.max_blocks_per_seq)
+            else:
+                # named kinds or not: what a decode kernel whose page
+                # pipe hands over from row to row does on this step
+                rec.update(_decode_pipe_walked(
+                    self.model.config.block, sched, self.pool))
+                if not mixed:
+                    # the pages a decode kernel that walks each row's
+                    # own pages copies, beside the grid steps of one
+                    # that visits every table slot of every row
+                    rec["pages_walked"] = int(
+                        (sched.positions[sched.active]
+                         // self.pool.block_size + 1).sum())
+                    rec["pages_grid"] = (self.max_batch
+                                         * sched.max_blocks_per_seq)
             if counters is not None:
                 rec["_counters"] = counters      # read when consumed
             # request-scoped view of the same step, from the record's
@@ -950,18 +992,8 @@ class _ModelWorker:
         layers, which is the attention's arithmetic. ``chunk_pages_walked``
         and ``chunk_keys_attended``: the same two over every kind, of the
         rows that hold more than one token alone (a mixed step's chunk
-        kernel; its one-token rows go through the decode kernel).
-
-        Where the block's decode kernel takes a row's pages in runs it
-        sizes itself (``block.decode_pages_per_run``: the grouped-query
-        blocks), also what the step's one-token rows make THAT kernel's
-        page pipe do, over the kinds' layers: ``decode_rows_walked``
-        (rows x layers: the kernel's live grid steps),
-        ``decode_runs_walked`` (their runs, ``ceil(pages / run)`` by the
-        kernel's own rule) and ``decode_rows_opened_warm`` (those whose
-        first run a live row before them started: every live row of a
-        call but its first; a chunk's tail tile of one token, which the
-        kernel also takes, is not counted)."""
+        kernel; its one-token rows go through the decode kernel). And
+        the decode kernel's page pipe (:func:`_decode_pipe_walked`)."""
         sched, bs = self.scheduler, self.pool.block_size
         on = sched.active
         pos0 = sched.positions[on].astype(np.int64)
@@ -971,15 +1003,6 @@ class _ModelWorker:
         out = {"window_pages_full": 0, "window_keys_attended": 0,
                "chunk_pages_walked": 0, "chunk_keys_attended": 0}
         chunk = n > 1
-        run_rule = getattr(self.model.config.block, "decode_pages_per_run",
-                           None)
-        if run_rule is not None:
-            # every kind's pool has the entry's page: one rule a step
-            per_run = run_rule(self.pool.arrays[0],
-                               sched.max_blocks_per_seq)
-            one_token = int((~chunk).sum())
-            out.update(decode_rows_walked=0, decode_runs_walked=0,
-                       decode_rows_opened_warm=0)
         for kind in self.pool.kinds:
             layers = len(kind.layers)
             if kind.window is None:
@@ -998,12 +1021,8 @@ class _ModelWorker:
             out[kind.name + "_keys_attended"] = layers * int(keys.sum())
             out["chunk_pages_walked"] += layers * int(pages[chunk].sum())
             out["chunk_keys_attended"] += layers * int(keys[chunk].sum())
-            if run_rule is not None:
-                out["decode_rows_walked"] += layers * one_token
-                out["decode_runs_walked"] += layers * int(
-                    (-(-pages[~chunk] // per_run)).sum())
-                out["decode_rows_opened_warm"] += layers * max(
-                    one_token - 1, 0)
+        out.update(_decode_pipe_walked(self.model.config.block, sched,
+                                       self.pool))
         return out
 
     def _dispatch_spec(self, plan):

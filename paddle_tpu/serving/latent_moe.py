@@ -110,6 +110,18 @@ class BlockDescription:
                                                return_logits, max_tokens)
 
 
+def decode_pages_per_run(pool, table_len):
+    """The pages of one run of the page pipe of
+    ``latent_paged_attention`` over ``pool``
+    (``pk.latent_pages_per_run``, which the kernel asks too). A block
+    whose layers attend through that kernel states it as its own
+    ``decode_pages_per_run``, and the step log counts the runs by it
+    (``engine._decode_pipe_walked``)."""
+    from ..ops.pallas_kernels import latent_pages_per_run
+
+    return latent_pages_per_run(pool, table_len)
+
+
 class LatentMoEBlock(BlockDescription):
     """The block's description, carried by ``GenerationConfig.block``
     (``d_model``, ``n_heads``, ``n_layers``, ``vocab_size`` and the
@@ -117,6 +129,7 @@ class LatentMoEBlock(BlockDescription):
 
     kind = "latent_moe"
     step_counters = COUNTERS
+    decode_pages_per_run = staticmethod(decode_pages_per_run)
     FIELDS = ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
               "kv_lora_rank", "rope_theta", "rms_norm_eps",
               "first_k_dense", "n_routed_experts", "experts_per_token",

@@ -518,6 +518,43 @@ def test_latent_step_updates_the_bf16_pool_in_place(kind, v5e_chip):
         compiled.memory_analysis()
 
 
+KANANA_POOL, LING_POOL = (8, 20481, 16, 640), (1, 26001, 64, 640)
+
+
+@pytest.mark.parametrize("rows,window,pool,table_len", [
+    (128, 1, KANANA_POOL, 160), (128, 16, KANANA_POOL, 160),
+    (256, 1, LING_POOL, 640), (320, 16, LING_POOL, 640)],
+    ids=["kanana-decode", "kanana-window", "ling-decode", "ling-tiles"])
+def test_latent_attention_hands_its_pipe_over_at_the_cells_shapes(
+        rows, window, pool, table_len, v5e_chip):
+    """`latent_paged_attention` as the two latent cells' steps call it
+    (kanana: 128 rows of one token or a window of 16 over pages of
+    `[16, 640]`; ling: 256 rows of one token or 320 query tiles of 16
+    over pages of `[64, 640]`; 32 heads, the value 512 wide), with what
+    the hand-over adds: the fifth prefetched scalar (each row's next
+    live row) and the SMEM words that hand the buffer half over."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    specs = [(pool, bf16), ((rows, window, 32, 640), bf16),
+             ((rows, table_len), i32), ((rows,), i32), ((rows,), i32)]
+
+    def call(*args):
+        return pk.latent_paged_attention(*args, layer=0, v_width=512)
+
+    with device.compiling_for(v5e_chip):
+        lowered = jax.jit(call).trace(
+            *[jax.ShapeDtypeStruct(s, d) for s, d in specs]).lower(
+                lowering_platforms=("tpu",)).as_text()
+    # the next live rows are made in front of the kernel, and go in
+    # with the tables, positions, lengths and the layer
+    assert "tpu_custom_call" in lowered and "cummin" in lowered
+    hlo = _compile(call, specs, v5e_chip).as_text()
+    assert "latent_paged_attention" in hlo
+    # a run is 2.5 MiB: 128 of kanana's pages of 20 KB, 32 of ling's
+    assert pk.latent_pages_per_run(
+        jax.ShapeDtypeStruct(pool, bf16), table_len) == (
+            128 if pool is KANANA_POOL else 32)
+
+
 # ---------------------------------------------------------------------------
 # the grouped-query window/global block (PR 37): its kernels at the served
 # geometry, and its steps over two kinds of packed bf16 page

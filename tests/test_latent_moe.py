@@ -445,11 +445,30 @@ def paged_case(rng, B, Mb, bs, W, C, lens, pos0, dtype=jnp.float32):
 
 @pytest.mark.parametrize("C,lens,pos0,pages", [
     (1, (1, 1, 1), (0, 17, 46), 2), (4, (4, 1, 3), (0, 17, 30), 2),
-    (4, (4, 1, 3), (0, 17, 30), 32), (8, (8, 8, 1), (3, 40, 9), 4)])
+    (4, (4, 1, 3), (0, 17, 30), 32), (8, (8, 8, 1), (3, 40, 9), 4),
+    # rows that are not live between live ones, before the first and
+    # after the last
+    (1, (0, 1, 0, 0, 1, 1, 0), (5, 17, 46, 0, 30, 9, 47), 2),
+    (4, (0, 4, 0, 1, 0), (20, 3, 9, 41, 0), 2),
+    # no row live: nothing copied, all zero
+    (1, (0, 0, 0), (3, 17, 46), 2), (4, (0, 0, 0), (3, 17, 30), 2),
+    # one live row, alone and among idle ones
+    (1, (1,), (45,), 2), (1, (0, 0, 1, 0), (7, 7, 33, 7), 2),
+    # rows of one run; of exactly one, two and three whole runs (pages of
+    # 8 tokens, 2 a run: positions 15, 31, 47); odd and even numbers of
+    # runs side by side, so that the handed half alternates both ways
+    (1, (1, 1, 1, 1), (0, 7, 12, 15), 2),
+    (1, (1, 1, 1, 1), (15, 31, 47, 31), 2),
+    (1, (1, 1, 1, 1, 1, 1), (40, 20, 47, 3, 33, 10), 2),
+    (1, (1, 1, 1, 1, 1), (47, 46, 45, 44, 43), 1),
+    # a mixed window whose window rows and one-token rows alternate
+    (4, (4, 1, 3, 1, 2, 1), (0, 17, 30, 44, 9, 2), 2),
+    (8, (1, 8, 1, 5, 1, 8), (39, 3, 9, 20, 0, 40), 2),
+    (8, (8, 1, 0, 1, 7, 0), (3, 40, 9, 15, 30, 1), 3)])
 def test_latent_attention_kernel_equals_the_gathered_fallback(C, lens, pos0,
                                                               pages):
     rng = np.random.default_rng(12)
-    B, Mb, bs, W, Vw, H = 3, 6, 8, 256, 128, 4
+    B, Mb, bs, W, Vw, H = len(lens), 6, 8, 256, 128, 4
     pool, tables, pos, lens = paged_case(rng, B, Mb, bs, W, C, lens, pos0)
     q = jnp.asarray(rng.normal(size=(B, C, H, W)) * 0.1, jnp.float32)
     got = pk.latent_paged_attention(pool, q, tables, pos, lens, layer=1,
@@ -461,6 +480,184 @@ def test_latent_attention_kernel_equals_the_gathered_fallback(C, lens, pos0,
         np.testing.assert_allclose(np.asarray(got)[b, :n],
                                    np.asarray(want)[b, :n], rtol=2e-4,
                                    atol=2e-4)
+        if int(lens[b]) <= 1:
+            # a row of one token computes its first slot only; a row
+            # that is not live nothing: zero in kernel and fallback
+            assert not np.asarray(got)[b, int(lens[b]):].any()
+            assert not np.asarray(want)[b, int(lens[b]):].any()
+
+
+@pytest.mark.parametrize("C", [1, 4])
+def test_a_row_that_is_not_live_moves_no_page(C):
+    """The pages of the rows that are not live hold NaN. Had the kernel
+    copied one (as it did while such a row walked ``pos // block_size +
+    1`` pages), the buffer's lines past a later, shorter row's last page
+    would hold NaN, and a masked NaN is still NaN in ``p @ v``."""
+    rng = np.random.default_rng(3)
+    lens = np.array([0, C, 0, 1, 0, 1], np.int32)
+    B, Mb, bs, W, Vw, H = len(lens), 6, 8, 256, 128, 4
+    pool, tables, pos, lens = paged_case(
+        rng, B, Mb, bs, W, C, lens, (47, 20, 44, 3, 40, 9))
+    idle = np.asarray(lens) == 0
+    pool = pool.at[:, np.asarray(tables)[idle].ravel()].set(np.nan)
+    q = jnp.asarray(rng.normal(size=(B, C, H, W)) * 0.1, jnp.float32)
+    got = np.asarray(pk.latent_paged_attention(
+        pool, q, tables, pos, lens, layer=1, v_width=Vw, pages_per_step=2))
+    assert np.isfinite(got).all() and not got[idle].any()
+    want = np.asarray(pk.latent_paged_attention_reference(
+        pool.at[:].set(jnp.nan_to_num(pool)), q, tables, pos, lens, layer=1,
+        v_width=Vw))
+    np.testing.assert_allclose(got[~idle, 0], want[~idle, 0], rtol=2e-4,
+                               atol=2e-4)
+
+
+@pytest.mark.parametrize("C", [1, 4])
+def test_a_rows_context_does_not_depend_on_the_rows_around_it(C):
+    """Which row opened a row's pipe, and into which buffer half, is the
+    batch's business: the row's result is bitwise the same with the
+    batch permuted, with its neighbours switched off, and alone."""
+    rng = np.random.default_rng(8)
+    lens = np.array([C, 1, 1, max(C - 1, 1), 1, C], np.int32)
+    B, Mb, bs, W, Vw, H = len(lens), 6, 8, 256, 128, 4
+    pool, tables, pos, lens = paged_case(
+        rng, B, Mb, bs, W, C, lens, (40, 3, 30, 12, 47 - C, 21))
+    q = jnp.asarray(rng.normal(size=(B, C, H, W)) * 0.1, jnp.float32)
+
+    def run(order, on):
+        order = np.asarray(order)
+        out = pk.latent_paged_attention(
+            pool, q[order], tables[order], pos[order],
+            jnp.where(jnp.asarray(on)[order], lens[order], 0), layer=1,
+            v_width=Vw, pages_per_step=2)
+        back = np.empty_like(order)
+        back[order] = np.arange(B)
+        return np.asarray(out)[back]
+
+    every = np.ones(B, bool)
+    base = run(np.arange(B), every)
+    assert base.any(axis=(1, 2, 3)).all()
+    for order in (np.arange(B)[::-1], rng.permutation(B), np.roll(
+            np.arange(B), 1)):
+        np.testing.assert_array_equal(run(order, every), base)
+    for on in ([1, 0, 1, 0, 1, 0], [0, 1, 1, 0, 0, 1], [0, 0, 0, 1, 0, 0],
+               [1, 1, 0, 0, 0, 0]):
+        on = np.array(on, bool)
+        got = run(np.arange(B), on)
+        np.testing.assert_array_equal(got[on], base[on])
+        assert not got[~on].any()
+
+
+@pytest.mark.parametrize("page,table_len,run", [
+    pytest.param((16, 640), 160, 128, id="kanana_pages_of_20KB"),
+    pytest.param((64, 640), 640, 32, id="ling_pages_of_80KB"),
+    pytest.param((16, 640), 40, 40, id="a_short_table"),
+    pytest.param((4096, 640), 160, 1, id="a_page_past_a_run")])
+def test_a_latent_run_is_sized_by_the_bytes_of_a_page(page, table_len, run):
+    """The rule on the two latent cells' pages (bfloat16): 2.5 MiB a
+    buffer half, at least a page, at most a block-table line."""
+    pool = jax.ShapeDtypeStruct((1, 9) + page, jnp.bfloat16)
+    assert pk.latent_pages_per_run(pool, table_len) == run
+
+
+@pytest.mark.parametrize("pages", [1, 3, 4, 6, 32, 128, 160])
+def test_a_runs_copies_are_waited_for_by_size(pages, monkeypatch):
+    """`_run_wait` takes a run's page copies off the semaphore by the
+    bits of their count: for every count up to a run the descriptors it
+    waits on hold exactly that many pages, in at most `log2(pages) + 1`
+    waits a pool. (The interpreter does not block on a semaphore, so a
+    wait too many or too few shows only on the chip; this holds the
+    arithmetic, `chip_smoke.py`'s `kernels` leg the rest.)"""
+    from types import SimpleNamespace
+
+    waited = []
+
+    class Buffer:
+        at = property(lambda self: self)
+
+        def __getitem__(self, index):
+            return index[1].size            # the descriptor's rows
+
+    monkeypatch.setattr(pk.pl, "when",
+                        lambda cond: (lambda f: f() if cond else None))
+    monkeypatch.setattr(
+        pk.pltpu, "make_async_copy",
+        lambda src, dst, sem: SimpleNamespace(
+            wait=lambda: waited.append((src, dst, sem))))
+    bs = 16
+    sems = SimpleNamespace(at={(0, 1): "k", (1, 1): "v"})
+    for count in range(1, pages + 1):
+        del waited[:]
+        pk._run_wait(count, (Buffer(), Buffer()), sems, 1, pages=pages,
+                     block_size=bs)
+        for sem in "kv":
+            rows = [dst for src, dst, s in waited if s == sem]
+            assert sum(rows) == count * bs and len(rows) == len(set(rows))
+            assert len(rows) <= pages.bit_length()
+        assert all(src == dst for src, dst, _ in waited)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 4, 6])
+def test_rows_of_every_count_of_pages_up_to_two_runs(pages):
+    rng = np.random.default_rng(21)
+    B, Mb, bs, W, Vw, H = 2 * pages, 12, 8, 256, 128, 4
+    pos0 = [bs * (n + 1) - 1 - (n % 2) for n in range(B)]   # 1..2P pages
+    pool, tables, pos, lens = paged_case(rng, B, Mb, bs, W, 1, [1] * B, pos0)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, W)) * 0.1, jnp.float32)
+    got = pk.latent_paged_attention(pool, q, tables, pos, lens, layer=0,
+                                    v_width=Vw, pages_per_step=pages)
+    want = pk.latent_paged_attention_reference(pool, q, tables, pos, lens,
+                                               layer=0, v_width=Vw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4,
+                               atol=2e-4)
+
+
+def test_the_step_log_and_the_latent_kernel_take_the_run_from_one_function(
+        monkeypatch):
+    """`engine._decode_pipe_walked` counts a step's runs by
+    `pk.latent_pages_per_run`, the function the kernel's call asks: a
+    rule that says 3 pages is what both then go by. The twin of the
+    grouped-query blocks' test (tests/test_gqa_kernels.py), on a block
+    that names no page kind."""
+    from types import SimpleNamespace
+
+    from paddle_tpu.serving import engine as serving_engine
+    from paddle_tpu.serving.kv_cache import PageKind
+    from paddle_tpu.serving.ling import LingBlock
+
+    asked = []
+
+    def rule(pool, table_len):
+        asked.append((tuple(pool.shape), int(table_len)))
+        return 3
+
+    monkeypatch.setattr(pk, "latent_pages_per_run", rule)
+    assert LingBlock.decode_pages_per_run is LatentMoEBlock.decode_pages_per_run
+    bs, mb, W = 8, 8, 256
+    pool = jax.ShapeDtypeStruct((3, 41, bs, W), jnp.float32)
+    sched = SimpleNamespace(
+        active=np.array([1, 1, 1, 0, 1, 1], bool),
+        positions=np.array([0, 23, 24, 50, 63, 5], np.int64),
+        chunk_lens=np.array([1, 1, 1, 1, 1, 4], np.int64),  # row 5 prefills
+        max_blocks_per_seq=mb)
+    rec = serving_engine._decode_pipe_walked(
+        LatentMoEBlock, sched, SimpleNamespace(
+            block_size=bs, arrays=(pool,),
+            kinds=(PageKind("all", range(3)),)))
+    assert asked == [((3, 41, bs, W), mb)]
+    # rows 0, 1, 2, 4 hold one token: 1, 3, 4, 8 pages on three layers
+    assert rec == {"decode_rows_walked": 4 * 3,
+                   "decode_runs_walked": 3 * (1 + 1 + 2 + 3),
+                   "decode_rows_opened_warm": 3 * 3}
+    # a block whose kernel has no such pipe has no such fields
+    assert serving_engine._decode_pipe_walked(object(), sched, None) == {}
+    # ... and the kernel's own call asks the same function
+    rng = np.random.default_rng(0)
+    pk.latent_paged_attention(
+        jnp.asarray(rng.normal(size=pool.shape), jnp.float32),
+        jnp.ones((2, 1, 4, W)), np.ones((2, mb), np.int32),
+        np.array([3, 20], np.int32), np.ones(2, np.int32), layer=0,
+        v_width=128)
+    assert asked[1:] == [((3, 41, bs, W), mb)]
 
 
 @pytest.mark.parametrize("C,lens,pos0", [

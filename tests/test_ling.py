@@ -290,8 +290,14 @@ def test_the_engine_serves_what_the_reference_decodes(monkeypatch):
                   "scan_fresh_rows", "global_pages_walked",
                   "global_keys_attended", "window_keys_attended"):
             assert f in r, (f, r)
-        # its pages go through the latent kernel, whose runs are its own
-        assert "decode_runs_walked" not in r
+        # its pages go through the latent kernel, whose page pipe hands
+        # over from row to row: one MLA layer, a run a one-token row
+        # (contexts of under 64 tokens in pages of 16, 32 pages a run)
+        assert r["decode_rows_walked"] == r["decode_runs_walked"]
+        assert r["decode_rows_opened_warm"] == max(
+            r["decode_rows_walked"] - 1, 0)
+        if r["kind"] == "decode":
+            assert r["decode_rows_walked"] == r["slots_used"]
         # two expert layers hold half of the router's experts
         assert r["expert_slots"] == 2 * 8
         assert r["expert_pairs"] <= 2 * 4 * r["slots_used"]

@@ -312,6 +312,8 @@ def test_the_ling_cell_reports_the_kernels_it_shares():
     assert own | {"gmm_roofline_pct.decode", "mfu_pct.batch",
                   "latent_attn_roofline_pct.decode",
                   "latent_attn_device_share_pct.decode",
+                  "latent_runs_per_row.decode",
+                  "latent_rows_opened_warm_pct.decode",
                   "experts_touched_pct.decode", "expert_top_load_pct.decode",
                   "kv_pool_used_pct.batch", "dry_dispatch_pct.batch",
                   "steps_queued_ahead.batch", "reach_chip_s"} <= mine
@@ -328,3 +330,54 @@ def test_the_ling_cell_reports_the_kernels_it_shares():
         spec.layer_metric(name)              # every one has its file
     e2e = {m["name"] for m in spec.metrics_of(BENCH, "end_to_end", cell)}
     assert e2e == {"serve_tokens_per_s", "setup_s"}
+
+
+LATENT_CELLS = ["kanana-2-30b-a3b.decode-closed",
+                "ling-3.0-flash.reason-long-closed"]
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("latent_runs_per_row.decode", (300 + 260) / (128 + 128)),
+    ("latent_rows_opened_warm_pct.decode", 100.0 * (127 + 127) / 256)])
+def test_the_latent_pipes_two_metrics_read_the_traced_decode_steps(metric,
+                                                                   want):
+    """The counters of the latent kernel's page pipe (PR 46), in both
+    cells whose programs call `latent_paged_attention`, beside its
+    roofline: data files on `traced_ratio`, which returns nothing (and
+    does not raise) on a parent whose step log has no such fields."""
+    from paddle_tpu.observability import metrics
+    from perfbench.layer_metrics.readers import traced_ratio
+
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    assert entry["workloads"] == LATENT_CELLS
+    assert (entry["layer"], entry["moves"], entry["source"]) == (
+        "kernels", "serve_tokens_per_s", "program_counter")
+    roofline = next(m for m in BENCH["per_layer"]
+                    if m["name"] == "latent_attn_roofline_pct.decode")
+    assert set(LATENT_CELLS) <= set(roofline["workloads"])
+    for cell in LATENT_CELLS:
+        assert metric in {m["name"] for m in
+                          spec.metrics_of(BENCH, "per_layer", cell)}
+    args, reader = spec.layer_metric(metric)
+    assert reader is traced_ratio.read and args["kind"] == "decode"
+    metrics.disable()
+    metrics.reset()
+    try:
+        log = metrics.registry().samples("serving/step")
+        obs = {"traced_span": (10.0, 15.0)}
+        assert reader(obs, **args) is None            # no log at all
+        step = {"kind": "decode", "cold": False, "rows": 128}
+        log.add(dict(step, t_dispatched=11.0))        # the parent's record
+        assert reader(obs, **args) is None
+        pipe = dict(decode_rows_walked=128, decode_rows_opened_warm=127)
+        log.add(dict(step, t_dispatched=12.0, decode_runs_walked=300, **pipe))
+        log.add(dict(step, t_dispatched=14.5, decode_runs_walked=260, **pipe))
+        # outside the traced stretch, and a mixed step: not read
+        log.add(dict(step, t_dispatched=20.0, decode_runs_walked=999, **pipe))
+        log.add(dict(step, kind="mixed", t_dispatched=13.0,
+                     decode_runs_walked=999, **pipe))
+        assert reader(obs, **args) == pytest.approx(want)
+        assert reader({}, **args) is None             # a run not traced
+    finally:
+        metrics.disable()
+        metrics.reset()
